@@ -6,9 +6,13 @@ Builds source -> jitted project -> row-id gen -> materialize, runs N barrier
 epochs with checkpoints, prints MV stats + barrier latency.
 
 Run: python examples/nexmark_q1.py [num_barriers] [chunk_size]
+
+Runs on the TPU. It refuses any other backend unless the caller asked
+for the CPU explicitly (`JAX_PLATFORMS=cpu`), and says which it ran on.
 """
 
 import asyncio
+import os
 import pathlib
 import sys
 import time
@@ -28,7 +32,12 @@ from risingwave_tpu.stream import (
 
 
 async def main(rounds: int = 5, chunk_size: int = 4096) -> None:
-    print(f"devices: {jax.devices()}")
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and \
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        sys.exit(f"nexmark_q1: jax found no TPU (platform={dev.platform!r}); "
+                 "set JAX_PLATFORMS=cpu to run on the CPU on purpose")
+    print(f"running on {dev.platform}:{dev.device_kind} x{len(jax.devices())}")
     store = MemoryStateStore()
     barrier_q = asyncio.Queue()
     gen = NexmarkGenerator("bid", chunk_size=chunk_size)

@@ -16,6 +16,9 @@ from typing import Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from .floatbits import (float_identity_bits, float_pair_bits,
+                        float_pair_bits_np)
+
 VNODE_BITS = 8
 VNODE_COUNT = 1 << VNODE_BITS  # 256
 
@@ -32,6 +35,17 @@ def _crc32_table_np() -> np.ndarray:
     return table
 
 
+def _uint_image(col: jnp.ndarray, f64_bits) -> jnp.ndarray:
+    """Column -> unsigned ints of the same width. The TPU compiler has no
+    bitcast FROM f64 (common/floatbits.py), so an f64 column goes through
+    `f64_bits` instead of a reinterpreting view."""
+    if col.dtype == jnp.bool_:
+        return col.astype(jnp.uint8)
+    if col.dtype == jnp.float64:
+        return f64_bits(col).view(jnp.uint64)
+    return col.view(jnp.dtype(f"uint{8 * col.dtype.itemsize}"))
+
+
 def crc32_columns(columns: Sequence[jnp.ndarray]) -> jnp.ndarray:
     """Vectorized crc32 over the little-endian bytes of fixed-width columns.
 
@@ -46,8 +60,7 @@ def crc32_columns(columns: Sequence[jnp.ndarray]) -> jnp.ndarray:
     for col in columns:
         nbytes = col.dtype.itemsize
         # reinterpret to unsigned of same width, then peel bytes LE
-        u = col.view(jnp.dtype(f"uint{8 * nbytes}")) if col.dtype != jnp.bool_ else col.astype(jnp.uint8)
-        u = u.astype(jnp.uint64)
+        u = _uint_image(col, float_identity_bits).astype(jnp.uint64)
         for b in range(nbytes):
             byte = ((u >> jnp.uint64(8 * b)) & jnp.uint64(0xFF)).astype(jnp.uint32)
             idx = (crc ^ byte) & jnp.uint32(0xFF)
@@ -71,9 +84,7 @@ def compute_vnodes(key_columns: Sequence[jnp.ndarray]) -> jnp.ndarray:
     h = jnp.full(key_columns[0].shape[0], 0x243F6A8885A308D3,
                  dtype=jnp.uint64)
     for col in key_columns:
-        nbytes = col.dtype.itemsize
-        u = (col.view(jnp.dtype(f"uint{8 * nbytes}"))
-             if col.dtype != jnp.bool_ else col.astype(jnp.uint8))
+        u = _uint_image(col, float_pair_bits)
         x = h ^ (u.astype(jnp.uint64) * jnp.uint64(0x9E3779B97F4A7C15))
         x = x + jnp.uint64(0x9E3779B97F4A7C15)
         x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
@@ -108,6 +119,9 @@ def compute_vnodes_numpy(key_columns: Sequence[np.ndarray]) -> np.ndarray:
             col = np.asarray(col)
             if col.dtype == np.bool_:
                 col = col.astype(np.uint8)
+            if col.dtype == np.float64:
+                # same f32-pair image the device hashes (floatbits.py)
+                col = float_pair_bits_np(col)
             u = col.view(f"uint{8 * col.dtype.itemsize}").astype(np.uint64)
             x = h ^ (u * np.uint64(0x9E3779B97F4A7C15))
             x = x + np.uint64(0x9E3779B97F4A7C15)

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common.floatbits import float_pair_bits, float_pair_bits_np
+
 M = 64               # registers
 LANES = 8            # int64 words per sketch
 ALPHA_M = 0.709      # alpha for m = 64
@@ -80,12 +82,14 @@ def _popcount_jnp(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _to_bits_np(vals: np.ndarray) -> np.ndarray:
-    """Distinct VALUES must map to distinct BIT patterns: floats bitcast
-    (a value-cast would collapse every float sharing an integer part)."""
+    """Distinct VALUES must map to distinct BIT patterns: floats take
+    their f32-pair image (common/floatbits.py — the TPU compiler has no
+    bitcast from f64; a value-cast would collapse every float sharing an
+    integer part)."""
     if vals.dtype == np.uint64:
         return vals
     if np.issubdtype(vals.dtype, np.floating):
-        return vals.astype(np.float64).view(np.uint64)
+        return float_pair_bits_np(vals).view(np.uint64)
     return vals.astype(np.int64).view(np.uint64)
 
 
@@ -102,8 +106,7 @@ def _bucket_rank_np(vals: np.ndarray):
 
 def _to_bits_jnp(vals: jnp.ndarray) -> jnp.ndarray:
     if jnp.issubdtype(vals.dtype, jnp.floating):
-        return jax.lax.bitcast_convert_type(
-            vals.astype(jnp.float64), jnp.uint64)
+        return float_pair_bits(vals).view(jnp.uint64)
     return vals.astype(jnp.int64).view(jnp.uint64)
 
 

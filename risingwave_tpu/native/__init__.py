@@ -2,14 +2,18 @@
 
 The reference's host runtime is native Rust end to end; here the pieces
 with real per-row Python overhead — batch key/value serde and vnode
-hashing on the persistence path — are C++ behind ctypes, with a pure-
-Python fallback when no toolchain is available. `lib()` returns None in
-that case and callers fall back transparently.
+hashing on the persistence path — are C++ behind ctypes. `_rowcodec.so`
+is built ONLY from the tracked `rowcodec.cc` next to this file (the
+artifact is git-ignored), with `g++` on first use. A machine without a
+toolchain keeps working on the pure-Python twins (`lib()` returns None
+and callers take them), but the choice is never silent: it is logged
+once, at WARNING with the reason, and `chip_smoke.py` reports it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import tempfile
@@ -19,6 +23,7 @@ from typing import Optional
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "rowcodec.cc")
+_log = logging.getLogger(__name__)
 
 
 @lru_cache(maxsize=1)
@@ -50,8 +55,16 @@ def lib() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p]
         l.crc32_i64_cols.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
+        _log.info("native row codec loaded: %s", so)
         return l
-    except Exception:
+    except (OSError, subprocess.CalledProcessError) as e:
+        # no toolchain / unloadable artifact: the Python twins take over.
+        # lru_cache makes this the ONE place the choice is made and said.
+        detail = (e.stderr.decode(errors="replace")[-400:]
+                  if isinstance(e, subprocess.CalledProcessError)
+                  and e.stderr else "")
+        _log.warning("native row codec unavailable (%r %s): the persist "
+                     "path runs on the pure-Python row codec", e, detail)
         return None
 
 

@@ -14,20 +14,8 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh
-
-try:                                      # jax >= 0.5 exports it top-level
-    from jax import shard_map
-except ImportError:                       # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        # 0.4.x's replication checker crashes on nested pjit equations
-        # ('NoneType' is not iterable in _check_rep) that the executor
-        # step bodies routinely contain; the check is an optimization
-        # validator, not a correctness requirement — disable it
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
 from ..common.vnode import VNODE_COUNT
 

@@ -27,6 +27,7 @@ import numpy as np
 from ..common.chunk import (
     Column, StreamChunk, OP_DELETE, OP_INSERT, OP_UPDATE_INSERT,
 )
+from ..common.floatbits import float_identity_bits
 from ..common.types import DataType, Field, Schema
 from ..ops.jit_state import jit_state
 from .executor import Executor
@@ -142,7 +143,7 @@ class DynamicFilterExecutor(GrowableSortedStore, Executor):
 
         lanes = []
         for c, v in zip(cols, valids):
-            d = (jax.lax.bitcast_convert_type(c, jnp.int64)
+            d = (float_identity_bits(c)
                  if jnp.issubdtype(c.dtype, jnp.floating)
                  else c.astype(jnp.int64))
             lanes.append(jnp.where(v, d, 0))

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.chunk import Column, StreamChunk, OP_DELETE, OP_INSERT
+from ..common.floatbits import float_identity_bits
 from ..common.types import DataType, Field, Schema
 from ..ops.hash_table import stable_lexsort
 from ..ops.jit_state import jit_state
@@ -261,11 +262,11 @@ class GeneralOverWindowExecutor(GrowableSortedStore,
         full_valids = s_valids + list(wvalids)
         s_live = live[order]
 
-        # identity for the diff: hash over ALL columns (floats bitcast)
+        # identity for the diff: hash over ALL columns (floats as their
+        # identity bits, common/floatbits.py)
         lanes = []
         for c, v in zip(full_cols, full_valids):
-            x = (jax.lax.bitcast_convert_type(
-                     c.astype(jnp.float64), jnp.int64)
+            x = (float_identity_bits(c)
                  if jnp.issubdtype(c.dtype, jnp.floating)
                  else c.astype(jnp.int64))
             lanes.append(jnp.where(v, x, 0))
@@ -280,10 +281,7 @@ class GeneralOverWindowExecutor(GrowableSortedStore,
         def lanes_of(cols_, valids_):
             out = []
             for c, v in zip(cols_, valids_):
-                # f32 upcasts before the bitcast (a 32->64 bitcast is a
-                # bit-width error at trace time)
-                x = (jax.lax.bitcast_convert_type(
-                         c.astype(jnp.float64), jnp.int64)
+                x = (float_identity_bits(c)
                      if jnp.issubdtype(c.dtype, jnp.floating)
                      else c.astype(jnp.int64))
                 out.append(jnp.where(v, x, 0))

@@ -236,10 +236,14 @@ class ShardedHashAggExecutor(HashAggExecutor):
         self._rehash = rehash_same_capacity
 
         def watchdog_sharded(ov, occ, dr, so):
+            # the accumulators promote to int64 through the apply's segment
+            # sums, and the TPU lowers a 64-bit all-reduce only for SUM:
+            # take the cross-shard MAX of the (slot-count-sized) values in
+            # int32
             total_ov = jax.lax.psum(ov[0], VNODE_AXIS)
-            max_occ = jax.lax.pmax(occ[0], VNODE_AXIS)
+            max_occ = jax.lax.pmax(occ[0].astype(jnp.int32), VNODE_AXIS)
             total_dr = jax.lax.psum(dr[0], VNODE_AXIS)
-            max_fill = jax.lax.pmax(so[0], VNODE_AXIS)
+            max_fill = jax.lax.pmax(so[0].astype(jnp.int32), VNODE_AXIS)
             return jnp.stack([total_ov, max_occ, total_dr, max_fill])[None]
 
         self._watchdog_pack = jit_state(shard_map(
@@ -521,11 +525,15 @@ class ShardedHashAggExecutor(HashAggExecutor):
             keys_dev, n_ev = self._evict_keys(self.state,
                                               self._pending_clean_wm)
             dev_evict = list(keys_dev)
+        # int32 before the concatenate: joining a mesh-sharded int64[S]
+        # with a replicated int64[1] ABORTS the TPU compiler (check failure
+        # "Unsupported conversion from vmreg/vreg to U64", libtpu 0.0.34;
+        # found on four real chips); the same program in int32 compiles
         count_parts = []
-        if dev is not None:
-            count_parts.append(jnp.ravel(dev[3]))      # n_dirty per shard
+        if dev is not None:                            # n_dirty per shard
+            count_parts.append(jnp.ravel(dev[3]).astype(jnp.int32))
         if dev_evict is not None:
-            count_parts.append(jnp.ravel(n_ev))
+            count_parts.append(jnp.ravel(n_ev).astype(jnp.int32))
         counts_dev = (jnp.concatenate(count_parts) if count_parts
                       else None)
         new_epoch = barrier.epoch.curr

@@ -188,9 +188,11 @@ class ShardedSortedStoreMixin:
 
         def watchdog_sharded(errs, n, dr, so):
             e = jax.lax.psum(errs, VNODE_AXIS)            # [2]
-            mx = jax.lax.pmax(n[0], VNODE_AXIS)
+            # int32 before the MAX: the TPU lowers a 64-bit all-reduce
+            # only for SUM (sharded_agg.py watchdog_sharded)
+            mx = jax.lax.pmax(n[0].astype(jnp.int32), VNODE_AXIS)
             td = jax.lax.psum(dr[0], VNODE_AXIS)
-            mf = jax.lax.pmax(so[0], VNODE_AXIS)
+            mf = jax.lax.pmax(so[0].astype(jnp.int32), VNODE_AXIS)
             return jnp.concatenate(
                 [e, jnp.stack([mx, td, mf])]).astype(jnp.int32)[None]
 

@@ -36,11 +36,11 @@ import sys
 def _pin_jax_platform() -> None:
     """Honor JAX_PLATFORMS IN-PROCESS before any jax use.
 
-    Deployment images may carry a sitecustomize that updates jax.config
-    at interpreter startup (e.g. to the real accelerator), which beats
-    the environment variable — so a parent that spawned this worker with
-    JAX_PLATFORMS=cpu would still get a worker touching (and possibly
-    hanging on) the device. jax.config.update wins over both."""
+    A chip belongs to ONE process: a parent that holds it must spawn
+    its workers with JAX_PLATFORMS=cpu (or hand each worker a chip of
+    its own). `jax.config.update` states the inherited choice
+    explicitly, so nothing that touched jax.config at interpreter
+    startup can point this worker at the parent's device."""
     plat = os.environ.get("JAX_PLATFORMS")
     if plat:
         import jax

@@ -2,8 +2,8 @@
 
 Sibling of dispatch_profile.py: a canned tumble-window MAX(price) agg over
 nexmark bids (the q7 window side) runs DURABLY against a Hummock store
-whose object-store uploads are artificially slowed (the stand-in for the
-tunneled link / remote object store), in two modes:
+whose object-store uploads are artificially slowed (the stand-in for a
+remote object store), in two modes:
 
   inline     checkpoint_max_inflight=0 — store.sync() on the barrier
              path, every checkpoint stalls the stream for build+upload
@@ -48,7 +48,7 @@ WINDOW_US = 1_000_000
 
 class SlowObjectStore:
     """In-memory object store with a fixed per-SST upload delay — the
-    canned stand-in for a remote object store / tunneled device link.
+    canned stand-in for a remote object store.
     Manifest swaps stay fast (they are one small PUT in production too)."""
 
     def __init__(self, inner, delay_s: float):

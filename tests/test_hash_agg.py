@@ -433,6 +433,36 @@ async def test_watermark_state_cleaning():
         assert (rc[s[0]] > 0) == alive
 
 
+async def test_group_key_watermark_follows_the_flushed_interval():
+    """A group-key watermark must not overtake the updates this executor
+    is still buffering: it leaves AFTER the barrier-time flush chunk and
+    before the barrier (reference hash_agg.rs `buffered_watermarks`).
+    Forwarded on arrival, a downstream join cleaned its state by it and
+    fail-stopped on the retraction that followed ("delete matched no
+    stored row") as soon as an interval spanned more event time than the
+    source's watermark lag — q7 at chunk_size=131072 on the chip."""
+    from risingwave_tpu.common.types import DataType as DT
+    from risingwave_tpu.stream import Watermark
+    src_msgs = [
+        barrier(1, 0, BarrierKind.INITIAL),
+        chunk([(OP_INSERT, 10, 1), (OP_INSERT, 20, 2)]),
+        Watermark(0, DT.INT64, 15),
+        chunk([(OP_INSERT, 20, 5)]),
+        Watermark(0, DT.INT64, 18),          # supersedes 15 in the interval
+        Watermark(1, DT.INT64, 99),          # not a group key: consumed
+        barrier(2, 1),
+        barrier(3, 2),                       # idle interval: nothing held
+    ]
+    _, out = await run_agg(src_msgs, [count_star()])
+    kinds = [type(m).__name__ for m in out]
+    assert kinds == ["Barrier", "StreamChunk", "Watermark", "Barrier",
+                     "Barrier"], kinds
+    wm = out[2]
+    assert (wm.col_idx, wm.val) == (0, 18)
+    assert sorted(out[1].to_rows()) == [
+        (OP_INSERT, (10, 1)), (OP_INSERT, (20, 2))]
+
+
 async def test_eviction_deletes_from_state_table():
     """Watermark eviction must bound DURABLE state too: evicted groups are
     deleted from the state table in the same epoch, and recovery does not
