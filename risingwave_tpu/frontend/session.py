@@ -2288,18 +2288,19 @@ class Session:
             self.catalog.mvs.clear()
             await self.cluster.stop()
             self.cluster = None
-            return
-        for name in reversed(list(self.catalog.sinks)):
-            sink = self.catalog.sinks.pop(name)
-            await sink.deployment.stop()
-            for up, ch in sink.upstream_taps:
-                up.tap.remove(ch)
-        for name in reversed(list(self.catalog.mvs)):
-            mv = self.catalog.mvs[name]
-            await mv.deployment.stop()
-            for up, ch in mv.upstream_taps:
-                up.tap.remove(ch)
-        self.catalog.mvs.clear()
+        else:
+            for name in reversed(list(self.catalog.sinks)):
+                sink = self.catalog.sinks.pop(name)
+                await sink.deployment.stop()
+                for up, ch in sink.upstream_taps:
+                    up.tap.remove(ch)
+            for name in reversed(list(self.catalog.mvs)):
+                mv = self.catalog.mvs[name]
+                await mv.deployment.stop()
+                for up, ch in mv.upstream_taps:
+                    up.tap.remove(ch)
+            self.catalog.mvs.clear()
+        await self.coord.join_watchdog()
 
     # -------------------------------------------------------- batch query
     def query(self, sql_text: str) -> list[tuple]:

@@ -219,6 +219,10 @@ class MvChangelog:
         # activation — everything <= it is covered by the snapshot a
         # subscriber backfills from)
         self.active_from: Optional[int] = None
+        # newest sealed epoch a writer passed while the log was
+        # inactive: its rows were dropped at the writer's barrier, which
+        # runs BEFORE the coordinator collects that barrier
+        self.dropped_through = 0
         # retention floor this incarnation truncated to (the durable
         # truth is the committed tombstones; this just avoids rescanning
         # when nothing advanced)
@@ -232,10 +236,14 @@ class MvChangelog:
         """Start logging. Every sealed epoch AFTER `last_collected_epoch`
         lands in the log (writers preserve their open-interval buffer,
         mirroring MvChangelogHook.activate), so a subscriber that
-        snapshots at any committed E0 >= last_collected_epoch tails
-        entries > E0 with no gap and no overlap."""
+        snapshots at any committed E0 >= `active_from` tails entries
+        > E0 with no gap and no overlap. An epoch a writer has already
+        sealed and dropped is not logged even if the coordinator has
+        not collected it yet: the floor covers it, so the snapshot
+        does."""
         if self.active_from is None:
-            self.active_from = last_collected_epoch
+            self.active_from = max(last_collected_epoch,
+                                   self.dropped_through)
 
     def deactivate(self) -> None:
         self.active_from = None
@@ -336,7 +344,11 @@ class MvChangelogWriter:
     def on_barrier(self, sealed_epoch: int) -> None:
         rows = self._pending
         self._pending = []
-        if not self.log.active or not rows:
+        if not rows:
+            return
+        if not self.log.active:
+            self.log.dropped_through = max(self.log.dropped_through,
+                                           sealed_epoch)
             return
         key = self.log.table_id.to_bytes(4, "big") + bytes([_ENTRIES]) \
             + sealed_epoch.to_bytes(8, "big") \

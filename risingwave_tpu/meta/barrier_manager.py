@@ -542,7 +542,8 @@ class BarrierCoordinator:
         the coordinator drains, so an idle session holds no timer)."""
         if not self.stall_threshold_ms:
             return
-        if self._watchdog_task is None or self._watchdog_task.done():
+        t = self._watchdog_task
+        if t is None or t.done() or t.cancelling():
             self._watchdog_task = asyncio.get_running_loop().create_task(
                 self._watchdog(), name="barrier-watchdog")
 
@@ -610,9 +611,16 @@ class BarrierCoordinator:
 
     def _stop_watchdog(self) -> None:
         t = self._watchdog_task
-        self._watchdog_task = None
         if t is not None and not t.done():
             t.cancel()
+
+    async def join_watchdog(self) -> None:
+        """Session shutdown: cancel the watchdog and wait until it has
+        ended (the barrier path only cancels; it never waits)."""
+        self._stop_watchdog()
+        if self._watchdog_task is not None:
+            await asyncio.gather(self._watchdog_task,
+                                 return_exceptions=True)
 
     async def wait_collected(self, barrier: Barrier) -> None:
         st = self._epochs[barrier.epoch.curr]

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from contextlib import aclosing
 from typing import Optional, Protocol
 
 from ..common.chunk import StreamChunk
@@ -67,92 +68,103 @@ class Actor:
 
     async def _run_inner(self) -> None:
         last_token = None
-        it = self.consumer.execute().__aiter__()
         mono = time.monotonic_ns
-        while True:
-            obs = self.obs
-            if obs is not None:
-                t_poll = mono()
-                w0 = obs.input_wait_ns
-            try:
-                msg = await it.__anext__()
-            except StopAsyncIteration:
-                return
-            if obs is not self.obs:
-                # re-instrumented while parked in the poll (SET
-                # metric_level): restart the span at the switch point so
-                # this very message already reports under the new level
+        # the chain is closed HERE, where it is dropped: an actor that
+        # stops or dies never resumes the generator, and an executor's
+        # `finally` (sockets, tasks) would otherwise run whenever the
+        # loop finalizes the collected generator — later, as a detached
+        # task nobody awaits
+        async with aclosing(self.consumer.execute()) as it:
+            while True:
                 obs = self.obs
                 if obs is not None:
                     t_poll = mono()
                     w0 = obs.input_wait_ns
-            if isinstance(msg, StreamChunk):
-                if msg.columns:
-                    last_token = msg.columns[0].data
-                if self.dispatcher is not None:
-                    await self.dispatcher.dispatch(msg)
-                if obs is not None:
-                    # poll span minus the channel-recv wait accrued inside
-                    # it = actual chunk compute + dispatch time
-                    waited = obs.input_wait_ns - w0
-                    obs.apply_ns += max(0, mono() - t_poll - waited)
-                    obs.note_chunk_out(msg,
-                                       dispatcher_fanout(self.dispatcher))
-            elif isinstance(msg, Barrier):
-                if FAULTS.active and FAULTS.hit(
-                        "actor_crash", actor=self.actor_id,
-                        epoch=msg.epoch.curr) is not None:
-                    # before the dispatch: downstream never sees this
-                    # barrier, exactly like a mid-interval executor death
-                    raise FaultInjected(
-                        f"injected actor_crash at actor {self.actor_id} "
-                        f"epoch {msg.epoch.curr}")
-                barrier = msg.with_passed(self.actor_id)
-                if self.dispatcher is not None:
-                    await self.dispatcher.dispatch(barrier)
-                if obs is not None:
-                    # the barrier-yielding poll is the chain's barrier
-                    # work: every executor's flush/persist/commit runs
-                    # inside it before the barrier emerges
-                    waited = obs.input_wait_ns - w0
-                    obs.persist_ns += max(0, mono() - t_poll - waited)
-                # Epoch fence: the barrier is only reported collected once
-                # every device program of the epoch has actually executed
-                # (the chain dispatches asynchronously) — the last chunk
-                # covers per-chunk programs; executor fence tokens cover
-                # barrier-time programs (flush/evict/purge) dispatched
-                # after it. block_until_ready moves no data (a d2h
-                # transfer here would serialise with dispatch).
-                # Blocking runs in a worker thread so other actors keep
-                # draining.
-                from .executor import gather_fence_tokens
-                if self.fence_exempt:
-                    tokens = []
-                else:
-                    tokens = ([last_token]
-                              if last_token is not None else [])
-                    tokens.extend(gather_fence_tokens(self.consumer))
-                t_fence = mono() if obs is not None else 0
-                for tok in tokens:
-                    if hasattr(tok, "block_until_ready"):
-                        await asyncio.to_thread(tok.block_until_ready)
-                last_token = None
-                if obs is not None:
-                    obs.fence_ns += mono() - t_fence
-                    phases = obs.on_barrier()
-                    ph = getattr(self.collector, "collect_phases", None)
-                    if ph is not None:
-                        ph(self.actor_id, barrier, phases)
-                if self.collector is not None:
-                    self.collector.collect(self.actor_id, barrier)
-                if barrier.is_stop(self.actor_id):
+                try:
+                    msg = await it.__anext__()
+                except StopAsyncIteration:
                     return
-            else:
-                if self.dispatcher is not None:
-                    await self.dispatcher.dispatch(msg)
-                if obs is not None:
-                    waited = obs.input_wait_ns - w0
-                    obs.apply_ns += max(0, mono() - t_poll - waited)
+                if obs is not self.obs:
+                    # re-instrumented while parked in the poll (SET
+                    # metric_level): restart the span at the switch point so
+                    # this very message already reports under the new level
+                    obs = self.obs
+                    if obs is not None:
+                        t_poll = mono()
+                        w0 = obs.input_wait_ns
+                if isinstance(msg, StreamChunk):
+                    if msg.columns:
+                        last_token = msg.columns[0].data
+                    if self.dispatcher is not None:
+                        await self.dispatcher.dispatch(msg)
+                    if obs is not None:
+                        # poll span minus the channel-recv wait accrued inside
+                        # it = actual chunk compute + dispatch time
+                        waited = obs.input_wait_ns - w0
+                        obs.apply_ns += max(0, mono() - t_poll - waited)
+                        obs.note_chunk_out(msg,
+                                           dispatcher_fanout(self.dispatcher))
+                elif isinstance(msg, Barrier):
+                    if FAULTS.active and FAULTS.hit(
+                            "actor_crash", actor=self.actor_id,
+                            epoch=msg.epoch.curr) is not None:
+                        # before the dispatch: downstream never sees this
+                        # barrier, exactly like a mid-interval executor death
+                        raise FaultInjected(
+                            f"injected actor_crash at actor {self.actor_id} "
+                            f"epoch {msg.epoch.curr}")
+                    barrier = msg.with_passed(self.actor_id)
+                    if self.dispatcher is not None:
+                        await self.dispatcher.dispatch(barrier)
+                    if obs is not None:
+                        # the barrier-yielding poll is the chain's barrier
+                        # work: every executor's flush/persist/commit runs
+                        # inside it before the barrier emerges
+                        waited = obs.input_wait_ns - w0
+                        obs.persist_ns += max(0, mono() - t_poll - waited)
+                    # Epoch fence: the barrier is only reported collected once
+                    # every device program of the epoch has actually executed
+                    # (the chain dispatches asynchronously) — the last chunk
+                    # covers per-chunk programs; executor fence tokens cover
+                    # barrier-time programs (flush/evict/purge) dispatched
+                    # after it. block_until_ready moves no data (a d2h
+                    # transfer here would serialise with dispatch).
+                    # Blocking runs in a worker thread so other actors keep
+                    # draining.
+                    from .executor import gather_fence_tokens
+                    if self.fence_exempt:
+                        tokens = []
+                    else:
+                        tokens = ([last_token]
+                                  if last_token is not None else [])
+                        tokens.extend(gather_fence_tokens(self.consumer))
+                    t_fence = mono() if obs is not None else 0
+                    for tok in tokens:
+                        if hasattr(tok, "block_until_ready"):
+                            await asyncio.to_thread(tok.block_until_ready)
+                    last_token = None
+                    if obs is not None:
+                        obs.fence_ns += mono() - t_fence
+                        phases = obs.on_barrier()
+                        ph = getattr(self.collector, "collect_phases", None)
+                        if ph is not None:
+                            ph(self.actor_id, barrier, phases)
+                    stop = barrier.is_stop(self.actor_id)
+                    if stop:
+                        # BEFORE the collect: whoever stops a deployment
+                        # cancels its tasks once the stop barrier is
+                        # collected, and a cancelled close is half a close
+                        await it.aclose()
+                    if self.collector is not None:
+                        self.collector.collect(self.actor_id, barrier)
+                    if stop:
+                        return
+                else:
+                    if self.dispatcher is not None:
+                        await self.dispatcher.dispatch(msg)
+                    if obs is not None:
+                        waited = obs.input_wait_ns - w0
+                        obs.apply_ns += max(0, mono() - t_poll - waited)
 
     def spawn(self) -> asyncio.Task:
         return asyncio.create_task(self.run(), name=f"actor-{self.actor_id}")

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import enum
 import time
+from contextlib import aclosing
 from typing import Optional
 
 import numpy as np
@@ -201,14 +202,17 @@ def _wrap_executor(ex) -> None:
     def execute(*a, **k):
         async def _gen():
             t0 = time.monotonic_ns()
-            async for item in inner(*a, **k):
-                obs = ex._exec_obs
-                if obs is not None:
-                    obs.busy_ns += time.monotonic_ns() - t0
-                    if hasattr(item, "cardinality"):
-                        obs.note_chunk(item)
-                yield item
-                t0 = time.monotonic_ns()
+            # closed with this wrapper: a passthrough must not turn
+            # the close of `ex` into a later finalizer task
+            async with aclosing(inner(*a, **k)) as items:
+                async for item in items:
+                    obs = ex._exec_obs
+                    if obs is not None:
+                        obs.busy_ns += time.monotonic_ns() - t0
+                        if hasattr(item, "cardinality"):
+                            obs.note_chunk(item)
+                    yield item
+                    t0 = time.monotonic_ns()
         return _gen()
 
     ex._exec_obs = None

@@ -198,15 +198,14 @@ class RemoteOutput:
                     self._credit_evt.set()
         except (asyncio.IncompleteReadError, ConnectionResetError,
                 OSError):
-            pass
-        except asyncio.CancelledError:
-            return        # rewind replaces the loop without killing the leg
-        finally:
             # a sender parked on the credit wait must WAKE once the
             # receiver is gone: legacy senders fail fast (recovery
             # teardown otherwise deadlocks — receiver waits for this
             # socket to close while we wait for its credits); replay
-            # senders park until rewind_replay re-establishes the leg
+            # senders park until rewind_replay re-establishes the leg.
+            # NOT on cancellation: a rewind replaces this loop on a
+            # live leg, and a loop that marked the leg dead on its way
+            # out would park every send after the rewind
             self._dead = True
             self._credit_evt.set()
 
@@ -286,8 +285,7 @@ class RemoteOutput:
         assert self._buf is not None, "replay not enabled on this leg"
         self._rewinding = True      # live sends park until the suffix
         try:                        # has streamed in order
-            if self._credit_task is not None:
-                self._credit_task.cancel()
+            await self._stop_credit_loop()
             if host is not None or self._dead:
                 try:
                     self._writer.close()
@@ -318,10 +316,20 @@ class RemoteOutput:
             # again and it writes in order behind the suffix
             self._credit_evt.set()
 
+    async def _stop_credit_loop(self) -> None:
+        """Cancel the credit loop AND wait for it: the caller goes on
+        to start a new loop on the same reader (rewind) or to drop the
+        leg (close), and the old loop must be off it by then."""
+        t, self._credit_task = self._credit_task, None
+        if t is not None:
+            t.cancel()
+            await asyncio.gather(t, return_exceptions=True)
+
     async def close(self) -> None:
-        if self._credit_task:
-            self._credit_task.cancel()
+        await self._stop_credit_loop()
         if self._writer:
+            # graceful: the frames already written (the stop barrier)
+            # still flush; nothing here waits for the peer
             self._writer.close()
 
 
@@ -344,6 +352,7 @@ class RemoteInput(Executor):
         self._queue: asyncio.Queue = asyncio.Queue()
         self._server = None
         self._conn_writer = None
+        self._handlers: set = set()     # live connection-handler tasks
         # per-worker partial recovery: a rebuilt consumer reading a
         # SURVIVING server arms this flag — everything queued before
         # the producer's 'R' rewind frame belongs to the dead
@@ -362,19 +371,23 @@ class RemoteInput(Executor):
 
     async def start(self) -> "RemoteInput":
         async def handle(reader, writer):
-            if self._conn_writer is not None:
-                # one producer per input (fan-in uses one RemoteInput per
-                # upstream edge) — a second LIVE connection would steal
-                # the credit channel and deadlock the first sender; a
-                # dead producer's slot frees below so a rewound or
-                # re-placed producer can re-attach
-                writer.close()
-                return
-            self._conn_writer = writer
-            # initial credit window
-            await _write_frame(writer, b"K",
-                               struct.pack("!I", self.queue_depth))
+            me = asyncio.current_task()
+            self._handlers.add(me)
             try:
+                if self._conn_writer is not None \
+                        or not self._server.is_serving():
+                    # one producer per input (fan-in uses one
+                    # RemoteInput per upstream edge) — a second LIVE
+                    # connection would steal the credit channel and
+                    # deadlock the first sender; a dead producer's slot
+                    # frees below so a rewound or re-placed producer
+                    # can re-attach. A connection accepted just before
+                    # stop() starts its handler after it: turned away
+                    return
+                self._conn_writer = writer
+                # initial credit window
+                await _write_frame(writer, b"K",
+                                   struct.pack("!I", self.queue_depth))
                 while True:
                     tag, payload = await _read_frame(reader)
                     if tag == b"R":
@@ -389,8 +402,17 @@ class RemoteInput(Executor):
                     OSError):
                 await self._queue.put((b"X", b""))
             finally:
+                # the handler owns the writer it was given, however it
+                # ends (peer gone, stop(), loop shutdown): asyncio
+                # closes the transport of a handler that RETURNS for
+                # nobody, and Server.wait_closed() (3.12+) waits for
+                # every transport. abort, not close: this writer only
+                # ever carries credit grants, which no one wants now,
+                # and abort needs no flush to a peer that may not read
+                writer.transport.abort()
                 if self._conn_writer is writer:
                     self._conn_writer = None
+                self._handlers.discard(me)
 
         self._server = await asyncio.start_server(handle, self.host,
                                                   self.port)
@@ -398,18 +420,17 @@ class RemoteInput(Executor):
         return self
 
     async def stop(self) -> None:
-        # close the live connection FIRST: wait_closed() (3.12+) waits
-        # for connection handlers, and ours is blocked reading a socket
-        # whose peer may itself be blocked on our credits — the
-        # recovery-teardown circular wait (round 5)
-        if self._conn_writer is not None:
-            try:
-                self._conn_writer.close()
-            except Exception:  # noqa: BLE001
-                pass
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+        """Stop listening, end the connection handlers, and wait until
+        the loop has let go of every socket — in that order, with no
+        help from the peer: each handler drops its own connection."""
+        if self._server is None:
+            return
+        self._server.close()
+        handlers = list(self._handlers)
+        for t in handlers:
+            t.cancel()
+        await asyncio.gather(*handlers, return_exceptions=True)
+        await self._server.wait_closed()
 
     async def recv(self):
         """Channel-compatible receive — the cluster partial build

@@ -32,6 +32,7 @@ import asyncio
 import json
 import pickle
 import struct
+from contextlib import aclosing
 from typing import Sequence
 
 from ..common.types import Schema
@@ -87,33 +88,38 @@ class RemoteFragmentExecutor(Executor):
         # socket (the DCN tier is cross-host by design)
         rx = await RemoteInput(self.schema, host="0.0.0.0",
                                queue_depth=8).start()
-        spec = pickle.dumps({
-            "node": self.node,
-            "in_schemas": self.in_schemas,
-            "out_schema": self.schema,
-            "out_port": rx.port,
-            "stop_actor_id": self.actor_id,
-        })
-        await _send_blob(writer, spec)
-        reply = json.loads(await _recv_blob(reader))
-        outs = []
-        for p in reply["input_ports"]:
-            outs.append(await RemoteOutput(host, p).connect())
-        pumps = [asyncio.create_task(self._pump(c, o))
-                 for c, o in zip(self.in_channels, outs)]
+        outs, pumps = [], []
         try:
-            async for msg in rx.execute():
-                yield msg
-                if isinstance(msg, Barrier) and msg.mutation is not None \
-                        and msg.is_stop(self.actor_id):
-                    break
+            spec = pickle.dumps({
+                "node": self.node,
+                "in_schemas": self.in_schemas,
+                "out_schema": self.schema,
+                "out_port": rx.port,
+                "stop_actor_id": self.actor_id,
+            })
+            await _send_blob(writer, spec)
+            reply = json.loads(await _recv_blob(reader))
+            for p in reply["input_ports"]:
+                outs.append(await RemoteOutput(host, p).connect())
+            pumps.extend(asyncio.create_task(self._pump(c, o))
+                         for c, o in zip(self.in_channels, outs))
+            async with aclosing(rx.execute()) as msgs:
+                async for msg in msgs:
+                    yield msg
+                    if isinstance(msg, Barrier) \
+                            and msg.mutation is not None \
+                            and msg.is_stop(self.actor_id):
+                        break
         finally:
+            # the one close order, needing nothing from the worker: end
+            # our pumps, drop our sending legs (credit loops awaited),
+            # stop the server with its connection, drop the control
+            # socket. The actor closes this generator when it returns
+            # on the stop barrier (stream/actor.py), so this runs then
             for t in pumps:
                 t.cancel()
+            await asyncio.gather(*pumps, return_exceptions=True)
             for o in outs:
-                try:
-                    await o.close()
-                except Exception:  # noqa: BLE001
-                    pass
+                await o.close()
             await rx.stop()
             writer.close()

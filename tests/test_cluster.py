@@ -27,8 +27,6 @@ import pytest
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.state import HummockStateStore, LocalFsObjectStore
 
-STEP_TIMEOUT_S = 180
-
 AGG_DDL = [
     ("CREATE SOURCE bid WITH (connector='nexmark', table='bid', "
      "chunk_size=256, splits=2, rate_limit=512)"),
@@ -52,10 +50,6 @@ Q7_DDL = [
 ]
 
 
-async def _step(coro):
-    return await asyncio.wait_for(coro, timeout=STEP_TIMEOUT_S)
-
-
 def _free_port() -> int:
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -64,43 +58,64 @@ def _free_port() -> int:
     return port
 
 
-def _spawn_worker(port: int) -> subprocess.Popen:
+def _spawn_workers():
+    """Two workers -> (ports, processes), both listeners up."""
     # no stdio pipes (pytest fd capture vs a child sharing stdio);
-    # pre-pick the port and poll for the listener — the established
+    # pre-pick the ports and poll for the listeners — the established
     # worker-spawn idiom (test_remote_fragment.py)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.Popen(
+    ports = [_free_port(), _free_port()]
+    procs = [subprocess.Popen(
         [sys.executable, "-m", "risingwave_tpu.worker", str(port)],
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        for port in ports]
     deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
+    waiting = list(ports)
+    while waiting and time.monotonic() < deadline:
         try:
-            socket.create_connection(("127.0.0.1", port),
+            socket.create_connection(("127.0.0.1", waiting[0]),
                                      timeout=1).close()
-            return p
+            waiting.pop(0)
         except OSError:
             time.sleep(0.2)
-    p.terminate()
-    raise RuntimeError("worker never started listening")
+    if waiting:
+        _terminate(procs)
+        raise RuntimeError("worker never started listening")
+    return ports, procs
 
 
-@pytest.fixture()
-def two_workers():
-    ports = [_free_port(), _free_port()]
-    procs = [_spawn_worker(p) for p in ports]
-    yield ports, procs
+def _terminate(procs) -> None:
     for p in procs:
         if p.poll() is None:
             p.terminate()
-            p.wait(timeout=10)
+    for p in procs:
+        p.wait(timeout=10)
+
+
+@pytest.fixture(scope="module")
+def two_workers():
+    """ONE pair of compute nodes for every test that kills none: each
+    test brings its own store (tmp_path) and its own meta session, and a
+    worker serves a fresh ComputeNode per control connection."""
+    ports, procs = _spawn_workers()
+    yield ports, procs
+    _terminate(procs)
+
+
+@pytest.fixture()
+def own_two_workers():
+    """A pair of its own for a test that kills one."""
+    ports, procs = _spawn_workers()
+    yield ports, procs
+    _terminate(procs)
 
 
 async def _cluster_session(tmp_path, ports, name="c") -> Session:
     store = HummockStateStore(LocalFsObjectStore(str(tmp_path / name)))
     s = Session(store=store)
     addr = ",".join(f"127.0.0.1:{p}" for p in ports)
-    await _step(s.execute(f"SET cluster = '{addr}'"))
+    await s.execute(f"SET cluster = '{addr}'")
     return s
 
 
@@ -162,24 +177,24 @@ async def test_two_worker_agg_bit_identical_to_single_process(
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
-    rows = await _step(s.execute("SHOW cluster"))
+        await s.execute(d)
+    rows = await s.execute("SHOW cluster")
     assert len(rows) == 2 and all(r[2] == "alive" for r in rows)
     for _ in range(6):
-        await _step(s.tick())
+        await s.tick()
     cluster_rows = sorted(s.query("SELECT auction, n, mx FROM agg"))
     offsets = _split_offsets(s)
-    await _step(s.shutdown())
+    await s.shutdown()
 
     single = Session(store=HummockStateStore(
         LocalFsObjectStore(str(tmp_path / "single"))))
     for d in AGG_DDL:
-        await _step(single.execute(d))
+        await single.execute(d)
     for _ in range(6):
-        await _step(single.tick())
+        await single.tick()
     single_rows = sorted(single.query("SELECT auction, n, mx FROM agg"))
     single_offsets = _split_offsets(single)
-    await _step(single.shutdown())
+    await single.shutdown()
 
     assert offsets and offsets == single_offsets, (offsets,
                                                    single_offsets)
@@ -196,26 +211,27 @@ async def test_two_worker_q7_converges_to_single_process(tmp_path,
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in Q7_DDL:
-        await _step(s.execute(d))
-    # 6 rounds: enough closed tumble windows for a non-empty interval
-    # join on both runs; the equality assert is tick-count-symmetric
-    for _ in range(6):
-        await _step(s.tick())
+        await s.execute(d)
+    # 4 rounds: the join emits from the first round and retracts at
+    # the third (a higher max arrives), so the result is non-empty on
+    # both runs; the equality assert is tick-count-symmetric
+    for _ in range(4):
+        await s.tick()
     cluster_rows = sorted(s.query(
         "SELECT auction, price, bidder, date_time FROM q7"))
     offsets = _split_offsets(s)
-    await _step(s.shutdown())
+    await s.shutdown()
 
     single = Session(store=HummockStateStore(
         LocalFsObjectStore(str(tmp_path / "single"))))
     for d in Q7_DDL:
-        await _step(single.execute(d))
-    for _ in range(6):
-        await _step(single.tick())
+        await single.execute(d)
+    for _ in range(4):
+        await single.tick()
     single_rows = sorted(single.query(
         "SELECT auction, price, bidder, date_time FROM q7"))
     single_offsets = _split_offsets(single)
-    await _step(single.shutdown())
+    await single.shutdown()
 
     assert offsets == single_offsets
     assert cluster_rows == single_rows
@@ -285,52 +301,51 @@ async def test_checkpoint_commit_waits_for_every_worker(tmp_path):
 
 
 async def test_worker_kill_auto_recovery_converges(tmp_path,
-                                                   two_workers):
+                                                   own_two_workers):
     """Kill one compute node mid-run: the lease/connection failure
     detector fails the epoch, auto-recovery re-places every fragment
     over the survivor at the ORIGINAL parallelism (same vnode bitmaps
     over the shared state), sources resume from committed offsets, and
     the MV converges to the exactly-once oracle."""
-    ports, procs = two_workers
+    ports, procs = own_two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
+        await s.execute(d)
     for _ in range(4):
-        await _step(s.tick())
+        await s.tick()
     pre = s.query("SELECT auction, n, mx FROM agg")
     assert pre, "no rows before the kill"
 
     procs[1].kill()
     procs[1].wait(timeout=10)
     for _ in range(5):
-        await _step(s.tick(max_recoveries=4))
+        await s.tick(max_recoveries=4)
     assert s.recoveries >= 1
-    rows = await _step(s.execute("SHOW cluster"))
+    rows = await s.execute("SHOW cluster")
     assert [r[2] for r in rows] == ["alive"], rows
 
     got = sorted(s.query("SELECT auction, n, mx FROM agg"))
     offsets = _split_offsets(s)
     assert got == _agg_oracle(offsets)
-    await _step(s.shutdown())
+    await s.shutdown()
 
 
 async def test_single_worker_kill_partial_recovery(tmp_path,
-                                                   two_workers):
+                                                   own_two_workers):
     """The per-worker recovery radius: killing ONE compute node
     re-places only its actors (plus their downstream closure) onto the
     survivor — scope=worker, strictly fewer actors than the topology,
     the survivor's STORE OBJECT stays open across the recovery (no
     reset+reopen), and the MV converges bit-identical to the
     generator-prefix oracle at the committed offsets."""
-    ports, procs = two_workers
+    ports, procs = own_two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
+        await s.execute(d)
     for _ in range(4):
-        await _step(s.tick())
+        await s.tick()
     h1 = s.cluster.workers[1]
-    store_id_before = (await _step(
-        h1.call("ping", timeout=10)))["store_id"]
+    store_id_before = (await h1.call("ping", timeout=10))["store_id"]
     all_actors = sorted(
         a for dep in s.cluster.deployments.values()
         for ids in dep.rebuild_info["actors"].values() for a in ids)
@@ -338,7 +353,7 @@ async def test_single_worker_kill_partial_recovery(tmp_path,
     procs[1].kill()
     procs[1].wait(timeout=10)
     for _ in range(5):
-        await _step(s.tick(max_recoveries=4))
+        await s.tick(max_recoveries=4)
 
     assert s.recoveries == 1
     assert s.last_recovery["scope"] == "worker"
@@ -347,20 +362,19 @@ async def test_single_worker_kill_partial_recovery(tmp_path,
     assert rebuilt < set(all_actors), (rebuilt, all_actors)
     # the survivor kept its store OBJECT — partial recovery re-points
     # it at the committed manifest instead of reset+reopen
-    store_id_after = (await _step(
-        h1.call("ping", timeout=10)))["store_id"]
+    store_id_after = (await h1.call("ping", timeout=10))["store_id"]
     assert store_id_after == store_id_before
-    rows = await _step(s.execute("SHOW cluster"))
+    rows = await s.execute("SHOW cluster")
     assert [r[2] for r in rows] == ["alive"], rows
     got = sorted(s.query("SELECT auction, n, mx FROM agg"))
     offsets = _split_offsets(s)
     assert got == _agg_oracle(offsets)
     # keeps converging with more progress
     for _ in range(2):
-        await _step(s.tick())
+        await s.tick()
     got = sorted(s.query("SELECT auction, n, mx FROM agg"))
     assert got == _agg_oracle(_split_offsets(s))
-    await _step(s.shutdown())
+    await s.shutdown()
 
 
 async def test_cluster_hbm_budget_partitioned_and_show_memory(
@@ -372,23 +386,23 @@ async def test_cluster_hbm_budget_partitioned_and_show_memory(
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
-    await _step(s.execute("SET hbm_budget_bytes = 1048576"))
+        await s.execute(d)
+    await s.execute("SET hbm_budget_bytes = 1048576")
     for _ in range(3):
-        await _step(s.tick())
+        await s.tick()
 
-    scrapes = await _step(s.cluster.scrape_all())
+    scrapes = await s.cluster.scrape_all()
     assert set(scrapes) == {1, 2}
     for wid, text in scrapes.items():
         line = next(ln for ln in text.splitlines()
                     if ln.startswith("hbm_budget_bytes"))
         assert float(line.rsplit(" ", 1)[1]) == 1048576 // 2, (wid, line)
 
-    rows = await _step(s.execute("SHOW memory"))
+    rows = await s.execute("SHOW memory")
     owners = {r[0].split("/")[0] for r in rows}
     assert {"w1", "w2"} <= owners, rows
     assert any(int(r[1]) > 0 for r in rows), rows
-    await _step(s.shutdown())
+    await s.shutdown()
 
 
 async def test_meta_metrics_merge_worker_label(tmp_path, two_workers):
@@ -397,10 +411,10 @@ async def test_meta_metrics_merge_worker_label(tmp_path, two_workers):
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
+        await s.execute(d)
     for _ in range(2):
-        await _step(s.tick())
-    mon = await _step(s.start_monitor(0))
+        await s.tick()
+    mon = await s.start_monitor(0)
     reader, writer = await asyncio.open_connection("127.0.0.1", mon.port)
     writer.write(b"GET /metrics HTTP/1.0\r\n\r\n")
     await writer.drain()
@@ -409,7 +423,7 @@ async def test_meta_metrics_merge_worker_label(tmp_path, two_workers):
     assert 'worker="w1"' in body and 'worker="w2"' in body
     # worker barrier latencies merged next to the unlabelled meta series
     assert body.count("meta_barrier_latency_seconds_count") >= 3
-    await _step(s.shutdown())
+    await s.shutdown()
 
 
 def test_merge_worker_label_rewrites_series_lines():
@@ -429,21 +443,21 @@ async def test_cluster_rejects_dict_typed_state_and_mv_on_mv(
     refuse the deploy loudly instead of running wrong."""
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
-    await _step(s.execute(
+    await s.execute(
         "CREATE SOURCE bid WITH (connector='nexmark', table='bid', "
-        "chunk_size=256, splits=2, rate_limit=512)"))
+        "chunk_size=256, splits=2, rate_limit=512)")
     with pytest.raises(Exception, match="dict-encoded"):
         # channel is VARCHAR and lands in materialize state
-        await _step(s.execute(
+        await s.execute(
             "CREATE MATERIALIZED VIEW v AS SELECT auction, channel "
-            "FROM bid"))
-    await _step(s.execute(
+            "FROM bid")
+    await s.execute(
         "CREATE MATERIALIZED VIEW ok AS SELECT auction, count(*) AS n "
-        "FROM bid GROUP BY auction"))
+        "FROM bid GROUP BY auction")
     with pytest.raises(Exception, match="stream_scan|MV-on-MV"):
-        await _step(s.execute(
-            "CREATE MATERIALIZED VIEW vv AS SELECT auction FROM ok"))
-    await _step(s.shutdown())
+        await s.execute(
+            "CREATE MATERIALIZED VIEW vv AS SELECT auction FROM ok")
+    await s.shutdown()
 
 
 async def _http_get(port: int, path: str) -> str:
@@ -476,10 +490,10 @@ async def test_cluster_flight_recorder_over_real_sockets(
     ports, _ = two_workers
     s = await _cluster_session(tmp_path, ports)
     for d in AGG_DDL:
-        await _step(s.execute(d))
+        await s.execute(d)
     for _ in range(3):
-        await _step(s.tick())
-    mon = await _step(s.start_monitor(0))
+        await s.tick()
+    mon = await s.start_monitor(0)
 
     payload = json.loads(await _http_get(
         mon.port, "/debug/traces?format=json"))
@@ -512,25 +526,29 @@ async def test_cluster_flight_recorder_over_real_sockets(
     assert "# device profile" in dev
     assert "w1/" in dev and "w2/" in dev, dev[:500]
 
-    await _step(s.execute("SET barrier_stall_threshold_ms = 400"))
+    await s.execute("SET barrier_stall_threshold_ms = 400")
     # rides the cluster config push: each worker's process-global
     # injector arms, and its ChannelInput consumer parks 1.5s on the
     # next matching chunk (fires once — at=1,times=1 defaults)
-    await _step(s.execute(
-        "SET fault_injection = 'channel_stall:ms=1500'"))
-    err = io.StringIO()
-    with contextlib.redirect_stderr(err):
-        for _ in range(3):
-            await _step(s.tick())
-    report = err.getvalue()
-    assert "[stuck barrier]" in report, report[:2000] or "(empty)"
-    assert "remaining actors" in report
-    # one section per live worker, each with its own await tree
-    assert "== worker w1 ==" in report, report
-    assert "== worker w2 ==" in report, report
-    assert "task " in report, report
-    # the stall also landed in the durable event log
-    stalls = s.event_log.records(kind="barrier_stall")
-    assert stalls and stalls[-1]["remaining"], stalls
-    await _step(s.execute("SET fault_injection = ''"))
-    await _step(s.shutdown())
+    await s.execute(
+        "SET fault_injection = 'channel_stall:ms=1500'")
+    try:
+        err = io.StringIO()
+        with contextlib.redirect_stderr(err):
+            for _ in range(3):
+                await s.tick()
+        report = err.getvalue()
+        assert "[stuck barrier]" in report, report[:2000] or "(empty)"
+        assert "remaining actors" in report
+        # one section per live worker, each with its own await tree
+        assert "== worker w1 ==" in report, report
+        assert "== worker w2 ==" in report, report
+        assert "task " in report, report
+        # the stall also landed in the durable event log
+        stalls = s.event_log.records(kind="barrier_stall")
+        assert stalls and stalls[-1]["remaining"], stalls
+    finally:
+        # the workers are the module's: whatever happens above, the next
+        # test gets them with nothing armed
+        await s.execute("SET fault_injection = ''")
+    await s.shutdown()

@@ -187,8 +187,11 @@ async def test_concurrent_rewind_preserves_per_leg_frame_order():
     for li, (rx, tx) in enumerate(legs):
         seen_r = False
         epochs_after_r = []
-        while not rx._queue.empty():
-            tag, payload = rx._queue.get_nowait()
+        # wait for the frames (they cross a real socket): the 'R' and
+        # the nine barriers behind it; a lost frame runs into the
+        # harness's per-test limit
+        while not seen_r or len(epochs_after_r) < 9:
+            tag, payload = await rx._queue.get()
             if tag == b"R":
                 seen_r = True
                 epochs_after_r = []

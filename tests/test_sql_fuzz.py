@@ -12,6 +12,8 @@ implementations, so agreement is a real check.
 import random
 from collections import Counter
 
+import pytest
+
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.frontend.binder import BindError
 
@@ -76,8 +78,16 @@ def _rand_query(rng, i):
             True)
 
 
-async def test_streaming_vs_batch_differential():
+def _queries(n: int = 20) -> list:
+    """The seeded queries, (index, sql, has_agg), drawn from one
+    generator in order."""
     rng = random.Random(20260730)
+    return [(i, *_rand_query(rng, i)) for i in range(n)]
+
+
+@pytest.mark.parametrize("queries", [_queries()[:10], _queries()[10:]],
+                         ids=["0-9", "10-19"])
+async def test_streaming_vs_batch_differential(queries):
     s = Session()
     await s.execute("CREATE SOURCE bid WITH (connector='nexmark', "
                     "table='bid', chunk_size=256, rate_limit=512)")
@@ -86,8 +96,7 @@ async def test_streaming_vs_batch_differential():
                     "bidder, price FROM bid")
 
     passed, skipped = 0, 0
-    for i in range(20):
-        sql_text, has_agg = _rand_query(rng, i)
+    for i, sql_text, has_agg in queries:
         name = f"fz{i}"
         try:
             await s.execute(
@@ -109,55 +118,49 @@ async def test_streaming_vs_batch_differential():
             f"{list((exp - got).items())[:3]}")
         passed += 1
         await s.drop_mv(name)
-    assert passed >= 15, f"only {passed} fuzz queries ran ({skipped} skipped)"
+    assert passed >= 8, f"only {passed} fuzz queries ran ({skipped} skipped)"
     await s.drop_all()
 
 
-async def test_streaming_vs_batch_join_differential():
+def _join_cases(n: int = 5) -> list:
+    """The seeded join shapes: (modulus, left filter, right filter, join
+    type), drawn in this order from one generator."""
+    rng = random.Random(20260731)
+    return [(rng.randint(3, 17), rng.randint(2, 5), rng.randint(2, 5),
+             rng.choice(["JOIN", "LEFT JOIN", "RIGHT JOIN", "FULL JOIN"]))
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("m, lf, rf, jt", _join_cases())
+async def test_streaming_vs_batch_join_differential(m, lf, rf, jt):
     """Join-shaped fuzzing incl. outer joins (VERDICT r4 #4): the newest
     machinery — outer-join degrees on the streaming side, NULL padding on
-    the batch side — checks itself differentially."""
-    rng = random.Random(20260731)
+    the batch side — checks itself differentially. One case per seeded
+    shape (each deploys three MVs: ~10 s on the CPU)."""
     s = Session()
     await s.execute("CREATE SOURCE bid WITH (connector='nexmark', "
                     "table='bid', chunk_size=256, rate_limit=512)")
-
-    passed = 0
-    saw_null = False
-    for i in range(5):
-        m = rng.randint(3, 17)
-        lf = rng.randint(2, 5)
-        rf = rng.randint(2, 5)
-        await s.execute(
-            f"CREATE MATERIALIZED VIEW ja{i} AS SELECT (auction % {m}) "
-            f"AS k, bidder, price FROM bid WHERE (bidder % {lf}) <> 0")
-        await s.execute(
-            f"CREATE MATERIALIZED VIEW jb{i} AS SELECT (auction % {m}) "
-            f"AS k, count(*) AS cnt, max(price) AS mp FROM bid "
-            f"WHERE (price % {rf}) = 0 GROUP BY (auction % {m})")
-        jt = rng.choice(["JOIN", "LEFT JOIN", "RIGHT JOIN", "FULL JOIN"])
-        sql_text = (f"SELECT A.bidder, A.price, B.cnt, B.mp "
-                    f"FROM ja{i} A {jt} jb{i} B ON A.k = B.k")
-        try:
-            await s.execute(
-                f"CREATE MATERIALIZED VIEW jm{i} AS {sql_text}")
-        except BindError:
-            await s.drop_mv(f"jb{i}")
-            await s.drop_mv(f"ja{i}")
-            continue
-        await s.tick(1)
-        got = Counter(s.query(f"SELECT bidder, price, cnt, mp FROM jm{i}"))
-        exp = Counter(s.query(sql_text))
-        assert got == exp, (
-            f"join divergence on {sql_text!r}: streaming={sum(got.values())}"
-            f" rows, batch={sum(exp.values())} rows; sample diff "
-            f"{list((got - exp).items())[:3]} / "
-            f"{list((exp - got).items())[:3]}")
-        saw_null |= any(None in row for row in got)
-        passed += 1
-        await s.drop_mv(f"jm{i}")
-        await s.drop_mv(f"jb{i}")
-        await s.drop_mv(f"ja{i}")
-    assert passed >= 4, f"only {passed} join fuzz queries ran"
-    assert saw_null, "no NULL-padded outer rows seen — outer fuzz vacuous"
+    await s.execute(
+        f"CREATE MATERIALIZED VIEW ja AS SELECT (auction % {m}) "
+        f"AS k, bidder, price FROM bid WHERE (bidder % {lf}) <> 0")
+    await s.execute(
+        f"CREATE MATERIALIZED VIEW jb AS SELECT (auction % {m}) "
+        f"AS k, count(*) AS cnt, max(price) AS mp FROM bid "
+        f"WHERE (price % {rf}) = 0 GROUP BY (auction % {m})")
+    sql_text = (f"SELECT A.bidder, A.price, B.cnt, B.mp "
+                f"FROM ja A {jt} jb B ON A.k = B.k")
+    # all five seeded shapes bind: a BindError here fails the case
+    await s.execute(f"CREATE MATERIALIZED VIEW jm AS {sql_text}")
+    await s.tick(1)
+    got = Counter(s.query("SELECT bidder, price, cnt, mp FROM jm"))
+    exp = Counter(s.query(sql_text))
+    assert got == exp, (
+        f"join divergence on {sql_text!r}: streaming={sum(got.values())}"
+        f" rows, batch={sum(exp.values())} rows; sample diff "
+        f"{list((got - exp).items())[:3]} / "
+        f"{list((exp - got).items())[:3]}")
+    if jt in ("LEFT JOIN", "FULL JOIN"):
+        # the filtered aggregate side misses keys the left side has
+        assert any(None in row for row in got), \
+            "no NULL-padded outer rows seen — outer fuzz vacuous"
     await s.drop_all()
