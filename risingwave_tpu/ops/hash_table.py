@@ -4,14 +4,42 @@ HashJoin.
 Reference analogue: the executors' group/join hash maps (`JoinHashMap`,
 src/stream/src/executor/managed_state/join/mod.rs; `AggGroup` cache keyed by
 `HashKey`, hash_agg.rs:50-56). On TPU the map is a struct-of-arrays in HBM:
-fixed-capacity key columns + occupancy.
+fixed-capacity key columns + ONE uint32 fingerprint lane.
 
 Layout: capacity C = B buckets x S slots (S static). A key hashes to TWO
 candidate buckets (two halves of a splitmix64 chain over the key columns
-— power-of-two-choices); it lives in exactly one of their 2S slots. This shape is chosen for the
-hardware: a lookup is ONE vectorized [N, 2S] gather + compare — constant
-cost, no data-dependent probe loop — and an insert is two device sorts plus
-scatters. The previous design (linear open addressing driven by a
+— power-of-two-choices); it lives in exactly one of their 2S slots.
+`fingerprint[slot]` is 0 for an empty slot and otherwise a non-zero 32-bit
+remix of the stored key's chain value (`_fingerprint`: 31 bits | 1, mixed
+once more so it is independent of the bits that chose the buckets).
+Occupancy is DERIVED (`occupied` = `fingerprint != 0`); fingerprints are
+derived from the keys, never persisted, and rebuilt by re-insertion.
+
+This shape is chosen for the hardware: a lookup is ONE vectorized [N, 2S]
+gather — of the fingerprint lane only, as 2N whole bucket rows — plus
+[N]-sized key gathers at the one slot whose fingerprint matched; constant
+cost, and an insert is two device sorts plus scatters. Lanes are counted
+because a gather on the v5e costs by its INDEX count, not its bytes: an
+element gather of N x 2S = 21 M indices (N = 655,360 hop rows) takes 0.18 s,
+the same for a `pred` as for a `u32` operand (ledger, PR 24, `q5.sat`;
+0.183 s in my chip run, PR 25), where 2N rows of S contiguous u32 take
+0.006 s and an [N] element gather 0.006 s (u32) / 0.011 s (int64) (my chip
+run, PR 25). Comparing every key lane over all 2S candidates cost
+1 + 2 x (int64 key columns) element gathers per chunk; the fingerprint
+probe costs one row gather whatever the key arity.
+
+Exact, not probable:
+  1. every occupied slot holds the fingerprint of the key stored in it, so a
+     key that IS in the table is among the slots whose fingerprint matches;
+  2. a fingerprint match is only a candidate: the full key is compared at
+     that slot, and only a verified slot is returned;
+  3. keys are unique in the table, so the first verified slot is THE slot;
+  4. a row whose every fingerprint match failed its verify is absent;
+  5. rows with further unverified matches after the first verify (expected
+     once per ~10^8 probed rows) walk them in a bounded loop (< 2S trips,
+     zero in a chunk with none), counted in `n_fallback`.
+
+The design before the bucketed one (linear open addressing driven by a
 `lax.while_loop` claim contest) had per-chunk cost proportional to the
 longest probe chain, which degrades sharply with load/clustering: a
 saturated table turned one chunk into an O(C)-iteration loop that stalled
@@ -39,8 +67,8 @@ import jax
 import jax.numpy as jnp
 
 # Slots per bucket. 16 keeps the two-choice overflow probability negligible
-# at the 0.7 rebuild threshold while the [N, 2S] compare stays one small
-# vectorized gather per chunk.
+# at the 0.7 rebuild threshold while the [N, 2S] fingerprint compare stays
+# one vectorized gather per chunk.
 
 BUCKET_SLOTS = 16
 
@@ -100,22 +128,28 @@ def stable_lexsort_rows(keys):
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class HashTable:
-    """keys: per-key-column [C] arrays; occupied: bool [C]."""
+    """keys: per-key-column [C] arrays; fingerprint: uint32 [C], 0 = empty
+    slot, else `_fingerprint` of the key stored there."""
 
     keys: tuple[jnp.ndarray, ...]
-    occupied: jnp.ndarray
+    fingerprint: jnp.ndarray
 
     def tree_flatten(self):
-        return (self.keys, self.occupied), None
+        return (self.keys, self.fingerprint), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        keys, occupied = children
-        return cls(tuple(keys), occupied)
+        keys, fingerprint = children
+        return cls(tuple(keys), fingerprint)
+
+    @property
+    def occupied(self) -> jnp.ndarray:
+        """bool [C], derived: a slot is occupied iff it holds a fingerprint."""
+        return self.fingerprint != 0
 
     @property
     def capacity(self) -> int:
-        return self.occupied.shape[0]
+        return self.fingerprint.shape[0]
 
     @staticmethod
     def empty(capacity: int, key_dtypes: Sequence) -> "HashTable":
@@ -123,25 +157,20 @@ class HashTable:
             f"capacity {capacity} must be a multiple of {BUCKET_SLOTS}"
         return HashTable(
             tuple(jnp.zeros(capacity, dtype=dt) for dt in key_dtypes),
-            jnp.zeros(capacity, dtype=bool),
+            jnp.zeros(capacity, dtype=jnp.uint32),
         )
 
 
-def _bucket_pair(key_cols: Sequence[jnp.ndarray], n_buckets: int):
-    """Two independent candidate buckets per row (int32 [N] each), plus a
-    per-key tiebreak bit so equal-fill choices split ~50/50 (without it, a
-    burst of new keys within one chunk — where fills are all read
-    pre-chunk — would pile into every key's first choice).
-
-    The candidates come from a splitmix64 chain over the key columns, NOT
-    from crc32: CRC is linear over GF(2), so structured key sets (window
-    multiples x small ids — the windowed-agg shape) project onto few
-    residues mod a small bucket count and saturate bucket pairs at 30%
-    global load (observed: 16/16 buckets at 335/1024 occupancy after a
-    memory-eviction rehash batch-reinserted such keys). The multiply-
-    xorshift mix is non-linear, so those sets disperse like random keys.
-    The crc stays the DISTRIBUTION hash (vnodes) — this only places rows
-    within a device table, nothing durable moves."""
+def _key_hash(key_cols: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """uint64 [N]: a splitmix64 chain over the key columns, NOT crc32: CRC
+    is linear over GF(2), so structured key sets (window multiples x small
+    ids — the windowed-agg shape) project onto few residues mod a small
+    bucket count and saturate bucket pairs at 30% global load (observed:
+    16/16 buckets at 335/1024 occupancy after a memory-eviction rehash
+    batch-reinserted such keys). The multiply-xorshift mix is non-linear,
+    so those sets disperse like random keys. The crc stays the DISTRIBUTION
+    hash (vnodes) — this only places rows within a device table, nothing
+    durable moves."""
     h = jnp.full(key_cols[0].shape[0], 0x243F6A8885A308D3,
                  dtype=jnp.uint64)
     for c in key_cols:
@@ -150,6 +179,15 @@ def _bucket_pair(key_cols: Sequence[jnp.ndarray], n_buckets: int):
         x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
         x = (x ^ (x >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
         h = x ^ (x >> jnp.uint64(31))
+    return h
+
+
+def _bucket_pair(h: jnp.ndarray, n_buckets: int):
+    """Two independent candidate buckets per row (int32 [N] each) from the
+    two halves of the key hash, plus a per-key tiebreak bit so equal-fill
+    choices split ~50/50 (without it, a burst of new keys within one chunk
+    — where fills are all read pre-chunk — would pile into every key's
+    first choice)."""
     nb = jnp.uint64(n_buckets)
     h1 = ((h & jnp.uint64(0xFFFFFFFF)) % nb).astype(jnp.int32)
     h2 = ((h >> jnp.uint64(32)) % nb).astype(jnp.int32)
@@ -157,57 +195,114 @@ def _bucket_pair(key_cols: Sequence[jnp.ndarray], n_buckets: int):
     return h1, h2, tie
 
 
-def _candidates(table: HashTable, key_cols: Sequence[jnp.ndarray]):
-    """[N, 2S] candidate slot ids + occupancy + key match per row."""
+def _fingerprint(h: jnp.ndarray) -> jnp.ndarray:
+    """uint32 [N], never 0: the top 31 bits of one more multiply-xorshift
+    round over the key hash, | 1. The round is a bijection of h that moves
+    every bit, so two keys that share both buckets (same residues of h's
+    halves) still draw independent fingerprints. (Tests replace this with
+    degenerate functions to drive the exact fallback.)"""
+    x = (h ^ (h >> jnp.uint64(32))) * jnp.uint64(0xD6E8FEB86659FD93)
+    x = (x ^ (x >> jnp.uint64(32))) * jnp.uint64(0xD6E8FEB86659FD93)
+    return (x >> jnp.uint64(32)).astype(jnp.uint32) | jnp.uint32(1)
+
+
+def _probe(table: HashTable, key_cols: Sequence[jnp.ndarray],
+           active: jnp.ndarray):
+    """Slot of each active row's key (int32 [N], -1 if absent) by ONE
+    [N, 2S] gather of the fingerprint lane; see the module docstring for
+    why the answer is exact. Also returns what an insert needs: the bucket
+    pair, the tiebreak, both buckets' fills, the rows' own fingerprints,
+    and n_fallback — the active rows that needed more than the fingerprint
+    lane and one verify."""
     S = BUCKET_SLOTS
     B = table.capacity // S
     N = key_cols[0].shape[0]
-    h1, h2, tie = _bucket_pair(key_cols, B)
-    bases = jnp.stack([h1 * S, h2 * S], axis=1)            # [N, 2]
-    cand = (bases[:, :, None] + jnp.arange(S, dtype=jnp.int32)).reshape(N, 2 * S)
-    occ = table.occupied[cand]
-    match = occ
-    for tk, k in zip(table.keys, key_cols):
-        match = match & (tk[cand] == k[:, None])
-    return h1, h2, tie, cand, occ, match
+    h = _key_hash(key_cols)
+    h1, h2, tie = _bucket_pair(h, B)
+    fp = _fingerprint(h)
+    # the one gather: both candidate buckets' fingerprints, lanes [0, S) of
+    # bucket h1 then [S, 2S) of bucket h2 — 2N row indices, not 2S x N
+    # element indices (30x cheaper on the chip, module docstring)
+    lanes = table.fingerprint.reshape(B, S)[
+        jnp.stack([h1, h2], axis=1)].reshape(N, 2 * S)
+    fill1 = (lanes[:, :S] != 0).sum(axis=1, dtype=jnp.int32)
+    fill2 = (lanes[:, S:] != 0).sum(axis=1, dtype=jnp.int32)
+    fm = lanes == fp[:, None]          # fp != 0, so a match is occupied
+    n_match = fm.sum(axis=1, dtype=jnp.int32)
+    lane_ids = jnp.arange(2 * S, dtype=jnp.int32)
+
+    def verify(lane, rows):
+        """Compare the full key at one candidate lane per row: [N] gathers."""
+        slot = jnp.where(lane < S, h1, h2) * S + lane % S
+        eq = rows
+        for tk, k in zip(table.keys, key_cols):
+            eq = eq & (tk[slot] == k)
+        return slot, eq
+
+    lane = jnp.argmax(fm, axis=1).astype(jnp.int32)
+    slot, hit = verify(lane, active & (n_match > 0))
+    found = jnp.where(hit, slot, -1)
+    # keys are unique in the table: a verified slot is final, and a row
+    # with no match left to verify is absent. What remains is a row whose
+    # first fingerprint match held another key and that has more matches.
+    pending = active & ~hit & (n_match > 1)
+    n_fallback = jnp.sum(pending.astype(jnp.int32))
+
+    def walk(carry):
+        found, lane, tried, pending = carry
+        rest = fm & (lane_ids > lane[:, None])
+        lane = jnp.argmax(rest, axis=1).astype(jnp.int32)
+        slot, hit = verify(lane, pending)
+        found = jnp.where(hit, slot, found)
+        tried = tried + 1
+        return found, lane, tried, pending & ~hit & (n_match > tried)
+
+    found, _, _, _ = jax.lax.while_loop(
+        lambda c: c[3].any(), walk, (found, lane, jnp.int32(1), pending))
+    return found, h1, h2, tie, fill1, fill2, fp, n_fallback
 
 
 def lookup(table: HashTable, key_cols: Sequence[jnp.ndarray],
            active: jnp.ndarray, max_probes: int = 0):
     """Read-only probe: slot of each active row's key, -1 if absent.
 
-    One vectorized compare against both candidate buckets — constant cost.
+    One fingerprint gather over both candidate buckets — constant cost.
     (`max_probes` is accepted for API compatibility; probing is inherently
     bounded by the bucket shape.)
     """
-    _, _, _, cand, _, match = _candidates(table, key_cols)
-    has = match.any(axis=1)
-    sel = jnp.argmax(match, axis=1)
-    slot = jnp.take_along_axis(cand, sel[:, None], axis=1)[:, 0]
-    return jnp.where(active & has, slot, -1)
+    return _probe(table, key_cols, active)[0]
 
 
 def lookup_or_insert(table: HashTable, key_cols: Sequence[jnp.ndarray],
                      active: jnp.ndarray, max_probes: int = 0):
+    """`lookup_or_insert_counted` without the fallback count."""
+    return lookup_or_insert_counted(table, key_cols, active)[:3]
+
+
+def lookup_or_insert_counted(table: HashTable,
+                             key_cols: Sequence[jnp.ndarray],
+                             active: jnp.ndarray):
     """Find or claim a slot for every active row.
 
     key_cols: [N] arrays matching table.keys dtypes; active: bool [N]
     (invisible rows resolve immediately to slot -1).
 
     Returns (table', slots int32 [N] (-1 for inactive/unresolved),
-    n_unresolved int32 scalar). n_unresolved > 0 means both candidate
-    buckets of some new key are full — the caller must rebuild larger and
-    retry (two-choice balancing makes this improbable below ~0.7 load).
+    n_unresolved int32 scalar, n_fallback int32 scalar). n_unresolved > 0
+    means both candidate buckets of some new key are full — the caller must
+    rebuild larger and retry (two-choice balancing makes this improbable
+    below ~0.7 load). n_fallback is `_probe`'s: rows the fingerprint lane
+    and one verify did not settle (resolved exactly all the same).
 
-    Insert algorithm (no data-dependent loops):
-      1. match pass as in `lookup`;
+    Insert algorithm (no data-dependent loops past the probe):
+      1. probe as in `lookup`;
       2. first device sort groups missing rows by key (in-chunk dedup:
          each distinct new key forms a run, its first row is the leader);
       3. each leader picks the emptier of its two buckets (pre-chunk fill —
-         within-bucket occupancy is a prefix, so fill = occ.sum);
+         within-bucket occupancy is a prefix, so fill = occupied count);
       4. second device sort ranks leaders within their chosen bucket, the
          run's slot = bucket*S + fill + rank;
-      5. scatter keys/occupancy for leaders; run members inherit the
+      5. scatter keys + fingerprint for leaders; run members inherit the
          leader's slot via a segmented gather; unsort.
     """
     S = BUCKET_SLOTS
@@ -215,13 +310,10 @@ def lookup_or_insert(table: HashTable, key_cols: Sequence[jnp.ndarray],
     N = key_cols[0].shape[0]
     row_ids = jnp.arange(N, dtype=jnp.int32)
 
-    h1, h2, tie, cand, occ, match = _candidates(table, key_cols)
-    has = match.any(axis=1)
-    msel = jnp.argmax(match, axis=1)
-    mslot = jnp.take_along_axis(cand, msel[:, None], axis=1)[:, 0]
+    mslot, h1, h2, tie, fill1, fill2, fp, n_fallback = _probe(
+        table, key_cols, active)
+    has = mslot >= 0
 
-    fill1 = occ[:, :S].sum(axis=1, dtype=jnp.int32)
-    fill2 = occ[:, S:].sum(axis=1, dtype=jnp.int32)
     choose2 = (fill2 < fill1) | ((fill2 == fill1) & tie)
     c_bucket = jnp.where(choose2, h2, h1)
     c_fill = jnp.minimum(fill1, fill2)
@@ -266,19 +358,19 @@ def lookup_or_insert(table: HashTable, key_cols: Sequence[jnp.ndarray],
             jnp.where(is_leader, slot_s1, -1), mode="drop")
     s_ins_slot = jnp.where(s_miss, leader_slot_by_run[run_id], -1)
 
-    # ---- write leaders' keys/occupancy ----
+    # ---- write leaders' keys + fingerprint (which IS the occupancy) ----
     w_idx = jnp.where(r_ok, r_slot, C)
     orig2 = order[order2]                          # sorted-2 -> original row
     keys = tuple(tk.at[w_idx].set(k[orig2], mode="drop")
                  for tk, k in zip(table.keys, key_cols))
-    occupied = table.occupied.at[w_idx].set(True, mode="drop")
+    fingerprint = table.fingerprint.at[w_idx].set(fp[orig2], mode="drop")
 
     # ---- unsort + combine ----
     ins_slot = jnp.zeros(N, dtype=jnp.int32).at[order].set(s_ins_slot)
     slots = jnp.where(has, mslot, jnp.where(miss, ins_slot, -1))
     slots = jnp.where(active, slots, -1)
     n_unresolved = jnp.sum((active & (slots < 0)).astype(jnp.int32))
-    return HashTable(keys, occupied), slots, n_unresolved
+    return HashTable(keys, fingerprint), slots, n_unresolved, n_fallback
 
 
 def load(table: HashTable) -> jnp.ndarray:

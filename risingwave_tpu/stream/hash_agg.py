@@ -43,11 +43,13 @@ from ..ops.extrema import (
 from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
 from ..ops.hash_table import (
-    BUCKET_SLOTS, HashTable, compact_mask, lookup_or_insert, lru_stamp,
+    BUCKET_SLOTS, HashTable, compact_mask, lookup_or_insert,
+    lookup_or_insert_counted, lru_stamp,
     needs_rebuild,
 )
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
+from ..utils.metrics import HASH_PROBE_FALLBACK_ROWS
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
 
@@ -206,10 +208,14 @@ class HashAggExecutor(Executor):
                                      donate_argnums=(0,),
                                      name="hash_agg_mem_rehash")
         self._mem_reloads: dict[int, object] = {}
-        self._overflow_dev = jnp.zeros((), dtype=jnp.int32)
+        # device-accumulated watchdog counters, int32 [2]: rows the table
+        # could not place or fold (fail-stop), rows whose probe went past
+        # the fingerprint lane and one verify (hash_table._probe; a metric)
+        self._overflow_dev = jnp.zeros(2, dtype=jnp.int32)
+        self._probe_fallback_seen = 0
         self._occ_dev = jnp.zeros((), dtype=jnp.int32)
         self._watchdog_pack = jit_state(
-            lambda ov, occ: jnp.stack([ov, occ]),
+            lambda ov, occ: jnp.stack([ov[0], occ, ov[1]]),
             name="hash_agg_watchdog_pack")
 
     def fence_tokens(self) -> list:
@@ -256,7 +262,7 @@ class HashAggExecutor(Executor):
     # ------------------------------------------------------- chunk apply
     def _apply_impl(self, state: AggState, overflow, chunk: StreamChunk):
         key_cols = [chunk.columns[i].data for i in self.group_key_indices]
-        table, slots, n_unresolved = lookup_or_insert(
+        table, slots, n_unresolved, n_fallback = lookup_or_insert_counted(
             state.table, key_cols, chunk.vis)
         C = table.capacity
         ok = slots >= 0
@@ -302,7 +308,8 @@ class HashAggExecutor(Executor):
         # keep the accumulator's dtype stable (the segment sums promote to
         # int64): donation can only reuse the input buffer — and lax.scan
         # only accepts the carry — when the dtype round-trips
-        overflow = (overflow + n_unresolved + n_err).astype(overflow.dtype)
+        overflow = (overflow + jnp.stack(
+            [n_unresolved + n_err, n_fallback])).astype(overflow.dtype)
         return new_state, overflow, occ
 
     # ---------------------------------------------------------- flush
@@ -460,10 +467,10 @@ class HashAggExecutor(Executor):
 
     def _check_watchdog(self) -> None:
         """ONE small blocking fetch of the device-accumulated (overflow,
-        occupied) pair — called per BARRIER, never per chunk. The counters
-        accumulate on device across the epoch; fetching them per chunk
-        gates throughput on d2h copy latency, so the fetch is a plain
-        blocking np.asarray of two scalars, once per barrier.
+        occupied, probe fallback) triple — called per BARRIER, never per
+        chunk. The counters accumulate on device across the epoch; fetching
+        them per chunk gates throughput on d2h copy latency, so the fetch
+        is a plain blocking np.asarray of three scalars, once per barrier.
 
         Overflow fail-stops BEFORE this epoch's checkpoint commits, so a
         chunk the table dropped rows from is never made durable; recovery
@@ -472,6 +479,7 @@ class HashAggExecutor(Executor):
         watchdog."""
         vals = np.asarray(self._watchdog_pack(self._overflow_dev,
                                               self._occ_dev))
+        self._note_probe_fallback(int(vals[2]))
         n_un = int(vals[0])
         if n_un:
             raise RuntimeError(
@@ -479,6 +487,12 @@ class HashAggExecutor(Executor):
                 f"capacity {self.capacity}); recovery must replay the "
                 f"epoch with a larger table")
         self._occ_known = int(vals[1])
+
+    def _note_probe_fallback(self, total: int) -> None:
+        """Publish the device's running fallback-row count as the increase
+        since the last watchdog fetch."""
+        HASH_PROBE_FALLBACK_ROWS.inc(total - self._probe_fallback_seen)
+        self._probe_fallback_seen = total
 
     def _maybe_rebuild_at_barrier(self) -> None:
         """Barrier-time growth: the table is examined between epochs, when
@@ -753,7 +767,8 @@ class HashAggExecutor(Executor):
 
     def _mem_reload_impl(self, state: AggState, overflow, key_cols,
                          call_cols, row_count, active):
-        table, slots, n_un = lookup_or_insert(state.table, key_cols, active)
+        table, slots, n_un, n_fb = lookup_or_insert_counted(
+            state.table, key_cols, active)
         C = table.capacity
         ok = active & (slots >= 0)
         tgt = jnp.where(ok, slots, C)
@@ -782,7 +797,7 @@ class HashAggExecutor(Executor):
             dirty=state.dirty.at[tgt].set(True, mode="drop"),
             prev_exists=state.prev_exists.at[tgt].set(True, mode="drop"),
             prev_emit=tuple(prev_emit),
-        ), (overflow + n_un).astype(overflow.dtype)
+        ), (overflow + jnp.stack([n_un, n_fb])).astype(overflow.dtype)
 
     def _clean_spilled(self, wm) -> None:
         """Watermark state cleaning of EVICTED ranges: spilled keys below

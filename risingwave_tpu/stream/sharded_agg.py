@@ -244,7 +244,8 @@ class ShardedHashAggExecutor(HashAggExecutor):
             max_occ = jax.lax.pmax(occ[0].astype(jnp.int32), VNODE_AXIS)
             total_dr = jax.lax.psum(dr[0], VNODE_AXIS)
             max_fill = jax.lax.pmax(so[0].astype(jnp.int32), VNODE_AXIS)
-            return jnp.stack([total_ov, max_occ, total_dr, max_fill])[None]
+            return jnp.stack([total_ov[0], max_occ, total_dr, max_fill,
+                              total_ov[1]])[None]
 
         self._watchdog_pack = jit_state(shard_map(
             watchdog_sharded, in_specs=(shard, shard, shard, shard),
@@ -269,7 +270,7 @@ class ShardedHashAggExecutor(HashAggExecutor):
         # per-shard watchdog accumulators replace the parent's scalars
         sharding = NamedSharding(mesh, P(VNODE_AXIS))
         self._overflow_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
+            jnp.zeros((self.n_shards, 2), dtype=jnp.int32), sharding)
         self._occ_dev = jax.device_put(
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
         self._dropped_dev = jax.device_put(
@@ -679,6 +680,7 @@ class ShardedHashAggExecutor(HashAggExecutor):
                                               self._send_occ_dev))[0]
         n_un, occ, n_drop, fill = (int(vals[0]), int(vals[1]),
                                    int(vals[2]), int(vals[3]))
+        self._note_probe_fallback(int(vals[4]))
         self._note_send_fill(fill)
         # the pack donated nothing, but the interval's demand signal is
         # consumed: start the next observation window from zero

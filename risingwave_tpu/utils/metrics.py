@@ -275,6 +275,13 @@ CHECKPOINT_BACKPRESSURE_SECONDS = GLOBAL_METRICS.counter(
 D2H_BYTES = GLOBAL_METRICS.counter("d2h_bytes_total")
 D2H_FETCHES = GLOBAL_METRICS.counter("d2h_fetch_count")
 
+# Hash-table probe (ops/hash_table._probe): rows that needed more than the
+# fingerprint lane and one key verify. Counted on the device; HashAgg
+# publishes it with its per-barrier watchdog fetch. Expected 0 in real runs
+# (~1 per 10^8 probed rows); a steady rate means a degenerate fingerprint.
+HASH_PROBE_FALLBACK_ROWS = GLOBAL_METRICS.counter(
+    "hash_probe_fallback_rows_total")
+
 # HBM memory manager (memory/manager.py): exact accounted device-state
 # bytes vs. the configured budget, plus eviction/reload activity. The
 # global series always render; per-executor `hbm_state_bytes{executor=..}`

@@ -398,6 +398,103 @@ async def test_persist_and_recover():
     assert by_key[2] == [(OP_DELETE, (2, 1, 5))]
 
 
+async def test_recover_rebuilds_fingerprints_and_keeps_probing():
+    """Durable state, crash, recover(), then chunks that hit recovered keys
+    and bring new ones: the MV equals a host recount. Only keys and agg
+    state are persisted; `_state_from_rows` re-inserts them, which is what
+    writes the recovered table's fingerprint lane (q5's key shape: two
+    int64 group keys)."""
+    from risingwave_tpu.ops import hash_table as ht
+    sch = schema(("auction", DataType.INT64), ("win", DataType.INT64),
+                 ("v", DataType.INT64))
+    store = MemoryStateStore()
+
+    def make_table():
+        return StateTable(
+            store, table_id=11,
+            schema=schema(("auction", DataType.INT64),
+                          ("win", DataType.INT64),
+                          ("count", DataType.INT64), ("sum", DataType.INT64),
+                          ("_row_count", DataType.INT64)),
+            pk_indices=[0, 1])
+
+    rng = np.random.default_rng(5)
+
+    def rows(lo, hi, n):
+        return [(int(a) << 33, int(w) * 2_000_000, int(v)) for a, w, v in zip(
+            rng.integers(lo, hi, n), rng.integers(0, 5, n),
+            rng.integers(1, 100, n))]
+
+    def as_chunk(rs):
+        cols = [np.asarray(c, dtype=np.int64) for c in zip(*rs)]
+        return StreamChunk.from_numpy(sch, cols, capacity=256)
+
+    mv, oracle = {}, {}
+
+    async def run(messages):
+        agg = HashAggExecutor(ScriptSource(sch, messages), [0, 1],
+                              [count_star(), agg_sum(2)], capacity=1024,
+                              state_table=make_table())
+        async for m in agg.execute():
+            if isinstance(m, StreamChunk):
+                for op, row in m.to_rows():
+                    if op in (OP_INSERT, OP_UPDATE_INSERT):
+                        mv[row[:2]] = row[2:]
+                    else:
+                        assert mv.pop(row[:2]) == row[2:]
+        return agg
+
+    def count(rs):
+        for a, w, v in rs:
+            c, s_ = oracle.get((a, w), (0, 0))
+            oracle[(a, w)] = (c + 1, s_ + v)
+
+    first = [rows(0, 60, 200), rows(0, 60, 200)]
+    for rs in first:
+        count(rs)
+    await run([barrier(1, 0, BarrierKind.INITIAL), as_chunk(first[0]),
+               barrier(2, 1), as_chunk(first[1]), barrier(3, 2)])
+    assert mv == oracle and len(mv) > 150
+
+    # the process is gone; a new executor recovers from the store at its
+    # INITIAL barrier, then sees old keys (0..60) and new ones (60..120)
+    second = [rows(0, 120, 200), rows(30, 120, 200)]
+    for rs in second:
+        count(rs)
+    agg = await run([barrier(4, 3, BarrierKind.INITIAL), as_chunk(second[0]),
+                     barrier(5, 4), as_chunk(second[1]), barrier(6, 5)])
+    assert mv == oracle and len(mv) > 300
+    t = agg.state.table
+    occ = np.asarray(t.occupied)
+    assert int(occ.sum()) == len(oracle)
+    np.testing.assert_array_equal(
+        np.asarray(t.fingerprint)[occ],
+        np.asarray(ht._fingerprint(ht._key_hash(list(t.keys))))[occ])
+    assert agg._probe_fallback_seen == 0
+
+
+async def test_constant_fingerprint_reaches_the_metric(monkeypatch):
+    """The fallback count accumulates on the device and is published with
+    the executor's per-barrier watchdog fetch; the answer stays exact."""
+    import jax.numpy as jnp
+    from risingwave_tpu.ops import hash_table as ht
+    from risingwave_tpu.utils.metrics import HASH_PROBE_FALLBACK_ROWS
+    monkeypatch.setattr(
+        ht, "_fingerprint", lambda h: jnp.full(h.shape, 7, dtype=jnp.uint32))
+    before = HASH_PROBE_FALLBACK_ROWS.value
+    msgs = [barrier(1, 0, BarrierKind.INITIAL),
+            chunk([(OP_INSERT, k, 1) for k in range(12)]),
+            barrier(2, 1),
+            chunk([(OP_INSERT, k, 1) for k in range(12)]),
+            barrier(3, 2)]
+    agg, out = await run_agg(msgs, [count_star()])
+    assert agg._probe_fallback_seen > 0
+    assert HASH_PROBE_FALLBACK_ROWS.value - before == agg._probe_fallback_seen
+    final = {row[0]: row[1] for op, row in emitted_rows(out)
+             if op in (OP_INSERT, OP_UPDATE_INSERT)}
+    assert final == {k: 2 for k in range(12)}
+
+
 async def test_watermark_state_cleaning():
     """Groups below the cleaning watermark are zeroed; reappearing keys at
     or above it stay correct (reference: state-cleaning watermarks,

@@ -253,4 +253,5 @@ def test_fused_sharded_agg_on_the_4_device_mesh(mesh4, no_persistent_cache,
     # s64 was refused)
     i32 = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded)
     i64 = jax.ShapeDtypeStruct((4,), jnp.int64, sharding=sharded)
-    ex._watchdog_pack._jitted.lower(i32, i64, i32, i32).compile()
+    ov = jax.ShapeDtypeStruct((4, 2), jnp.int32, sharding=sharded)
+    ex._watchdog_pack._jitted.lower(ov, i64, i32, i32).compile()
