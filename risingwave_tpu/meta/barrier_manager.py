@@ -197,6 +197,7 @@ class BarrierCoordinator:
         # per epoch. The registry makes that legible to /healthz, tests
         # and the mesh_profile gate.
         self.mesh_fragments: dict[int, tuple[int, str]] = {}
+        self._mesh_shuffle_labels: dict[int, tuple] = {}
         # ---- fused mesh CHAINS (plan/build.py _fuse_mesh_chains) ----
         # chain label -> {"fids": (producer..., consumer), "hollow": bool,
         # "consumer_actor": id}. A chain spans MULTIPLE fragments whose
@@ -264,11 +265,14 @@ class BarrierCoordinator:
         self.actor_ids.add(actor_id)
 
     def register_mesh_fragment(self, actor_id: int, n_shards: int,
-                               identity: str = "") -> None:
+                               identity: str = "",
+                               shuffle_labels=()) -> None:
         """A fused mesh fragment announces itself: `actor_id` is its ONE
-        collection unit covering all `n_shards` device shards."""
+        collection unit covering all `n_shards` device shards;
+        `shuffle_labels` name its executors' mesh_shuffle_* series."""
         from ..utils.metrics import GLOBAL_METRICS
         self.mesh_fragments[actor_id] = (int(n_shards), identity)
+        self._mesh_shuffle_labels[actor_id] = tuple(shuffle_labels)
         GLOBAL_METRICS.gauge("mesh_fragment_shards",
                              actor=str(actor_id)).set(float(n_shards))
 
@@ -301,6 +305,11 @@ class BarrierCoordinator:
             # per-actor streaming series)
             GLOBAL_METRICS.remove("mesh_fragment_shards",
                                   actor=str(actor_id))
+            from ..utils.metrics import (MESH_SHUFFLE_COUNTERS,
+                                         MESH_SHUFFLE_MAX_FILL)
+            for label in self._mesh_shuffle_labels.pop(actor_id, ()):
+                for name in (*MESH_SHUFFLE_COUNTERS, MESH_SHUFFLE_MAX_FILL):
+                    GLOBAL_METRICS.remove(name, executor=label)
 
     def split_enumerator(self, frag_key: int, factory):
         """One enumerator per source fragment, shared by its actors and

@@ -108,6 +108,21 @@ def shuffle_by_vnode(columns: Sequence[jnp.ndarray], vis: jnp.ndarray,
     return shuffle_rows(columns, vis, dest, axis_name, n_shards, cap_out)
 
 
+def shuffle_bytes(chunk: StreamChunk, key_indices, n_shards: int,
+                  cap_out: int) -> int:
+    """Bytes the all_to_all buffers of `mesh_ingest_chunk` hold for one
+    chunk, over all shards: n_shards^2 x cap_out rows of ops, every
+    column (data + validity) and visibility. Shapes and dtypes only, so
+    it can be asked while the program traces."""
+    if key_indices is None:
+        return 0
+    row = chunk.ops.dtype.itemsize + chunk.vis.dtype.itemsize + sum(
+        c.data.dtype.itemsize
+        + (c.valid.dtype.itemsize if c.valid is not None else 0)
+        for c in chunk.columns)
+    return n_shards * n_shards * cap_out * row
+
+
 def mesh_ingest_chunk(chunk: StreamChunk, key_indices, vnode_to_shard_table,
                       axis_name: str, n_shards: int, cap_out: int):
     """The fused exchange ingest (call INSIDE shard_map): this shard's

@@ -254,10 +254,19 @@ def _register_mesh(dep: Deployment, env: BuildEnv, root,
     reg = getattr(env.coord, "register_mesh_fragment", None)
     if reg is None:
         return
+    # the executors' mesh_shuffle_*{executor=...} series are named as the
+    # memory manager names its series, and go when the fragment does
+    scope = env.memory_scope or "flow"
+    labels = []
+    for ex in _iter_executor_chain(root):
+        if hasattr(ex, "take_mesh_interval"):
+            ex.mesh_label = f"{scope}/{ex.identity}@a{actor_id}"
+            labels.append(ex.mesh_label)
     for ex in _iter_executor_chain(root):
         n = getattr(ex, "n_shards", 0)
         if n and getattr(ex, "mesh", None) is not None:
-            reg(actor_id, n, getattr(ex, "identity", type(ex).__name__))
+            reg(actor_id, n, getattr(ex, "identity", type(ex).__name__),
+                labels)
             dep.mesh_actor_ids.append(actor_id)
             ilog = getattr(ex, "ingest_log", None)
             if ilog is not None and getattr(env, "partial_recovery",

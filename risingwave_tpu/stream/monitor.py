@@ -10,7 +10,9 @@ totals. This module is that subsystem for the TPU port:
   * `MetricLevel` — off | info | debug (SET metric_level ...);
   * `ActorObs` — one bundle of instruments per actor: row/chunk counts,
     busy vs. align-wait seconds, dispatch fanout, plus the interval
-    phase split (apply / persist / align) the EpochTrace shows;
+    phase split (apply / persist / align) the EpochTrace shows, and for
+    an actor whose chain holds a sharded executor what crossed the mesh
+    (mesh_rows / mesh_rows_max_shard / mesh_shuffle_bytes);
   * `ChannelObs` — queue depth + blocked-put (backpressure) seconds on
     every exchange channel feeding an actor;
   * `StreamingStats` — the per-coordinator registrar: `build_graph`
@@ -228,7 +230,7 @@ class ActorObs:
         "actor_id", "debug", "apply_ns", "persist_ns", "input_wait_ns",
         "fence_ns", "_row_acc", "row_count", "chunks_in", "chunks_out",
         "dispatch", "busy_seconds", "align_seconds", "keys",
-        "_occupancy", "registry", "children",
+        "_occupancy", "registry", "children", "mesh",
     )
 
     def __init__(self, registry: MetricsRegistry, actor_id: int,
@@ -245,6 +247,8 @@ class ActorObs:
         #                               cardinalities this interval)
         self._occupancy = []          # (executor_label, part, gauge, fn)
         self.children = []            # ExecutorObs, chain-walk order
+        self.mesh = []                # the chain's sharded executors
+        #                               (stream/mesh_shuffle.py)
         self.keys = []
         if debug:
             labels = dict(actor=str(actor_id), executor=executor_label)
@@ -301,6 +305,16 @@ class ActorObs:
         phases = {"apply_ns": self.apply_ns,
                   "persist_ns": self.persist_ns,
                   "align_ns": align_ns}
+        if self.mesh:
+            # what crossed the mesh this interval (rows received in all
+            # and by the fullest shard, all_to_all bytes), as the
+            # executors' barrier watchdog fetch brought it: host numbers
+            # by now. Of several mesh executors in one chain, the most
+            # skewed one's.
+            phases.update(max(
+                (ex.take_mesh_interval() for ex in self.mesh),
+                key=lambda iv: (iv["mesh_rows_max_shard"]
+                                / max(1, iv["mesh_rows"]))))
         if self.debug:
             if self._row_acc is not None:
                 self.row_count.inc(int(np.asarray(self._row_acc)))
@@ -440,6 +454,8 @@ class StreamingStats:
             else:
                 ex._exec_obs = None
         for ex in _iter_chain(root):
+            if hasattr(ex, "take_mesh_interval"):
+                obs.mesh.append(ex)
             if hasattr(ex, "barrier_queue") and hasattr(ex, "obs"):
                 # sources: barrier-queue wait is align (idle) time
                 ex.obs = obs

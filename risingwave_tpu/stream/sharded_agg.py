@@ -59,10 +59,11 @@ from ..common.chunk import StreamChunk
 from ..common.vnode import compute_vnodes
 from ..expr.agg import AggCall
 from ..ops.jit_state import jit_state
-from ..parallel.exchange import mesh_ingest_chunk, shuffle_cap_out
+from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
 from .executor import Executor
 from .hash_agg import AggState, HashAggExecutor
+from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 
 
 class MeshIngestLog:
@@ -113,7 +114,7 @@ class MeshIngestLog:
             + len(self._pending)
 
 
-class ShardedHashAggExecutor(HashAggExecutor):
+class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
     """HashAgg over `mesh`: state sharded on the vnode axis, input routed
     to its owner shard by the fused in-mesh shuffle (or replicated and
     masked as the fallback). `capacity` is PER SHARD."""
@@ -132,28 +133,8 @@ class ShardedHashAggExecutor(HashAggExecutor):
         self.n_shards = mesh.shape[VNODE_AXIS]
         self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
         self.mesh_shuffle = bool(mesh_shuffle)
-        self.mesh_shuffle_slack = int(mesh_shuffle_slack)
-        if self.mesh_shuffle_slack and watchdog_interval is None:
-            raise ValueError(
-                "mesh_shuffle_slack > 0 needs the barrier watchdog fetch "
-                "(watchdog_interval=1): shuffle drops would otherwise go "
-                "unchecked and a checkpoint could commit with rows "
-                "missing; transfer-free pipelines must use slack 0 "
-                "(zero-drop sizing)")
-        # adaptive shuffle slack (ROADMAP 3c): send-bucket capacity derived
-        # from OBSERVED per-destination demand (watchdog-fetched max fill,
-        # asymmetric EWMA + peak floor), instead of the manual slack var.
-        # Engages only under zero-drop default sizing (manual slack stays
-        # an override) and only with the watchdog fetch active — overflow
-        # under an adapted cap still fail-stops, recovery replays, and the
-        # fresh executor restarts at zero-drop sizing.
-        self.mesh_shuffle_adaptive = (bool(mesh_shuffle_adaptive)
-                                      and self.mesh_shuffle_slack == 0
-                                      and watchdog_interval is not None)
-        self._cap_hint: Optional[int] = None
-        self._fill_ewma = 0.0
-        self._fill_peak = 0
-        self._fill_obs = 0
+        self._init_mesh_shuffle(mesh_shuffle_slack, mesh_shuffle_adaptive,
+                                watchdog_interval is not None)
         # mesh-chain fusion (plan/build._fuse_mesh_chains): hollow producer
         # stage impls run INSIDE the fused program, before the shuffle
         self._mesh_preludes: tuple = ()
@@ -243,9 +224,11 @@ class ShardedHashAggExecutor(HashAggExecutor):
             total_ov = jax.lax.psum(ov[0], VNODE_AXIS)
             max_occ = jax.lax.pmax(occ[0].astype(jnp.int32), VNODE_AXIS)
             total_dr = jax.lax.psum(dr[0], VNODE_AXIS)
-            max_fill = jax.lax.pmax(so[0].astype(jnp.int32), VNODE_AXIS)
+            max_fill = jax.lax.pmax(so[0, OBS_FILL], VNODE_AXIS)
+            rows = jax.lax.psum(so[0, OBS_ROWS], VNODE_AXIS)
+            rows_max = jax.lax.pmax(so[0, OBS_ROWS], VNODE_AXIS)
             return jnp.stack([total_ov[0], max_occ, total_dr, max_fill,
-                              total_ov[1]])[None]
+                              total_ov[1], rows, rows_max])[None]
 
         self._watchdog_pack = jit_state(shard_map(
             watchdog_sharded, in_specs=(shard, shard, shard, shard),
@@ -275,10 +258,10 @@ class ShardedHashAggExecutor(HashAggExecutor):
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
         self._dropped_dev = jax.device_put(
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
-        # max send-bucket DEMAND seen since the last watchdog fetch — the
-        # adaptive slack signal (reset to fresh zeros at each fetch)
-        self._send_occ_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
+        # per shard, since the last watchdog fetch: the max send-bucket
+        # DEMAND (the adaptive slack signal) and the rows received from the
+        # shuffle (mesh_shuffle.py; fresh zeros at each fetch)
+        self._shuffle_obs_dev = self._fresh_shuffle_obs()
 
     # ------------------------------------------------ fused mesh shuffle
     def set_mesh_preludes(self, fns, chain: Optional[str] = None) -> None:
@@ -305,17 +288,7 @@ class ShardedHashAggExecutor(HashAggExecutor):
             from .monitor import mesh_host_round_trip
             mesh_host_round_trip(self.mesh_chain, n)
 
-    def _trace_cap(self, local_rows: int) -> int:
-        """Per-(src,dst) send capacity at TRACE time: the manual slack
-        override wins; otherwise the adaptive hint (2x pow2-quantized
-        observed peak demand) once enough barriers have been observed;
-        zero-drop sizing until then."""
-        if not self.mesh_shuffle_adaptive or self._cap_hint is None:
-            return shuffle_cap_out(local_rows, self.n_shards,
-                                   self.mesh_shuffle_slack)
-        return min(local_rows, max(64, self._cap_hint))
-
-    def _fused_step(self, state, overflow, dropped, chunk):
+    def _fused_step(self, state, overflow, dropped, obs, chunk):
         """One chunk's preludes + shuffle + apply, INSIDE shard_map
         (per-shard views; `chunk` fields are this shard's local [L] row
         slices). Hollow producer stages run here first — device-resident,
@@ -323,15 +296,18 @@ class ShardedHashAggExecutor(HashAggExecutor):
         transformed rows to their owner shards. Shapes are static under
         trace, so the per-pair send capacity re-derives per
         chunk-capacity signature (and per adaptive cap hint)."""
+        raw_rows = chunk.capacity
         for fn in self._mesh_preludes:
             chunk = fn(chunk)
         cap = self._trace_cap(chunk.capacity)
+        self._note_traced_shuffle(shuffle_bytes(
+            chunk, self.group_key_indices, self.n_shards, cap), raw_rows)
         local, n_drop, fill = mesh_ingest_chunk(
             chunk, self.group_key_indices, self._routing, VNODE_AXIS,
             self.n_shards, cap)
         st, ov, occ = self._apply_impl(state, overflow, local)
         return (st, ov, (dropped + n_drop).astype(dropped.dtype), occ,
-                fill)
+                fold_shuffle_obs(obs, fill, local.vis))
 
     def _get_fused_apply(self):
         prog = self._fused_applies.get(self._cap_hint)
@@ -339,10 +315,9 @@ class ShardedHashAggExecutor(HashAggExecutor):
             return prog
         shard = P(VNODE_AXIS)
 
-        def apply_fused(state, overflow, dropped, sendocc, chunk):
-            st, ov, dr, occ, fill = self._fused_step(
-                state, overflow[0], dropped[0], chunk)
-            so = jnp.maximum(sendocc[0], fill)
+        def apply_fused(state, overflow, dropped, obs, chunk):
+            st, ov, dr, occ, so = self._fused_step(
+                state, overflow[0], dropped[0], obs[0], chunk)
             return st, ov[None], dr[None], occ[None], so[None]
 
         prog = jit_state(shard_map(
@@ -360,20 +335,18 @@ class ShardedHashAggExecutor(HashAggExecutor):
         regardless of shard count."""
         shard = P(VNODE_AXIS)
 
-        def scan_body(state, overflow, dropped, sendocc, *chunks):
+        def scan_body(state, overflow, dropped, obs, *chunks):
             stacked = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *chunks)
 
             def step(carry, chunk):
                 st, ov, dr, so = carry
-                st, ov2, dr2, occ, fill = self._fused_step(
-                    st, ov, dr, chunk)
-                return (st, ov2.astype(ov.dtype), dr2,
-                        jnp.maximum(so, fill)), occ
+                st, ov2, dr2, occ, so2 = self._fused_step(
+                    st, ov, dr, so, chunk)
+                return (st, ov2.astype(ov.dtype), dr2, so2), occ
 
             (st, ov, dr, so), occs = jax.lax.scan(
-                step, (state, overflow[0], dropped[0], sendocc[0]),
-                stacked)
+                step, (state, overflow[0], dropped[0], obs[0]), stacked)
             return st, ov[None], dr[None], occs[-1][None], so[None]
 
         return jit_state(shard_map(
@@ -392,9 +365,10 @@ class ShardedHashAggExecutor(HashAggExecutor):
     def _apply_chunk_raw(self, chunk: StreamChunk) -> None:
         if self._fused_eligible(chunk):
             (self.state, self._overflow_dev, self._dropped_dev,
-             self._occ_dev, self._send_occ_dev) = self._get_fused_apply()(
+             self._occ_dev, self._shuffle_obs_dev) = self._get_fused_apply()(
                 self.state, self._overflow_dev, self._dropped_dev,
-                self._send_occ_dev, chunk)
+                self._shuffle_obs_dev, chunk)
+            self._count_shuffle_dispatch(chunk)
             self.mesh_shuffle_applies += 1
         else:
             # per-chunk host-plane fallback: a chain member couldn't stay
@@ -453,9 +427,10 @@ class ShardedHashAggExecutor(HashAggExecutor):
             scan = self._make_fused_scan(k)
             self._fused_scans[(k, self._cap_hint)] = scan
         (self.state, self._overflow_dev, self._dropped_dev,
-         self._occ_dev, self._send_occ_dev) = scan(
+         self._occ_dev, self._shuffle_obs_dev) = scan(
             self.state, self._overflow_dev, self._dropped_dev,
-            self._send_occ_dev, *p)
+            self._shuffle_obs_dev, *p)
+        self._count_shuffle_dispatch(p[0], chunks=k)
         self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
 
@@ -550,14 +525,15 @@ class ShardedHashAggExecutor(HashAggExecutor):
             cell["nev"] = 0
             if dev is not None:
                 cols, ops, vis, _ = dev
-                for sh in range(S):
-                    nd = int(counts[i + sh])
-                    if not nd:
-                        continue
+                # every shard's prefix at the largest shard's bucket, the
+                # empty ones too: the packed shapes repeat (d2h.py)
+                nd_max = int(max(counts[i:i + S]))
+                for sh in range(S if nd_max else 0):
                     lo = sh * C
                     groups.append((
                         [ops[lo:lo + C], vis[lo:lo + C]]
-                        + [c[lo:lo + C] for c in cols], nd))
+                        + [c[lo:lo + C] for c in cols],
+                        int(counts[i + sh]), nd_max))
                 cell["n_rows_groups"] = len(groups)
                 i += S
             if dev_evict is not None:
@@ -576,7 +552,8 @@ class ShardedHashAggExecutor(HashAggExecutor):
             if prep is not None:
                 outs = finish_prefix_groups(host_flat, prep[1], prep[2])
                 for seg in outs[:cell["n_rows_groups"]]:
-                    st.write_chunk_columns(seg[0], seg[2:], seg[1])
+                    if len(seg[0]):
+                        st.write_chunk_columns(seg[0], seg[2:], seg[1])
                 if cell["nev"]:
                     self._apply_evict_deletes(outs[-1], cell["nev"])
             st.commit(new_epoch)
@@ -651,54 +628,16 @@ class ShardedHashAggExecutor(HashAggExecutor):
     def memory_evict(self, target_bytes: int, epoch: int) -> int:
         return 0
 
-    def _note_send_fill(self, fill: int) -> None:
-        """Adaptive slack observation (barrier-collection cadence): track
-        the max per-destination send demand with an ASYMMETRIC EWMA —
-        jumps up instantly on a larger fill (overflow safety beats
-        smoothing), decays slowly on smaller ones — plus an all-time peak
-        floor. The cap hint is 2x the pow2-ceiling of the worst signal
-        and only engages after 3 observations, so caps never shrink below
-        twice the worst demand ever seen; a workload whose skew suddenly
-        doubles past that still fail-stops and replays at zero-drop."""
-        if not self.mesh_shuffle_adaptive:
-            return
-        if fill > self._fill_ewma:
-            self._fill_ewma = float(fill)
-        else:
-            self._fill_ewma = 0.8 * self._fill_ewma + 0.2 * fill
-        self._fill_peak = max(self._fill_peak, fill)
-        self._fill_obs += 1
-        if self._fill_obs < 3:
-            return
-        worst = max(self._fill_ewma, float(self._fill_peak), 1.0)
-        self._cap_hint = 1 << (int(2 * worst) - 1).bit_length()
-
     def _check_watchdog(self) -> None:
         vals = np.asarray(self._watchdog_pack(self._overflow_dev,
                                               self._occ_dev,
                                               self._dropped_dev,
-                                              self._send_occ_dev))[0]
+                                              self._shuffle_obs_dev))[0]
         n_un, occ, n_drop, fill = (int(vals[0]), int(vals[1]),
                                    int(vals[2]), int(vals[3]))
         self._note_probe_fallback(int(vals[4]))
-        self._note_send_fill(fill)
-        # the pack donated nothing, but the interval's demand signal is
-        # consumed: start the next observation window from zero
-        sharding = NamedSharding(self.mesh, P(VNODE_AXIS))
-        self._send_occ_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
-        if n_drop:
-            # fail-stop BEFORE this epoch's checkpoint commits: a row the
-            # shuffle dropped was never applied, so committing would make
-            # the loss durable and silent. Recovery replays from the last
-            # committed epoch; the slack needs raising (0 = zero-drop).
-            from ..utils.metrics import MESH_SHUFFLE_DROPPED
-            MESH_SHUFFLE_DROPPED.inc(n_drop)
-            raise RuntimeError(
-                f"mesh shuffle overflow: {n_drop} rows dropped en route "
-                f"to their owner shard (per-pair send capacity sized by "
-                f"mesh_shuffle_slack={self.mesh_shuffle_slack}; 0 = "
-                f"zero-drop sizing)")
+        self._publish_shuffle(int(vals[5]), int(vals[6]), fill)
+        self._fail_on_shuffle_drops(n_drop)
         if n_un:
             raise RuntimeError(
                 f"sharded hash-agg overflow ({n_un} rows, per-shard "

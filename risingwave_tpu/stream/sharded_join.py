@@ -40,10 +40,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..common.chunk import StreamChunk
 from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
-from ..parallel.exchange import mesh_ingest_chunk, shuffle_cap_out
+from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
 from .align import LEFT, RIGHT
 from .executor import Executor
+from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 from .sorted_join import SortedJoinExecutor, SortedSideState, _empty_sorted_side
 
 
@@ -57,7 +58,7 @@ def _vec_n(state: SortedSideState) -> SortedSideState:
                            state.degree, state.n.reshape((1,)))
 
 
-class ShardedSortedJoinExecutor(SortedJoinExecutor):
+class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
     def __init__(self, left: Executor, right: Executor, mesh: Mesh,
                  mesh_shuffle: bool = True, mesh_shuffle_slack: int = 0,
                  mesh_shuffle_adaptive: bool = True, **kwargs):
@@ -65,24 +66,11 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
         self.n_shards = mesh.shape[VNODE_AXIS]
         self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
         self.mesh_shuffle = bool(mesh_shuffle)
-        self.mesh_shuffle_slack = int(mesh_shuffle_slack)
-        if self.mesh_shuffle_slack \
-                and kwargs.get("watchdog_interval", 1) is None:
-            raise ValueError(
-                "mesh_shuffle_slack > 0 needs the barrier watchdog fetch "
-                "(watchdog_interval=1): shuffle drops would otherwise go "
-                "unchecked — transfer-free pipelines must use slack 0 "
-                "(zero-drop sizing)")
+        self._init_mesh_shuffle(
+            mesh_shuffle_slack, mesh_shuffle_adaptive,
+            kwargs.get("watchdog_interval", 1) is not None)
         self.mesh_shuffle_applies = 0
-        # adaptive shuffle slack + mesh-chain preludes: same contract as
-        # ShardedHashAggExecutor (the agg carries the full commentary)
-        self.mesh_shuffle_adaptive = (
-            bool(mesh_shuffle_adaptive) and self.mesh_shuffle_slack == 0
-            and kwargs.get("watchdog_interval", 1) is not None)
-        self._cap_hint = None
-        self._fill_ewma = 0.0
-        self._fill_peak = 0
-        self._fill_obs = 0
+        # mesh-chain preludes: same contract as ShardedHashAggExecutor
         self._mesh_preludes: dict = {}   # side -> tuple of prelude fns
         self.mesh_chain = None
         # mesh-plane replay point (sharded_agg.py MeshIngestLog): the
@@ -124,16 +112,20 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
         # owned rows. `dropped` (arg 3) accumulates shuffle overflow per
         # shard for the barrier watchdog's fail-stop.
         def make_apply_fused(side, mf, use_preludes):
-            def apply_fused(own, other, errs, dropped, sendocc, chunk,
-                            wm):
+            def apply_fused(own, other, errs, dropped, obs, chunk, wm):
                 # preludes transform RAW source chunks; recovery's state
                 # replay feeds rows already in join-input schema, so its
                 # trace (use_preludes=False) must skip them
                 pres = (self._mesh_preludes.get(side, ())
                         if use_preludes else ())
+                raw_rows = chunk.capacity
                 for fn in pres:
                     chunk = fn(chunk)
                 cap = self._trace_cap(chunk.capacity)
+                self._note_traced_shuffle(
+                    shuffle_bytes(chunk, self.key_indices[side],
+                                  self.n_shards, cap),
+                    raw_rows, side, mf, use_preludes)
                 local, n_drop, fill = mesh_ingest_chunk(
                     chunk, self.key_indices[side], self._routing,
                     VNODE_AXIS, self.n_shards, cap)
@@ -143,9 +135,9 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
                 own2, odeg, cols, ops, vis, errs2, _ = out
                 return (_vec_n(own2), odeg, cols, ops, vis, errs2[None],
                         (dropped[0] + n_drop)[None],
-                        jnp.maximum(sendocc[0], fill)[None],
+                        fold_shuffle_obs(obs[0], fill, local.vis)[None],
                         own2.n.reshape((1,)))
-            # donation: the error + shuffle-drop + send-demand
+            # donation: the error + shuffle-drop + shuffle-observation
             # accumulators (threaded); side states stay aliased by the
             # snapshot diff base (_snap)
             return jit_state(shard_map(
@@ -160,6 +152,17 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
         # replay buffer gets its own trace instead of being refused
         applies: dict = {}
 
+        def apply_program(side, mf, fused, use_pre):
+            # programs also key by the adaptive cap hint active at trace
+            # time (None = zero-drop sizing)
+            key = (side, mf, fused, self._cap_hint if fused else None,
+                   use_pre)
+            if key not in applies:
+                applies[key] = (make_apply_fused(side, mf, use_pre)
+                                if fused else make_apply(side, mf))
+            return applies[key]
+        self._apply_program = apply_program
+
         def apply_dispatch(own, other, errs, chunk, wm, side,
                            match_factor=None):
             mf = match_factor or self.match_factors[side]
@@ -168,13 +171,7 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
             # state replay (recover) feeds join-schema rows, not raw
             # source chunks: skip chain preludes AND the ingest log
             use_pre = not getattr(self, "_state_replay", False)
-            # programs also key by the adaptive cap hint active at trace
-            # time (None = zero-drop sizing)
-            key = (side, mf, fused, self._cap_hint if fused else None,
-                   use_pre)
-            if key not in applies:
-                applies[key] = (make_apply_fused(side, mf, use_pre)
-                                if fused else make_apply(side, mf))
+            prog = apply_program(side, mf, fused, use_pre)
             if fused:
                 # replay point: retain the ingest by reference before
                 # the fused program consumes it (sharded_agg.py
@@ -184,9 +181,10 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
                 if use_pre:
                     self.ingest_log.note((side, chunk))
                 (own2, odeg, cols, ops, vis, errs2, self._dropped_dev,
-                 self._send_occ_dev, n) = applies[key](
+                 self._shuffle_obs_dev, n) = prog(
                     own, other, errs, self._dropped_dev,
-                    self._send_occ_dev, chunk, wm)
+                    self._shuffle_obs_dev, chunk, wm)
+                self._count_shuffle_dispatch(chunk, side, mf, use_pre)
                 self.mesh_shuffle_applies += 1
                 return own2, odeg, cols, ops, vis, errs2, n
             # per-chunk host-plane fallback: hollowed producer stages (if
@@ -197,7 +195,7 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
             if use_pre and self.mesh_chain is not None:
                 from .monitor import mesh_host_round_trip
                 mesh_host_round_trip(self.mesh_chain)
-            return applies[key](own, other, errs, chunk, wm)
+            return prog(own, other, errs, chunk, wm)
         self._apply = apply_dispatch
 
         def set_mesh_preludes(side, fns, chain=None):
@@ -232,15 +230,17 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
         # would delete it out from under the watchdog fetch
         self._dropped_dev = jax.device_put(
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
-        self._send_occ_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
+        self._shuffle_obs_dev = self._fresh_shuffle_obs()
         self.sides = [self._sharded_empty(s) for s in (LEFT, RIGHT)]
-        # one packed fetch per barrier: summed errs + shuffle drops +
-        # max send-bucket demand (the adaptive slack signal)
+        # one packed fetch per barrier: summed errs + shuffle drops + the
+        # shuffle observations (max send-bucket demand = the adaptive slack
+        # signal; rows received in all and by the fullest shard)
         self._watchdog_pack_sh = jit_state(
             lambda errs, dr, so: jnp.concatenate(
                 [jnp.sum(errs, axis=0), jnp.sum(dr)[None],
-                 jnp.max(so)[None]]),
+                 jnp.max(so[:, OBS_FILL])[None],
+                 jnp.sum(so[:, OBS_ROWS])[None],
+                 jnp.max(so[:, OBS_ROWS])[None]]),
             name="sharded_join_watchdog_pack")
 
     def _sharded_empty(self, side: int) -> SortedSideState:
@@ -327,11 +327,15 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
             cell["counts"] = counts
             groups, ci = [], 0
             for _, diffs in pending:
+                # the shards of one side at the largest shard's bucket, so
+                # the packed shapes repeat from barrier to barrier (d2h.py)
+                side = counts[ci:ci + 2 * len(diffs)]
+                nd_max, ni_max = int(max(side[0::2])), int(max(side[1::2]))
                 for d in diffs:
                     nd, ni = int(counts[ci]), int(counts[ci + 1])
                     ci += 2
-                    groups.append((list(d[0]), nd))
-                    groups.append((list(d[2]), ni))
+                    groups.append((list(d[0]), nd, nd_max))
+                    groups.append((list(d[2]), ni, ni_max))
             cell["prep"] = prepare_prefix_groups(groups)
 
         def wait_flat():
@@ -409,49 +413,14 @@ class ShardedSortedJoinExecutor(SortedJoinExecutor):
         return [int(vals[:S].max()), int(vals[S:].max())]
 
     # --------------------------------------------------------- watchdog
-    def _trace_cap(self, local_rows: int) -> int:
-        """Send capacity at trace time: manual slack override, else the
-        adaptive hint (sharded_agg._trace_cap, same contract)."""
-        if not self.mesh_shuffle_adaptive or self._cap_hint is None:
-            return shuffle_cap_out(local_rows, self.n_shards,
-                                   self.mesh_shuffle_slack)
-        return min(local_rows, max(64, self._cap_hint))
-
-    def _note_send_fill(self, fill: int) -> None:
-        """Asymmetric EWMA + peak floor over the observed per-destination
-        demand (sharded_agg._note_send_fill carries the commentary)."""
-        if not self.mesh_shuffle_adaptive:
-            return
-        if fill > self._fill_ewma:
-            self._fill_ewma = float(fill)
-        else:
-            self._fill_ewma = 0.8 * self._fill_ewma + 0.2 * fill
-        self._fill_peak = max(self._fill_peak, fill)
-        self._fill_obs += 1
-        if self._fill_obs < 3:
-            return
-        worst = max(self._fill_ewma, float(self._fill_peak), 1.0)
-        self._cap_hint = 1 << (int(2 * worst) - 1).bit_length()
-
     def _check_watchdog(self) -> None:
         vals = np.asarray(self._watchdog_pack_sh(self._errs_dev,
                                                  self._dropped_dev,
-                                                 self._send_occ_dev))
-        n_mo, n_miss, n_ro, n_drop, fill = (int(x) for x in vals)
-        self._note_send_fill(fill)
-        sharding = NamedSharding(self.mesh, P(VNODE_AXIS))
-        self._send_occ_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
-        if n_drop:
-            # fail-stop before this epoch's checkpoint commits (same
-            # contract as the sharded agg's shuffle-overflow check)
-            from ..utils.metrics import MESH_SHUFFLE_DROPPED
-            MESH_SHUFFLE_DROPPED.inc(n_drop)
-            raise RuntimeError(
-                f"mesh shuffle overflow: {n_drop} rows dropped en route "
-                f"to their owner shard (per-pair send capacity sized by "
-                f"mesh_shuffle_slack={self.mesh_shuffle_slack}; 0 = "
-                f"zero-drop sizing)")
+                                                 self._shuffle_obs_dev))
+        n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max = (
+            int(x) for x in vals)
+        self._publish_shuffle(rows, rows_max, fill)
+        self._fail_on_shuffle_drops(n_drop)
         if n_mo:
             raise RuntimeError(
                 f"sharded-join match-buffer overflow ({n_mo} dropped)")

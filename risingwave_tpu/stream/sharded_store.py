@@ -46,14 +46,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..common.chunk import StreamChunk
 from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
-from ..parallel.exchange import mesh_ingest_chunk, shuffle_cap_out
+from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
 from .sharded_agg import MeshIngestLog
+from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 from .sorted_join import _HSENTINEL
 from .sorted_store import sorted_store_apply
 
 
-class ShardedSortedStoreMixin:
+class ShardedSortedStoreMixin(MeshShuffleHost):
     """Mesh plumbing over a (khash, cols, valids, n) sorted store plus a
     same-capacity secondary set. Subclasses (which also inherit the
     single-device executor) must provide:
@@ -78,21 +79,8 @@ class ShardedSortedStoreMixin:
         self.n_shards = mesh.shape[VNODE_AXIS]
         self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
         self.mesh_shuffle = bool(mesh_shuffle)
-        self.mesh_shuffle_slack = int(mesh_shuffle_slack)
-        if self.mesh_shuffle_slack and watchdog_interval is None:
-            raise ValueError(
-                "mesh_shuffle_slack > 0 needs the barrier watchdog fetch "
-                "(watchdog_interval=1): shuffle drops would otherwise go "
-                "unchecked and a checkpoint could commit with rows "
-                "missing; transfer-free pipelines must use slack 0 "
-                "(zero-drop sizing)")
-        self.mesh_shuffle_adaptive = (bool(mesh_shuffle_adaptive)
-                                      and self.mesh_shuffle_slack == 0
-                                      and watchdog_interval is not None)
-        self._cap_hint: Optional[int] = None
-        self._fill_ewma = 0.0
-        self._fill_peak = 0
-        self._fill_obs = 0
+        self._init_mesh_shuffle(mesh_shuffle_slack, mesh_shuffle_adaptive,
+                                watchdog_interval is not None)
         self._mesh_preludes: tuple = ()
         self.mesh_chain: Optional[str] = None
         self._replay_preload: list = []
@@ -131,7 +119,7 @@ class ShardedSortedStoreMixin:
         # shard) + the shuffle watchdog lanes, all mesh-sharded
         self._errs_dev = put(jnp.zeros(S * 2, dtype=jnp.int32))
         self._dropped_dev = put(jnp.zeros(S, dtype=jnp.int32))
-        self._send_occ_dev = put(jnp.zeros(S, dtype=jnp.int32))
+        self._shuffle_obs_dev = self._fresh_shuffle_obs()
 
     def _alloc_sharded_secondary(self) -> None:
         S, C = self.n_shards, self.capacity
@@ -192,9 +180,12 @@ class ShardedSortedStoreMixin:
             # only for SUM (sharded_agg.py watchdog_sharded)
             mx = jax.lax.pmax(n[0].astype(jnp.int32), VNODE_AXIS)
             td = jax.lax.psum(dr[0], VNODE_AXIS)
-            mf = jax.lax.pmax(so[0].astype(jnp.int32), VNODE_AXIS)
+            mf = jax.lax.pmax(so[0, OBS_FILL], VNODE_AXIS)
+            rows = jax.lax.psum(so[0, OBS_ROWS], VNODE_AXIS)
+            rows_max = jax.lax.pmax(so[0, OBS_ROWS], VNODE_AXIS)
             return jnp.concatenate(
-                [e, jnp.stack([mx, td, mf])]).astype(jnp.int32)[None]
+                [e, jnp.stack([mx, td, mf, rows, rows_max])]
+            ).astype(jnp.int32)[None]
 
         self._watchdog_pack = jit_state(shard_map(
             watchdog_sharded, in_specs=(shard,) * 4, out_specs=shard,
@@ -224,18 +215,16 @@ class ShardedSortedStoreMixin:
             from .monitor import mesh_host_round_trip
             mesh_host_round_trip(self.mesh_chain, n)
 
-    def _trace_cap(self, local_rows: int) -> int:
-        if not self.mesh_shuffle_adaptive or self._cap_hint is None:
-            return shuffle_cap_out(local_rows, self.n_shards,
-                                   self.mesh_shuffle_slack)
-        return min(local_rows, max(64, self._cap_hint))
-
-    def _fused_step(self, khash, cols, valids, n, errs, dropped, chunk):
+    def _fused_step(self, khash, cols, valids, n, errs, dropped, obs,
+                    chunk):
         """Preludes + in-mesh shuffle + sorted-store apply for ONE chunk,
         inside shard_map (per-shard views, scalar n/dropped)."""
+        raw_rows = chunk.capacity
         for fn in self._mesh_preludes:
             chunk = fn(chunk)
         cap = self._trace_cap(chunk.capacity)
+        self._note_traced_shuffle(shuffle_bytes(
+            chunk, self.route_key_indices, self.n_shards, cap), raw_rows)
         local, n_drop, fill = mesh_ingest_chunk(
             chunk, self.route_key_indices, self._routing, VNODE_AXIS,
             self.n_shards, cap)
@@ -243,7 +232,7 @@ class ShardedSortedStoreMixin:
             khash, cols, valids, n, errs, local,
             pk_idx=self.pk_indices, capacity=self.capacity)
         return kh, c, v, n2, e2, (dropped + n_drop).astype(dropped.dtype), \
-            fill
+            fold_shuffle_obs(obs, fill, local.vis)
 
     def _get_fused_apply(self):
         prog = self._fused_applies.get(self._cap_hint)
@@ -251,11 +240,9 @@ class ShardedSortedStoreMixin:
             return prog
         shard = P(VNODE_AXIS)
 
-        def apply_fused(khash, cols, valids, n, errs, dropped, sendocc,
-                        chunk):
-            kh, c, v, n2, e2, dr, fill = self._fused_step(
-                khash, cols, valids, n[0], errs, dropped[0], chunk)
-            so = jnp.maximum(sendocc[0], fill)
+        def apply_fused(khash, cols, valids, n, errs, dropped, obs, chunk):
+            kh, c, v, n2, e2, dr, so = self._fused_step(
+                khash, cols, valids, n[0], errs, dropped[0], obs[0], chunk)
             return kh, c, v, n2[None], e2, dr[None], so[None]
 
         prog = jit_state(shard_map(
@@ -272,21 +259,19 @@ class ShardedSortedStoreMixin:
         shard_map, each step shuffling then applying."""
         shard = P(VNODE_AXIS)
 
-        def scan_body(khash, cols, valids, n, errs, dropped, sendocc,
-                      *chunks):
+        def scan_body(khash, cols, valids, n, errs, dropped, obs, *chunks):
             stacked = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *chunks)
 
             def step(carry, chunk):
                 kh, c, v, nn, e, dr, so = carry
-                kh, c, v, n2, e2, dr2, fill = self._fused_step(
-                    kh, c, v, nn, e, dr, chunk)
-                return (kh, c, v, n2.astype(nn.dtype), e2, dr2,
-                        jnp.maximum(so, fill)), ()
+                kh, c, v, n2, e2, dr2, so2 = self._fused_step(
+                    kh, c, v, nn, e, dr, so, chunk)
+                return (kh, c, v, n2.astype(nn.dtype), e2, dr2, so2), ()
 
             (kh, c, v, nn, e, dr, so), _ = jax.lax.scan(
                 step, (khash, cols, valids, n[0], errs, dropped[0],
-                       sendocc[0]), stacked)
+                       obs[0]), stacked)
             return kh, c, v, nn[None], e, dr[None], so[None]
 
         return jit_state(shard_map(
@@ -302,11 +287,12 @@ class ShardedSortedStoreMixin:
     def _apply_chunk_raw(self, chunk: StreamChunk) -> None:
         if self._fused_eligible(chunk):
             (self.khash, self.cols, self.valids, self.n, self._errs_dev,
-             self._dropped_dev, self._send_occ_dev) = \
+             self._dropped_dev, self._shuffle_obs_dev) = \
                 self._get_fused_apply()(
                     self.khash, self.cols, self.valids, self.n,
                     self._errs_dev, self._dropped_dev,
-                    self._send_occ_dev, chunk)
+                    self._shuffle_obs_dev, chunk)
+            self._count_shuffle_dispatch(chunk)
             self.mesh_shuffle_applies += 1
         else:
             # per-chunk host-plane fallback: hollowed producer stages run
@@ -350,9 +336,10 @@ class ShardedSortedStoreMixin:
             scan = self._make_fused_scan(k)
             self._fused_scans[(k, self._cap_hint)] = scan
         (self.khash, self.cols, self.valids, self.n, self._errs_dev,
-         self._dropped_dev, self._send_occ_dev) = scan(
+         self._dropped_dev, self._shuffle_obs_dev) = scan(
             self.khash, self.cols, self.valids, self.n, self._errs_dev,
-            self._dropped_dev, self._send_occ_dev, *p)
+            self._dropped_dev, self._shuffle_obs_dev, *p)
+        self._count_shuffle_dispatch(p[0], chunks=k)
         self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
 
@@ -390,21 +377,11 @@ class ShardedSortedStoreMixin:
         self._drain_pending()
         vals = np.asarray(self._watchdog_pack(
             self._errs_dev, self.n, self._dropped_dev,
-            self._send_occ_dev))[0]
-        n_ovf, n_miss, max_n, n_drop, fill = (int(vals[0]), int(vals[1]),
-                                              int(vals[2]), int(vals[3]),
-                                              int(vals[4]))
-        self._note_send_fill(fill)
-        self._send_occ_dev = jax.device_put(
-            jnp.zeros(self.n_shards, dtype=jnp.int32), self._sharding())
-        if n_drop:
-            from ..utils.metrics import MESH_SHUFFLE_DROPPED
-            MESH_SHUFFLE_DROPPED.inc(n_drop)
-            raise RuntimeError(
-                f"mesh shuffle overflow: {n_drop} rows dropped en route "
-                f"to their owner shard (per-pair send capacity sized by "
-                f"mesh_shuffle_slack={self.mesh_shuffle_slack}; 0 = "
-                f"zero-drop sizing)")
+            self._shuffle_obs_dev))[0]
+        (n_ovf, n_miss, max_n, n_drop, fill, rows,
+         rows_max) = (int(x) for x in vals)
+        self._publish_shuffle(rows, rows_max, fill)
+        self._fail_on_shuffle_drops(n_drop)
         if n_ovf:
             raise RuntimeError(
                 f"{self._overflow_what} overflow ({n_ovf} rows dropped; "
@@ -413,23 +390,6 @@ class ShardedSortedStoreMixin:
             raise RuntimeError(
                 f"{self._overflow_what}: {n_miss} deletes matched no row")
         self._occ_known = max_n
-
-    def _note_send_fill(self, fill: int) -> None:
-        """Adaptive shuffle slack — identical policy to the sharded agg
-        (asymmetric EWMA + all-time peak floor, 2x pow2 cap hint after
-        3 observations)."""
-        if not self.mesh_shuffle_adaptive:
-            return
-        if fill > self._fill_ewma:
-            self._fill_ewma = float(fill)
-        else:
-            self._fill_ewma = 0.8 * self._fill_ewma + 0.2 * fill
-        self._fill_peak = max(self._fill_peak, fill)
-        self._fill_obs += 1
-        if self._fill_obs < 3:
-            return
-        worst = max(self._fill_ewma, float(self._fill_peak), 1.0)
-        self._cap_hint = 1 << (int(2 * worst) - 1).bit_length()
 
     def persist(self, barrier, flushed) -> None:
         # stamp the interval's replay point with the epoch this barrier

@@ -98,11 +98,20 @@ def prepare_prefix_groups(groups):
     arrays to the pow2 bucket of its host-known prefix length and pack
     everything into ONE packed `flat` payload (pack_for_fetch). Returns
     (flat, metas, group_meta) for `finish_prefix_groups`. MUST run on
-    the event-loop thread — it dispatches device ops (see fetch_flat)."""
+    the event-loop thread — it dispatches device ops (see fetch_flat).
+
+    A group is `(arrays, n)` or `(arrays, n, bucket_n)`: sliced to the
+    bucket of `bucket_n >= n`, trimmed to `n` on the host. The sharded
+    executors give the shards of one payload the SAME `bucket_n` (the
+    largest shard's count): per-shard counts sit around total / shards,
+    which for a pow2 chunk is itself a bucket edge, so per-group buckets
+    flip shard by shard and every new combination of lengths is a new
+    eager concatenate program (found on four real chips, PR 26: 6 s of
+    compile inside a 48 s window)."""
     sliced, meta = [], []
-    for arrays, n in groups:
+    for arrays, n, *bucket_n in groups:
         cap = int(arrays[0].shape[0]) if arrays else 0
-        b = _bucket(int(n), cap)
+        b = _bucket(int(bucket_n[0] if bucket_n else n), cap)
         for a in arrays:
             sliced.append(a[:b])
         meta.append((len(arrays), int(n)))

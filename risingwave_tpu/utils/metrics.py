@@ -322,16 +322,44 @@ SERVING_TIMEOUTS = GLOBAL_METRICS.counter("serving_timeouts_total")
 BARRIER_STALLS = GLOBAL_METRICS.counter("barrier_stalls_total")
 
 # Mesh-parallel fragment execution (parallel/exchange.py +
-# stream/sharded_*.py): rows the in-mesh all_to_all shuffle dropped
-# because a (src, dst) send bucket overflowed its per-pair capacity
-# (streaming_mesh_shuffle_slack sized it too tight for the key skew).
-# Nonzero is a FAIL-STOP: the owning executor raises at the barrier
-# watchdog fetch before the epoch's checkpoint commits, so a dropped
-# row is never silently absent from durable state.
-# `mesh_fragment_shards{actor=...}` gauges ride alongside once fused
-# mesh fragments register with the barrier coordinator.
+# stream/sharded_*.py, host half in stream/mesh_shuffle.py). The series:
+# - `mesh_shuffle_dropped_rows_total`: rows the in-mesh all_to_all
+#   shuffle dropped because a (src, dst) send bucket overflowed its
+#   per-pair capacity (streaming_mesh_shuffle_slack sized it too tight
+#   for the key skew). Nonzero is a FAIL-STOP: the owning executor
+#   raises at the barrier watchdog fetch before the epoch's checkpoint
+#   commits, so a dropped row is never silently absent from durable
+#   state.
+# - `mesh_shuffle_rows_total`: rows the shards received from the
+#   shuffle; `mesh_shuffle_max_shard_rows_total`: per barrier interval
+#   the rows of the shard that received most, summed over intervals (so
+#   shards x max / rows is the skew: 1 balanced, the shard count when
+#   one shard gets everything); `mesh_shuffle_bytes_total`: the bytes
+#   the all_to_all buffers held (shards^2 x send capacity x row bytes a
+#   chunk, from the traced shapes). Process totals here, and the same
+#   names `{executor=...}` per sharded executor with the gauge
+#   `mesh_shuffle_max_fill{executor=...}` (largest per-(src, dst) send
+#   demand of the last interval: the adaptive slack's signal). All of
+#   them ride the watchdog fetch the barrier makes anyway; the labelled
+#   ones go when their fragment does.
+# - `mesh_fragment_shards{actor=...}`, `mesh_chain_fragments{chain=...}`,
+#   `mesh_host_round_trips_total{chain=...}`: set when a fused mesh
+#   fragment / chain registers with the barrier coordinator
+#   (meta/barrier_manager.py), removed when it is dropped.
 MESH_SHUFFLE_DROPPED = GLOBAL_METRICS.counter(
     "mesh_shuffle_dropped_rows_total")
+MESH_SHUFFLE_ROWS = GLOBAL_METRICS.counter("mesh_shuffle_rows_total")
+MESH_SHUFFLE_MAX_SHARD_ROWS = GLOBAL_METRICS.counter(
+    "mesh_shuffle_max_shard_rows_total")
+MESH_SHUFFLE_BYTES = GLOBAL_METRICS.counter("mesh_shuffle_bytes_total")
+MESH_SHUFFLE_MAX_FILL = "mesh_shuffle_max_fill"        # gauge, labelled only
+# name -> process total, of the counters a sharded executor also keeps
+# under `{executor=...}`
+MESH_SHUFFLE_COUNTERS = {
+    "mesh_shuffle_rows_total": MESH_SHUFFLE_ROWS,
+    "mesh_shuffle_max_shard_rows_total": MESH_SHUFFLE_MAX_SHARD_ROWS,
+    "mesh_shuffle_bytes_total": MESH_SHUFFLE_BYTES,
+}
 
 # Recovery plane (frontend/session.py): every auto-recovery increments
 # `recovery_total{scope=fragment|cone|mesh|worker|full,cause=...}`

@@ -45,7 +45,11 @@ class EpochTrace:
     # phase split reported by the actor at its collect (stream/actor.py):
     # apply = chunk compute+dispatch, persist = barrier-time flush/commit
     # work in the chain, align = input-channel + fence waiting. A slow
-    # epoch's trace shows WHO held the barrier and DOING WHAT.
+    # epoch's trace shows WHO held the barrier and DOING WHAT. An actor
+    # whose chain holds a sharded (mesh) executor adds "mesh_rows",
+    # "mesh_rows_max_shard" (rows the shards received from the in-mesh
+    # shuffle: all of them, the fullest shard's) and "mesh_shuffle_bytes"
+    # (stream/mesh_shuffle.py); a one-device actor has the three keys only.
     phases: dict = field(default_factory=dict)
     sync_ns: int = 0        # inline store sync duration (pipelining off)
     # checkpoint-pipeline phases (annotated AFTER the span closes — the
@@ -106,6 +110,10 @@ class EpochTrace:
             line += (f" (apply {ph.get('apply_ns', 0) / 1e6:.1f}ms, "
                      f"persist {ph.get('persist_ns', 0) / 1e6:.1f}ms, "
                      f"align {ph.get('align_ns', 0) / 1e6:.1f}ms)")
+            if "mesh_rows" in ph:
+                line += (f" [mesh rows {ph['mesh_rows']}, max shard "
+                         f"{ph['mesh_rows_max_shard']}, shuffle "
+                         f"{ph['mesh_shuffle_bytes']} B]")
         return line
 
     def render(self) -> str:
@@ -315,6 +323,12 @@ class RecoveryRing:
             for r in self.recoveries]
 
 
+def _phase_args(ph: dict) -> dict:
+    """An actor's phase dict as chrome-trace args: times in ms, the mesh
+    counts as they are."""
+    return {k: v / 1e6 if k.endswith("_ns") else v for k, v in ph.items()}
+
+
 def traces_to_json(traces, recoveries=()) -> dict:
     """format=json: the stitched spans + recovery ring, verbatim."""
     return {
@@ -373,7 +387,7 @@ def traces_to_chrome(traces) -> list:
         for actor_id, dt in t.collects:
             ph = t.phases.get(actor_id, {})
             ev(f"collect actor {actor_id}", 0, actor_id, 0, dt,
-               **{k: v / 1e6 for k, v in ph.items()})
+               **_phase_args(ph))
         for wid in sorted(t.worker_spans):
             w = t.worker_spans[wid]
             ev(f"w{wid} epoch {t.epoch}", wid, 0, 0,
@@ -383,7 +397,7 @@ def traces_to_chrome(traces) -> list:
                 ph = phases.get(str(actor_id), {})
                 ev(f"w{wid} collect actor {actor_id}", wid,
                    actor_id, 0, dt,
-                   **{k: v / 1e6 for k, v in ph.items()})
+                   **_phase_args(ph))
         # cross-engine links: one slice per delivery/ingest on the
         # broker i/o track + a flow event INSIDE it (flow events bind
         # to their enclosing slice by pid/tid/ts)

@@ -13,6 +13,7 @@ import asyncio
 from collections import Counter
 
 import numpy as np
+import pytest
 
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.stream.sharded_agg import ShardedHashAggExecutor
@@ -316,3 +317,264 @@ async def test_mesh_over_window_planned_and_matches_oracle():
         f"{list((exp - got).items())[:3]}")
     assert off > 0 and len(exp) > 10
     await s.drop_all()
+
+
+# ------------------------------ q7 on the mesh: what crossed it, counted
+
+Q7_CHUNK, Q7_CHUNKS, Q7_INTERVALS = 256, 4, 7
+Q7_QUOTA = Q7_CHUNK * Q7_CHUNKS
+# a bid every 50/46 x 3600 us: an interval of 1024 bids spans 4.0 s of
+# event time, so most intervals lie inside one 10 s window (every row of
+# the agg's shuffle goes to one shard) and two of the seven cross an edge
+Q7_INTER_EVENT_US = 3600
+_Q7_RUNS: dict = {}
+
+
+def _q7_events(n: int) -> dict:
+    """The oracle's own events: the first n bids of the run's generator."""
+    from risingwave_tpu.connectors.nexmark import (NexmarkConfig,
+                                                   NexmarkGenerator)
+    gen = NexmarkGenerator("bid", chunk_size=n, cfg=NexmarkConfig(
+        inter_event_us=Q7_INTER_EVENT_US))
+    cols = [np.asarray(c.data) for c in gen.next_chunk().columns]
+    t = cols[5]
+    return {"auction": cols[0], "bidder": cols[1], "price": cols[2],
+            "date_time": t, "window_end": t - t % W + W}
+
+
+def _shards_of(col, n_shards: int):
+    from risingwave_tpu.common.vnode import compute_vnodes_numpy
+    from risingwave_tpu.parallel.mesh import vnode_to_shard
+    return vnode_to_shard(n_shards)[compute_vnodes_numpy([col])]
+
+
+async def _q7_run(tmp_path, devices: int) -> dict:
+    """q7 through SQL, durable, watchdog on, `Q7_INTERVALS` checkpoints of
+    exactly `Q7_QUOTA` bids; what a test needs of it, as plain data."""
+    if devices in _Q7_RUNS:
+        return _Q7_RUNS[devices]
+    from risingwave_tpu.state import HummockStateStore, LocalFsObjectStore
+    from risingwave_tpu.stream.source import SourceExecutor
+    from risingwave_tpu.utils.metrics import GLOBAL_METRICS
+    from risingwave_tpu.utils import metrics as m
+    totals = (m.MESH_SHUFFLE_ROWS, m.MESH_SHUFFLE_MAX_SHARD_ROWS,
+              m.MESH_SHUFFLE_BYTES)
+    before = [c.value for c in totals]
+
+    def labelled() -> set:
+        """executor labels of the mesh_shuffle_* series now registered
+        (other tests of the worker may have left theirs)."""
+        return {e["labels"]["executor"]
+                for k, v in GLOBAL_METRICS.snapshot().items()
+                if k.startswith("mesh_shuffle_") for e in v if e["labels"]}
+
+    labels_before = labelled()
+    s = Session(store=HummockStateStore(
+        LocalFsObjectStore(str(tmp_path / "d"))))
+    for stmt in (
+            "SET streaming_join_capacity = 16384",
+            "SET streaming_join_match_factor = 2",
+            "SET streaming_agg_capacity = 256",
+            "SET streaming_durability = 1",
+            "SET streaming_watchdog = 1",
+            "SET checkpoint_max_inflight = 2",
+            f"SET streaming_parallelism_devices = {devices}",
+            "CREATE SOURCE bid WITH (connector='nexmark', table='bid', "
+            f"chunk_size={Q7_CHUNK}, inter_event_us={Q7_INTER_EVENT_US}, "
+            "emit_watermarks=1, watermark_lag_us=20000000, "
+            f"rate_limit={Q7_QUOTA})",
+            "CREATE MATERIALIZED VIEW q7 AS "
+            "SELECT B.auction, B.price, B.bidder, B.date_time "
+            "FROM bid B JOIN ("
+            "  SELECT max(price) AS maxprice, window_end "
+            f"  FROM TUMBLE(bid, date_time, {W}) GROUP BY window_end) B1 "
+            "ON B.price = B1.maxprice "
+            f"AND B.date_time > B1.window_end - {W} "
+            "AND B.date_time <= B1.window_end"):
+        await s.execute(stmt)
+    src, = _executors(s, "q7", SourceExecutor)
+    epochs, compiles = [], []
+    import jax
+
+    def on_compile(event: str, _duration: float, **_kw) -> None:
+        if event.endswith("backend_compile_duration"):
+            compiles[-1] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    for i in range(Q7_INTERVALS):
+        compiles.append(0)
+        # the source parks on its quota: every interval is Q7_QUOTA bids
+        for _ in range(20000):
+            if src.connector.offset >= (i + 1) * Q7_QUOTA:
+                break
+            await asyncio.sleep(0.002)
+        await s.tick(1)
+        epochs.append({a: dict(p) for a, p in
+                       s.coord.tracer._ring[-1].phases.items()})
+    from oracle import committed_offsets
+    sharded = {type(ex).__name__: ex
+               for k in (ShardedHashAggExecutor, ShardedSortedJoinExecutor)
+               for ex in _executors(s, "q7", k)}
+    run = {
+        "offset": committed_offsets(s, "q7")["bid"],
+        "mv": Counter(s.query(
+            "SELECT auction, price, bidder, date_time FROM q7")),
+        "epochs": epochs,
+        "compiles": compiles,
+        "actor_of": {ident.split("(")[0]: a for a, (_n, ident)
+                     in s.coord.mesh_fragments.items()},
+        "labels": {k: ex.mesh_label for k, ex in sharded.items()},
+        "traced_bytes": {k: dict(ex._shuffle_chunk_bytes)
+                         for k, ex in sharded.items()},
+        "rendered": s.coord.tracer._ring[-1].render(),
+        "series": {k: v for k, v in GLOBAL_METRICS.snapshot().items()
+                   if k.startswith("mesh_shuffle_")},
+    }
+    run["totals_delta"] = [c.value - b for c, b in zip(totals, before)]
+    run["labels_added"] = labelled() - labels_before
+    await s.drop_all()
+    run["labels_after_drop"] = labelled() - labels_before
+    _Q7_RUNS[devices] = run
+    return run
+
+
+def _q7_expected_mesh(ev: dict, lo: int, hi: int, n_shards: int) -> dict:
+    """Rows each shard receives in the interval of bids [lo, hi), counted
+    in numpy: the agg routes on window_end; the join routes the bids on
+    price and the agg's changelog of the interval on maxprice (a new
+    window: one insert; a window whose max rose: the old max retracted,
+    the new inserted; an unchanged max: nothing)."""
+    agg = np.bincount(_shards_of(ev["window_end"][lo:hi], n_shards),
+                      minlength=n_shards)
+    changelog = []
+    for we in np.unique(ev["window_end"][lo:hi]):
+        in_w = ev["window_end"][:hi] == we
+        old = ev["price"][:lo][in_w[:lo]]
+        new_max = ev["price"][:hi][in_w].max()
+        if old.size == 0:
+            changelog.append(new_max)
+        elif new_max != old.max():
+            changelog += [old.max(), new_max]
+    join = np.bincount(
+        _shards_of(np.concatenate([ev["price"][lo:hi],
+                                   np.asarray(changelog, np.int64)]),
+                   n_shards), minlength=n_shards)
+    return {"HashAgg": agg, "SortedJoin": join}
+
+
+@pytest.mark.parametrize("case", ["mv_and_rows", "one_window_is_skew_4",
+                                  "bytes", "registry_and_trace",
+                                  "steady_shapes"])
+async def test_q7_on_the_mesh_counts_what_crossed_it(tmp_path, case):
+    S = 4
+    run = await _q7_run(tmp_path, S)
+    n = run["offset"]
+    assert n == Q7_INTERVALS * Q7_QUOTA
+    ev = _q7_events(n)
+    agg_a, join_a = run["actor_of"]["HashAgg"], run["actor_of"]["SortedJoin"]
+    bounds = [(i * Q7_QUOTA, (i + 1) * Q7_QUOTA)
+              for i in range(Q7_INTERVALS)]
+    if case == "mv_and_rows":
+        exp = Counter()
+        for we in np.unique(ev["window_end"]):
+            best = ev["price"][ev["window_end"] == we].max()
+            hit = ((ev["price"] == best) & (ev["date_time"] > we - W)
+                   & (ev["date_time"] <= we))
+            for j in np.flatnonzero(hit):
+                exp[(int(ev["auction"][j]), int(ev["price"][j]),
+                     int(ev["bidder"][j]), int(ev["date_time"][j]))] += 1
+        assert run["mv"] == exp and len(exp) >= 3
+        for (lo, hi), phases in zip(bounds, run["epochs"]):
+            want = _q7_expected_mesh(ev, lo, hi, S)
+            for actor, kind in ((agg_a, "HashAgg"), (join_a, "SortedJoin")):
+                got = phases[actor]
+                assert (got["mesh_rows"], got["mesh_rows_max_shard"]) == (
+                    int(want[kind].sum()), int(want[kind].max())), (
+                    kind, lo, got, want[kind])
+        # the join routes on price, which spreads
+        assert all(S * p[join_a]["mesh_rows_max_shard"]
+                   < 1.5 * p[join_a]["mesh_rows"] for p in run["epochs"])
+    elif case == "one_window_is_skew_4":
+        # the case parallel/exchange.shuffle_cap_out's comment describes:
+        # GROUP BY window_end sends an interval that lies inside one
+        # tumble window to ONE shard
+        inside = [i for i, (lo, hi) in enumerate(bounds)
+                  if len(np.unique(ev["window_end"][lo:hi])) == 1]
+        assert 0 < len(inside) < Q7_INTERVALS
+        for i, phases in enumerate(run["epochs"]):
+            p = phases[agg_a]
+            assert p["mesh_rows"] == Q7_QUOTA
+            assert (p["mesh_rows_max_shard"] == p["mesh_rows"]) == (
+                i in inside)
+    elif case == "bytes":
+        L = Q7_CHUNK // S
+        # shards^2 x cap_out x row bytes x chunks, for the traced shapes.
+        # The agg's shuffle carries (window_end, price) + ops + vis; a
+        # shard slice inside one window fills one send bucket, so the
+        # adaptive hint never goes under L: cap_out stays L
+        assert set(run["traced_bytes"]["ShardedHashAggExecutor"].values()) \
+            == {S * S * L * (8 + 8 + 1 + 1)}
+        for phases in run["epochs"]:
+            assert phases[agg_a]["mesh_shuffle_bytes"] == (
+                S * S * L * 18 * Q7_CHUNKS)
+        # the join: four bid chunks (four int64 columns + the row id) and
+        # the agg's changelog chunk (capacity 2 x agg capacity), at
+        # zero-drop sizing until the adaptive hint engages (3 barriers)
+        left, right = S * S * L * (5 * 8 + 2), S * S * (2 * 256 // S) * 18
+        for phases in run["epochs"][:3]:
+            assert phases[join_a]["mesh_shuffle_bytes"] == (
+                Q7_CHUNKS * left + right)
+        assert all(0 < p[join_a]["mesh_shuffle_bytes"]
+                   <= Q7_CHUNKS * left + right for p in run["epochs"])
+    elif case == "steady_shapes":
+        # a shard's share of an interval's 1024 bids sits around 256, a
+        # pow2 bucket edge of the persist's prefix fetch: bucketed shard by
+        # shard, the packed payload's shapes flip from barrier to barrier
+        # and each new combination compiles an eager program (3 and 4 of
+        # them in these two intervals; 6 s of a 48 s window on four real
+        # chips, PR 26). The shards of a payload share the largest shard's
+        # bucket, so steady intervals compile nothing.
+        assert run["compiles"][4:6] == [0, 0], run["compiles"]
+    else:
+        by_label = {
+            name: {e["labels"]["executor"]: e["value"] for e in entries
+                   if e["labels"]}
+            for name, entries in run["series"].items()}
+        for kind, actor in (("ShardedHashAggExecutor", agg_a),
+                            ("ShardedSortedJoinExecutor", join_a)):
+            label = run["labels"][kind]
+            assert label.endswith(f"@a{actor}") and label.startswith("q7/")
+            for series, key in (
+                    ("mesh_shuffle_rows_total", "mesh_rows"),
+                    ("mesh_shuffle_max_shard_rows_total",
+                     "mesh_rows_max_shard"),
+                    ("mesh_shuffle_bytes_total", "mesh_shuffle_bytes")):
+                assert by_label[series][label] == sum(
+                    p[actor][key] for p in run["epochs"])
+            assert 0 < by_label["mesh_shuffle_max_fill"][label] \
+                <= Q7_CHUNK // S
+        assert run["totals_delta"] == [
+            sum(p[a][key] for p in run["epochs"] for a in (agg_a, join_a))
+            for key in ("mesh_rows", "mesh_rows_max_shard",
+                        "mesh_shuffle_bytes")]
+        # the labelled series are these two executors', and go with them
+        assert run["labels_added"] == set(run["labels"].values())
+        assert run["labels_after_drop"] == set()
+        assert "[mesh rows " in run["rendered"]
+        # only the two mesh actors carry the keys
+        for phases in run["epochs"]:
+            assert {a for a, p in phases.items() if "mesh_rows" in p} \
+                == {agg_a, join_a}
+
+
+async def test_one_device_q7_has_three_phase_keys_and_no_mesh_counts(
+        tmp_path):
+    run = await _q7_run(tmp_path, 1)
+    assert run["offset"] == Q7_INTERVALS * Q7_QUOTA and run["mv"]
+    for phases in run["epochs"]:
+        assert phases
+        for p in phases.values():
+            assert set(p) == {"apply_ns", "persist_ns", "align_ns"}
+    assert run["totals_delta"] == [0, 0, 0]
+    assert run["labels_added"] == set()
+    assert "mesh" not in run["rendered"]

@@ -207,26 +207,17 @@ def test_hll_float_bits_compile(one_chip, no_persistent_cache):
     jax.jit(_bucket_rank_jnp).lower(f64).compile()
 
 
-def test_fused_sharded_agg_on_the_4_device_mesh(mesh4, no_persistent_cache,
-                                                monkeypatch):
-    """The sharded agg of q7's window-max shape, BUILT on the mesh of the
-    four described chips: its fused shard_map program (in-mesh all_to_all
-    shuffle + sharded hash-table apply) and its barrier watchdog's
-    cross-shard reduction; per-device footprint against 16 GB."""
-    from risingwave_tpu.common.types import DataType, schema as mk_schema
-    from risingwave_tpu.expr.agg import agg_max
-    from risingwave_tpu.parallel.mesh import VNODE_AXIS, make_mesh
-    from risingwave_tpu.stream.sharded_agg import ShardedHashAggExecutor
-
-    class _Input:
-        schema = mk_schema(("window_end", DataType.TIMESTAMP),
-                           ("price", DataType.INT64))
-        pk_indices = ()
-
-    # nothing can be placed on a described device: the constructor's
-    # initial state lands on four of the suite's virtual CPU devices
-    # instead; every program is traced against `mesh4`
-    cpu_mesh = make_mesh(4, devices=jax.devices("cpu"))
+@pytest.fixture(scope="module")
+def q7_mesh_executors(mesh4):
+    """q7 at `streaming_parallelism_devices = 4`, deployed through SQL with
+    its mesh made of the four DESCRIBED chips (benchmark cell q7x4.sat's
+    plan; no data is run). Nothing can be placed on a described device:
+    the initial state lands on four of the suite's virtual CPU devices
+    instead; every program is traced against `mesh4`."""
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.parallel import mesh as mesh_mod
+    from risingwave_tpu.plan.build import _iter_executor_chain
+    cpu_mesh = mesh_mod.make_mesh(4, devices=jax.devices("cpu"))
     real_put = jax.device_put
 
     def put(x, sharding=None, **kw):
@@ -234,19 +225,62 @@ def test_fused_sharded_agg_on_the_4_device_mesh(mesh4, no_persistent_cache,
             sharding = NamedSharding(cpu_mesh, sharding.spec)
         return real_put(x, sharding, **kw)
 
-    monkeypatch.setattr(jax, "device_put", put)
-    ex = ShardedHashAggExecutor(
-        _Input(), group_key_indices=[0],
-        agg_calls=[agg_max(1, DataType.INT64, append_only=True)],
-        mesh=mesh4, capacity=AGG_CAP // 4)
+    async def deploy():
+        s = Session()
+        for stmt in [
+            f"SET streaming_join_capacity = {JOIN_CAP}",
+            "SET streaming_join_match_factor = 2",
+            f"SET streaming_agg_capacity = {AGG_CAP}",
+            "SET streaming_parallelism_devices = 4",
+            # rate_limit 0: the source parks on its barrier queue, so no
+            # chunk ever reaches a program of the described mesh
+            ("CREATE SOURCE bid WITH (connector='nexmark', table='bid', "
+             f"chunk_size={CHUNK}, inter_event_us=250, emit_watermarks=1, "
+             f"watermark_lag_us={2 * W}, rate_limit=0)"),
+            ("CREATE MATERIALIZED VIEW q7 AS "
+             "SELECT B.auction, B.price, B.bidder, B.date_time "
+             "FROM bid B JOIN ("
+             "  SELECT max(price) AS maxprice, window_end "
+             f"  FROM TUMBLE(bid, date_time, {W}) GROUP BY window_end) B1 "
+             "ON B.price = B1.maxprice "
+             f"AND B.date_time > B1.window_end - {W} "
+             "AND B.date_time <= B1.window_end"),
+        ]:
+            await s.execute(stmt)
+        return {type(ex).__name__: ex
+                for roots in s.catalog.mvs["q7"].deployment.roots.values()
+                for root in roots for ex in _iter_executor_chain(root)}
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mesh_mod, "make_mesh", lambda n, **_kw: mesh4)
+        mp.setattr(jax, "device_put", put)
+        return asyncio.run(deploy())
+
+
+def test_fused_sharded_agg_on_the_4_device_mesh(q7_mesh_executors, mesh4,
+                                                no_persistent_cache):
+    """The sharded agg of q7 at parallelism 4, on the mesh of the four
+    described chips: its fused shard_map programs (the hollowed TUMBLE
+    projections, the in-mesh all_to_all shuffle, the sharded hash-table
+    apply; one chunk, and four chunks as one `lax.scan`: what q7x4.sat
+    dispatches per checkpoint) and its barrier watchdog's cross-shard
+    reduction; per-device footprint against 16 GB."""
+    from risingwave_tpu.parallel.mesh import VNODE_AXIS
+    ex = q7_mesh_executors["ShardedHashAggExecutor"]
+    assert ex.capacity == AGG_CAP // 4 and len(ex._mesh_preludes) == 2
     sharded = NamedSharding(mesh4, P(VNODE_AXIS))
-    compiled = ex._get_fused_apply()._jitted.lower(
-        abstract(ex.state, sharded), abstract(ex._overflow_dev, sharded),
-        abstract(ex._dropped_dev, sharded),
-        abstract(ex._send_occ_dev, sharded),
-        abstract_chunk(_Input.schema, CHUNK, sharded)).compile()
-    assert "all-to-all" in compiled.as_text(), "no in-mesh shuffle lowered"
-    fits_one_chip(compiled)
+    bid = q7_mesh_executors["SourceExecutor"].schema
+    state = (abstract(ex.state, sharded), abstract(ex._overflow_dev, sharded),
+             abstract(ex._dropped_dev, sharded),
+             abstract(ex._shuffle_obs_dev, sharded))
+    chunk = abstract_chunk(bid, CHUNK, sharded)
+    for compiled in (
+            ex._get_fused_apply()._jitted.lower(*state, chunk).compile(),
+            ex._make_fused_scan(4)._jitted.lower(
+                *state, chunk, chunk, chunk, chunk).compile()):
+        assert "all-to-all" in compiled.as_text(), \
+            "no in-mesh shuffle lowered"
+        fits_one_chip(compiled)
     # the watchdog at the dtypes a RUN hands it: the occupancy accumulator
     # is int64 after the first apply, and the TPU lowers a 64-bit
     # all-reduce only for SUM (found on the four real chips: `pmax` of an
@@ -254,4 +288,34 @@ def test_fused_sharded_agg_on_the_4_device_mesh(mesh4, no_persistent_cache,
     i32 = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded)
     i64 = jax.ShapeDtypeStruct((4,), jnp.int64, sharding=sharded)
     ov = jax.ShapeDtypeStruct((4, 2), jnp.int32, sharding=sharded)
-    ex._watchdog_pack._jitted.lower(ov, i64, i32, i32).compile()
+    obs = jax.ShapeDtypeStruct((4, 2), jnp.int32, sharding=sharded)
+    ex._watchdog_pack._jitted.lower(ov, i64, i32, obs).compile()
+
+
+def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
+                                                 no_persistent_cache):
+    """q7's sharded join on the four described chips: the fused program of
+    each side (all_to_all on price / maxprice, then the shard-local probe
+    and state update) and the watchdog pack with the shuffle's
+    observation lanes."""
+    from risingwave_tpu.parallel.mesh import VNODE_AXIS
+    from risingwave_tpu.stream.align import LEFT, RIGHT
+    join = q7_mesh_executors["ShardedSortedJoinExecutor"]
+    assert join.capacity[LEFT] == JOIN_CAP // 4
+    sharded = NamedSharding(mesh4, P(VNODE_AXIS))
+    wm = jax.ShapeDtypeStruct((), jnp.int64,
+                              sharding=NamedSharding(mesh4, P()))
+    acc = (abstract(join._errs_dev, sharded),
+           abstract(join._dropped_dev, sharded),
+           abstract(join._shuffle_obs_dev, sharded))
+    for side, cap in ((LEFT, CHUNK), (RIGHT, 2 * AGG_CAP)):
+        own, other = (abstract(join.sides[s], sharded)
+                      for s in (side, 1 - side))
+        compiled = join._apply_program(
+            side, join.match_factors[side], True, True)._jitted.lower(
+            own, other, *acc,
+            abstract_chunk(join.inputs[side].schema, cap, sharded),
+            wm).compile()
+        assert "all-to-all" in compiled.as_text()
+        fits_one_chip(compiled)
+    join._watchdog_pack_sh._jitted.lower(*acc).compile()
