@@ -163,6 +163,9 @@ class MemoryManager:
         self._participants[name] = participant
         try:
             participant.mem_guard = self.reload_guard
+            # what the participant labels its own series with, so that
+            # unregister() finds them
+            participant.mem_name = name
         except AttributeError:
             pass
         if self.enabled:
@@ -174,9 +177,12 @@ class MemoryManager:
     def unregister(self, name: str) -> None:
         p = self._participants.pop(name, None)
         if p is not None:
-            # drop the labelled series entirely — a dead executor must
-            # not linger in every future scrape
-            GLOBAL_METRICS.remove("hbm_state_bytes", executor=name)
+            # drop every gauge under the name entirely (hbm_state_bytes
+            # from here, what the executor set itself) — a dead executor
+            # must not linger in every future scrape
+            for key in [k for k in GLOBAL_METRICS.gauges
+                        if ("executor", name) in k[1]]:
+                GLOBAL_METRICS.gauges.pop(key, None)
 
     # --------------------------------------------------------- reporting
     def total_bytes(self) -> int:

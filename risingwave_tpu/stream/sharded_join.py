@@ -30,7 +30,7 @@ unchanged inside shard_map.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -49,13 +49,11 @@ from .sorted_join import SortedJoinExecutor, SortedSideState, _empty_sorted_side
 
 
 def _scalar_n(state: SortedSideState) -> SortedSideState:
-    return SortedSideState(state.khash, state.cols, state.valids,
-                           state.degree, state.n.reshape(()))
+    return replace(state, n=state.n.reshape(()))
 
 
 def _vec_n(state: SortedSideState) -> SortedSideState:
-    return SortedSideState(state.khash, state.cols, state.valids,
-                           state.degree, state.n.reshape((1,)))
+    return replace(state, n=state.n.reshape((1,)))
 
 
 class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
@@ -97,7 +95,9 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                         own2.n.reshape((1,)))
             # donation mirrors the parent's: ONLY the sharded error
             # accumulator (arg 2) — the side states stay aliased by the
-            # per-shard snapshot diff base (_snap)
+            # diff base (_snap: the state as of the last flush, which the
+            # persist reads the deleted rows from, per shard, at the
+            # positions no live row's `src` lane carries any more)
             return jit_state(shard_map(
                 apply_sharded, mesh=mesh,
                 in_specs=(shard, shard, shard, repl, repl),
@@ -139,7 +139,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                         own2.n.reshape((1,)))
             # donation: the error + shuffle-drop + shuffle-observation
             # accumulators (threaded); side states stay aliased by the
-            # snapshot diff base (_snap)
+            # diff base (_snap, as above)
             return jit_state(shard_map(
                 apply_fused, mesh=mesh,
                 in_specs=(shard, shard, shard, shard, shard, shard,
@@ -232,15 +232,18 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
         self._shuffle_obs_dev = self._fresh_shuffle_obs()
         self.sides = [self._sharded_empty(s) for s in (LEFT, RIGHT)]
+        self._snap = list(self.sides)
         # one packed fetch per barrier: summed errs + shuffle drops + the
         # shuffle observations (max send-bucket demand = the adaptive slack
-        # signal; rows received in all and by the fullest shard)
+        # signal; rows received in all and by the fullest shard) + each
+        # side's live rows over all shards
         self._watchdog_pack_sh = jit_state(
-            lambda errs, dr, so: jnp.concatenate(
+            lambda errs, dr, so, nl, nr: jnp.concatenate(
                 [jnp.sum(errs, axis=0), jnp.sum(dr)[None],
                  jnp.max(so[:, OBS_FILL])[None],
                  jnp.sum(so[:, OBS_ROWS])[None],
-                 jnp.max(so[:, OBS_ROWS])[None]]),
+                 jnp.max(so[:, OBS_ROWS])[None],
+                 jnp.sum(nl)[None], jnp.sum(nr)[None]]),
             name="sharded_join_watchdog_pack")
 
     def _sharded_empty(self, side: int) -> SortedSideState:
@@ -275,12 +278,14 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
             tuple(c[lo:lo + C] for c in st.cols),
             tuple(v[lo:lo + C] for v in st.valids),
             st.degree[lo:lo + C],
+            st.src[lo:lo + C],
             st.n[sh].reshape(()))
 
     def _persist(self, barrier) -> None:
-        """Durable flush of the sharded sides: per-shard snapshot diffs
-        (each shard's slice is a valid local sorted state, the parent's
-        diff program is shape-local), with ALL shards'/sides' payloads
+        """Durable flush of the sharded sides: per-shard diffs (each
+        shard's slice is a valid local sorted state whose `src` lane holds
+        shard-local positions, so the parent's diff program applies
+        unchanged), with ALL shards'/sides' payloads
         shipped in TWO d2h calls — one counts fetch, one packed buffer
         (the per-call fetch tax would otherwise multiply by 2·S·sides).
         The diff programs dispatch AT the barrier (against non-donated
@@ -308,11 +313,11 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                     self._shard_slice(self.sides[s], sh, s),
                     self._shard_slice(self._snap[s], sh, s))
                     for sh in range(self.n_shards)]
-                pending.append((st, diffs))
-                self._snap[s] = self.sides[s]
+                pending.append((s, st, diffs))
+                self._rebase(s)
                 self._flush_dirty[s] = False
         counts_dev = (jnp.stack(
-            [x for _, diffs in pending
+            [x for _, _, diffs in pending
              for d in diffs for x in (d[1], d[3])])
             if pending else None)
         new_epoch = barrier.epoch.curr
@@ -326,7 +331,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                 return
             cell["counts"] = counts
             groups, ci = [], 0
-            for _, diffs in pending:
+            for _, _, diffs in pending:
                 # the shards of one side at the largest shard's bucket, so
                 # the packed shapes repeat from barrier to barrier (d2h.py)
                 side = counts[ci:ci + 2 * len(diffs)]
@@ -348,10 +353,11 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                 fetched = finish_prefix_groups(host_flat, prep[1], prep[2])
                 counts = cell["counts"]
                 gi = ci = 0
-                for st, diffs in pending:
+                for s, st, diffs in pending:
                     for d in diffs:
                         nd, ni = int(counts[ci]), int(counts[ci + 1])
                         ci += 2
+                        self._count_persisted(s, nd, ni)
                         del_cols = fetched[gi]
                         ins_cols = fetched[gi + 1]
                         gi += 2
@@ -370,6 +376,11 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                                     (wait_counts, cont_prepare),
                                     (wait_flat, cont_apply),
                                     table_id=tables[0].table_id)
+
+    def _src_iota(self, capacity: int) -> jnp.ndarray:
+        return jax.device_put(
+            jnp.tile(jnp.arange(capacity, dtype=jnp.int32), self.n_shards),
+            NamedSharding(self.mesh, P(VNODE_AXIS)))
 
     def _recover_reset(self, s: int, rows: list) -> None:
         """Per-shard capacity is sized by the WORST shard's row count
@@ -414,12 +425,13 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
 
     # --------------------------------------------------------- watchdog
     def _check_watchdog(self) -> None:
-        vals = np.asarray(self._watchdog_pack_sh(self._errs_dev,
-                                                 self._dropped_dev,
-                                                 self._shuffle_obs_dev))
-        n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max = (
+        vals = np.asarray(self._watchdog_pack_sh(
+            self._errs_dev, self._dropped_dev, self._shuffle_obs_dev,
+            *self._n_dev))
+        n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max, n_l, n_r = (
             int(x) for x in vals)
         self._publish_shuffle(rows, rows_max, fill)
+        self._publish_live_rows(n_l, n_r)
         self._fail_on_shuffle_drops(n_drop)
         if n_mo:
             raise RuntimeError(

@@ -53,26 +53,35 @@ emit their NULL-padded row inline at insert/delete time, including
 NULL-key rows (which can never match). The non-equi condition therefore
 evaluates INSIDE the jitted apply.
 
-Durability (state_tables): the dense sorted layout has no stable slot a
-dirty-bit could follow (merge-inserts shift every row), so persistence is
-a barrier-time SNAPSHOT DIFF instead of hash_join.py's per-slot dirty
-mask: the executor keeps the device state as of the last flush and one
-jitted program aligns current-vs-snapshot rows by a 63-bit row hash, then
-verifies candidate pairs with an EXACT all-column compare — a hash
-collision can only cause a redundant delete+insert of identical rows,
-never a missed change. Changed rows compact into [deletes][inserts]
-buffers, are written columnar to the per-side StateTable, and committed
-at every barrier (reference: state_table.rs:1036 commits everything at
-every checkpoint). Degrees are NOT persisted: recovery replays the stored
-rows through the normal probe path (right side first into an empty mesh,
-then left probing right), which rebuilds both sides' degree columns and
-the condition evaluation for free — a TPU-first simplification of the
-reference's degree tables (managed_state/join/mod.rs:252).
+Durability (state_tables): the dense sorted layout has no stable SLOT a
+dirty bit could follow (merge-inserts shift every row), so the dirty
+information rides WITH the row instead: each side carries a provenance
+lane `src` — a stored row's position in the state as of the last durable
+flush (`_snap`, kept by aliasing the arrays that were live then), or -1 if
+it was inserted since. Merge and eviction move it exactly like `degree`.
+At a barrier the changed rows fall out elementwise: live rows with
+src < 0 are the inserts; positions of `_snap` that no live row carries
+any more are the deletes (one scatter marks the carried ones). A stored
+row is never edited in place (only `degree` is, and degrees are not
+persisted), so "same snapshot position" IS "same row": no hashing, no
+compare, no collision case, and the cost follows the capacity once, not
+capacity x log(capacity). A row deleted and re-inserted unchanged within
+one interval is written as delete + insert of identical rows (deletes go
+first, so the table ends the same). Changed rows compact into
+[deletes][inserts] buffers, are written columnar to the per-side
+StateTable, and committed at every barrier (reference:
+state_table.rs:1036 commits everything at every checkpoint); then the
+live side becomes the new base (`_rebase`). Degrees are NOT persisted:
+recovery replays the stored rows through the normal probe path (right
+side first into an empty mesh, then left probing right), which rebuilds
+both sides' degree columns and the condition evaluation for free — a
+TPU-first simplification of the reference's degree tables
+(managed_state/join/mod.rs:252).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Optional, Sequence
 
 import jax
@@ -82,12 +91,12 @@ import numpy as np
 from ..common.chunk import (
     Column, StreamChunk, OP_DELETE, OP_INSERT, op_sign,
 )
-from ..common.floatbits import float_identity_bits
 from ..common.types import Field, Schema
 from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
 from ..ops.hash_table import pack_rows, stable_lexsort
 from ..ops.jit_state import jit_state
+from ..utils.metrics import GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_PERSIST_ROWS
 from .align import LEFT, RIGHT, barrier_align
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -121,16 +130,19 @@ class SortedSideState:
     cols: tuple[jnp.ndarray, ...]      # per input column [C]
     valids: tuple[jnp.ndarray, ...]    # per input column bool [C]
     degree: jnp.ndarray                # int32 [C] — matches on other side
+    src: jnp.ndarray                   # int32 [C] — the row's position in
+    #                                    the last flushed state, -1 if it
+    #                                    was inserted since (provenance)
     n: jnp.ndarray                     # int32 scalar — live rows
 
     def tree_flatten(self):
         return ((self.khash, self.cols, self.valids, self.degree,
-                 self.n), None)
+                 self.src, self.n), None)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        kh, cols, valids, degree, n = children
-        return cls(kh, tuple(cols), tuple(valids), degree, n)
+        kh, cols, valids, degree, src, n = children
+        return cls(kh, tuple(cols), tuple(valids), degree, src, n)
 
     @property
     def capacity(self) -> int:
@@ -143,6 +155,7 @@ def _empty_sorted_side(capacity: int, col_dtypes: Sequence) -> SortedSideState:
         cols=tuple(jnp.zeros(capacity, dtype=dt) for dt in col_dtypes),
         valids=tuple(jnp.zeros(capacity, dtype=bool) for _ in col_dtypes),
         degree=jnp.zeros(capacity, dtype=jnp.int32),
+        src=jnp.full(capacity, -1, dtype=jnp.int32),
         n=jnp.int32(0),
     )
 
@@ -176,6 +189,10 @@ def _count_le(sorted_arr: jnp.ndarray, dead_cum: jnp.ndarray,
 class SortedJoinExecutor(Executor):
     """Inner equi-join over sorted dense state. Drop-in for
     HashJoinExecutor (same constructor surface minus state_tables)."""
+
+    # the name MemoryManager.register() gave this join: the `executor`
+    # label of its series (utils/metrics.py JOIN_*)
+    mem_name: Optional[str] = None
 
     def __init__(self, left: Executor, right: Executor,
                  left_key_indices: Sequence[int],
@@ -292,8 +309,10 @@ class SortedJoinExecutor(Executor):
                          f"r={self.key_indices[1]})")
         self.state_tables = tuple(state_tables) if state_tables else (None, None)
         self.sides = [self._empty(s) for s in (LEFT, RIGHT)]
-        # device snapshot as of the last durable flush (diff base)
+        # the device state as of the last durable flush, kept by aliasing:
+        # the live rows' `src` lane indexes it (_rebase)
         self._snap = [self.sides[LEFT], self.sides[RIGHT]]
+        self._src_iotas: dict[int, jnp.ndarray] = {}
         self._flush_dirty = [False, False]
         # Donation: ONLY the error accumulator (arg 2). The side states
         # must NOT be donated here, unlike hash_join: `_snap` keeps the
@@ -606,8 +625,12 @@ class SortedJoinExecutor(Executor):
             own.degree, mode="drop")
         if any_outer:
             degree = degree.at[tgt_r].set(match_cnt[iorder], mode="drop")
+        # provenance travels with the kept rows; merged-in rows land on
+        # the -1 fill
+        src2 = jnp.full(C, -1, dtype=jnp.int32).at[tgt_t].set(
+            own.src, mode="drop")
         own2 = SortedSideState(new_khash, tuple(out_cols), tuple(out_valids),
-                               degree, n_after.astype(jnp.int32))
+                               degree, src2, n_after.astype(jnp.int32))
         errs = errs + jnp.stack(
             [n_match_overflow, n_del_miss, n_row_overflow]).astype(jnp.int32)
         return own2, other_degree, tuple(cols), ops_out, emit, errs, own2.n
@@ -636,53 +659,35 @@ class SortedJoinExecutor(Executor):
                        for v in own.valids)
         degree = jnp.zeros(C, dtype=jnp.int32).at[tgt].set(own.degree,
                                                            mode="drop")
+        src = jnp.full(C, -1, dtype=jnp.int32).at[tgt].set(own.src,
+                                                           mode="drop")
         n2 = jnp.sum(keep.astype(jnp.int32))
-        return SortedSideState(kh, cols, valids, degree, n2)
+        return SortedSideState(kh, cols, valids, degree, src, n2)
+
+    def _evict_side(self, s: int, wm, kh) -> None:
+        self.sides[s] = self._evict(self.sides[s], wm, kh, side=s)
+        # the watchdog's live count follows (it fed on apply outputs only)
+        self._n_dev[s] = self.sides[s].n
 
     # ------------------------------------------------------- persistence
     @staticmethod
-    def _row_lanes(st: SortedSideState) -> list[jnp.ndarray]:
-        """Row identity/content lanes for diffing: khash ++ data (invalid
-        lanes canonical 0, floats as their identity bits) ++ valid bits."""
-        lanes = [st.khash]
-        for c, v in zip(st.cols, st.valids):
-            x = (float_identity_bits(c)
-                 if jnp.issubdtype(c.dtype, jnp.floating)
-                 else c.astype(jnp.int64))
-            lanes.append(jnp.where(v, x, 0))
-        lanes.extend(v.astype(jnp.int64) for v in st.valids)
-        return lanes
-
-    def _diff_impl(self, cur: SortedSideState, snap: SortedSideState):
-        """Snapshot diff: rows in `cur` not in `snap` (inserts) and rows
-        in `snap` not in `cur` (deletes), matched by row hash + exact
-        compare. Returns compacted (del_cols, n_del, ins_cols, n_ins);
-        only the first n entries of each buffer are meaningful."""
-        def rowhash(st):
-            lanes = self._row_lanes(st)
-            live = jnp.arange(st.capacity, dtype=jnp.int32) < st.n
-            return jnp.where(live, key_hash(lanes), _HSENTINEL), live
-
-        rh_c, live_c = rowhash(cur)
-        rh_s, live_s = rowhash(snap)
-        order_c = jnp.argsort(rh_c)
-        order_s = jnp.argsort(rh_s)
-        lanes_c = self._row_lanes(cur)
-        lanes_s = self._row_lanes(snap)
-
-        def unmatched(rh_a, live_a, lanes_a, rh_b_sorted, order_b, lanes_b,
-                      cap_b):
-            pos = jnp.clip(jnp.searchsorted(rh_b_sorted, rh_a), 0, cap_b - 1)
-            cand = order_b[pos]
-            eq = rh_b_sorted[pos] == rh_a
-            for la, lb in zip(lanes_a, lanes_b):
-                eq &= la == lb[cand]
-            return live_a & ~eq
-
-        ins_mask = unmatched(rh_c, live_c, lanes_c, rh_s[order_s], order_s,
-                             lanes_s, snap.capacity)
-        del_mask = unmatched(rh_s, live_s, lanes_s, rh_c[order_c], order_c,
-                             lanes_c, cur.capacity)
+    def _diff_impl(cur: SortedSideState, snap: SortedSideState):
+        """The rows that changed since `snap` was flushed, read off the
+        provenance lane: a live row of `cur` with src < 0 is an insert; a
+        live row of `snap` whose position no row of `cur` carries is a
+        delete. O(C) elementwise, one scatter and the compaction — no sort,
+        no search.
+        Returns compacted (del_cols, n_del, ins_cols, n_ins), both in
+        state order; only the first n entries of each are meaningful."""
+        C, Cs = cur.capacity, snap.capacity
+        live_c = jnp.arange(C, dtype=jnp.int32) < cur.n
+        live_s = jnp.arange(Cs, dtype=jnp.int32) < snap.n
+        carried = live_c & (cur.src >= 0)
+        # an int32 mask, not a bool one: the TPU compiler sorts the indices
+        # of a `pred` scatter first (a capacity-sized sort, ~1 ms of the 4
+        # at 2^19, but eight more seconds to compile)
+        survived = jnp.zeros(Cs, dtype=jnp.int32).at[
+            jnp.where(carried, cur.src, Cs)].set(1, mode="drop")
 
         def compact(mask, cols):
             cap = mask.shape[0]
@@ -692,9 +697,40 @@ class SortedJoinExecutor(Executor):
                 jnp.arange(cap, dtype=jnp.int32), mode="drop")
             return tuple(c[sel] for c in cols), jnp.sum(mask.astype(jnp.int32))
 
-        del_cols, n_del = compact(del_mask, snap.cols)
-        ins_cols, n_ins = compact(ins_mask, cur.cols)
+        del_cols, n_del = compact(live_s & (survived == 0), snap.cols)
+        ins_cols, n_ins = compact(live_c & ~carried, cur.cols)
         return del_cols, n_del, ins_cols, n_ins
+
+    def _src_iota(self, capacity: int) -> jnp.ndarray:
+        """src of a side that IS the diff base: every row at its own
+        position (the sharded subclass: each shard's local positions)."""
+        return jnp.arange(capacity, dtype=jnp.int32)
+
+    def _rebase(self, s: int) -> None:
+        """The live side becomes the diff base (after a flush, a recovery,
+        a spill that must not turn into durable deletes): `_snap` aliases
+        it and its provenance lane restarts at the identity. The one place
+        that does both, so no site re-points the base and keeps a stale
+        lane."""
+        C = self.capacity[s]
+        if C not in self._src_iotas:
+            self._src_iotas[C] = self._src_iota(C)
+        self.sides[s] = replace(self.sides[s], src=self._src_iotas[C])
+        self._snap[s] = self.sides[s]
+
+    def _count_persisted(self, s: int, n_del: int, n_ins: int) -> None:
+        for op, n in (("delete", n_del), ("insert", n_ins)):
+            GLOBAL_METRICS.counter(
+                JOIN_PERSIST_ROWS, executor=self.mem_name or self.identity,
+                side=("left", "right")[s], op=op).inc(n)
+
+    def _publish_live_rows(self, n_left: int, n_right: int) -> None:
+        """How full the pools are, from the counts the watchdog's barrier
+        fetch brings anyway."""
+        for side, n in (("left", n_left), ("right", n_right)):
+            GLOBAL_METRICS.gauge(
+                JOIN_LIVE_ROWS, executor=self.mem_name or self.identity,
+                side=side).set(float(n))
 
     def _persist(self, barrier: Barrier) -> None:
         for s in (LEFT, RIGHT):
@@ -702,24 +738,25 @@ class SortedJoinExecutor(Executor):
             if st is None:
                 continue
             if self._flush_dirty[s]:
-                self._persist_diff_write(st, self.sides[s], self._snap[s])
-                self._snap[s] = self.sides[s]
+                self._persist_diff_write(s)
+                self._rebase(s)
                 self._flush_dirty[s] = False
             st.commit(barrier.epoch.curr)
 
-    def _persist_diff_write(self, st, cur: SortedSideState,
-                            snap: SortedSideState) -> None:
-        """Diff one (current, snapshot) state pair and write the changed
-        rows (the sharded subclass calls this per shard slice).
+    def _persist_diff_write(self, s: int) -> None:
+        """Write side s's changed rows to its StateTable.
 
         d2h discipline: a blocking fetch has a fixed per-call cost and
         serialises with dispatch, so the whole diff ships in TWO calls —
         one for the two counts, one for every changed row as one packed
         payload (utils/d2h.py), never a fetch per column."""
         from ..utils.d2h import fetch_prefix_groups
-        del_cols, n_del, ins_cols, n_ins = self._diff(cur, snap)
+        st = self.state_tables[s]
+        del_cols, n_del, ins_cols, n_ins = self._diff(self.sides[s],
+                                                      self._snap[s])
         counts = np.asarray(jnp.stack([n_del, n_ins]))
         nd, ni = int(counts[0]), int(counts[1])
+        self._count_persisted(s, nd, ni)
         if not nd and not ni:
             return
         dels, inss = fetch_prefix_groups(
@@ -789,14 +826,14 @@ class SortedJoinExecutor(Executor):
                         StreamChunk.from_numpy(sch, arrays, capacity=cap),
                         jnp.int64(NO_WATERMARK), side=s, match_factor=mf)
                     self.sides[s] = out[0]
-                    o = self.sides[1 - s]
-                    self.sides[1 - s] = SortedSideState(
-                        o.khash, o.cols, o.valids, out[1], o.n)
+                    self.sides[1 - s] = replace(self.sides[1 - s],
+                                                degree=out[1])
                     self._errs_dev = out[5]
                     self._n_dev[s] = out[6]
         finally:
             self._state_replay = False
-        self._snap = [self.sides[LEFT], self.sides[RIGHT]]
+        for s in (LEFT, RIGHT):
+            self._rebase(s)
 
     # ------------------------------------------------- HBM memory manager
     def state_bytes(self) -> int:
@@ -890,8 +927,8 @@ class SortedJoinExecutor(Executor):
                          kh_thresh: int) -> int:
         """Pack + fetch the rows under the thresholds, park them in the
         host spill, drop them on device. The durable table KEEPS them
-        (the snapshot diff base is re-pointed past the eviction), which is
-        what makes crash recovery rebuild them for free."""
+        (the durable diff is re-based past the eviction), which is what
+        makes crash recovery rebuild them for free."""
         from ..utils.d2h import fetch_prefix_groups
         nc = len(self._col_dtypes[s])
         t_dev = jnp.int64(cc_thresh)
@@ -910,12 +947,11 @@ class SortedJoinExecutor(Executor):
                 valids = tuple(bool(host[nc + c][r]) for c in range(nc))
                 key = tuple(vals[i] for i in self.key_indices[s])
                 self._spill[s].add(key, (vals, valids))
-        self.sides[s] = self._evict(self.sides[s], t_dev, kh_dev, side=s)
-        # the eviction must NOT become durable deletes: re-point the diff
-        # base so the next persist diff skips it (the rows stay in the
-        # table for recovery; reloads re-insert them as idempotent
-        # upserts)
-        self._snap[s] = self.sides[s]
+        self._evict_side(s, t_dev, kh_dev)
+        # the eviction must NOT become durable deletes: re-base the diff
+        # so the next persist skips it (the rows stay in the table for
+        # recovery; reloads re-insert them as idempotent upserts)
+        self._rebase(s)
         from ..utils.metrics import HBM_EVICTIONS
         HBM_EVICTIONS.inc()
         return total
@@ -974,9 +1010,7 @@ class SortedJoinExecutor(Executor):
                               jnp.int64(self._pending_clean[t]), side=t,
                               match_factor=mf)
             self.sides[t] = out[0]
-            o = self.sides[1 - t]
-            self.sides[1 - t] = SortedSideState(o.khash, o.cols, o.valids,
-                                                out[1], o.n)
+            self.sides[1 - t] = replace(self.sides[1 - t], degree=out[1])
             self._errs_dev = out[5]
             self._n_dev[t] = out[6]
         self._dirty[t] = True
@@ -1047,10 +1081,12 @@ class SortedJoinExecutor(Executor):
                     continue
                 kh, cols, valids = grow_sorted_arrays(
                     side.khash, side.cols, side.valids, new_c)
+                pad = new_c - side.capacity
                 deg = jnp.concatenate([
-                    side.degree,
-                    jnp.zeros(new_c - side.capacity, dtype=jnp.int32)])
-                st[s] = SortedSideState(kh, cols, valids, deg, side.n)
+                    side.degree, jnp.zeros(pad, dtype=jnp.int32)])
+                src = jnp.concatenate([
+                    side.src, jnp.full(pad, -1, dtype=jnp.int32)])
+                st[s] = SortedSideState(kh, cols, valids, deg, src, side.n)
             self.capacity[s] = new_c
             self.rebuilds += 1
 
@@ -1060,6 +1096,7 @@ class SortedJoinExecutor(Executor):
             self._errs_dev, self._n_dev[LEFT], self._n_dev[RIGHT]))
         n_mo, n_miss, n_ro = (int(x) for x in vals[:3])
         self._n_known = [int(vals[3]), int(vals[4])]
+        self._publish_live_rows(*self._n_known)
         if n_mo:
             raise RuntimeError(
                 f"sorted-join match-buffer overflow ({n_mo} matches "
@@ -1086,9 +1123,8 @@ class SortedJoinExecutor(Executor):
                  self._n_dev[s]) = self._apply(
                     self.sides[s], self.sides[1 - s], self._errs_dev, msg,
                     wm, side=s, match_factor=self.match_factors[s])
-                o = self.sides[1 - s]
-                self.sides[1 - s] = SortedSideState(
-                    o.khash, o.cols, o.valids, oth_degree, o.n)
+                self.sides[1 - s] = replace(self.sides[1 - s],
+                                            degree=oth_degree)
                 self._dirty[s] = True
                 self._flush_dirty[s] = True
                 if self.temporal and s == RIGHT:
@@ -1114,10 +1150,9 @@ class SortedJoinExecutor(Executor):
                             and self._pending_clean[s2] != NO_WATERMARK
                             and self._pending_clean[s2] != self._cleaned_to[s2]
                             and not self._dirty[s2]):
-                        self.sides[s2] = self._evict(
-                            self.sides[s2],
-                            jnp.int64(self._pending_clean[s2]),
-                            jnp.int64(-1), side=s2)
+                        self._evict_side(
+                            s2, jnp.int64(self._pending_clean[s2]),
+                            jnp.int64(-1))
                         self._cleaned_to[s2] = self._pending_clean[s2]
                         self._flush_dirty[s2] = True
                     self._mem_clean_spilled(s2)

@@ -282,6 +282,19 @@ D2H_FETCHES = GLOBAL_METRICS.counter("d2h_fetch_count")
 HASH_PROBE_FALLBACK_ROWS = GLOBAL_METRICS.counter(
     "hash_probe_fallback_rows_total")
 
+# Sorted join (stream/sorted_join.py), labelled only, `executor` = the
+# name the memory manager registered the join under (its `identity` when
+# it runs outside a flow):
+# - `join_persist_rows_total{executor,side=left|right,op=delete|insert}`:
+#   rows each durable flush wrote to the side's state table, from the two
+#   counts the persist fetches anyway.
+# - `join_live_rows{executor,side}`: rows the side's device pool holds (all
+#   shards together on a mesh), from the barrier watchdog's fetch; over the
+#   pool's capacity it is the fill. Goes when the memory manager
+#   unregisters the join.
+JOIN_PERSIST_ROWS = "join_persist_rows_total"
+JOIN_LIVE_ROWS = "join_live_rows"
+
 # HBM memory manager (memory/manager.py): exact accounted device-state
 # bytes vs. the configured budget, plus eviction/reload activity. The
 # global series always render; per-executor `hbm_state_bytes{executor=..}`

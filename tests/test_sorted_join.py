@@ -22,6 +22,8 @@ from risingwave_tpu.stream import Barrier, BarrierKind, Watermark
 from risingwave_tpu.stream.executor import Executor
 from risingwave_tpu.stream.hash_join import HashJoinExecutor
 from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+from risingwave_tpu.utils.metrics import (GLOBAL_METRICS, JOIN_LIVE_ROWS,
+                                          JOIN_PERSIST_ROWS)
 
 L_SCHEMA = schema(("k", DataType.INT64), ("lv", DataType.INT64))
 R_SCHEMA = schema(("k", DataType.INT64), ("rv", DataType.INT64))
@@ -674,3 +676,196 @@ def test_sorted_persist_recover_randomized():
                 got[vals] += sign
     assert ({k: v for k, v in got.items() if v}
             == {k: v for k, v in want.items() if v})
+
+
+# ------------------------------------------------ the diff by provenance lane
+# Each case: constructor options, the share of a chunk's rows that retract a
+# live row, and what else the run goes through.
+DIFF_CASES = {
+    "append_only": dict(kw=dict(append_only=(True, True)), p_delete=0.0),
+    "retracting": dict(kw={}, p_delete=0.35),
+    "left_outer": dict(kw=dict(join_type="left"), p_delete=0.35),
+    "full_outer": dict(kw=dict(join_type="full"), p_delete=0.35),
+    # keys are event times; the right side sits out every other interval,
+    # so both the apply's inline eviction and the barrier's run
+    "watermark_eviction": dict(kw=dict(clean_watermark_cols=(0, 0)),
+                               p_delete=0.2, watermarks=True),
+    "maybe_grow": dict(kw=dict(capacity=16), p_delete=0.1),
+    # append-only: a spilled row that is reloaded and retracted within one
+    # interval stays in the durable table (a fault older than the lane; the
+    # content diff has it too: PERF.md, program faults not repaired)
+    "spill_repoint": dict(kw=dict(capacity=64), p_delete=0.0, spill=True),
+    "recover": dict(kw={}, p_delete=0.3, restart_after=4),
+}
+
+
+def _persist_counters(join):
+    """[deletes, inserts] `join` has written so far, both sides together."""
+    label = join.mem_name or join.identity
+    return np.asarray([sum(
+        GLOBAL_METRICS.counter(JOIN_PERSIST_ROWS, executor=label, side=sd,
+                               op=op).value for sd in ("left", "right"))
+        for op in ("delete", "insert")])
+
+
+@pytest.mark.parametrize("case", list(DIFF_CASES))
+def test_lane_diff_equals_snapshot_diff(case):
+    """Random churn over several barriers: every diff the persist makes
+    equals the content diff of the same two states (the old sort-and-search
+    program, kept under tests/), a diff right after a barrier — the first
+    after recover() too — holds 0 rows, and the durable tables end equal to
+    the device state."""
+    from _snapshot_diff_reference import check_diffs_against_reference
+    from risingwave_tpu.memory import MemoryManager
+    from risingwave_tpu.state import MemoryStateStore
+    cfg = DIFF_CASES[case]
+    rng = np.random.default_rng(sorted(DIFF_CASES).index(case))
+    store = MemoryStateStore()
+    live = [dict(), dict()]          # pk -> key
+    next_pk = [0, 1_000_000]
+    n_barriers = 8
+
+    def rand_rows(side, ep):
+        rows = []
+        for _ in range(int(rng.integers(6, 16))):
+            if live[side] and rng.random() < cfg["p_delete"]:
+                pk = int(rng.choice(list(live[side])))
+                rows.append((OP_DELETE, live[side].pop(pk), pk))
+            else:
+                k = (ep * 10 + int(rng.integers(0, 10))
+                     if cfg.get("watermarks") else int(rng.integers(0, 8)))
+                pk = next_pk[side]
+                next_pk[side] += 1
+                live[side][pk] = k
+                rows.append((OP_INSERT, k, pk))
+        return rows
+
+    def script(first, last, kind):
+        msgs = [[barrier(first, first - 1, kind)],
+                [barrier(first, first - 1, kind)]]
+        for ep in range(first + 1, last + 1):
+            for side, sch in ((0, L_SCHEMA), (1, R_SCHEMA)):
+                if not (cfg.get("watermarks") and side == 1 and ep % 2):
+                    msgs[side].append(chunk(sch, rand_rows(side, ep)))
+                if cfg.get("watermarks"):
+                    wm = (ep - 3) * 10
+                    msgs[side].append(Watermark(0, DataType.INT64, wm))
+                    for pk in [p for p, k in live[side].items() if k < wm]:
+                        del live[side][pk]
+                msgs[side].append(barrier(ep, ep - 1))
+        return msgs
+
+    async def run(msgs):
+        kw = dict(capacity=128, match_factor=16,
+                  state_tables=_durable_tables(store, 90))
+        kw.update(cfg["kw"])
+        join = SortedJoinExecutor(
+            ScriptSource(L_SCHEMA, msgs[0]), ScriptSource(R_SCHEMA, msgs[1]),
+            left_key_indices=[0], right_key_indices=[0],
+            left_pk_indices=[1], right_pk_indices=[1], **kw)
+        mgr = None
+        if cfg.get("spill"):
+            mgr = MemoryManager()
+            mgr.register("join", join)
+            mgr.configure(budget_bytes=1)
+        seen = check_diffs_against_reference(join)
+        written = _persist_counters(join)
+        async for m in join.execute():
+            if isinstance(m, Barrier):
+                if mgr is not None:
+                    mgr.on_barrier(m.epoch.curr)
+                for s in (0, 1):
+                    join._diff(join.sides[s], join._snap[s])
+                    assert seen.pop() == (0, 0), (case, m.epoch.curr, s)
+        # the two series the mechanism brings: what the flushes wrote, and
+        # (from the last watchdog fetch) what the pools hold
+        written = _persist_counters(join) - written
+        assert written.tolist() == [sum(nd for nd, _ in seen),
+                                    sum(ni for _, ni in seen)]
+        label = join.mem_name or join.identity
+        if mgr is None:
+            assert [GLOBAL_METRICS.gauge(JOIN_LIVE_ROWS, executor=label,
+                                         side=sd).value
+                    for sd in ("left", "right")] == [
+                int(join.sides[0].n), int(join.sides[1].n)]
+        else:
+            mgr.unregister(label)
+            assert not [k for k in GLOBAL_METRICS.labelled_series(
+                JOIN_LIVE_ROWS) if ("executor", label) in k[1]]
+        return join, seen
+
+    cut = cfg.get("restart_after", n_barriers)
+    join, seen = asyncio.run(run(script(1, 1 + cut, BarrierKind.INITIAL)))
+    if cut < n_barriers:
+        store.sync(1 + cut)
+        join, seen2 = asyncio.run(run(
+            script(2 + cut, 2 + n_barriers, BarrierKind.INITIAL)))
+        seen += seen2
+    store.sync(2 + n_barriers)
+
+    assert sum(ni for _, ni in seen) > 40, seen
+    if cfg["p_delete"] or cfg.get("watermarks"):
+        assert sum(nd for nd, _ in seen) > 5, seen
+    if case == "maybe_grow":
+        assert join.rebuilds >= 1
+    if case == "spill_repoint":
+        assert join.mem_reload_count > 0 or join.mem_spilled_rows > 0
+    for s, table in enumerate(_durable_tables(store, 90)):
+        st = join.sides[s]
+        held = Counter(zip(*(np.asarray(c)[:int(st.n)].tolist()
+                             for c in st.cols)))
+        for rows in join._spill[s]._d.values():
+            held.update(vals for vals, _ in rows)
+        # (a side that took a chunk evicts at its NEXT apply: rows under
+        # the last watermark may still be held, and are then durable too)
+        wm_last = (n_barriers - 2) * 10 if cfg.get("watermarks") else -1
+        assert (Counter({r: c for r, c in held.items() if r[0] >= wm_last})
+                == Counter((k, pk) for pk, k in live[s].items()))
+        assert Counter(r for _, r in table.iter_all()) == held, (case, s)
+
+
+def test_lane_diff_row_that_leaves_and_returns_is_a_pair():
+    """The one difference from the content diff: a row deleted and
+    re-inserted unchanged within one interval is written as delete +
+    insert (deletes first), where the content diff wrote nothing; the
+    table ends the same."""
+    from _snapshot_diff_reference import check_diffs_against_reference
+    from risingwave_tpu.state import MemoryStateStore
+    store = MemoryStateStore()
+
+    async def go():
+        l = [barrier(1, 0, BarrierKind.INITIAL),
+             chunk(L_SCHEMA, [(OP_INSERT, 1, 10), (OP_INSERT, 2, 20)]),
+             barrier(2, 1),
+             chunk(L_SCHEMA, [(OP_DELETE, 1, 10)]),
+             chunk(L_SCHEMA, [(OP_INSERT, 1, 10)]),
+             barrier(3, 2)]
+        r = [barrier(1, 0, BarrierKind.INITIAL), barrier(2, 1),
+             barrier(3, 2)]
+        join = SortedJoinExecutor(
+            ScriptSource(L_SCHEMA, l), ScriptSource(R_SCHEMA, r),
+            left_key_indices=[0], right_key_indices=[0],
+            left_pk_indices=[1], right_pk_indices=[1], capacity=64,
+            state_tables=_durable_tables(store, 95))
+        seen = check_diffs_against_reference(join, rows_return=True)
+        async for _ in join.execute():
+            pass
+        return seen
+    assert asyncio.run(go()) == [(0, 2), (1, 1)]
+    store.sync(3)
+    lt, _ = _durable_tables(store, 95)
+    assert sorted(r for _, r in lt.iter_all()) == [(1, 10), (2, 20)]
+
+
+def test_lane_diff_program_has_no_sort_and_no_loop():
+    """A count on the CPU, of the program as jax lowers it (before any
+    backend rewrites it): the diff is elementwise passes, prefix sums,
+    scatters and gathers, whatever the capacity."""
+    import jax
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import _empty_sorted_side
+    side = _empty_sorted_side(1 << 12, (jnp.int64,) * 5)
+    text = jax.jit(SortedJoinExecutor._diff_impl).lower(side, side).as_text()
+    assert "scatter" in text and "gather" in text
+    assert text.count("stablehlo.sort") == 0
+    assert text.count("stablehlo.while") == 0

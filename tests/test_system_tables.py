@@ -13,6 +13,8 @@ labelled series its lifetime registered."""
 import json
 import time
 
+import pytest
+
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.utils.metrics import GLOBAL_METRICS, MetricsRegistry
 from risingwave_tpu.utils.metrics_history import MetricsHistory
@@ -228,20 +230,37 @@ async def test_retention_floor_gauge_dropped_with_source():
         "retention_floor_epoch")
 
 
-async def test_no_labelled_series_leak_after_drop_all():
+@pytest.mark.parametrize("flow", ["stateless", "join"])
+async def test_no_labelled_series_leak_after_drop_all(flow):
     """The audit itself: a full create/tick/drop cycle must leave ZERO
     new labelled gauge/histogram series behind — anything in the diff
     is stale point-in-time state some teardown path forgot to
     `GLOBAL_METRICS.remove`. Cumulative counters are exempt: totals
-    stay meaningful after a drop (and tests elsewhere read them)."""
+    stay meaningful after a drop (and tests elsewhere read them).
+    `join`: a sorted join sets `join_live_rows{executor,side}` from its
+    own watchdog fetch; the memory manager's unregister takes it away."""
     audit = ("gauge", "histogram")
     before = GLOBAL_METRICS.labelled_series(kinds=audit)
     s = Session()
     await s.execute("SET metric_level = debug")
-    await s.execute(SRC_DDL)
-    await s.execute(
-        "CREATE MATERIALIZED VIEW lk AS SELECT auction FROM bid")
+    if flow == "join":
+        for table, rows in (("person", 128), ("auction", 384)):
+            await s.execute(
+                f"CREATE SOURCE {table} WITH (connector='nexmark', "
+                f"table='{table}', primary_key='id', chunk_size={rows}, "
+                f"rate_limit={2 * rows}, emit_watermarks=1)")
+        await s.execute(
+            "CREATE MATERIALIZED VIEW lk AS SELECT P.id, P.window_start "
+            "FROM TUMBLE(person, date_time, 10000000) P "
+            "JOIN TUMBLE(auction, date_time, 10000000) A "
+            "ON P.id = A.seller AND P.window_start = A.window_start")
+    else:
+        await s.execute(SRC_DDL)
+        await s.execute(
+            "CREATE MATERIALIZED VIEW lk AS SELECT auction FROM bid")
     await s.tick(3)
+    if flow == "join":
+        assert GLOBAL_METRICS.labelled_series("join_live_rows") - before
     await s.drop_all()
     await s.shutdown()
     leaked = GLOBAL_METRICS.labelled_series(kinds=audit) - before

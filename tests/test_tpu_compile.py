@@ -142,11 +142,17 @@ def test_q7_sorted_join_apply(q7_executors, one_chip,
 
 def test_q7_sorted_join_durable_diff(q7_executors, one_chip,
                                                     no_persistent_cache):
-    """The durable snapshot diff (`_row_lanes` identity lanes) — the
-    program the volatile bench cells never reached."""
+    """The durable diff by the provenance lane — the program the volatile
+    bench cells never reached. As the chip's compiler leaves it: no sort,
+    and no loop (the content diff it replaced had two of each: its
+    searches were `while` ops over capacity-sized state)."""
     join = q7_executors["SortedJoinExecutor"]
     side = abstract(join.sides[0], one_chip)
-    fits_one_chip(join._diff._jitted.lower(side, side).compile())
+    compiled = join._diff._jitted.lower(side, side).compile()
+    fits_one_chip(compiled)
+    ops = [ln.split("=", 1)[1] for ln in compiled.as_text().splitlines()
+           if "=" in ln]
+    assert not [op for op in ops if " sort(" in op or " while(" in op]
 
 
 def test_q7_hash_agg_apply(q7_executors, one_chip,
@@ -161,19 +167,21 @@ def test_q7_hash_agg_apply(q7_executors, one_chip,
 
 
 def test_float_column_diff_lanes_compile(one_chip, no_persistent_cache):
-    """A sorted-join side holding an f64 and an f32 column: the identity
-    lanes and the row hash compile (float_identity_bits picks the f32-pair
-    image for the TPU at lowering time)."""
+    """A sorted-join side holding an f64 and an f32 column: the diff
+    compiles (its row gathers move the floats as they are; nothing
+    reinterprets an f64 on the device)."""
     from risingwave_tpu.stream.sorted_join import (SortedJoinExecutor,
-                                                   SortedSideState, key_hash)
+                                                   SortedSideState)
     C = 1 << 16
     sds = lambda dt, shape=(C,): jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     st = SortedSideState(
         sds(jnp.int64), (sds(jnp.int64), sds(jnp.float64), sds(jnp.float32)),
-        (sds(jnp.bool_),) * 3, sds(jnp.int32), sds(jnp.int32, ()))
-    jax.jit(lambda s: key_hash(SortedJoinExecutor._row_lanes(s))
-            ).lower(st).compile()
+        (sds(jnp.bool_),) * 3, sds(jnp.int32), sds(jnp.int32),
+        sds(jnp.int32, ()))
+    compiled = jax.jit(SortedJoinExecutor._diff_impl).lower(st, st).compile()
+    assert "bitcast-convert" not in "".join(
+        ln for ln in compiled.as_text().splitlines() if "f64" in ln)
 
 
 def test_pack_for_fetch_with_f64_and_f32_columns(one_chip,
@@ -296,8 +304,9 @@ def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
                                                  no_persistent_cache):
     """q7's sharded join on the four described chips: the fused program of
     each side (all_to_all on price / maxprice, then the shard-local probe
-    and state update) and the watchdog pack with the shuffle's
-    observation lanes."""
+    and state update, the provenance lane moved shard by shard) and the
+    watchdog pack with the shuffle's observation lanes and the two live-row
+    sums."""
     from risingwave_tpu.parallel.mesh import VNODE_AXIS
     from risingwave_tpu.stream.align import LEFT, RIGHT
     join = q7_mesh_executors["ShardedSortedJoinExecutor"]
@@ -318,4 +327,5 @@ def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
             wm).compile()
         assert "all-to-all" in compiled.as_text()
         fits_one_chip(compiled)
-    join._watchdog_pack_sh._jitted.lower(*acc).compile()
+    n = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded)
+    join._watchdog_pack_sh._jitted.lower(*acc, n, n).compile()
