@@ -15,8 +15,8 @@ materialized views over unbounded streams, with
 Layer map (mirrors SURVEY.md §1 of the reference):
   frontend/   SQL -> bound plan -> stream fragment graph
   meta/       barrier manager, catalog, cluster, recovery
-  stream/     executors (source, project, filter, hash_agg, hash_join,
-              hop_window, top_n, materialize, dispatch/merge), actors
+  stream/     executors (source, project, filter, hash_agg, sorted_join,
+              hop_window, retract_top_n, materialize, dispatch/merge), actors
   expr/       expression IR + vectorized jnp evaluation + aggregates
   state/      StateTable facade, memory & LSM (hummock-lite) state stores
   parallel/   vnode<->mesh mapping, all_to_all exchange

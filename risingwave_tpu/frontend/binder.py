@@ -8,7 +8,7 @@ the engine's Expr IR, and emits a `StreamGraph` directly:
   FROM source            -> source fragment
   TUMBLE(...)            -> + project appending window_start/window_end
   HOP(...)               -> + hop_window node
-  JOIN ... ON            -> two upstream fragments + hash_join fragment
+  JOIN ... ON            -> two upstream fragments + sorted_join fragment
                             (equi conjunctions become key columns, the
                             rest becomes the non-equi condition)
   WHERE                  -> filter node
@@ -376,15 +376,6 @@ class StreamPlanner:
                             clean_r = spec
                         if clean_l is not None and clean_r is not None:
                             break
-            # The sorted-merge join (fast path: dense sorted state, no
-            # chain walks) requires integer-comparable keys — true for
-            # every engine type except FLOAT64 (varchar = dict ids,
-            # decimal = scaled int, timestamps = int64). Non-integer keys
-            # fall back to the chained hash join.
-            import numpy as np
-            key_int = all(
-                np.issubdtype(sc.schema[i].data_type.np_dtype, np.integer)
-                for sc, keys in ((ls, lkeys), (rs, rkeys)) for i in keys)
             wd = 1 if self.cfg("streaming_watchdog", 1) else None
             # per-side match buffers: probing a side whose rows are
             # UNIQUE per join key (stream key covered by its equi keys)
@@ -393,45 +384,30 @@ class StreamPlanner:
             mf = self.cfg("streaming_join_match_factor", 64)
             mf_l = min(2, mf) if set(rpk) <= set(rkeys) else mf
             mf_r = min(2, mf) if set(lpk) <= set(lkeys) else mf
-            if key_int:
-                node = Node("sorted_join", dict(
-                    left_key_indices=lkeys, right_key_indices=rkeys,
-                    left_pk_indices=list(lpk),
-                    right_pk_indices=list(rpk),
-                    condition=cond, join_type=jt, temporal=temporal,
-                    capacity=self.cfg("streaming_join_capacity", 1 << 17),
-                    match_factor=mf, match_factors=(mf_l, mf_r),
-                    append_only=(li.append_only, ri.append_only),
-                    clean_specs=(clean_l, clean_r),
-                    mesh_devices=self.cfg(
-                        "streaming_parallelism_devices", 1),
-                    mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
-                    mesh_shuffle_slack=self.cfg(
-                        "streaming_mesh_shuffle_slack", 0),
-                    mesh_shuffle_adaptive=self.cfg(
-                        "streaming_mesh_shuffle_adaptive", 1),
-                    mesh_chain=self.cfg("streaming_mesh_chain", 1),
-                    watchdog_interval=wd,
-                    durable=self.durable()),
-                    inputs=(Exchange(lf), Exchange(rf)))
-            else:
-                if jt != "inner" or temporal:
-                    raise BindError(
-                        "outer/temporal joins require integer-comparable "
-                        "keys")
-                node = Node("hash_join", dict(
-                    left_key_indices=lkeys, right_key_indices=rkeys,
-                    left_pk_indices=list(lpk),
-                    right_pk_indices=list(rpk),
-                    condition=cond,
-                    match_factor=self.cfg("streaming_join_match_factor", 64),
-                    watchdog_interval=wd,
-                    durable=self.durable()),
-                    inputs=(Exchange(lf), Exchange(rf)))
+            node = Node("sorted_join", dict(
+                left_key_indices=lkeys, right_key_indices=rkeys,
+                left_pk_indices=list(lpk),
+                right_pk_indices=list(rpk),
+                condition=cond, join_type=jt, temporal=temporal,
+                capacity=self.cfg("streaming_join_capacity", 1 << 17),
+                match_factor=mf, match_factors=(mf_l, mf_r),
+                append_only=(li.append_only, ri.append_only),
+                clean_specs=(clean_l, clean_r),
+                mesh_devices=self.cfg(
+                    "streaming_parallelism_devices", 1),
+                mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
+                mesh_shuffle_slack=self.cfg(
+                    "streaming_mesh_shuffle_slack", 0),
+                mesh_shuffle_adaptive=self.cfg(
+                    "streaming_mesh_shuffle_adaptive", 1),
+                mesh_chain=self.cfg("streaming_mesh_chain", 1),
+                watchdog_interval=wd,
+                durable=self.durable()),
+                inputs=(Exchange(lf), Exchange(rf)))
             f = self.graph.add(Fragment(self.fid(), node,
                                         dispatch="broadcast"))
             rw = self.cfg("streaming_fragment_worker", "")
-            if rw and node.kind == "sorted_join":
+            if rw:
                 # DCN placement: this fragment deploys in the worker
                 # process (stream/remote_fragment.py). v1 runs the
                 # remote fragment volatile, so the SESSION must be

@@ -50,9 +50,8 @@ class ReloadGuard:
     it) instead of spilling.
 
     `scope` is any hashable the executor chooses (hash_agg uses
-    `id(self)`, hash_join `(id(self), side)`) so key tuples never
-    collide across executors or join sides. `window=0` disables the
-    guard."""
+    `id(self)`) so key tuples never collide across executors.
+    `window=0` disables the guard."""
 
     _MAX_EVENTS_PER_KEY = 4
 

@@ -30,8 +30,8 @@ from ..state.state_table import StateTable
 from ..state.store import StateStore
 from ..stream import (
     Actor, AppendOnlyDedupExecutor, BroadcastDispatcher, Channel,
-    ChannelInput, FilterExecutor, GroupTopNExecutor, HashAggExecutor,
-    HashDispatcher, HashJoinExecutor, HopWindowExecutor,
+    ChannelInput, FilterExecutor, HashAggExecutor,
+    HashDispatcher, HopWindowExecutor,
     MaterializeExecutor, MergeExecutor, ProjectExecutor, RowIdGenExecutor,
     SimpleAggExecutor, SimpleDispatcher, SortedJoinExecutor, SourceExecutor,
     StatelessSimpleAggExecutor,
@@ -738,7 +738,7 @@ def _infer_fragment_schema(graph, frag, built_schema) -> Schema:
             return built_schema[n.upstream]
         ins = [rec(i) for i in n.inputs]
         k = n.kind
-        if k in ("sorted_join", "hash_join"):
+        if k == "sorted_join":
             fields = tuple(ins[0]) + tuple(ins[1])
             oi = n.args.get("output_indices")
             if oi is not None:
@@ -975,33 +975,6 @@ def _build_hash_agg(args, inputs, ctx: ActorCtx, key):
         minput_k=minput_k)
 
 
-@register_builder("hash_join")
-def _build_hash_join(args, inputs, ctx: ActorCtx, key):
-    state_tables = None
-    if args.get("durable"):
-        tabs = []
-        for s, inp in enumerate(inputs):
-            tid = ctx.table_id((key, s))
-            pk = tuple(args["left_pk_indices" if s == 0 else "right_pk_indices"])
-            tabs.append(ctx.env.state_table(
-                tid, inp.schema, pk, vnode_bitmap=ctx.vnode_bitmap))
-        state_tables = tuple(tabs)
-    return HashJoinExecutor(
-        inputs[0], inputs[1],
-        left_key_indices=args["left_key_indices"],
-        right_key_indices=args["right_key_indices"],
-        left_pk_indices=args["left_pk_indices"],
-        right_pk_indices=args["right_pk_indices"],
-        key_capacity=args.get("key_capacity", 1 << 14),
-        row_capacity=args.get("row_capacity", 1 << 16),
-        match_factor=args.get("match_factor", 2),
-        condition=args.get("condition"),
-        output_indices=args.get("output_indices"),
-        state_tables=state_tables,
-        clean_watermark_cols=args.get("clean_watermark_cols", (None, None)),
-        watchdog_interval=args.get("watchdog_interval", 1))
-
-
 @register_builder("sorted_join")
 def _build_sorted_join(args, inputs, ctx: ActorCtx, key):
     state_tables = None
@@ -1050,25 +1023,6 @@ def _build_sorted_join(args, inputs, ctx: ActorCtx, key):
         # two-input walk (join-side producer hollowing)
         ex.mesh_chain_fuse = bool(args.get("mesh_chain", True))
     return ex
-
-
-@register_builder("group_top_n")
-def _build_top_n(args, inputs, ctx: ActorCtx, key):
-    st = None
-    if args.get("durable"):
-        tid = ctx.table_id(key)
-        gk = tuple(args.get("group_key_indices", ()))
-        pk = gk + (args["order_col"],) + tuple(inputs[0].pk_indices)
-        st = ctx.env.state_table(tid, inputs[0].schema,
-                                 tuple(dict.fromkeys(pk)),
-                                 vnode_bitmap=ctx.vnode_bitmap)
-    return GroupTopNExecutor(
-        inputs[0], args.get("group_key_indices", ()), args["order_col"],
-        args["limit"], offset=args.get("offset", 0),
-        descending=args.get("descending", False),
-        capacity=args.get("capacity", 1 << 12),
-        state_table=st,
-        watchdog_interval=args.get("watchdog_interval", 1))
 
 
 @register_builder("general_over_window")
@@ -1399,12 +1353,10 @@ def _state_table_keys(kind: str, args: dict, key) -> list:
     `kind` will request, in request order — the single source of truth
     the deterministic pre-assigner shares with the builders above."""
     durable = bool(args.get("durable"))
-    if kind in ("nexmark_source", "hash_agg", "group_top_n",
-                "general_over_window", "dedup", "simple_agg",
-                "retract_top_n"):
+    if kind in ("nexmark_source", "hash_agg", "general_over_window",
+                "dedup", "simple_agg", "retract_top_n"):
         return [key] if durable else []
-    if kind in ("hash_join", "sorted_join", "eowc_over_window",
-                "snapshot_join_agg"):
+    if kind in ("sorted_join", "eowc_over_window", "snapshot_join_agg"):
         return [(key, 0), (key, 1)] if durable else []
     if kind == "stream_scan":
         return [key] if args.get("durable", True) else []
@@ -1479,8 +1431,8 @@ def infer_fragment_schemas(graph: StreamGraph,
                                        for i in range(len(a["exprs"]))]
             return Schema(tuple(SchemaField(nm, e.ret_type)
                                 for nm, e in zip(names, a["exprs"])))
-        if k in ("filter", "no_op", "dedup", "group_top_n",
-                 "retract_top_n", "materialize", "sink", "dynamic_filter"):
+        if k in ("filter", "no_op", "dedup", "retract_top_n",
+                 "materialize", "sink", "dynamic_filter"):
             return ins[0]
         if k == "row_id_gen":
             return Schema(tuple(ins[0])
@@ -1504,7 +1456,7 @@ def infer_fragment_schemas(graph: StreamGraph,
         if k in ("simple_agg", "stateless_simple_agg"):
             return Schema(tuple(SchemaField(f"agg{j}", c.ret_type)
                                 for j, c in enumerate(a["agg_calls"])))
-        if k in ("hash_join", "sorted_join"):
+        if k == "sorted_join":
             fields = tuple(ins[0]) + tuple(ins[1])
             oi = a.get("output_indices")
             if oi is not None:

@@ -139,7 +139,7 @@ def render_node(node, depth: int = 0) -> list:
     if isinstance(node, Exchange):
         return [f"{'  ' * depth}exchange({node.upstream})"]
     extra = ""
-    if node.kind in ("sorted_join", "hash_join"):
+    if node.kind == "sorted_join":
         extra = (f" lkeys={node.args['left_key_indices']}"
                  f" rkeys={node.args['right_key_indices']}")
     if node.kind == "project":

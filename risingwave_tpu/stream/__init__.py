@@ -14,7 +14,6 @@ from .exchange import (
     ChannelInput, MergeExecutor, TapDispatcher,
 )
 from .hash_agg import HashAggExecutor
-from .hash_join import HashJoinExecutor
 from .sorted_join import SortedJoinExecutor
 from .sharded_join import ShardedSortedJoinExecutor
 from .backfill import BackfillExecutor
@@ -23,10 +22,8 @@ from .align import barrier_align
 from .hop_window import HopWindowExecutor
 from .dedup import AppendOnlyDedupExecutor
 from .simple_agg import SimpleAggExecutor, StatelessSimpleAggExecutor
-from .top_n import GroupTopNExecutor, top_n
 from .retract_top_n import RetractableTopNExecutor
 from .sort import SortExecutor
-from .over_window import OverWindowExecutor, ROW_NUMBER
 from .misc import (
     ExpandExecutor, FlowControlExecutor, NoOpExecutor, UnionExecutor,
     ValuesExecutor, WatermarkFilterExecutor,

@@ -22,8 +22,10 @@ At each barrier the flush program:
      when a top row is retracted, rank promotion pulls the next row in
      and the diff emits it.
 
-v1 scope: device-resident (durable TopN remains the append-only
-GroupTopNExecutor; this one serves retracting inputs).
+The one top-N executor: every ORDER BY ... LIMIT and rank filter plans
+it, append-only inputs included. Given a state table it persists each
+interval's input chunks by stream key and replays the stored rows on
+recovery.
 """
 
 from __future__ import annotations

@@ -1,12 +1,13 @@
 """Sorted-merge streaming join — dense sorted state, no chains, no loops.
 
-Semantics match HashJoinExecutor (the reference's two-sided streaming
-equi-join, src/stream/src/executor/hash_join.rs:478 with the multimap state
+The engine's one streaming join: the reference's two-sided streaming
+equi-join (src/stream/src/executor/hash_join.rs:478 with the multimap state
 of managed_state/join/mod.rs:238-268): a chunk from one side probes the
 OTHER side's stored rows and emits joined changelog rows, then updates its
-OWN store (update pairs degrade to Delete/Insert, NULL keys never match).
+OWN store (update pairs degrade to Delete/Insert; NULL keys never match,
+and neither do NaN keys).
 
-TPU re-design — why not the chained hash multimap of hash_join.py:
+TPU re-design — why not the reference's chained hash multimap:
   * The chain walk is a `lax.while_loop` whose trip count is the longest
     key chain: hot keys turn one chunk into hundreds of tiny dependent
     kernel launches.
@@ -34,8 +35,8 @@ data-dependent control flow:
           of equal hash) + scatters — O(C + N) bandwidth, no table sort.
   delete  a retraction finds its victim row via its own side's range +
           exact (key, pk) compare; one victim per retraction (within-chunk
-          insert/delete runs on the same pk are netted first, exactly like
-          hash_join.py's pk-run resolution).
+          insert/delete runs on the same pk are netted first: a run's
+          first delete and last insert are the ones that take effect).
 
 `append_only=(left, right)` statically removes the retraction machinery
 from a side's program — the common windowed-join case compiles to the
@@ -91,6 +92,7 @@ import numpy as np
 from ..common.chunk import (
     Column, StreamChunk, OP_DELETE, OP_INSERT, op_sign,
 )
+from ..common.floatbits import float_pair_bits
 from ..common.types import Field, Schema
 from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
@@ -110,9 +112,16 @@ NO_WATERMARK = -(1 << 62)
 
 
 def key_hash(key_cols: Sequence[jnp.ndarray]) -> jnp.ndarray:
-    """63-bit nonnegative hash of the composite key (splitmix64 chain)."""
+    """63-bit nonnegative hash of the composite key (splitmix64 chain).
+
+    A float column enters through `float_pair_bits`, an integer image of
+    its VALUE (-0.0 and +0.0 alike, as `==` has them; every NaN alike; the
+    same arithmetic on every backend and in numpy) — a value cast would
+    hash 1.2 and 1.7 alike. An integer column enters by value."""
     h = jnp.full(key_cols[0].shape[0], 0x243F6A8885A308D3, dtype=jnp.uint64)
     for c in key_cols:
+        if jnp.issubdtype(c.dtype, jnp.floating):
+            c = float_pair_bits(c)
         x = h ^ (c.astype(jnp.uint64) * jnp.uint64(0x9E3779B97F4A7C15))
         x = x + jnp.uint64(0x9E3779B97F4A7C15)
         x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
@@ -187,8 +196,9 @@ def _count_le(sorted_arr: jnp.ndarray, dead_cum: jnp.ndarray,
 
 
 class SortedJoinExecutor(Executor):
-    """Inner equi-join over sorted dense state. Drop-in for
-    HashJoinExecutor (same constructor surface minus state_tables)."""
+    """Equi-join (inner, outer, temporal) over sorted dense state. Keys
+    of any column type: the state is ordered by `key_hash`, candidates
+    are verified by `==` on the stored key columns."""
 
     # the name MemoryManager.register() gave this join: the `executor`
     # label of its series (utils/metrics.py JOIN_*)
@@ -219,8 +229,6 @@ class SortedJoinExecutor(Executor):
         for li, ri in zip(*self.key_indices):
             assert lt[li].data_type.np_dtype == rt[ri].data_type.np_dtype, \
                 f"join key dtype mismatch {lt[li]} vs {rt[ri]}"
-            assert np.issubdtype(lt[li].data_type.np_dtype, np.integer), \
-                "sorted join keys must be integer-typed (ints/dict/timestamps)"
         self._col_dtypes = (
             tuple(f.data_type.jnp_dtype for f in lt),
             tuple(f.data_type.jnp_dtype for f in rt),
@@ -315,9 +323,8 @@ class SortedJoinExecutor(Executor):
         self._src_iotas: dict[int, jnp.ndarray] = {}
         self._flush_dirty = [False, False]
         # Donation: ONLY the error accumulator (arg 2). The side states
-        # must NOT be donated here, unlike hash_join: `_snap` keeps the
-        # last-persisted side as the durable diff base by ALIASING the
-        # live arrays (`self._snap[s] = self.sides[s]` in _persist), so
+        # must NOT be donated: `_snap` keeps the last-persisted side as
+        # the durable diff base by ALIASING the live arrays (_rebase), so
         # the buffers an apply consumes are still live as the snapshot.
         self._apply = jit_state(self._apply_impl,
                                 static_argnames=("side", "match_factor"),
@@ -331,7 +338,8 @@ class SortedJoinExecutor(Executor):
         self.watchdog_interval = watchdog_interval
         self.rebuilds = 0
         # device error accumulator [match_overflow, del_miss, row_overflow];
-        # fetched once per barrier (hash_join.py:546 rationale)
+        # fetched once per barrier: a fetch per chunk would serialise
+        # dispatch behind the device
         self._errs_dev = jnp.zeros(3, dtype=jnp.int32)
         zero = jnp.zeros((), dtype=jnp.int32)
         self._n_dev = [zero, zero]
@@ -396,14 +404,19 @@ class SortedJoinExecutor(Executor):
 
         key_cols = [chunk.columns[i].data for i in key_idx]
         key_valid = jnp.ones(N, dtype=bool)
-        for i in key_idx:
+        for i, kc in zip(key_idx, key_cols):
             key_valid &= chunk.columns[i].valid_mask()
-        active = chunk.vis & key_valid               # NULL keys never join
+            if jnp.issubdtype(kc.dtype, jnp.floating):
+                # a NaN equals nothing, itself included: like a NULL key
+                # it joins nothing and is not stored, so its retraction
+                # has no stored row to look for
+                key_valid &= ~jnp.isnan(kc)
+        active = chunk.vis & key_valid     # NULL and NaN keys never join
         signs = op_sign(chunk.ops)
         row_ids = jnp.arange(N, dtype=jnp.int32)
         h = key_hash(key_cols)
 
-        # ---- within-chunk pk-run netting (hash_join.py:272 semantics) ----
+        # ---- within-chunk pk-run netting ----
         if append_only:
             is_ins = active
             is_del = jnp.zeros(N, dtype=bool)
@@ -1066,8 +1079,7 @@ class SortedJoinExecutor(Executor):
     def _maybe_grow(self) -> None:
         """Double a side's capacity at 0.7 occupancy (memory-pressure
         growth instead of fail-stop; needs the watchdog's barrier fetch
-        for the live count — transfer-free mode keeps fixed capacity,
-        the same contract as hash_join's rebuild gating)."""
+        for the live count — transfer-free mode keeps fixed capacity)."""
         known = getattr(self, "_n_known", None)
         if known is None:
             return
@@ -1158,7 +1170,7 @@ class SortedJoinExecutor(Executor):
                     self._mem_clean_spilled(s2)
                     self._dirty[s2] = False
                 # watchdog BEFORE the durable commit: errors fail-stop
-                # this epoch's checkpoint (hash_join.py contract)
+                # this epoch's checkpoint
                 if self.watchdog_interval and (stopping or dirty_any):
                     self._check_watchdog()
                     self._maybe_grow()

@@ -5,8 +5,8 @@ warmup for a canned windowed-agg + join pipeline fed many SMALL chunks
 per interval, in two modes:
 
   baseline   per-chunk applies (chunk batching off, no coalescing)
-  optimized  ChunkCoalescer packs the runs + hash_agg/hash_join scan
-             multiple chunks per dispatch
+  optimized  ChunkCoalescer packs the runs + hash_agg scans multiple
+             chunks per dispatch (the join applies each packed chunk)
 
 The counters come from ops/jit_state.py (every jitted step program in the
 engine routes through it), so the numbers cover the WHOLE chain, not a
@@ -124,11 +124,11 @@ def _coalesce_messages(msgs, max_capacity):
 
 async def _run_pipeline(optimized: bool) -> dict:
     """q7 shape: bids -> window max agg; agg output JOINed back against
-    the bid stream on price (hash join) -> counted sink."""
+    the bid stream on price (sorted join) -> counted sink."""
     from risingwave_tpu.common.chunk import StreamChunk
     from risingwave_tpu.expr.agg import AggCall, AggKind
     from risingwave_tpu.stream import HashAggExecutor
-    from risingwave_tpu.stream.hash_join import HashJoinExecutor
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
     from risingwave_tpu.stream.message import Barrier
     from risingwave_tpu.stream.project import ProjectExecutor
     from risingwave_tpu.expr import call, col, lit
@@ -150,14 +150,13 @@ async def _run_pipeline(optimized: bool) -> dict:
         proj, [2], [AggCall(AggKind.MAX, 1, sch[1].data_type,
                             append_only=True)],
         capacity=1 << 12)
-    join = HashJoinExecutor(
+    join = SortedJoinExecutor(
         _Script(sch, left_msgs), agg,
         left_key_indices=[1], right_key_indices=[1],
         left_pk_indices=[0, 2], right_pk_indices=[0],
-        key_capacity=1 << 12, row_capacity=1 << 14, match_factor=64)
+        capacity=1 << 14, match_factor=64)
     if not optimized:
         agg._use_chunk_batching = False
-        join._use_chunk_batching = False
 
     d0, c0 = _metrics()
     warm_d = warm_c = None

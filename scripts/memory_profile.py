@@ -6,14 +6,18 @@ window again) runs twice:
 
   unbounded   hbm_budget_bytes = 0 — today's grow-forever behavior;
               the run's peak accounted bytes is the reference point
-  budgeted    hbm_budget_bytes = ~half the unbounded peak — the
-              MemoryManager evicts cold slots to host at barriers and
-              late rows reload through the read-through path
+  budgeted    hbm_budget_bytes = the join's reserved pools + half the
+              agg's unbounded peak — the MemoryManager evicts the agg's
+              cold slots to host at barriers (its table shrinks); the
+              sorted join's pools are reserved at their capacity whatever
+              they hold, so it spills by OCCUPANCY (past 60%) instead;
+              late rows reload through the read-through path of both
 
 Exit status is 0 iff, after warmup:
   * the budgeted run's accounted device state stays under budget at
     every barrier,
-  * eviction and at least one read-through reload actually happened,
+  * eviction and at least one read-through reload actually happened, in
+    the agg and in the join,
   * the materialized results (changelog applied to a dict) and the join
     match multiset are IDENTICAL to the unbounded run.
 
@@ -104,7 +108,7 @@ async def _run(budget_bytes: int) -> dict:
     from risingwave_tpu.memory import MemoryManager
     from risingwave_tpu.state import MemoryStateStore, StateTable
     from risingwave_tpu.stream import HashAggExecutor
-    from risingwave_tpu.stream.hash_join import HashJoinExecutor
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
     from risingwave_tpu.stream.message import Barrier
 
     sch = _bid_schema()
@@ -124,15 +128,18 @@ async def _run(budget_bytes: int) -> dict:
         _Script(sch, _script_messages(seed=7)), [2, 0],
         [AggCall(AggKind.MAX, 1, sch[1].data_type, append_only=True)],
         capacity=1 << 12, state_table=agg_state)
-    join = HashJoinExecutor(
+    join = SortedJoinExecutor(
         _Script(sch, _script_messages(seed=7)), agg,
         left_key_indices=[2], right_key_indices=[0],
         left_pk_indices=[0, 1, 2], right_pk_indices=[0, 1],
-        key_capacity=1 << 12, row_capacity=1 << 13, match_factor=64,
-        state_tables=join_states)
+        capacity=1 << 12, match_factor=64, state_tables=join_states)
     mgr = MemoryManager()
     mgr.register("agg", agg)
     mgr.register("join", join)
+    # the join's pools are accounted at their reserved size, which no
+    # spill changes: the budget the agg is held to sits on top of them
+    if budget_bytes:
+        budget_bytes += join.state_bytes()
     mgr.configure(budget_bytes=budget_bytes)
 
     from risingwave_tpu.common.chunk import OP_INSERT, OP_UPDATE_INSERT
@@ -141,7 +148,7 @@ async def _run(budget_bytes: int) -> dict:
     # transient changelog interleaving is alignment-dependent (two-input
     # polling order), but the net materialized result must be exact
     matches = Counter()
-    peak = peak_after_warmup = 0
+    peak = peak_after_warmup = agg_peak = 0
     barriers = 0
     over_budget_barriers = 0
     async for msg in join.execute():
@@ -158,6 +165,7 @@ async def _run(budget_bytes: int) -> dict:
             mgr.on_barrier(msg.epoch.curr)
             total = mgr.total_bytes()
             peak = max(peak, total)
+            agg_peak = max(agg_peak, agg.state_bytes())
             if barriers > WARMUP_INTERVALS:
                 peak_after_warmup = max(peak_after_warmup, total)
                 if budget_bytes and total > budget_bytes:
@@ -168,10 +176,12 @@ async def _run(budget_bytes: int) -> dict:
     return {
         "budget_bytes": budget_bytes,
         "peak_bytes": peak,
+        "agg_peak_bytes": agg_peak,
         "peak_after_warmup": peak_after_warmup,
         "over_budget_barriers": over_budget_barriers,
-        "evicted_bytes": agg.mem_evicted_bytes + join.mem_evicted_bytes,
-        "reloads": agg.mem_reload_count + join.mem_reload_count,
+        "evicted_bytes": agg.mem_evicted_bytes,
+        "reloads": agg.mem_reload_count,
+        "join_reloads": join.mem_reload_count,
         "spilled_rows": agg.mem_spilled_rows + join.mem_spilled_rows,
         "mat": mat,
         "matches": matches,
@@ -180,15 +190,15 @@ async def _run(budget_bytes: int) -> dict:
 
 async def main() -> int:
     base = await _run(0)
-    budget = base["peak_bytes"] // 2
-    bud = await _run(budget)
+    bud = await _run(base["agg_peak_bytes"] // 2)
     verdict = {
-        "budget_bytes": budget,
+        "budget_bytes": bud["budget_bytes"],
         "unbounded_peak": base["peak_bytes"],
         "budgeted_peak_after_warmup": bud["peak_after_warmup"],
         "under_budget_after_warmup": bud["over_budget_barriers"] == 0,
         "evicted_bytes": bud["evicted_bytes"],
         "reloads": bud["reloads"],
+        "join_reloads": bud["join_reloads"],
         "spilled_rows_final": bud["spilled_rows"],
         "mat_rows": len(base["mat"]),
         "results_identical": (base["mat"] == bud["mat"]
@@ -202,6 +212,7 @@ async def main() -> int:
     ok = (verdict["under_budget_after_warmup"]
           and verdict["evicted_bytes"] > 0
           and verdict["reloads"] > 0
+          and verdict["join_reloads"] > 0
           and verdict["results_identical"]
           and verdict["mat_rows"] > 0)
     return 0 if ok else 1

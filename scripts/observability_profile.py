@@ -161,7 +161,7 @@ async def _run_q7(metric_level: str, profile_seconds: float = 0.0,
     from risingwave_tpu.stream import (
         Actor, BroadcastDispatcher, Channel, ChannelInput,
         HashAggExecutor, StopMutation)
-    from risingwave_tpu.stream.hash_join import HashJoinExecutor
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
     from risingwave_tpu.stream.project import ProjectExecutor
 
     sch = _bid_schema()
@@ -185,11 +185,11 @@ async def _run_q7(metric_level: str, profile_seconds: float = 0.0,
         proj, [2], [AggCall(AggKind.MAX, 1, sch[1].data_type,
                             append_only=True)],
         capacity=1 << 12)
-    join = HashJoinExecutor(
+    join = SortedJoinExecutor(
         ChannelInput(ch_l, sch), agg,
         left_key_indices=[1], right_key_indices=[1],
         left_pk_indices=[0, 2], right_pk_indices=[0],
-        key_capacity=1 << 12, row_capacity=1 << 14, match_factor=64)
+        capacity=1 << 14, match_factor=64)
     join_actor = Actor(2, join, None, coord)
 
     for actor, root in ((src_actor, src), (join_actor, join)):

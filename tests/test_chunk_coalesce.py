@@ -2,7 +2,7 @@
 
 Regression contract for the O(1)-dispatches-per-interval work:
 (a) results through the coalesced/batched paths are IDENTICAL to the
-    un-coalesced per-chunk path (hash_agg and hash_join), and
+    un-coalesced per-chunk path (hash_agg), and
 (b) compile counts stay bounded — shape bucketing means a run with
     varying chunk cardinalities and batch lengths stops recompiling
     after warmup.
@@ -20,7 +20,6 @@ from risingwave_tpu.common.epoch import EpochPair
 from risingwave_tpu.expr.agg import agg_sum, count_star
 from risingwave_tpu.stream import Barrier, BarrierKind, HashAggExecutor
 from risingwave_tpu.stream.executor import Executor
-from risingwave_tpu.stream.hash_join import HashJoinExecutor
 from risingwave_tpu.utils.metrics import GLOBAL_METRICS
 
 SCHEMA = schema(("k", DataType.INT64), ("v", DataType.INT64))
@@ -114,44 +113,6 @@ async def test_agg_coalesced_equals_per_chunk():
     both = await _run_agg(batching=True, coalesce=128)
     assert sorted(coalesced) == sorted(base)
     assert sorted(both) == sorted(base)
-
-
-# ------------------------------------------------------------ hash_join
-
-async def _run_join(batching: bool):
-    n_intervals, n_chunks = 4, 5
-    left_msgs = _script(n_intervals, n_chunks)
-    right_msgs = [barrier(1, BarrierKind.INITIAL)]
-    for e in range(2, 2 + n_intervals):
-        # right side gets fewer chunks so the two sides interleave and
-        # same-side runs actually form on the left
-        right_msgs.extend(_interval_chunks(100 + e, 2))
-        right_msgs.append(barrier(e))
-    join = HashJoinExecutor(
-        ScriptSource(SCHEMA, left_msgs), ScriptSource(SCHEMA, right_msgs),
-        left_key_indices=[0], right_key_indices=[0],
-        left_pk_indices=[0, 1], right_pk_indices=[0, 1],
-        key_capacity=64, row_capacity=256, match_factor=64)
-    join._use_chunk_batching = batching
-    # group emitted rows per barrier interval: cross-side interleaving
-    # WITHIN an interval is scheduler-dependent either way (barrier_align
-    # drains an unordered asyncio.wait set), but the set of rows an
-    # interval emits is the executor's contract
-    intervals, cur = [], []
-    async for msg in join.execute():
-        if isinstance(msg, StreamChunk):
-            cur.extend(msg.to_rows())
-        elif isinstance(msg, Barrier):
-            intervals.append(sorted(cur))
-            cur = []
-    intervals.append(sorted(cur))
-    return intervals
-
-
-async def test_join_batched_equals_per_chunk():
-    base = await _run_join(batching=False)
-    batched = await _run_join(batching=True)
-    assert batched == base
 
 
 # ------------------------------------------- compile-count boundedness

@@ -1,4 +1,4 @@
-"""Dedup / SimpleAgg / StatelessSimpleAgg / GroupTopN executor tests.
+"""Dedup / SimpleAgg / StatelessSimpleAgg executor tests.
 
 Golden-model style (reference executor #[cfg(test)] suites): scripted
 chunks + barriers in, changelog out, compared against plain-Python models.
@@ -18,8 +18,8 @@ from risingwave_tpu.common.epoch import EpochPair
 from risingwave_tpu.expr.agg import agg_max, agg_sum, count_star
 from risingwave_tpu.state import MemoryStateStore, StateTable
 from risingwave_tpu.stream import (
-    AppendOnlyDedupExecutor, Barrier, BarrierKind, GroupTopNExecutor,
-    SimpleAggExecutor, StatelessSimpleAggExecutor, top_n,
+    AppendOnlyDedupExecutor, Barrier, BarrierKind,
+    SimpleAggExecutor, StatelessSimpleAggExecutor,
 )
 from risingwave_tpu.stream.executor import Executor
 
@@ -159,90 +159,3 @@ async def test_simple_agg_persist_recover():
     got = rows_of(await drive(agg2))
     # recovered (2, 30) -> (3, 35) as an update, not a fresh Insert
     assert got == [(OP_UPDATE_DELETE, (2, 30)), (OP_UPDATE_INSERT, (3, 35))]
-
-
-# ------------------------------------------------------------------- topn
-
-def apply_changelog(state: Counter, out):
-    for op, row in rows_of(out):
-        if op in (OP_INSERT, OP_UPDATE_INSERT):
-            state[row] += 1
-        else:
-            state[row] -= 1
-            if state[row] == 0:
-                del state[row]
-    return state
-
-
-async def test_group_topn_smallest():
-    msgs = [barrier(1, 0, BarrierKind.INITIAL),
-            chunk([(OP_INSERT, 1, 30), (OP_INSERT, 1, 10),
-                   (OP_INSERT, 2, 7)]),
-            barrier(2, 1),
-            chunk([(OP_INSERT, 1, 20), (OP_INSERT, 1, 5),
-                   (OP_INSERT, 2, 9)]),
-            barrier(3, 2)]
-    tn = GroupTopNExecutor(ScriptSource(SCHEMA, msgs), [0], order_col=1,
-                           limit=2, capacity=32)
-    out = await drive(tn)
-    mv = apply_changelog(Counter(), out)
-    assert mv == Counter({(1, 10): 1, (1, 5): 1, (2, 7): 1, (2, 9): 1})
-
-
-async def test_group_topn_descending_with_offset():
-    rows = [(OP_INSERT, 1, v) for v in [4, 9, 1, 7, 3, 8]]
-    msgs = [barrier(1, 0, BarrierKind.INITIAL), chunk(rows), barrier(2, 1)]
-    tn = GroupTopNExecutor(ScriptSource(SCHEMA, msgs), [0], order_col=1,
-                           limit=2, offset=1, descending=True, capacity=32)
-    out = await drive(tn)
-    mv = apply_changelog(Counter(), out)
-    # desc sorted: 9 8 7 4 3 1; skip 1, take 2 -> {8, 7}
-    assert mv == Counter({(1, 8): 1, (1, 7): 1})
-
-
-async def test_ungrouped_topn():
-    msgs = [barrier(1, 0, BarrierKind.INITIAL),
-            chunk([(OP_INSERT, 1, 30), (OP_INSERT, 2, 10)]),
-            barrier(2, 1),
-            chunk([(OP_INSERT, 3, 20), (OP_INSERT, 4, 40)]),
-            barrier(3, 2)]
-    tn = top_n(ScriptSource(SCHEMA, msgs), order_col=1, limit=2)
-    out = await drive(tn)
-    mv = apply_changelog(Counter(), out)
-    assert mv == Counter({(2, 10): 1, (3, 20): 1})
-
-
-async def test_group_topn_golden_random():
-    rng = np.random.default_rng(7)
-    msgs = [barrier(1, 0, BarrierKind.INITIAL)]
-    all_rows = []
-    ep = 2
-    for _ in range(4):
-        rows = [(OP_INSERT, int(rng.integers(0, 5)),
-                 int(rng.integers(0, 1000)))
-                for _ in range(40)]
-        all_rows.extend(rows)
-        msgs.append(chunk(rows, cap=64))
-        msgs.append(barrier(ep, ep - 1))
-        ep += 1
-    tn = GroupTopNExecutor(ScriptSource(SCHEMA, msgs), [0], order_col=1,
-                           limit=3, capacity=32)
-    out = await drive(tn)
-    mv = apply_changelog(Counter(), out)
-    want = Counter()
-    by_group = {}
-    for _, k, v in all_rows:
-        by_group.setdefault(k, []).append(v)
-    for k, vs in by_group.items():
-        for v in sorted(vs)[:3]:
-            want[(k, v)] += 1
-    assert mv == want
-
-
-async def test_topn_append_only_violation():
-    msgs = [barrier(1, 0, BarrierKind.INITIAL),
-            chunk([(OP_INSERT, 1, 30), (OP_DELETE, 1, 30)]),
-            barrier(2, 1)]
-    tn = top_n(ScriptSource(SCHEMA, msgs), order_col=1, limit=2)
-    with pytest.raises(RuntimeError, match="append-only"):
-        await drive(tn)

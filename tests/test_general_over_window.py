@@ -134,6 +134,26 @@ def test_row_number_and_running_sum_with_retractions():
     assert accumulate(out) == oracle(live, windows, ((2, False),))
 
 
+def test_append_only_arrival_order_over_two_chunks():
+    """Append-only rows ordered by arrival, two chunks inside ONE interval:
+    row_number and the running sum / count of each partition carry over
+    from the first chunk's rows to the second's."""
+    windows = (WindowSpec("row_number"), WindowSpec("sum", arg=3),
+               WindowSpec("count", arg=3))
+    arrivals = [(1, 10), (2, 5), (1, 3), (1, 7), (2, 8), (1, 1)]  # (p, v)
+    live = [(i, p, i, v) for i, (p, v) in enumerate(arrivals)]
+    msgs = [barrier(1, 0, BarrierKind.INITIAL),
+            chunk([(OP_INSERT,) + r for r in live[:4]]),
+            chunk([(OP_INSERT,) + r for r in live[4:]]),
+            barrier(2, 1)]
+    _, out = asyncio.run(run(msgs, windows))
+    got = accumulate(out)
+    assert got == oracle(live, windows, ((2, False),))
+    # partition 1, arrival order: (row_number, running sum, count)
+    assert sorted(r[4:] for r in got if r[1] == 1) == [
+        (1, 10, 1), (2, 13, 2), (3, 20, 3), (4, 21, 4)]
+
+
 def test_rank_ties_and_multi_order():
     windows = (WindowSpec("rank"),)
     order_specs = ((2, False), (3, True))

@@ -471,7 +471,7 @@ async def test_q7_actor_row_counters_agree_with_direct_run():
     from risingwave_tpu.state import MemoryStateStore
     from risingwave_tpu.stream import (
         Actor, Barrier, BarrierKind, BroadcastDispatcher, Channel,
-        ChannelInput, HashAggExecutor, HashJoinExecutor, ProjectExecutor,
+        ChannelInput, HashAggExecutor, SortedJoinExecutor, ProjectExecutor,
         StopMutation)
     from risingwave_tpu.stream.executor import Executor
 
@@ -509,11 +509,11 @@ async def test_q7_actor_row_counters_agree_with_direct_run():
                     call("less_than_or_equal",
                          col(3, DataType.TIMESTAMP),
                          col(4, DataType.TIMESTAMP)))
-        join = HashJoinExecutor(
+        join = SortedJoinExecutor(
             ChannelInput(ch_l, BID), agg,
             left_key_indices=[2], right_key_indices=[1],
             left_pk_indices=[0, 1, 2, 3], right_pk_indices=[0],
-            key_capacity=256, row_capacity=256, match_factor=8,
+            capacity=256, match_factor=8,
             condition=cond, output_indices=[0, 2, 1, 3])
         return join, disp
 

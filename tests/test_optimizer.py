@@ -15,7 +15,7 @@ def _render(node, depth=0):
     if isinstance(node, Exchange):
         return [f"{'  ' * depth}exchange({node.upstream})"]
     extra = ""
-    if node.kind in ("sorted_join", "hash_join"):
+    if node.kind == "sorted_join":
         extra = (f" lkeys={node.args['left_key_indices']}"
                  f" rkeys={node.args['right_key_indices']}")
     if node.kind == "project":

@@ -149,10 +149,10 @@ async def test_plan_self_join_dual_exchange():
     # selective join (auction == auction+1 never matches itself densely) on
     # a rate-limited source (bounded volume per barrier regardless of host
     # speed): this test is about channel independence + 2-input alignment
-    g.add(Fragment(2, Node("hash_join", dict(
+    g.add(Fragment(2, Node("sorted_join", dict(
         left_key_indices=[0], right_key_indices=[2],
         left_pk_indices=[0, 1], right_pk_indices=[0, 1],
-        key_capacity=1 << 10, row_capacity=1 << 13, match_factor=8),
+        capacity=1 << 13, match_factor=8),
         inputs=(Exchange(1), Exchange(1)))))
     dep = await run_deployment(g, rounds=2)
     # both ChannelInputs aligned and the join ran to completion: the stop

@@ -26,7 +26,7 @@ from risingwave_tpu.expr import call, col, lit
 from risingwave_tpu.expr.agg import agg_max
 from risingwave_tpu.stream import (
     Barrier, BarrierKind, BroadcastDispatcher, Channel, ChannelInput,
-    HashAggExecutor, HashJoinExecutor, ProjectExecutor, StopMutation,
+    HashAggExecutor, SortedJoinExecutor, ProjectExecutor, StopMutation,
 )
 from risingwave_tpu.stream.executor import Executor
 
@@ -80,11 +80,11 @@ def build_q7(source: Executor):
                      call("subtract", col(4, DataType.TIMESTAMP), lit(W))),
                 call("less_than_or_equal", col(3, DataType.TIMESTAMP),
                      col(4, DataType.TIMESTAMP)))
-    join = HashJoinExecutor(
+    join = SortedJoinExecutor(
         ChannelInput(ch_l, BID), agg,
         left_key_indices=[2], right_key_indices=[1],
         left_pk_indices=[0, 1, 2, 3], right_pk_indices=[0],
-        key_capacity=256, row_capacity=256, match_factor=8,
+        capacity=256, match_factor=8,
         condition=cond,
         output_indices=[0, 2, 1, 3])   # auction, price, bidder, date_time
     return join, pump

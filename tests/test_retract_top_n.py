@@ -6,6 +6,7 @@ import asyncio
 from collections import Counter
 
 import numpy as np
+import pytest
 
 from risingwave_tpu.common import DataType, schema
 from risingwave_tpu.common.chunk import (
@@ -94,6 +95,48 @@ async def test_refill_from_below():
     assert net == {(1, 20, 2): 1, (1, 30, 3): 1}
 
 
+def _ins(rows):
+    """(g, v) rows as inserts with the running index as pk."""
+    return [(OP_INSERT, g, v, pk) for pk, (g, v) in rows]
+
+
+# append-only inputs of shapes the retraction tests do not take: several
+# groups over two intervals, DESC with an OFFSET, and no group key at all
+TOP_N_SHAPES = {
+    "smallest_two_groups": dict(
+        chunks=[[(1, 30), (1, 10), (2, 7)], [(1, 20), (1, 5), (2, 9)]],
+        kw=dict(group_key_indices=(0,), order_col=1, limit=2),
+        want={(1, 10), (1, 5), (2, 7), (2, 9)}),
+    # desc sorted: 9 8 7 4 3 1; skip 1, take 2 -> {8, 7}
+    "descending_with_offset": dict(
+        chunks=[[(1, v) for v in (4, 9, 1, 7, 3, 8)]],
+        kw=dict(group_key_indices=(0,), order_col=1, limit=2, offset=1,
+                descending=True),
+        want={(1, 8), (1, 7)}),
+    "ungrouped": dict(
+        chunks=[[(1, 30), (2, 10)], [(3, 20), (4, 40)]],
+        kw=dict(group_key_indices=(), order_col=1, limit=2),
+        want={(2, 10), (3, 20)}),
+}
+
+
+@pytest.mark.parametrize("case", list(TOP_N_SHAPES))
+async def test_top_n_shapes(case):
+    cfg = TOP_N_SHAPES[case]
+    msgs, live, pk = [bar(1, 0, BarrierKind.INITIAL)], {}, 0
+    for ep, rows in enumerate(cfg["chunks"], start=2):
+        numbered = list(enumerate(rows, start=pk))
+        pk += len(rows)
+        live.update({i: (g, v, i) for i, (g, v) in numbered})
+        msgs += [chunk(_ins(numbered)), bar(ep, ep - 1)]
+    net = _net(await _run(msgs, **cfg["kw"]))
+    assert set(net.values()) == {1}
+    assert {r[:2] for r in net} == cfg["want"]
+    kw = cfg["kw"]
+    assert net == _golden(live, kw["group_key_indices"], 1, kw["limit"],
+                          kw.get("offset", 0), kw.get("descending", False))
+
+
 async def test_randomized_golden_with_retractions():
     rng = np.random.default_rng(5)
     live = {}
@@ -175,3 +218,33 @@ async def test_sql_top_n_survives_rescale_and_recovery(tmp_path):
     rows = s.query("SELECT a, n FROM t")
     assert len(rows) == 3
     await s.drop_all()
+
+
+async def test_float_value_changing_below_its_integer_part_is_emitted():
+    """The top set is diffed by a hash of the whole row: a row whose FLOAT64
+    column moves from 1.2 to 1.7, all else equal, is another row (a hash of
+    the value cast to an integer kept the old one in the view)."""
+    sch = schema(("g", DataType.INT64), ("v", DataType.FLOAT64),
+                 ("pk", DataType.INT64))
+
+    class FScript(Script):
+        def __init__(self, msgs):
+            super().__init__(msgs)
+            self.schema = sch
+
+    def fchunk(rows):
+        return StreamChunk.from_numpy(
+            sch, [np.asarray([r[1] for r in rows], dtype=np.int64),
+                  np.asarray([r[2] for r in rows], dtype=np.float64),
+                  np.asarray([r[3] for r in rows], dtype=np.int64)],
+            ops=np.asarray([r[0] for r in rows], dtype=np.int8), capacity=32)
+
+    msgs = [bar(1, 0, BarrierKind.INITIAL),
+            fchunk([(OP_INSERT, 1, 1.2, 1), (OP_INSERT, 1, 5.5, 2)]),
+            bar(2, 1),
+            fchunk([(OP_DELETE, 1, 1.2, 1), (OP_INSERT, 1, 1.7, 1)]),
+            bar(3, 2)]
+    t = RetractableTopNExecutor(FScript(msgs), group_key_indices=(0,),
+                                order_col=1, limit=1)
+    out = [m async for m in t.execute()]
+    assert _net(out) == {(1, 1.7, 1): 1}

@@ -1,10 +1,10 @@
-"""SortedJoinExecutor: changelog semantics vs a golden model AND a
-differential run against HashJoinExecutor on identical scripted inputs.
+"""SortedJoinExecutor: changelog semantics vs a golden model, on integer
+keys and on FLOAT64 keys, and differential runs against the nested-loop
+reference of tests/_join_reference.py on identical scripted inputs.
 
-The sorted join must be behaviorally indistinguishable from the chained
-hash join (reference semantics: hash_join.rs into_stream) — same multiset
-of emitted change rows for any interleaving of inserts/deletes/update
-pairs, NULL keys, and watermark cleaning.
+The join is the reference's hash_join.rs into_stream in behaviour: the
+same multiset of emitted change rows for any interleaving of
+inserts/deletes/update pairs, NULL keys, and watermark cleaning.
 """
 
 import asyncio
@@ -13,6 +13,7 @@ from collections import Counter
 import numpy as np
 import pytest
 
+from _join_reference import InnerJoinReference, join_rows
 from risingwave_tpu.common import DataType, schema
 from risingwave_tpu.common.chunk import (
     OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, StreamChunk,
@@ -20,13 +21,34 @@ from risingwave_tpu.common.chunk import (
 from risingwave_tpu.common.epoch import EpochPair
 from risingwave_tpu.stream import Barrier, BarrierKind, Watermark
 from risingwave_tpu.stream.executor import Executor
-from risingwave_tpu.stream.hash_join import HashJoinExecutor
-from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+from risingwave_tpu.stream.sorted_join import SortedJoinExecutor, key_hash
 from risingwave_tpu.utils.metrics import (GLOBAL_METRICS, JOIN_LIVE_ROWS,
                                           JOIN_PERSIST_ROWS)
 
 L_SCHEMA = schema(("k", DataType.INT64), ("lv", DataType.INT64))
 R_SCHEMA = schema(("k", DataType.INT64), ("rv", DataType.INT64))
+LF_SCHEMA = schema(("k", DataType.FLOAT64), ("lv", DataType.INT64))
+RF_SCHEMA = schema(("k", DataType.FLOAT64), ("rv", DataType.INT64))
+
+# Scenarios below run once per key dtype. A float scenario's keys are
+# k / 4 + 0.125: exact in binary, never integral, and several of them
+# share an integer part, so a hash of the truncated value would put them
+# in one hash range and only the exact compare would tell them apart.
+KEY_DTYPES = ["int64", "float64"]
+key_dtypes = pytest.mark.parametrize("kd", KEY_DTYPES)
+
+
+def schemas(kd):
+    return (LF_SCHEMA, RF_SCHEMA) if kd == "float64" else (L_SCHEMA, R_SCHEMA)
+
+
+def K(kd, k):
+    return k / 4 + 0.125 if kd == "float64" else k
+
+
+def keyed(kd, rows):
+    """(op, k, v) rows with the logical key k turned into `kd`'s key."""
+    return [(op, K(kd, k), v) for op, k, v in rows]
 
 
 class ScriptSource(Executor):
@@ -43,8 +65,8 @@ class ScriptSource(Executor):
 
 def chunk(sch, rows, cap=16):
     ops = np.asarray([r[0] for r in rows], dtype=np.int8)
-    cols = [np.asarray([r[1 + i] for r in rows], dtype=np.int64)
-            for i in range(len(sch))]
+    cols = [np.asarray([r[1 + i] for r in rows], dtype=f.data_type.np_dtype)
+            for i, f in enumerate(sch)]
     return StreamChunk.from_numpy(sch, cols, ops=ops, capacity=cap)
 
 
@@ -52,10 +74,11 @@ def barrier(curr, prev, kind=BarrierKind.CHECKPOINT):
     return Barrier(EpochPair(curr, prev), kind)
 
 
-async def run_sorted(l_msgs, r_msgs, **kw):
+async def run_sorted(l_msgs, r_msgs, kd="int64", **kw):
     kw.setdefault("capacity", 64)
+    ls, rs = schemas(kd)
     join = SortedJoinExecutor(
-        ScriptSource(L_SCHEMA, l_msgs), ScriptSource(R_SCHEMA, r_msgs),
+        ScriptSource(ls, l_msgs), ScriptSource(rs, r_msgs),
         left_key_indices=[0], right_key_indices=[0],
         left_pk_indices=[1], right_pk_indices=[1], **kw)
     out = []
@@ -65,8 +88,8 @@ async def run_sorted(l_msgs, r_msgs, **kw):
 
 
 def changelog_counter(out):
-    """Multiset of (sign, row) over all emitted chunks — op-pair encoding
-    degrades to Delete/Insert in both joins, so compare by sign."""
+    """Multiset of (sign, row) over all emitted chunks — op pairs degrade
+    to Delete/Insert, so compare by sign."""
     c = Counter()
     for m in out:
         if isinstance(m, StreamChunk):
@@ -76,18 +99,384 @@ def changelog_counter(out):
     return c
 
 
-def test_inner_join_basic():
-    async def go():
-        l = [barrier(1, 0, BarrierKind.INITIAL),
-             chunk(L_SCHEMA, [(OP_INSERT, 1, 10), (OP_INSERT, 2, 20)]),
-             barrier(2, 1)]
-        r = [barrier(1, 0, BarrierKind.INITIAL),
-             chunk(R_SCHEMA, [(OP_INSERT, 1, 100), (OP_INSERT, 3, 300)]),
-             barrier(2, 1)]
-        _, out = await run_sorted(l, r)
-        got = changelog_counter(out)
-        assert got == Counter({(1, (1, 10, 1, 100)): 1})
-    asyncio.run(go())
+def net(counter):
+    """barrier_align interleaves the two sides nondeterministically, and
+    different interleavings legitimately differ in transient +/- pairs —
+    the interleaving-independent invariant is the NET changelog."""
+    acc = Counter()
+    for (sign, row), cnt in counter.items():
+        acc[row] += sign * cnt
+    return {r: c for r, c in acc.items() if c}
+
+
+def _named_nan(row):
+    """A NaN is not `==` itself: name it, so that equal rows are equal."""
+    return tuple("NaN" if v != v else v for v in row)
+
+
+def _accumulate(out):
+    """Net changelog -> final row multiset; NULLs (by validity) as None."""
+    acc = Counter()
+    for m in out:
+        if not isinstance(m, StreamChunk):
+            continue
+        vis = np.asarray(m.vis)
+        ops = np.asarray(m.ops)[vis]
+        data = [np.asarray(c.data)[vis].tolist() for c in m.columns]
+        valid = [np.asarray(c.valid_mask())[vis] for c in m.columns]
+        for r in range(len(ops)):
+            row = _named_nan(d[r] if v[r] else None
+                             for d, v in zip(data, valid))
+            acc[row] += 1 if ops[r] in (OP_INSERT, OP_UPDATE_INSERT) else -1
+    return Counter({k: v for k, v in acc.items() if v})
+
+
+def _golden_outer(events, join_type):
+    """The reference's join of what a list of (side, op, key, pk) events
+    leaves live: output rows (l_k, l_pk, r_k, r_pk), None for NULL."""
+    live = [{}, {}]   # side -> pk -> key
+    for side, op, k, pk in events:
+        if op == OP_INSERT:
+            live[side][pk] = k
+        else:
+            live[side].pop(pk, None)
+    return join_rows(*([(k, pk) for pk, k in lv.items()] for lv in live),
+                     join_type=join_type)
+
+
+def joined(kd, lk, lv, rk, rv):
+    return (K(kd, lk), lv, K(kd, rk), rv)
+
+
+# ------------------------------------------- scenarios, once per key dtype
+
+@key_dtypes
+async def test_inner_join_basic(kd):
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10), (OP_INSERT, 2, 20)])),
+         barrier(2, 1)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100), (OP_INSERT, 3, 300)])),
+         barrier(2, 1)]
+    _, out = await run_sorted(l, r, kd)
+    assert changelog_counter(out) == Counter(
+        {(1, joined(kd, 1, 10, 1, 100)): 1})
+
+
+@key_dtypes
+async def test_join_both_orders_and_duplicates(kd):
+    # left rows arrive first epoch; right rows with duplicate keys second
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10), (OP_INSERT, 1, 11)])),
+         barrier(2, 1),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         barrier(2, 1),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100), (OP_INSERT, 1, 101),
+                              (OP_INSERT, 1, 102)])),
+         barrier(3, 2)]
+    _, out = await run_sorted(l, r, kd)
+    assert net(changelog_counter(out)) == {
+        joined(kd, 1, lv, 1, rv): 1
+        for lv in (10, 11) for rv in (100, 101, 102)}
+
+
+@key_dtypes
+async def test_join_retraction(kd):
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10)])),
+         barrier(2, 1),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100)])),
+         barrier(2, 1),
+         chunk(rs, keyed(kd, [(OP_DELETE, 1, 100)])),
+         barrier(3, 2)]
+    _, out = await run_sorted(l, r, kd)
+    row = joined(kd, 1, 10, 1, 100)
+    assert changelog_counter(out) == Counter({(1, row): 1, (-1, row): 1})
+
+
+@key_dtypes
+async def test_join_update_pair_retracts_old_match(kd):
+    """An UD/UI pair on the right (e.g. a max-agg output) swaps matches."""
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 5, 50), (OP_INSERT, 7, 70)])),
+         barrier(2, 1),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 5, 900)])),
+         barrier(2, 1),
+         chunk(rs, keyed(kd, [(OP_UPDATE_DELETE, 5, 900),
+                              (OP_UPDATE_INSERT, 7, 900)])),
+         barrier(3, 2)]
+    _, out = await run_sorted(l, r, kd)
+    assert net(changelog_counter(out)) == {joined(kd, 7, 70, 7, 900): 1}
+
+
+@key_dtypes
+async def test_join_within_chunk_update_pair_same_pk(kd):
+    # UD/UI with the same key AND pk (a value-in-place change):
+    # delete-then-insert must leave the one new row stored
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10)])),
+         barrier(2, 1),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100)])),
+         barrier(2, 1),
+         chunk(rs, keyed(kd, [(OP_UPDATE_DELETE, 1, 100),
+                              (OP_UPDATE_INSERT, 1, 100)])),
+         barrier(3, 2)]
+    join, out = await run_sorted(l, r, kd)
+    assert net(changelog_counter(out)) == {joined(kd, 1, 10, 1, 100): 1}
+    assert int(join.sides[1].n) == 1
+
+
+@key_dtypes
+async def test_join_condition(kd):
+    from risingwave_tpu.expr import call, col
+    cond = call("greater_than", col(3), col(1))  # rv > lv
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10), (OP_INSERT, 1, 200)])),
+         barrier(2, 1)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100)])),
+         barrier(2, 1)]
+    _, out = await run_sorted(l, r, kd, condition=cond)
+    assert changelog_counter(out) == Counter(
+        {(1, joined(kd, 1, 10, 1, 100)): 1})
+
+
+def random_script(rng, kd, n_epochs, rows_per_chunk):
+    """Random inserts/deletes on both sides, one chunk a side an epoch.
+    Returns the two message lists and the reference fed the same rows."""
+    ref = InnerJoinReference()
+    ls, rs = schemas(kd)
+    msgs = ([barrier(1, 0, BarrierKind.INITIAL)],
+            [barrier(1, 0, BarrierKind.INITIAL)])
+    next_pk = [0, 1_000_000]
+    for epoch in range(2, 2 + n_epochs):
+        for s, sch in ((0, ls), (1, rs)):
+            rows = []
+            for _ in range(int(rng.integers(*rows_per_chunk))):
+                live = ref.live[s]
+                if live and rng.random() < 0.35:
+                    row = live[int(rng.integers(len(live)))]
+                    rows.append((OP_DELETE,) + row)
+                    ref.apply(s, [(-1, row)])
+                else:
+                    row = (K(kd, int(rng.integers(0, 6))), next_pk[s])
+                    next_pk[s] += 1
+                    rows.append((OP_INSERT,) + row)
+                    ref.apply(s, [(1, row)])
+            msgs[s].append(chunk(sch, rows, cap=16))
+            msgs[s].append(barrier(epoch, epoch - 1))
+    return msgs, ref
+
+
+@key_dtypes
+async def test_join_golden_random(kd):
+    """Random inserts/deletes on both sides; the accumulated changelog must
+    equal the inner join of the final live multisets."""
+    (l_msgs, r_msgs), ref = random_script(
+        np.random.default_rng(7), kd, n_epochs=5, rows_per_chunk=(12, 13))
+    _, out = await run_sorted(l_msgs, r_msgs, kd, capacity=256,
+                              match_factor=16)
+    got = net(changelog_counter(out))
+    assert got and got == dict(ref.joined())
+
+
+@key_dtypes
+async def test_differential_vs_reference_random(kd):
+    """Randomized differential test: the message streams the executor runs
+    and the rows the nested-loop reference is fed are the same; the NET
+    changelog must be the reference's join of what is live at the end."""
+    (l_msgs, r_msgs), ref = random_script(
+        np.random.default_rng(17), kd, n_epochs=12, rows_per_chunk=(1, 8))
+    _, out = await run_sorted(l_msgs, r_msgs, kd, capacity=256)
+    got = net(changelog_counter(out))
+    assert got == dict(ref.joined())
+    # every delete must retract a prior insert (no negative prefix)
+    assert all(c > 0 for c in got.values())
+
+
+@key_dtypes
+def test_differential_lockstep_apply(kd):
+    """Deterministic differential: apply the SAME chunk sequence directly
+    through the join's _apply and through the reference (no async
+    interleaving) — per-chunk outputs and live state multisets must match
+    exactly."""
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import NO_WATERMARK
+
+    rng = np.random.default_rng(11)
+    ls, rs = schemas(kd)
+    ref = InnerJoinReference()
+    sj = SortedJoinExecutor(
+        ScriptSource(ls, []), ScriptSource(rs, []),
+        left_key_indices=[0], right_key_indices=[0],
+        left_pk_indices=[1], right_pk_indices=[1], capacity=256)
+    next_pk = [0, 1_000_000]
+
+    def sj_live(s):
+        st = sj.sides[s]
+        n = int(np.asarray(st.n))
+        return Counter(zip(*(np.asarray(c)[:n].tolist() for c in st.cols)))
+
+    wm = jnp.int64(NO_WATERMARK)
+    for _ in range(40):
+        side = int(rng.integers(0, 2))
+        rows, pool = [], list(ref.live[side])
+        for _ in range(int(rng.integers(1, 8))):
+            if pool and rng.random() < 0.4:
+                row = pool.pop(int(rng.integers(len(pool))))
+                rows.append((OP_DELETE,) + row)
+            else:
+                row = (K(kd, int(rng.integers(0, 6))), next_pk[side])
+                next_pk[side] += 1
+                pool.append(row)
+                rows.append((OP_INSERT,) + row)
+        c = chunk(ls if side == 0 else rs, rows)
+        (sj.sides[side], _od, cols_s, ops_s, vis_s, sj._errs_dev, _) = \
+            sj._apply(sj.sides[side], sj.sides[1 - side], sj._errs_dev, c,
+                      wm, side=side)
+        out_s = StreamChunk(tuple(cols_s[i] for i in sj.output_indices),
+                            ops_s, vis_s, sj.schema)
+        want = ref.apply(side, [(1 if r[0] == OP_INSERT else -1, r[1:])
+                                for r in rows])
+        assert changelog_counter([out_s]) == want
+        assert sj_live(side) == Counter(ref.live[side])
+    assert int(np.asarray(sj._errs_dev).sum()) == 0
+
+
+# ------------------------------------------------------- FLOAT64 key values
+
+def _float_chunk(sch, ops, keys, vals, key_valid=None):
+    return StreamChunk.from_numpy(
+        sch, [np.asarray(keys, dtype=np.float64),
+              np.asarray(vals, dtype=np.int64)],
+        ops=np.asarray(ops, dtype=np.int8), capacity=16,
+        valids=[None if key_valid is None else np.asarray(key_valid), None])
+
+
+@pytest.mark.parametrize("case", ["signed_zero", "nan", "null"])
+async def test_float_key_values(case):
+    """-0.0 and +0.0 are one key (they are `==`); a NaN key equals nothing,
+    itself included, so it joins nothing; a NULL key never joins. In a LEFT
+    join each unmatched left row shows NULL-padded, and retracting it takes
+    the padded row back without tripping the delete-miss watchdog."""
+    nan = float("nan")
+    lkey, rkey, lvalid = {"signed_zero": (-0.0, 0.0, True),
+                          "nan": (nan, nan, True),
+                          "null": (1.5, 1.5, False)}[case]
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         _float_chunk(LF_SCHEMA, [OP_INSERT, OP_INSERT], [lkey, 2.5],
+                      [10, 20], key_valid=[lvalid, True]),
+         barrier(2, 1),
+         _float_chunk(LF_SCHEMA, [OP_DELETE], [2.5], [20]),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         _float_chunk(RF_SCHEMA, [OP_INSERT, OP_INSERT], [rkey, 2.5],
+                      [100, 200]),
+         barrier(2, 1), barrier(3, 2)]
+    want = join_rows([(lkey if lvalid else None, 10)],
+                     [(rkey, 100), (2.5, 200)], join_type="left")
+    _, out = await run_sorted(list(l), list(r), "float64", join_type="left")
+    got = _accumulate(out)
+    assert got == Counter({_named_nan(row): c for row, c in want.items()})
+    (row,) = got
+    if case == "signed_zero":
+        assert row == (-0.0, 10, 0.0, 100)
+        assert np.signbit(row[0]) and not np.signbit(row[2])
+    else:
+        assert row[1:] == (10, None, None)
+    # the unmatched row's own retraction (a NaN / NULL key is not stored,
+    # so there is no stored row it could miss)
+    l2 = l[:3] + [_float_chunk(LF_SCHEMA, [OP_DELETE, OP_DELETE],
+                               [lkey, 2.5], [10, 20],
+                               key_valid=[lvalid, True]), barrier(3, 2)]
+    join, out2 = await run_sorted(l2, list(r), "float64", join_type="left")
+    assert _accumulate(out2) == Counter()
+    assert int(join.sides[0].n) == 0
+
+
+# the parent's `key_hash` (PR 27) on these columns, computed there
+_INT64_KEYS = np.array([0, 1, -1, 7, 2**40 + 3, -2**62, 2**63 - 1],
+                       dtype=np.int64)
+_INT32_KEYS = np.array([5, -5, 0, 123456789, 42, 1, -1], dtype=np.int32)
+_PINNED = {
+    "int64": [1610172448792072464, 3510239654435238608, 5813959610162567889,
+              3858702458973525374, 460566425422708489, 7726348192752811715,
+              2339369700579237230],
+    "int64,int32": [8817788084488479165, 6296065877570977183,
+                    4620001967084662399, 7504037406712506029,
+                    6818141598011270626, 1306871546894992134,
+                    5234006721980145236],
+    "int32": [2149605058787081185, 2064198114435380727, 1610172448792072464,
+              5161658241008303108, 1551494370527391033, 3510239654435238608,
+              5813959610162567889],
+}
+
+
+def _key_hash_np(cols):
+    """numpy twin of `key_hash`: a float column by its `float_pair_bits_np`
+    image, an integer column by value."""
+    from risingwave_tpu.common.floatbits import float_pair_bits_np
+    m = np.uint64
+    with np.errstate(over="ignore"):
+        h = np.full(len(cols[0]), 0x243F6A8885A308D3, dtype=m)
+        for c in cols:
+            c = np.asarray(c)
+            if np.issubdtype(c.dtype, np.floating):
+                c = float_pair_bits_np(c)
+            x = h ^ (c.astype(m) * m(0x9E3779B97F4A7C15))
+            x = x + m(0x9E3779B97F4A7C15)
+            x = (x ^ (x >> m(30))) * m(0xBF58476D1CE4E5B9)
+            x = (x ^ (x >> m(27))) * m(0x94D049BB133111EB)
+            h = x ^ (x >> m(31))
+    return (h >> m(1)).astype(np.int64)
+
+
+def test_key_hash_integer_columns_as_before_and_float_twin():
+    """Integer key columns hash to the bits they hashed to before float keys
+    were taken (the state's order, and every compiled join program, follow
+    them); a float column hashes alike in jnp and in numpy, so a host
+    mirror of a device hash agrees; -0.0 / +0.0 alike, every NaN alike."""
+    import jax.numpy as jnp
+    cols = {"int64": [_INT64_KEYS], "int64,int32": [_INT64_KEYS, _INT32_KEYS],
+            "int32": [_INT32_KEYS]}
+    for name, want in _PINNED.items():
+        got = np.asarray(key_hash([jnp.asarray(c) for c in cols[name]]))
+        assert got.tolist() == want, name
+        assert _key_hash_np(cols[name]).tolist() == want, name
+    nan2 = np.array([0x7FF8000000000001], dtype=np.uint64).view(np.float64)[0]
+    f = np.array([0.0, -0.0, 1.2, 1.7, -1.2, 0.1 + 0.2, 0.3, 1e300, -1e300,
+                  1e-310, np.inf, -np.inf, np.nan, nan2, 2.0**53 + 2, 7.25],
+                 dtype=np.float64)
+    got = np.asarray(key_hash([jnp.asarray(f), jnp.asarray(
+        np.arange(len(f), dtype=np.int64) % 3)]))
+    assert got.tolist() == _key_hash_np(
+        [f, np.arange(len(f), dtype=np.int64) % 3]).tolist()
+    h = np.asarray(key_hash([jnp.asarray(f)]))
+    assert h[0] == h[1] and h[12] == h[13]
+    assert h[2] != h[3] and h[2] != h[4] and (h >= 0).all()
+
+
+def test_key_hash_float_keys_with_one_integer_part_spread():
+    """64 keys 7.0 + i / 64: a hash of the value cast to an integer gives
+    all of them ONE hash (one candidate range, 64 compares a probe)."""
+    import jax.numpy as jnp
+    f = 7.0 + np.arange(64, dtype=np.float64) / 64
+    h = np.asarray(key_hash([jnp.asarray(f)]))
+    assert len(set(h.tolist())) >= 32
+    assert len(set((h >> 55).tolist())) >= 32    # in the top bits too
 
 
 def test_retraction_and_update_pair():
@@ -174,138 +563,6 @@ def test_watermark_eviction_inline():
     asyncio.run(go())
 
 
-def test_differential_vs_hash_join_random():
-    """Randomized differential test: identical scripted message streams
-    through SortedJoinExecutor and HashJoinExecutor must yield identical
-    changelog multisets."""
-    rng = np.random.default_rng(7)
-    live = [dict(), dict()]   # pk -> key, per side
-    next_pk = [0, 1_000_000]
-
-    def random_chunk(side):
-        sch = L_SCHEMA if side == 0 else R_SCHEMA
-        rows = []
-        for _ in range(int(rng.integers(1, 8))):
-            if live[side] and rng.random() < 0.35:
-                pk = int(rng.choice(list(live[side].keys())))
-                k = live[side].pop(pk)
-                rows.append((OP_DELETE, k, pk))
-            else:
-                k = int(rng.integers(0, 6))
-                pk = next_pk[side]
-                next_pk[side] += 1
-                live[side][pk] = k
-                rows.append((OP_INSERT, k, pk))
-        return chunk(sch, rows)
-
-    msgs = [[barrier(1, 0, BarrierKind.INITIAL)],
-            [barrier(1, 0, BarrierKind.INITIAL)]]
-    epoch = 2
-    for _ in range(12):
-        for side in (0, 1):
-            for _ in range(int(rng.integers(1, 3))):
-                msgs[side].append(random_chunk(side))
-        msgs[0].append(barrier(epoch, epoch - 1))
-        msgs[1].append(barrier(epoch, epoch - 1))
-        epoch += 1
-
-    def net(counter):
-        """barrier_align interleaves the two sides nondeterministically, and
-        different interleavings legitimately differ in transient +/- pairs —
-        the interleaving-independent invariant is the NET changelog."""
-        acc = Counter()
-        for (sign, row), cnt in counter.items():
-            acc[row] += sign * cnt
-        return {r: c for r, c in acc.items() if c}
-
-    async def go():
-        _, out_s = await run_sorted(list(msgs[0]), list(msgs[1]),
-                                    capacity=256)
-        hj = HashJoinExecutor(
-            ScriptSource(L_SCHEMA, list(msgs[0])),
-            ScriptSource(R_SCHEMA, list(msgs[1])),
-            left_key_indices=[0], right_key_indices=[0],
-            left_pk_indices=[1], right_pk_indices=[1],
-            key_capacity=256, row_capacity=256)
-        out_h = []
-        async for m in hj.execute():
-            out_h.append(m)
-        assert net(changelog_counter(out_s)) == net(changelog_counter(out_h))
-        # every delete must retract a prior insert (no negative prefix)
-        assert all(c > 0 for c in net(changelog_counter(out_s)).values())
-    asyncio.run(go())
-
-
-def test_differential_lockstep_apply():
-    """Deterministic differential: apply the SAME chunk sequence directly
-    through both joins' _apply (no async interleaving) — per-chunk outputs
-    and live state multisets must match exactly."""
-    import jax.numpy as jnp
-    from risingwave_tpu.stream.sorted_join import NO_WATERMARK
-
-    rng = np.random.default_rng(11)
-    live = [dict(), dict()]
-    next_pk = [0, 1_000_000]
-
-    def random_chunk(side):
-        sch = L_SCHEMA if side == 0 else R_SCHEMA
-        rows = []
-        for _ in range(int(rng.integers(1, 8))):
-            if live[side] and rng.random() < 0.4:
-                pk = int(rng.choice(list(live[side].keys())))
-                k = live[side].pop(pk)
-                rows.append((OP_DELETE, k, pk))
-            else:
-                k = int(rng.integers(0, 6))
-                pk = next_pk[side]
-                next_pk[side] += 1
-                live[side][pk] = k
-                rows.append((OP_INSERT, k, pk))
-        return chunk(sch, rows)
-
-    seq = []
-    for _ in range(40):
-        s = int(rng.integers(0, 2))
-        seq.append((s, random_chunk(s)))
-
-    sj = SortedJoinExecutor(
-        ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
-        left_key_indices=[0], right_key_indices=[0],
-        left_pk_indices=[1], right_pk_indices=[1], capacity=256)
-    hj = HashJoinExecutor(
-        ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
-        left_key_indices=[0], right_key_indices=[0],
-        left_pk_indices=[1], right_pk_indices=[1],
-        key_capacity=256, row_capacity=256)
-
-    def sj_live(s):
-        st = sj.sides[s]
-        n = int(np.asarray(st.n))
-        c0, c1 = np.asarray(st.cols[0]), np.asarray(st.cols[1])
-        return Counter((int(c0[i]), int(c1[i])) for i in range(n))
-
-    def hj_live(s):
-        st = hj.sides[s]
-        liv = np.asarray(st.live)
-        r0, r1 = np.asarray(st.rows[0]), np.asarray(st.rows[1])
-        return Counter((int(r0[i]), int(r1[i])) for i in np.flatnonzero(liv))
-
-    wm = jnp.int64(NO_WATERMARK)
-    for side, c in seq:
-        (sj.sides[side], _od, cols_s, ops_s, vis_s, sj._errs_dev, _) = sj._apply(
-            sj.sides[side], sj.sides[1 - side], sj._errs_dev, c, wm,
-            side=side)
-        out_s = StreamChunk(tuple(cols_s[i] for i in sj.output_indices),
-                            ops_s, vis_s, sj.schema)
-        (hj.sides[side], cols_h, ops_h, vis_h, hj._errs_dev, _, _) = hj._apply(
-            hj.sides[side], hj.sides[1 - side], hj._errs_dev, c, side=side)
-        out_h = StreamChunk(tuple(cols_h[i] for i in hj.output_indices),
-                            ops_h, vis_h, hj.schema)
-        assert changelog_counter([out_s]) == changelog_counter([out_h])
-        assert sj_live(side) == hj_live(side)
-    assert int(np.asarray(sj._errs_dev).sum()) == 0
-
-
 def test_append_only_fast_path():
     """append_only sides compile without the retraction machinery but
     produce the same changelog."""
@@ -339,55 +596,6 @@ def test_overflow_fail_stops():
 
 
 # ---------------------------------------------------------------- outer joins
-
-def _mv_state(rows_by_pk):
-    return dict(rows_by_pk)
-
-
-def _golden_outer(events, join_type):
-    """Python model: final materialized LEFT/RIGHT/FULL join result from a
-    list of (side, op, key, pk) events. Returns multiset of output rows
-    (l_k, l_pk, r_k, r_pk) with None for NULL."""
-    live = [{}, {}]   # side -> pk -> key
-    for side, op, k, pk in events:
-        if op == OP_INSERT:
-            live[side][pk] = k
-        else:
-            live[side].pop(pk, None)
-    out = Counter()
-    matched_r = set()
-    for lpk, lk in live[0].items():
-        ms = [(rpk, rk) for rpk, rk in live[1].items() if rk == lk]
-        if ms:
-            for rpk, rk in ms:
-                out[(lk, lpk, rk, rpk)] += 1
-                matched_r.add(rpk)
-        elif join_type in ("left", "full"):
-            out[(lk, lpk, None, None)] += 1
-    if join_type in ("right", "full"):
-        for rpk, rk in live[1].items():
-            if not any(lk == rk for lk in live[0].values()):
-                out[(None, None, rk, rpk)] += 1
-    return out
-
-
-def _accumulate(out):
-    """Net changelog -> final row multiset, decoding NULLs via validity."""
-    acc = Counter()
-    for m in out:
-        if not isinstance(m, StreamChunk):
-            continue
-        vis = np.asarray(m.vis)
-        ops = np.asarray(m.ops)[vis]
-        data = [np.asarray(c.data)[vis] for c in m.columns]
-        valid = [np.asarray(c.valid_mask())[vis] for c in m.columns]
-        for r in range(len(ops)):
-            row = tuple(int(d[r]) if v[r] else None
-                        for d, v in zip(data, valid))
-            sign = 1 if ops[r] in (OP_INSERT, OP_UPDATE_INSERT) else -1
-            acc[row] += sign
-    return Counter({k: v for k, v in acc.items() if v})
-
 
 def _run_outer(events, join_type, n_epochs=4):
     """Split events into epochs, run the executor, compare final result."""
@@ -480,37 +688,36 @@ def test_outer_randomized_golden():
 
 # ---------------------------------------------------------------- durability
 
-def _durable_tables(store, base=30):
+def _durable_tables(store, base=30, kd="int64"):
     from risingwave_tpu.state import StateTable
-    return (StateTable(store, base, L_SCHEMA, pk_indices=[1]),
-            StateTable(store, base + 1, R_SCHEMA, pk_indices=[1]))
+    ls, rs = schemas(kd)
+    return (StateTable(store, base, ls, pk_indices=[1]),
+            StateTable(store, base + 1, rs, pk_indices=[1]))
 
 
-def test_sorted_persist_recover_inner():
+@key_dtypes
+async def test_sorted_persist_recover_inner(kd):
     from risingwave_tpu.state import MemoryStateStore
     store = MemoryStateStore()
-
-    async def run1():
-        l = [barrier(1, 0, BarrierKind.INITIAL),
-             chunk(L_SCHEMA, [(OP_INSERT, 1, 10), (OP_INSERT, 2, 20)]),
-             barrier(2, 1)]
-        r = [barrier(1, 0, BarrierKind.INITIAL),
-             chunk(R_SCHEMA, [(OP_INSERT, 1, 100)]),
-             barrier(2, 1)]
-        await run_sorted(l, r, state_tables=_durable_tables(store))
-    asyncio.run(run1())
+    ls, rs = schemas(kd)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10), (OP_INSERT, 2, 20)])),
+         barrier(2, 1)]
+    r = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(rs, keyed(kd, [(OP_INSERT, 1, 100)])),
+         barrier(2, 1)]
+    await run_sorted(l, r, kd, state_tables=_durable_tables(store, kd=kd))
     store.sync(2)
 
-    async def run2():
-        l2 = [barrier(3, 2, BarrierKind.INITIAL), barrier(4, 3)]
-        r2 = [barrier(3, 2, BarrierKind.INITIAL),
-              chunk(R_SCHEMA, [(OP_INSERT, 2, 200)]),
-              barrier(4, 3)]
-        _, out = await run_sorted(l2, r2,
-                                  state_tables=_durable_tables(store))
-        return out
-    out2 = asyncio.run(run2())
-    assert changelog_counter(out2) == Counter({(1, (2, 20, 2, 200)): 1})
+    # restart: right side gains a row matching recovered left row 2
+    l2 = [barrier(3, 2, BarrierKind.INITIAL), barrier(4, 3)]
+    r2 = [barrier(3, 2, BarrierKind.INITIAL),
+          chunk(rs, keyed(kd, [(OP_INSERT, 2, 200)])),
+          barrier(4, 3)]
+    _, out2 = await run_sorted(l2, r2, kd,
+                               state_tables=_durable_tables(store, kd=kd))
+    assert changelog_counter(out2) == Counter(
+        {(1, joined(kd, 2, 20, 2, 200)): 1})
 
 
 def test_sorted_persist_update_across_restart():
@@ -581,29 +788,30 @@ def test_sorted_outer_recover_rebuilds_degrees():
                                          (2, 20, 2, 200): 1})
 
 
-def test_sorted_state_cleaning_durable():
-    """Watermark-evicted rows disappear from the durable state too (the
-    snapshot diff writes their deletes)."""
+@key_dtypes
+async def test_sorted_state_cleaning_durable(kd):
+    """Rows below the per-side cleaning watermark are evicted from device
+    AND durable state (the diff writes their deletes). Integer keys clean
+    on the key column, as a windowed join does; float keys on the event
+    time beside it, as a plan would ask (no watermark is a float)."""
     from risingwave_tpu.state import MemoryStateStore
     store = MemoryStateStore()
-
-    async def go():
-        l = [barrier(1, 0, BarrierKind.INITIAL),
-             chunk(L_SCHEMA, [(OP_INSERT, 1, 10), (OP_INSERT, 9, 20)]),
-             barrier(2, 1),
-             Watermark(0, DataType.INT64, 5),
-             barrier(3, 2)]
-        r = [barrier(1, 0, BarrierKind.INITIAL), barrier(2, 1),
-             Watermark(0, DataType.INT64, 5),
-             barrier(3, 2)]
-        join, _ = await run_sorted(l, r, clean_watermark_cols=(0, 0),
-                                   state_tables=_durable_tables(store, 60))
-        return join
-    join = asyncio.run(go())
+    ls, rs = schemas(kd)
+    cc, wm = (0, 5) if kd == "int64" else (1, 15)
+    l = [barrier(1, 0, BarrierKind.INITIAL),
+         chunk(ls, keyed(kd, [(OP_INSERT, 1, 10), (OP_INSERT, 9, 20)])),
+         barrier(2, 1),
+         Watermark(cc, DataType.INT64, wm),
+         barrier(3, 2)]
+    r = [barrier(1, 0, BarrierKind.INITIAL), barrier(2, 1),
+         Watermark(cc, DataType.INT64, wm),
+         barrier(3, 2)]
+    join, _ = await run_sorted(
+        l, r, kd, clean_watermark_cols=(cc, cc),
+        state_tables=_durable_tables(store, 60, kd))
     store.sync(3)
-    lt, _ = _durable_tables(store, 60)
-    remaining = sorted(r[0] for _, r in lt.iter_all())
-    assert remaining == [9]
+    lt, _ = _durable_tables(store, 60, kd)
+    assert [r for _, r in lt.iter_all()] == [(K(kd, 9), 20)]
     assert int(join.sides[0].n) == 1
 
 
