@@ -3,10 +3,11 @@
 // The reference implements row serde / hashing in Rust (src/common/src/
 // row/, util/memcmp_encoding.rs, hash/); the TPU build keeps device
 // compute in XLA and gives the HOST runtime the same native treatment:
-// batch memcomparable key encoding, value-row encoding, and the crc32
-// vnode hash, each vectorized over whole column batches instead of
-// per-row Python. Byte formats are bit-identical to state/serde.py and
-// common/vnode.py (golden-tested from tests/test_native.py).
+// batch memcomparable key encoding, value-row encoding, the crc32 vnode
+// hash, and the SST record packer / unpacker for fixed-width sorted runs, each
+// vectorized over whole column batches instead of per-row Python. Byte
+// formats are bit-identical to state/serde.py, common/vnode.py and
+// state/sstable.py (golden-tested from tests/test_native.py).
 
 #include <cstdint>
 #include <cstring>
@@ -64,6 +65,68 @@ void crc32_i64_cols(const int64_t* vals /* n*k row-major */, int64_t n,
             }
         }
         out[r] = crc ^ 0xFFFFFFFFu;
+    }
+}
+
+// SST records (state/sstable.py, format RWS1) of one sorted run whose keys
+// are all K bytes and whose put values are all V bytes wide:
+//   put:       u32le K | key | u32le V | value
+//   tombstone: u32le K | key | u32le 0xFFFFFFFF
+// Returns the bytes written (n * (8 + K) + puts * V).
+int64_t sst_pack_fixed(const uint8_t* keys, const uint8_t* vals,
+                       const uint8_t* put, int64_t n, int64_t K, int64_t V,
+                       uint8_t* out) {
+    const uint32_t klen = (uint32_t)K, vlen = (uint32_t)V;
+    const uint32_t tomb = 0xFFFFFFFFu;
+    uint8_t* p = out;
+    for (int64_t r = 0; r < n; ++r) {
+        std::memcpy(p, &klen, 4);
+        std::memcpy(p + 4, keys + r * K, (size_t)K);
+        p += 4 + K;
+        if (put[r]) {
+            std::memcpy(p, &vlen, 4);
+            std::memcpy(p + 4, vals + r * V, (size_t)V);
+            p += 4 + V;
+        } else {
+            std::memcpy(p, &tomb, 4);
+            p += 4;
+        }
+    }
+    return (int64_t)(p - out);
+}
+
+// Walk the `count` records of an SST body laid out as above from `off`:
+// per record the offset and length of its key and its value length
+// (0xFFFFFFFF = tombstone). Returns the offset after the last record, -1
+// if a record runs past `size`.
+int64_t sst_index(const uint8_t* body, int64_t size, int64_t off,
+                  int64_t count, int64_t* koff, uint32_t* klen,
+                  uint32_t* vlen) {
+    for (int64_t r = 0; r < count; ++r) {
+        if (off + 4 > size) return -1;
+        std::memcpy(&klen[r], body + off, 4);
+        koff[r] = off + 4;
+        off += 4 + (int64_t)klen[r];
+        if (off + 4 > size) return -1;
+        std::memcpy(&vlen[r], body + off, 4);
+        off += 4;
+        if (vlen[r] != 0xFFFFFFFFu) off += (int64_t)vlen[r];
+        if (off > size) return -1;
+    }
+    return off;
+}
+
+// The inverse of sst_pack_fixed for n records that sst_index found to be
+// K / V wide: keys and put values copied out into the matrices (a
+// tombstone's value row is zeroed).
+void sst_unpack_fixed(const uint8_t* body, const int64_t* koff,
+                      const uint8_t* put, int64_t n, int64_t K, int64_t V,
+                      uint8_t* keys, uint8_t* vals) {
+    for (int64_t r = 0; r < n; ++r) {
+        const uint8_t* p = body + koff[r];
+        std::memcpy(keys + r * K, p, (size_t)K);
+        if (put[r]) std::memcpy(vals + r * V, p + K + 4, (size_t)V);
+        else std::memset(vals + r * V, 0, (size_t)V);
     }
 }
 

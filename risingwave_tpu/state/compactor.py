@@ -249,7 +249,7 @@ class BackgroundCompactor:
                                         sst_id=task.out_sst_id,
                                         error=repr(exc))
                 return
-        if task.data is None:           # merge never ran (aborted early)
+        if task.parts is None:          # merge never ran (aborted early)
             self.store.abandon_compaction(task)
             return
         obsolete = self.store.install_compaction(task)

@@ -5,7 +5,13 @@ Reference: src/storage/src/hummock/ (shared buffer -> L0 SST upload on
 docs/checkpoint.md:38-44). The shape kept here:
 
 - `ingest_batch` stages writes in a per-epoch shared buffer (immediately
-  readable — mem-table read-through semantics match MemoryStateStore).
+  readable — mem-table read-through semantics match MemoryStateStore). A
+  per-epoch buffer is a list of write SEGMENTS in staging order: a
+  `ColumnarSegment` (state/store.py: the key matrix, value matrix and put
+  lane of one `StateTable.write_chunk_columns` call, as the native codec
+  made them) or a dict (row-form writes, the log store, source offsets).
+  Later segments overlay earlier ones; within a columnar segment the last
+  row of a key counts.
 - The checkpoint pipeline is split into three phases (reference: the
   event-handler uploader, src/storage/src/hummock/event_handler/uploader/ —
   epochs seal at the barrier, SSTs build/upload in background tasks, and
@@ -13,11 +19,16 @@ docs/checkpoint.md:38-44). The shape kept here:
     * `seal(epoch)`   — cheap: move every buffered epoch <= `epoch` into an
       immutable SealedBatch on the sealed queue (no merging, no encoding).
     * `upload_sealed(batch)` — slow, thread-safe: merge the batch into ONE
-      sorted run, build the SST, PUT it to the object store. Touches only
-      the immutable batch and the object store, so a background thread can
-      run it while the stream keeps computing.
-    * `commit_sealed(batch)` — the commit point: insert the SST into L0,
-      maybe compact, atomically swap the manifest. Refuses out-of-order
+      sorted run (`sstable.merge_runs`: a table written only in fixed-width
+      columnar segments is sorted and packed as arrays, with no Python
+      object per key), build the SST, PUT it to the object store. Touches
+      only the immutable batch and the object store, so a background thread
+      can run it while the stream keeps computing.
+    * `commit_sealed(batch)` — the commit point: insert the run the upload
+      phase sorted into L0 (array-backed; the bytes just built are not
+      parsed back — `SsTable.parse` and its crc check are for what is READ
+      from the object store), maybe compact, atomically swap the
+      manifest. Refuses out-of-order
       commits (`batch` must be the oldest sealed batch). Only after the
       manifest lands is the epoch committed — a crash at any point recovers
       to the last manifest, never a torn state.
@@ -28,7 +39,13 @@ docs/checkpoint.md:38-44). The shape kept here:
   see neither staged nor sealed data.
 - When L0 grows past a threshold, a full compaction merges L0+L1 into one
   bottom-level SST and drops tombstones (the reference's compactor collapsed
-  to its essential effect).
+  to its essential effect). Inline and background compaction run the same
+  `merge_runs` over the runs' parts and install its output without a parse.
+
+The object format (`RWS1`, state/sstable.py) and the manifest did not change
+when runs became array-backed: an upload PUTs the bytes
+`build_sstable(epoch, sorted(<dict overlay of the same writes>.items()))`
+gives.
 
 Recovery: `HummockStateStore.open(object_store)` reads the manifest and
 serves `get`/`iter_range` at the committed version; `committed_epoch()`
@@ -38,12 +55,13 @@ seeds the barrier coordinator's epoch floor.
 from __future__ import annotations
 
 import json
-from typing import Iterator, Optional
+from typing import Iterator, Optional, Union
 
 from .object_store import ObjectStore, ResilientObjectStore
-from .sstable import (SsTable, SsTableCorruption, build_sstable,
-                      frame_meta, unframe_meta)
-from .store import StateStore, WriteBatch, lazy_merge_ranges
+from .sstable import (Part, SsTable, SsTableCorruption, build_sstable_parts,
+                      frame_meta, merge_runs, unframe_meta)
+from .store import (ColumnarSegment, StateStore, WriteBatch,
+                    lazy_merge_ranges, segments_get, segments_range)
 
 MANIFEST_PATH = "MANIFEST"
 QUARANTINE_PREFIX = "quarantine/"
@@ -53,27 +71,32 @@ def _sst_path(sst_id: int) -> str:
     return f"ssts/{sst_id:010d}.sst"
 
 
+# one write segment of a per-epoch buffer: row form or columnar
+Segment = Union[dict, ColumnarSegment]
+
+
 class SealedBatch:
     """Immutable snapshot of shared-buffer epochs <= seal_epoch, queued for
-    background upload. The per-epoch dicts are kept distinct (not merged)
-    so reads and `max_epoch` filtering keep exact shared-buffer semantics
-    until the commit lands; the merge happens in `upload_sealed`, off the
-    barrier path. `sst_id` is allocated at seal time (on the event loop, so
-    ids stay ordered even with uploads in flight); `data` is set by the
-    upload phase and is what `commit_sealed` installs into L0."""
+    background upload. The per-epoch segment lists are kept distinct (not
+    merged) so reads and `max_epoch` filtering keep exact shared-buffer
+    semantics until the commit lands; the merge happens in `upload_sealed`,
+    off the barrier path. `sst_id` is allocated at seal time (on the event
+    loop, so ids stay ordered even with uploads in flight); the upload
+    phase sets `parts`: the merged run whose SST it PUT, and what
+    `commit_sealed` installs into L0."""
 
-    __slots__ = ("seal_epoch", "epochs", "sst_id", "data")
+    __slots__ = ("seal_epoch", "epochs", "sst_id", "parts")
 
-    def __init__(self, seal_epoch: int,
-                 epochs: dict[int, dict[bytes, Optional[bytes]]]):
+    def __init__(self, seal_epoch: int, epochs: dict[int, list[Segment]]):
         self.seal_epoch = seal_epoch
         self.epochs = epochs
         self.sst_id: Optional[int] = None
-        self.data: Optional[bytes] = None
+        self.parts: Optional[list[Part]] = None
 
     @property
     def is_empty(self) -> bool:
-        return not any(self.epochs.values())
+        return not any(len(seg) for segs in self.epochs.values()
+                       for seg in segs)
 
 
 class CompactionTask:
@@ -87,7 +110,7 @@ class CompactionTask:
     that the scrubber sweeps."""
 
     __slots__ = ("run_ids", "ssts", "l1_id", "l1_sst", "into_l1",
-                 "out_sst_id", "out_epoch", "input_bytes", "data",
+                 "out_sst_id", "out_epoch", "input_bytes", "parts",
                  "keys_in", "keys_out")
 
     def __init__(self, runs: list["SsTable"], l1: Optional["SsTable"],
@@ -100,9 +123,9 @@ class CompactionTask:
         self.out_sst_id = out_sst_id
         self.out_epoch = max([t.epoch for t in runs]
                              + ([l1.epoch] if l1 is not None else []))
-        self.input_bytes = sum(_sst_bytes(t) for t in runs) \
-            + (_sst_bytes(l1) if l1 is not None else 0)
-        self.data: Optional[bytes] = None
+        self.input_bytes = sum(t.payload_bytes for t in runs) \
+            + (l1.payload_bytes if l1 is not None else 0)
+        self.parts: Optional[list[Part]] = None   # the merged run, once PUT
         self.keys_in = sum(len(t) for t in runs) \
             + (len(l1) if l1 is not None else 0)
         self.keys_out = 0
@@ -113,9 +136,12 @@ class CompactionTask:
                                else [])
 
 
-def _sst_bytes(sst: SsTable) -> int:
-    return sum(len(k) for k in sst.keys) \
-        + sum(len(v) for v in sst.vals if v is not None)
+def _merge_ssts(l1: Optional[SsTable], l0: list[SsTable],
+                drop_tombstones: bool) -> list[Part]:
+    """The one run that L1 (if any) below `l0` (newest first) reads as."""
+    oldest_first = ([l1] if l1 is not None else []) + l0[::-1]
+    return merge_runs([p for t in oldest_first for p in t.parts],
+                      drop_tombstones)
 
 
 class HummockStateStore(StateStore):
@@ -140,8 +166,8 @@ class HummockStateStore(StateStore):
         self.quarantined: list[str] = []
         self.restored_objects: list[str] = []
         self.backup_store: Optional[ObjectStore] = backup_store
-        # epoch -> {key: value|None}; dict order = staging order within epoch
-        self._shared: dict[int, dict[bytes, Optional[bytes]]] = {}
+        # epoch -> its write segments in staging order (see module doc)
+        self._shared: dict[int, list[Segment]] = {}
         # sealed-but-uncommitted batches, oldest first (the uploader queue)
         self._sealed: list[SealedBatch] = []
         self._l0: list[SsTable] = []   # newest first
@@ -303,25 +329,21 @@ class HummockStateStore(StateStore):
                             frame_meta(json.dumps(m).encode()))
 
     # --------------------------------------------------------------- reads
-    def get(self, key: bytes) -> Optional[bytes]:
+    def _staged(self) -> Iterator[tuple[int, list[Segment]]]:
+        """(epoch, segments) of every uncommitted buffer, newest first:
+        the shared buffer, then the sealed queue (sealed = still staged)."""
         for epoch in sorted(self._shared, reverse=True):
-            buf = self._shared[epoch]
-            if key in buf:
-                return buf[key]
+            yield epoch, self._shared[epoch]
         for batch in reversed(self._sealed):          # newest batch first
             for epoch in sorted(batch.epochs, reverse=True):
-                buf = batch.epochs[epoch]
-                if key in buf:
-                    return buf[key]
-        for sst in self._l0:
-            found, v = sst.get(key)
+                yield epoch, batch.epochs[epoch]
+
+    def get(self, key: bytes) -> Optional[bytes]:
+        for _epoch, segs in self._staged():
+            found, v = segments_get(segs, key)
             if found:
                 return v
-        if self._l1 is not None:
-            found, v = self._l1.get(key)
-            if found:
-                return v
-        return None
+        return self.get_committed(key)
 
     def get_committed(self, key: bytes) -> Optional[bytes]:
         """Point get at the COMMITTED snapshot (SSTs under the manifest
@@ -353,23 +375,32 @@ class HummockStateStore(StateStore):
         in-flight barrier epoch, so only staged epochs need filtering)."""
         streams = []
         if not committed_only:
-            buffers = [(e, self._shared[e])
-                       for e in sorted(self._shared, reverse=True)]
-            for batch in reversed(self._sealed):  # sealed = still staged
-                buffers.extend(
-                    (e, batch.epochs[e])
-                    for e in sorted(batch.epochs, reverse=True))
-            for epoch, buf in buffers:            # newest first
+            for epoch, segs in self._staged():    # newest first
                 if max_epoch is not None and epoch > max_epoch:
                     continue
-                streams.append(sorted(
-                    (k, v) for k, v in buf.items()
-                    if start <= k and (not end or k < end)))
+                streams.append(
+                    sorted(segments_range(segs, start, end).items()))
         for sst in self._l0:                      # newest first
             streams.append(sst.iter_range(start, end))
         if self._l1 is not None:
             streams.append(self._l1.iter_range(start, end))
         yield from lazy_merge_ranges(streams)
+
+    def scan_range(self, start: bytes, end: bytes
+                   ) -> list[tuple[bytes, bytes]]:
+        """`iter_range(start, end)` in one piece, through the merge the
+        uploads and the compactor use: every run's share of the range
+        (array slices where the run is array-backed) and the staged writes
+        above them, newest version per key, tombstones dropped. Recovery's
+        scans read a table this way; the lazy k-way merge stays for
+        readers that stop early (backfill snapshot batches)."""
+        runs = ([self._l1] if self._l1 is not None else []) + self._l0[::-1]
+        pieces: list = [part.range_part(start, end) for sst in runs
+                        for part in sst.parts_in(start, end)]
+        for _epoch, segs in reversed(list(self._staged())):
+            pieces.append(segments_range(segs, start, end))
+        return [kv for part in merge_runs(pieces, drop_tombstones=True)
+                for kv in part.iter_range(b"", b"")]
 
     def committed_epoch(self) -> int:
         return self._committed_epoch
@@ -415,17 +446,30 @@ class HummockStateStore(StateStore):
             self._l0 = [t for t in self._l0 if t.sst_id not in drop_ids]
         for b in self._unconfirmed:
             for e in sorted(b.epochs):
-                buf = self._shared.setdefault(e, {})
                 # original staging order preserved; existing (newer)
                 # staged writes for the same epoch overlay the restage
-                merged = dict(b.epochs[e])
-                merged.update(buf)
-                self._shared[e] = merged
+                self._shared[e] = b.epochs[e] + self._shared.get(e, [])
         self._unconfirmed = []
 
     # -------------------------------------------------------------- writes
     def ingest_batch(self, batch: WriteBatch) -> None:
-        self._shared.setdefault(batch.epoch, {}).update(batch.puts)
+        self._count_write_keys(batch)
+        segs = self._shared.setdefault(batch.epoch, [])
+        if isinstance(batch.puts, ColumnarSegment):
+            segs.append(batch.puts)
+        elif segs and isinstance(segs[-1], dict):
+            segs[-1].update(batch.puts)
+        else:
+            segs.append(dict(batch.puts))
+
+    def _discard_staged(self, table_ids: set) -> None:
+        for epoch, segs in self._shared.items():
+            for seg in segs:
+                if isinstance(seg, dict):
+                    self._discard_from_dict(seg, table_ids)
+            self._shared[epoch] = [
+                seg for seg in segs if isinstance(seg, dict)
+                or seg.table_id not in table_ids]
 
     # ------------------------------------------------- seal/upload/commit
     def seal(self, epoch: int) -> SealedBatch:
@@ -451,14 +495,13 @@ class HummockStateStore(StateStore):
         background uploader runs it via asyncio.to_thread while the stream
         keeps computing. No store state mutates here; a failure or a crash
         mid-upload leaves at worst an orphan object no manifest references."""
-        if batch.sst_id is None or batch.data is not None:
+        if batch.sst_id is None or batch.parts is not None:
             return
-        merged: dict[bytes, Optional[bytes]] = {}
-        for e in sorted(batch.epochs):           # oldest -> newest overlay
-            merged.update(batch.epochs[e])
-        data = build_sstable(batch.seal_epoch, sorted(merged.items()))
-        self.objects.upload(_sst_path(batch.sst_id), data)
-        batch.data = data
+        parts = merge_runs([seg for e in sorted(batch.epochs)
+                            for seg in batch.epochs[e]])   # oldest first
+        self.objects.upload(_sst_path(batch.sst_id),
+                            build_sstable_parts(batch.seal_epoch, parts))
+        batch.parts = parts
 
     def commit_sealed(self, batch: SealedBatch) -> dict:
         """Phase 3, the commit point (event loop only): install the SST
@@ -471,9 +514,10 @@ class HummockStateStore(StateStore):
             f"{batch.seal_epoch} is not the oldest sealed batch)")
         new_ids: list[int] = []
         if batch.sst_id is not None:
-            assert batch.data is not None, \
+            assert batch.parts is not None, \
                 "commit_sealed before upload_sealed"
-            self._l0.insert(0, SsTable.parse(batch.sst_id, batch.data))
+            self._l0.insert(
+                0, SsTable(batch.sst_id, batch.seal_epoch, batch.parts))
             new_ids.append(batch.sst_id)
         self._sealed.pop(0)
         self._committed_epoch = max(self._committed_epoch, batch.seal_epoch)
@@ -538,20 +582,16 @@ class HummockStateStore(StateStore):
         """Full merge of L1 + L0 into one bottom-level SST; tombstones are
         dropped (nothing lives below L1). Returns obsolete sst ids — the
         caller deletes them only after the new manifest is durable."""
-        merged: dict[bytes, Optional[bytes]] = {}
-        if self._l1 is not None:
-            merged.update(zip(self._l1.keys, self._l1.vals))
-        for sst in reversed(self._l0):
-            merged.update(zip(sst.keys, sst.vals))
-        live = sorted((k, v) for k, v in merged.items() if v is not None)
+        live = _merge_ssts(self._l1, self._l0, drop_tombstones=True)
         obsolete = [t.sst_id for t in self._l0]
         if self._l1 is not None:
             obsolete.append(self._l1.sst_id)
         sst_id = self._next_sst_id
         self._next_sst_id += 1
-        data = build_sstable(self._committed_epoch, live)
-        self.objects.upload(_sst_path(sst_id), data)
-        self._l1 = SsTable.parse(sst_id, data)
+        self.objects.upload(
+            _sst_path(sst_id),
+            build_sstable_parts(self._committed_epoch, live))
+        self._l1 = SsTable(sst_id, self._committed_epoch, live)
         self._l0 = []
         return obsolete
 
@@ -582,7 +622,7 @@ class HummockStateStore(StateStore):
         for sst in reversed(self._l0):
             if sst.epoch > floor_epoch:
                 break
-            size = _sst_bytes(sst)
+            size = sst.payload_bytes
             if eligible and (len(eligible) >= max_runs
                              or spent + size > max_bytes):
                 break
@@ -593,7 +633,7 @@ class HummockStateStore(StateStore):
         covers_l0 = len(eligible) == len(self._l0)
         l1 = None
         if covers_l0 and self._l1 is not None \
-                and spent + _sst_bytes(self._l1) <= max_bytes:
+                and spent + self._l1.payload_bytes <= max_bytes:
             l1 = self._l1
         into_l1 = covers_l0 and (l1 is not None or self._l1 is None)
         if len(eligible) < 2 and not into_l1:
@@ -609,17 +649,12 @@ class HummockStateStore(StateStore):
         the immutable input SsTables and the object store (the uploader
         discipline of `upload_sealed`). A crash here leaves an orphan
         output object no manifest references."""
-        merged: dict[bytes, Optional[bytes]] = {}
-        if task.l1_sst is not None:
-            merged.update(zip(task.l1_sst.keys, task.l1_sst.vals))
-        for sst in reversed(task.ssts):        # oldest -> newest overlay
-            merged.update(zip(sst.keys, sst.vals))
-        items = sorted((k, v) for k, v in merged.items()
-                       if v is not None or not task.into_l1)
-        task.keys_out = len(items)
-        data = build_sstable(task.out_epoch, items)
-        self.objects.upload(_sst_path(task.out_sst_id), data)
-        task.data = data
+        parts = _merge_ssts(task.l1_sst, task.ssts,
+                            drop_tombstones=task.into_l1)
+        task.keys_out = sum(len(p) for p in parts)
+        self.objects.upload(_sst_path(task.out_sst_id),
+                            build_sstable_parts(task.out_epoch, parts))
+        task.parts = parts
 
     def install_compaction(self, task: CompactionTask) -> Optional[list[int]]:
         """Commit point of a background merge (event loop only): swap the
@@ -628,7 +663,7 @@ class HummockStateStore(StateStore):
         manifest landed), or None when the task no longer applies (the
         manifest was reloaded underneath it: restore, quarantine reopen).
         An abandoned output is an orphan the scrubber sweeps."""
-        assert self.manifest_owner and task.data is not None
+        assert self.manifest_owner and task.parts is not None
         k = len(task.run_ids)
         tail = [t.sst_id for t in self._l0[-k:]]
         l1_now = self._l1.sst_id if self._l1 is not None else None
@@ -636,7 +671,7 @@ class HummockStateStore(StateStore):
                 or (task.l1_id is not None and l1_now != task.l1_id):
             self.abandon_compaction(task)
             return None
-        out = SsTable.parse(task.out_sst_id, task.data)
+        out = SsTable(task.out_sst_id, task.out_epoch, task.parts)
         if task.into_l1:
             self._l1 = out
             self._l0 = self._l0[:-k]

@@ -14,18 +14,31 @@ executor writes its state delta here, `commit` flushes to the state store,
 and recovery rebuilds HBM arrays by scanning this table. Consistency checks
 (insert-must-not-exist etc.) mirror the reference's OpConsistencyLevel
 (mem_table.rs) and catch changelog bugs early.
+
+The mem-table is a list of write SEGMENTS in staging order. One
+`write_chunk_columns` call on an all-INT64, ascending-pk table is one
+`ColumnarSegment` (state/store.py): the `[n, K]` key matrix, `[n, V]` value
+matrix and put lane the native codec made, never taken apart into a `bytes`
+object per key — `commit` hands it to the store as it is, and it stays that
+way up to the L0 run (state/hummock.py, state/sstable.py). Every other
+write (insert / delete / update / write_chunk_rows, or a schema the batch
+codec cannot encode) goes to a dict segment, the row form. A later segment
+overlays an earlier one and within a columnar segment the last row of a key
+counts, so the last write wins exactly as one dict gave it. What reaches
+the object store (`RWS1`) is the same bytes either way.
 """
 
 from __future__ import annotations
 
-from typing import Iterator, Optional, Sequence
+from typing import Iterator, Optional, Sequence, Union
 
 import numpy as np
 
 from ..common.types import Schema
 from ..common.vnode import VNODE_COUNT, compute_vnodes_numpy
 from .serde import RowSerde, encode_memcomparable, decode_memcomparable
-from .store import StateStore, WriteBatch, encode_table_key
+from .store import (ColumnarSegment, StateStore, WriteBatch,
+                    encode_table_key, segments_get, segments_range)
 
 
 class StateTableError(Exception):
@@ -58,10 +71,11 @@ class StateTable:
         self.check_consistency = check_consistency
         self._pk_types = tuple(schema[i].data_type for i in self.pk_indices)
         self._serde = RowSerde(schema)
-        # mem-table: full key -> (op, row|None, enc|None); op in {+1 put,
-        # -1 delete}. Batch writes store pre-ENCODED values (native codec)
-        # and decode lazily on read-through.
-        self._mem: dict[bytes, tuple[int, Optional[tuple], Optional[bytes]]] = {}
+        # mem-table: write segments in staging order (see module doc). A
+        # dict segment maps full key -> row (None = delete); a columnar
+        # one holds pre-ENCODED values, decoded lazily on read-through.
+        self._mem: list[Union[dict[bytes, Optional[tuple]],
+                              ColumnarSegment]] = []
         self.epoch: Optional[int] = None
         self._all_i64 = all(
             np.dtype(f.data_type.np_dtype).kind in "i" and
@@ -111,23 +125,40 @@ class StateTable:
     def init_epoch(self, epoch: int) -> None:
         self.epoch = epoch
 
+    def _row_segment(self) -> dict[bytes, Optional[tuple]]:
+        """The dict segment row-form writes go to: the newest segment if
+        it is one, else a new one on top."""
+        if not self._mem or not isinstance(self._mem[-1], dict):
+            self._mem.append({})
+        return self._mem[-1]
+
+    def _mem_get(self, key: bytes) -> tuple[bool, Optional[tuple]]:
+        """(found, row) of the newest staged write of `key`; found with
+        row None is a staged delete."""
+        found, v = segments_get(self._mem, key)
+        return found, self._staged_row(v)
+
+    def _staged_row(self, v) -> Optional[tuple]:
+        """A mem-table value as a row: dict segments hold rows, columnar
+        ones encoded values."""
+        return self._serde.decode(v) if isinstance(v, bytes) else v
+
     def insert(self, row: tuple) -> None:
         k = self._key_of(row)
-        prev = self._mem.get(k)
-        if self.check_consistency and prev is not None and prev[0] > 0:
+        if self.check_consistency and self._mem_get(k)[1] is not None:
             raise StateTableError(f"double insert for key {row!r} in table {self.table_id}")
-        self._mem[k] = (1, tuple(row), None)
+        self._row_segment()[k] = tuple(row)
 
     def delete(self, row: tuple) -> None:
         # Always record a tombstone: an insert+delete within one epoch must
         # still delete any version of the key from a PRIOR epoch in the store
         # (cancelling the put alone would resurrect the old row).
-        self._mem[self._key_of(row)] = (-1, None, None)
+        self._row_segment()[self._key_of(row)] = None
 
     def update(self, old_row: tuple, new_row: tuple) -> None:
         ko, kn = self._key_of(old_row), self._key_of(new_row)
         if ko == kn:
-            self._mem[kn] = (1, tuple(new_row), None)
+            self._row_segment()[kn] = tuple(new_row)
         else:
             self.delete(old_row)
             self.insert(new_row)
@@ -140,12 +171,11 @@ class StateTable:
         if not rows:
             return
         vnodes = self._vnodes_of_batch([r for _, r in rows])
+        seg = self._row_segment()
         for (op, row), vn in zip(rows, vnodes):
             k = self.key_of_pk(tuple(row[i] for i in self.pk_indices), int(vn))
-            if op in (OP_INSERT, OP_UPDATE_INSERT):
-                self._mem[k] = (1, tuple(row), None)
-            else:
-                self._mem[k] = (-1, None, None)
+            seg[k] = (tuple(row) if op in (OP_INSERT, OP_UPDATE_INSERT)
+                      else None)
 
     def _vnodes_of_batch(self, rows: Sequence[tuple]) -> np.ndarray:
         if not self.dist_key_indices:
@@ -166,8 +196,9 @@ class StateTable:
 
         For all-int64 schemas with ascending pk, key and value encoding run
         in the native C++ codec (risingwave_tpu/native) over the whole
-        batch; otherwise falls back to the per-row path. `ops` uses chunk
-        Op encoding; rows with vis False are skipped."""
+        batch and the batch is staged as ONE columnar segment; otherwise
+        falls back to the per-row path. `ops` uses chunk Op encoding; rows
+        with vis False are skipped."""
         from ..common.chunk import OP_INSERT, OP_UPDATE_INSERT
         ops = np.asarray(ops)
         vis = np.asarray(vis, dtype=bool)
@@ -177,7 +208,7 @@ class StateTable:
         native_ok = (self._all_i64 and self.pk_descending is None)
         enc_keys = enc_vals = None
         if native_ok:
-            from ..native import crc32_i64_batch, mc_encode_i64_batch,                 row_encode_i64_batch
+            from ..native import mc_encode_i64_batch, row_encode_i64_batch
             pk_mat = np.stack([np.asarray(cols[i], dtype=np.int64)[idx]
                                for i in self.pk_indices], axis=1)
             mc = mc_encode_i64_batch(pk_mat)
@@ -205,13 +236,9 @@ class StateTable:
                     all_mat, self._serde._nbytes_nulls)
         if enc_keys is not None:
             ops_v = ops[idx]
-            put = (ops_v == OP_INSERT) | (ops_v == OP_UPDATE_INSERT)
-            for r in range(idx.size):
-                k = enc_keys[r].tobytes()
-                if put[r]:
-                    self._mem[k] = (1, None, enc_vals[r].tobytes())
-                else:
-                    self._mem[k] = (-1, None, None)
+            self._mem.append(ColumnarSegment(
+                self.table_id, enc_keys, enc_vals,
+                (ops_v == OP_INSERT) | (ops_v == OP_UPDATE_INSERT)))
             return
         rows = [(int(ops[i]), tuple(
             np.asarray(cols[j])[i].item() for j in range(len(cols))))
@@ -228,11 +255,9 @@ class StateTable:
             for j, i in enumerate(self.dist_key_indices):
                 row_for_vnode[i] = dist_values[j]
         k = self._key_of(tuple(row_for_vnode))
-        if k in self._mem:
-            op, row, enc = self._mem[k]
-            if op <= 0:
-                return None
-            return row if row is not None else self._serde.decode(enc)
+        found, row = self._mem_get(k)
+        if found:
+            return row
         v = self.store.get(k)
         return self._serde.decode(v) if v is not None else None
 
@@ -258,13 +283,9 @@ class StateTable:
         out: list = []
         pending_keys, pending_pos = [], []
         for i, k in enumerate(keys):
-            if k in self._mem:
-                op, row, enc = self._mem[k]
-                out.append(None if op <= 0 else
-                           (row if row is not None
-                            else self._serde.decode(enc)))
-            else:
-                out.append(None)
+            found, row = self._mem_get(k)
+            out.append(row)
+            if not found:
                 pending_keys.append(k)
                 pending_pos.append(i)
         for i, v in zip(pending_pos, self.store.get_many(pending_keys)):
@@ -276,15 +297,11 @@ class StateTable:
         """All rows of one vnode, pk order, mem-table merged (:1255)."""
         start, end = self.vnode_key_range(vnode)
         merged: dict[bytes, Optional[tuple]] = {}
-        for k, v in self.store.iter_range(start, end):
+        for k, v in self.store.scan_range(start, end):
             merged[k] = self._serde.decode(v)
-        for k, (op, row, enc) in self._mem.items():
-            if start <= k < end:
-                if op <= 0:
-                    merged[k] = None
-                else:
-                    merged[k] = (row if row is not None
-                                 else self._serde.decode(enc))
+        merged.update(
+            (k, self._staged_row(v))
+            for k, v in segments_range(self._mem, start, end).items())
         for k in sorted(merged):
             if merged[k] is not None:
                 yield k, merged[k]
@@ -298,15 +315,15 @@ class StateTable:
         """Flush mem-table to the store and advance the epoch (:1036).
         Returns number of kv writes."""
         assert self.epoch is not None, "init_epoch not called"
-        puts: dict[bytes, Optional[bytes]] = {}
-        for k, (op, row, enc) in self._mem.items():
-            if op <= 0:
-                puts[k] = None
-            else:
-                puts[k] = enc if enc is not None else self._serde.encode(row)
-        n = len(puts)
-        if puts:
-            self.store.ingest_batch(WriteBatch(self.table_id, self.epoch, puts))
+        n = 0
+        for seg in self._mem:
+            if isinstance(seg, dict):
+                seg = {k: None if row is None else self._serde.encode(row)
+                       for k, row in seg.items()}
+            if len(seg):
+                n += len(seg)
+                self.store.ingest_batch(
+                    WriteBatch(self.table_id, self.epoch, seg))
         self._mem.clear()
         self.epoch = new_epoch
         return n

@@ -15,8 +15,10 @@ from __future__ import annotations
 
 import bisect
 import heapq
-from dataclasses import dataclass, field
-from typing import Iterator, Optional
+from dataclasses import dataclass
+from typing import Iterator, Optional, Union
+
+import numpy as np
 
 
 def encode_table_key(table_id: int, vnode: int, pk_bytes: bytes) -> bytes:
@@ -48,12 +50,133 @@ def lazy_merge_ranges(streams):
             yield k, v
 
 
+class ColumnarSegment:
+    """One batch of writes to one table as the native codec made it: a
+    `[n, K]` uint8 key matrix, a `[n, V]` uint8 value matrix and a put lane
+    (False = tombstone; that row's value is ignored). Rows are in STAGING
+    order and keys may repeat: the last row of a key is the write that
+    counts. No `bytes` object exists per key; reads build a sorted index on
+    first use and materialise only what they return. The arrays are never
+    written after construction, so a segment can be read from the upload
+    thread while the event loop serves reads from it."""
+
+    __slots__ = ("table_id", "keys", "vals", "put", "_index")
+
+    def __init__(self, table_id: int, keys: np.ndarray, vals: np.ndarray,
+                 put: np.ndarray):
+        assert keys.ndim == 2 and vals.ndim == 2 \
+            and len(keys) == len(vals) == len(put)
+        self.table_id = table_id
+        self.keys = np.ascontiguousarray(keys, dtype=np.uint8)
+        self.vals = np.ascontiguousarray(vals, dtype=np.uint8)
+        self.put = np.ascontiguousarray(put, dtype=np.bool_)
+        self._index = None
+
+    def __len__(self) -> int:
+        return len(self.put)
+
+    @property
+    def key_view(self) -> np.ndarray:
+        """The key matrix as `[n]` fixed-width byte strings: numpy orders
+        and compares them like `bytes` of that one width (memcmp)."""
+        return self.keys.view(f"S{self.keys.shape[1]}").ravel()
+
+    def _sorted(self) -> tuple[np.ndarray, np.ndarray]:
+        """(keys in order, their rows): built once, on the first read.
+        The sort is stable, so among equal keys the newest row is last."""
+        if self._index is None:
+            view = self.key_view
+            order = np.argsort(view, kind="stable")
+            self._index = (view[order], order)
+        return self._index
+
+    def _value_at(self, row: int) -> Optional[bytes]:
+        return self.vals[row].tobytes() if self.put[row] else None
+
+    def get(self, key: bytes) -> tuple[bool, Optional[bytes]]:
+        """(found, value) — found with value None means tombstone."""
+        if len(key) != self.keys.shape[1]:
+            return False, None
+        skeys, order = self._sorted()
+        needle = np.frombuffer(key, dtype=skeys.dtype)[0]
+        i = int(np.searchsorted(skeys, needle, side="right")) - 1
+        if i < 0 or skeys[i] != needle:
+            return False, None
+        return True, self._value_at(order[i])
+
+    def _bounds(self, start: bytes, end: bytes) -> tuple[int, int]:
+        """Positions [i, j) of the sorted index that hold the keys in
+        [start, end) (`end` empty = unbounded)."""
+        skeys, _ = self._sorted()
+        width = self.keys.shape[1]
+
+        def first_at_or_after(key: bytes) -> int:
+            # pad or cut the bound to the key width; a LONGER bound with an
+            # equal head sorts after the stored key
+            head = np.frombuffer(key[:width].ljust(width, b"\0"),
+                                 dtype=skeys.dtype)[0]
+            side = "right" if len(key) > width else "left"
+            return int(np.searchsorted(skeys, head, side=side))
+
+        i = first_at_or_after(start)
+        return i, max(i, first_at_or_after(end) if end else len(skeys))
+
+    def range_items(self, start: bytes, end: bytes
+                    ) -> list[tuple[bytes, Optional[bytes]]]:
+        """The newest write of every key in [start, end), in key order."""
+        skeys, order = self._sorted()
+        i, j = self._bounds(start, end)
+        if i == j:
+            return []
+        newest = np.ones(j - i, dtype=bool)      # last row of each key
+        newest[:-1] = skeys[i + 1:j] != skeys[i:j - 1]
+        return [(self.keys[r].tobytes(), self._value_at(r))
+                for r in order[i:j][newest]]
+
+    def to_puts(self) -> dict[bytes, Optional[bytes]]:
+        """The per-key form (a dict in staging order, later rows
+        overwriting earlier ones): what a store that keeps dicts, or a
+        merge that cannot stay columnar, falls back to."""
+        width, vwidth = self.keys.shape[1], self.vals.shape[1]
+        kbuf, vbuf = self.keys.tobytes(), self.vals.tobytes()
+        return {kbuf[r * width:(r + 1) * width]:
+                (vbuf[r * vwidth:(r + 1) * vwidth] if p else None)
+                for r, p in enumerate(self.put.tolist())}
+
+
+def segments_get(segs: list, key: bytes) -> tuple[bool, object]:
+    """(found, value) of the newest write of `key` in write segments (dicts
+    or ColumnarSegments) given in staging order; found with value None is
+    a delete."""
+    for seg in reversed(segs):
+        if isinstance(seg, dict):
+            if key in seg:
+                return True, seg[key]
+        else:
+            found, v = seg.get(key)
+            if found:
+                return True, v
+    return False, None
+
+
+def segments_range(segs: list, start: bytes, end: bytes) -> dict:
+    """The newest write per key in [start, end) (`end` empty = unbounded)
+    of write segments given in staging order, as one dict."""
+    merged: dict = {}
+    for seg in segs:
+        merged.update(
+            ((k, v) for k, v in seg.items()
+             if start <= k and (not end or k < end))
+            if isinstance(seg, dict) else seg.range_items(start, end))
+    return merged
+
+
 @dataclass
 class WriteBatch:
     table_id: int
     epoch: int
-    # key -> value (None = tombstone/delete)
-    puts: dict[bytes, Optional[bytes]]
+    # key -> value (None = tombstone/delete), or the same writes columnar
+    puts: Union[dict[bytes, Optional[bytes]], ColumnarSegment]
 
 
 class StateStore:
@@ -122,14 +245,29 @@ class StateStore:
         replayed intervals itself."""
         ids = set(table_ids)
         self._deferred = [t for t in self._deferred if t[2] not in ids]
-        for buf in getattr(self, "_shared", {}).values():
-            for k in [k for k in buf
-                      if int.from_bytes(k[:4], "big") in ids]:
-                del buf[k]
+        self._discard_staged(ids)
+
+    def _discard_staged(self, table_ids: set) -> None:
+        """Drop the shared buffer's writes to these tables."""
+        raise NotImplementedError
+
+    @staticmethod
+    def _discard_from_dict(buf: dict, table_ids: set) -> None:
+        for k in [k for k in buf
+                  if int.from_bytes(k[:4], "big") in table_ids]:
+            del buf[k]
 
     def run_deferred(self, epoch: int) -> None:
         for stages in self.take_deferred(epoch):
             self._run_stages(stages)
+
+    @staticmethod
+    def _count_write_keys(batch: WriteBatch) -> None:
+        """`state_write_keys_total{path}`: every `ingest_batch` counts
+        the keys it is handed, by the form they came in."""
+        from ..utils.metrics import STATE_WRITE_KEYS
+        STATE_WRITE_KEYS[isinstance(batch.puts, ColumnarSegment)].inc(
+            len(batch.puts))
 
     def get(self, key: bytes) -> Optional[bytes]:
         raise NotImplementedError
@@ -161,6 +299,13 @@ class StateStore:
         (no_shuffle_backfill.rs reads the upstream table at exactly the
         barrier epoch)."""
         raise NotImplementedError
+
+    def scan_range(self, start: bytes, end: bytes
+                   ) -> list[tuple[bytes, bytes]]:
+        """Everything `iter_range(start, end)` yields, at once: for a
+        reader that takes the whole range anyway (a table's recovery scan),
+        so a backend may merge it in bulk rather than entry by entry."""
+        return list(self.iter_range(start, end))
 
     def ingest_batch(self, batch: WriteBatch) -> None:
         raise NotImplementedError
@@ -222,7 +367,15 @@ class MemoryStateStore(StateStore):
         yield from lazy_merge_ranges(streams)
 
     def ingest_batch(self, batch: WriteBatch) -> None:
-        self._shared.setdefault(batch.epoch, {}).update(batch.puts)
+        self._count_write_keys(batch)
+        puts = batch.puts
+        if isinstance(puts, ColumnarSegment):
+            puts = puts.to_puts()      # this store keeps dicts: volatile
+        self._shared.setdefault(batch.epoch, {}).update(puts)
+
+    def _discard_staged(self, table_ids: set) -> None:
+        for buf in self._shared.values():
+            self._discard_from_dict(buf, table_ids)
 
     def sync(self, epoch: int) -> dict:
         self.run_deferred(epoch)

@@ -901,10 +901,11 @@ class HashAggExecutor(Executor):
     def _apply_evict_deletes(self, keys_np, n: int) -> None:
         width = sum(self._call_persist_width(j)
                     for j in range(len(self.specs))) + 1
-        pad = (0,) * width                  # non-pk columns unused by delete
-        rows = [(int(OP_DELETE), tuple(k[r].item() for k in keys_np) + pad)
-                for r in range(n)]
-        self.state_table.write_chunk_rows(rows)
+        pad = np.zeros(n, dtype=np.int64)   # non-pk columns unused by delete
+        self.state_table.write_chunk_columns(
+            np.full(n, OP_DELETE, dtype=np.int8),
+            [np.asarray(k)[:n] for k in keys_np] + [pad] * width,
+            np.ones(n, dtype=bool))
 
     def _flush_persist_view(self):
         """The state rows that changed this epoch (computed pre-flush)."""
