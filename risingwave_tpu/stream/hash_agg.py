@@ -15,9 +15,11 @@ ones) exactly like the reference's changelog contract. Zombie slots (groups
 at row_count 0) keep their keys so probe chains stay intact; the executor
 rebuilds/grows the table when load crosses the threshold.
 
-min/max require append-only input here (the reference's retractable min/max
-uses materialized input state, aggregation/minput.rs — that variant lives in
-the planner's fallback path, not this executor yet).
+min/max over append-only input keep one scalar per group; over a retracting
+input (the reference's materialized input state, aggregation/minput.rs) they
+keep a bounded top-K value buffer per group (ops/extrema.py) whose bound is a
+fail-stop contract: the barrier watchdog raises before the checkpoint commits
+where the buffer can no longer know the extremum.
 """
 
 from __future__ import annotations
@@ -37,8 +39,8 @@ from ..common.chunk import (
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall, AggKind
 from ..ops.extrema import (
-    extrema_emit, extrema_empty, extrema_gather, extrema_mask_keep,
-    extrema_underflow, extrema_update,
+    extrema_emit, extrema_empty, extrema_gather, extrema_lossy_groups,
+    extrema_mask_keep, extrema_underflow, extrema_update,
 )
 from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
@@ -49,9 +51,21 @@ from ..ops.hash_table import (
 )
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
-from ..utils.metrics import HASH_PROBE_FALLBACK_ROWS
+from ..utils.metrics import (
+    GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EXTREMA_ERRORS,
+    HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_PROBE_FALLBACK_ROWS,
+)
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
+
+
+# the fail-stop counts of a retractable MIN/MAX (ops/extrema.py), in the
+# order the apply step accumulates them and
+# `hash_agg_extrema_errors_total{kind=...}` names them
+EXTREMA_KINDS = ("underflow", "dropped_delete", "negative_residue")
+
+# the narrowest barrier flush: this many dirty slots (twice the rows)
+FLUSH_MIN_SLOTS = 128
 
 
 @jax.tree_util.register_pytree_node_class
@@ -78,6 +92,10 @@ class AggState:
 
 
 class HashAggExecutor(Executor):
+    # the name MemoryManager.register() gave this agg: the `executor`
+    # label of its series (utils/metrics.py HASH_AGG_*)
+    mem_name: Optional[str] = None
+
     def __init__(self, input: Executor, group_key_indices: Sequence[int],
                  agg_calls: Sequence[AggCall], capacity: int = 1 << 16,
                  state_table: Optional[StateTable] = None,
@@ -140,6 +158,7 @@ class HashAggExecutor(Executor):
         self._apply = jit_state(self._apply_impl, donate_argnums=(0, 1),
                                 name="hash_agg_apply")
         self._flush = jit_state(self._flush_impl, donate_argnums=(0,),
+                                static_argnames=("n_slots",),
                                 name="hash_agg_flush")
         self._live_zombie = jit_state(self._live_zombie_impl,
                                       name="hash_agg_live_zombie")
@@ -150,6 +169,7 @@ class HashAggExecutor(Executor):
         self._rehash = jit_state(self._rehash_impl, static_argnums=1,
                                  donate_argnums=(0,), name="hash_agg_rehash")
         self._persist_view = jit_state(self._persist_view_impl,
+                                       static_argnames=("n_slots",),
                                        name="hash_agg_persist_view")
         # multi-chunk apply: chunks buffered within a barrier interval are
         # applied in ONE dispatch via lax.scan over a stacked batch (the
@@ -209,14 +229,63 @@ class HashAggExecutor(Executor):
                                      name="hash_agg_mem_rehash")
         self._mem_reloads: dict[int, object] = {}
         # device-accumulated watchdog counters, int32 [2]: rows the table
-        # could not place or fold (fail-stop), rows whose probe went past
-        # the fingerprint lane and one verify (hash_table._probe; a metric)
-        self._overflow_dev = jnp.zeros(2, dtype=jnp.int32)
+        # could not place or fold plus the retractable calls' errors
+        # (fail-stop), rows whose probe went past the fingerprint lane and
+        # one verify (hash_table._probe; a metric); with a retractable
+        # MIN/MAX three more, the errors by kind (EXTREMA_KINDS)
+        self._overflow_width = 2 + (len(EXTREMA_KINDS)
+                                    if any(self._retractable) else 0)
+        self._overflow_dev = jnp.zeros(self._overflow_width,
+                                       dtype=jnp.int32)
         self._probe_fallback_seen = 0
+        self._extrema_errs_seen = [0] * len(EXTREMA_KINDS)
         self._occ_dev = jnp.zeros((), dtype=jnp.int32)
-        self._watchdog_pack = jit_state(
-            lambda ov, occ: jnp.stack([ov[0], occ, ov[1]]),
-            name="hash_agg_watchdog_pack")
+        self._watchdog_pack = jit_state(self._watchdog_pack_impl,
+                                        name="hash_agg_watchdog_pack")
+        # what the last watchdog fetch read of this barrier interval, for
+        # the actor's phase dict (take_phase_counts)
+        self._phase_counts: dict = {}
+        # The barrier's flush and persist view lay the dirty slots out at
+        # the front of capacity-wide buffers; a consumer's cost follows
+        # the WIDTH of the chunk it is handed (a second agg's probe, a
+        # join's sorts, the MV's d2h), so a 2^20-slot table that moved
+        # 6,000 groups handed on 2^21-row chunks. Where the watchdog fetch
+        # has brought the dirty count, both programs stop at a power-of-two
+        # number of dirty slots that holds it with room. The width only
+        # ever grows (each new one is a compile, and a consumer's), so
+        # steady traffic settles on one in its first interval. None = not
+        # known (no watchdog fetch): the whole capacity.
+        self._dirty_slots_known: Optional[int] = None
+        self._flush_slots = FLUSH_MIN_SLOTS
+
+    def _watchdog_pack_impl(self, state: AggState, ov, occ):
+        """The barrier's one fetch: [overflow, occupied, probe fallback,
+        rows the flush that follows will emit, lossy groups, dirty slots,
+        then the extrema errors by kind where a call is retractable]. The
+        emit count repeats `_flush_impl`'s visibility per slot, without its
+        compaction: both read the same state, nothing runs between."""
+        exists = state.row_count > 0
+        unchanged = state.prev_exists & exists
+        n_lossy = jnp.int32(0)
+        for j, (st, pe) in enumerate(zip(state.agg_states,
+                                         state.prev_emit)):
+            unchanged &= self._call_emit(j, st) == pe
+            if self._retractable[j]:
+                n_lossy += extrema_lossy_groups(st, state.row_count)
+        moved = state.dirty & ~unchanged
+        n_emit = (jnp.sum((moved & state.prev_exists).astype(jnp.int32))
+                  + jnp.sum((moved & exists).astype(jnp.int32)))
+        n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
+        return jnp.concatenate([
+            jnp.stack([ov[0], occ, ov[1], n_emit, n_lossy, n_dirty]),
+            ov[2:]])
+
+    def take_phase_counts(self) -> dict:
+        """This barrier interval's `agg_emit_rows` (and, with a retractable
+        MIN/MAX, `agg_extrema_lossy_groups`) for the actor's phase dict:
+        host numbers the watchdog fetch brought; empty where it made none."""
+        counts, self._phase_counts = self._phase_counts, {}
+        return counts
 
     def fence_tokens(self) -> list:
         # the state root depends on every program dispatched this epoch,
@@ -273,6 +342,7 @@ class HashAggExecutor(Executor):
             signs.astype(jnp.int64), seg, C + 1)[:C]
         new_states = []
         n_err = jnp.int32(0)
+        ext_errs = []       # per retractable call, int32 [3] by EXTREMA_KINDS
         for j, (spec, call, st) in enumerate(
                 zip(self.specs, self.agg_calls, state.agg_states)):
             if call.arg is None:
@@ -288,9 +358,13 @@ class HashAggExecutor(Executor):
                 st2, e = extrema_update(
                     st, values.astype(spec.state_dtype), valid_in, signs,
                     seg, C, is_max=(call.kind is AggKind.MAX))
+                # a group no row is left of holds no untracked value
+                st2 = (st2[0], st2[1], st2[2] & (row_count > 0))
                 # lossy + emptied + live rows = unknowable extremum
-                e = e + extrema_underflow(st2, row_count)
-                n_err = n_err + e
+                e = jnp.concatenate(
+                    [extrema_underflow(st2, row_count)[None], e])
+                n_err = n_err + jnp.sum(e)
+                ext_errs.append(e)
                 new_states.append(st2)
             else:
                 row_signs = jnp.where(valid_in, signs, 0)
@@ -308,18 +382,23 @@ class HashAggExecutor(Executor):
         # keep the accumulator's dtype stable (the segment sums promote to
         # int64): donation can only reuse the input buffer — and lax.scan
         # only accepts the carry — when the dtype round-trips
-        overflow = (overflow + jnp.stack(
-            [n_unresolved + n_err, n_fallback])).astype(overflow.dtype)
+        counts = jnp.stack([n_unresolved + n_err, n_fallback])
+        if ext_errs:
+            counts = jnp.concatenate([counts, sum(ext_errs)])
+        overflow = (overflow + counts).astype(overflow.dtype)
         return new_state, overflow, occ
 
     # ---------------------------------------------------------- flush
-    def _flush_impl(self, state: AggState):
-        """Emit the barrier diff as one chunk of capacity 2*C with
-        interleaved UD/UI pairs; returns (state', chunk arrays...).
+    def _flush_impl(self, state: AggState, n_slots: Optional[int] = None):
+        """Emit the barrier diff as one chunk of capacity 2 * n_slots (2*C
+        where `n_slots` is None) with interleaved UD/UI pairs; returns
+        (state', chunk arrays...). `n_slots` must hold every dirty slot:
+        the caller has the count from the watchdog fetch.
 
         Compaction is a cumsum-scatter (O(C) scan), not a sort: dirty slot
         with rank j lands at output positions 2j (old value) / 2j+1 (new)."""
         C = state.table.capacity
+        R = C if n_slots is None else n_slots
         exists_now = state.row_count > 0
         dirty = state.dirty
         rank = jnp.cumsum(dirty.astype(jnp.int32)) - 1   # rank among dirty
@@ -328,9 +407,10 @@ class HashAggExecutor(Executor):
         d_slot = jnp.zeros(C, dtype=jnp.int32).at[
             jnp.where(dirty, rank, C)].set(slot_ids, mode="drop")
         n_dirty = jnp.sum(dirty.astype(jnp.int32))
+        d_slot = d_slot[:R]
         existed = state.prev_exists[d_slot]
         exists = exists_now[d_slot]
-        is_dirty = slot_ids < n_dirty
+        is_dirty = slot_ids[:R] < n_dirty
 
         # no-change skip (reference agg_group.rs:71 build_change -> NoChange):
         # a group that existed before, still exists, and whose emitted outputs
@@ -346,7 +426,7 @@ class HashAggExecutor(Executor):
         ops_new = jnp.where(existed, OP_UPDATE_INSERT, OP_INSERT)
 
         def interleave(a, b):
-            return jnp.stack([a, b], axis=1).reshape(2 * C)
+            return jnp.stack([a, b], axis=1).reshape(2 * R)
 
         out_ops = interleave(ops_old, ops_new).astype(jnp.int8)
         out_vis = interleave(vis_old, vis_new)
@@ -466,21 +546,30 @@ class HashAggExecutor(Executor):
         return int(occ)
 
     def _check_watchdog(self) -> None:
-        """ONE small blocking fetch of the device-accumulated (overflow,
-        occupied, probe fallback) triple — called per BARRIER, never per
-        chunk. The counters accumulate on device across the epoch; fetching
-        them per chunk gates throughput on d2h copy latency, so the fetch
-        is a plain blocking np.asarray of three scalars, once per barrier.
+        """ONE small blocking fetch of the device-accumulated counters
+        (`_watchdog_pack_impl`) — called per BARRIER, never per chunk. The
+        counters accumulate on device across the epoch; fetching them per
+        chunk gates throughput on d2h copy latency, so the fetch is a plain
+        blocking np.asarray of a few scalars, once per barrier.
 
         Overflow fail-stops BEFORE this epoch's checkpoint commits, so a
         chunk the table dropped rows from is never made durable; recovery
         replays from the last committed epoch (SURVEY.md §3.5). Capacity
         provisioning + barrier-time growth make this a last-resort
         watchdog."""
-        vals = np.asarray(self._watchdog_pack(self._overflow_dev,
-                                              self._occ_dev))
+        vals = np.asarray(self._watchdog_pack(
+            self.state, self._overflow_dev, self._occ_dev))
         self._note_probe_fallback(int(vals[2]))
+        self._note_flush_counts(int(vals[3]), int(vals[4]))
+        self._dirty_slots_known = int(vals[5])
+        ext = self._note_extrema_errors([int(v) for v in vals[6:]])
         n_un = int(vals[0])
+        if ext:
+            raise RuntimeError(
+                f"retractable MIN/MAX lost its bound mid-epoch ({ext}; "
+                f"top-{self.minput_k} value buffer per group); the "
+                f"extremum is unknowable without a refill from durable "
+                f"input state, so the epoch must not commit")
         if n_un:
             raise RuntimeError(
                 f"hash-agg table overflow mid-epoch ({n_un} rows, "
@@ -493,6 +582,31 @@ class HashAggExecutor(Executor):
         since the last watchdog fetch."""
         HASH_PROBE_FALLBACK_ROWS.inc(total - self._probe_fallback_seen)
         self._probe_fallback_seen = total
+
+    def _note_flush_counts(self, n_emit: int, n_lossy: int) -> None:
+        """What the flush after this watchdog fetch emits, and how many
+        groups of a retractable MIN/MAX are lossy: into the registry, and
+        kept for the epoch trace."""
+        label = self.mem_name or self.identity
+        GLOBAL_METRICS.counter(HASH_AGG_EMIT_ROWS, executor=label).inc(
+            n_emit)
+        self._phase_counts = {"agg_emit_rows": n_emit}
+        if any(self._retractable):
+            GLOBAL_METRICS.gauge(HASH_AGG_EXTREMA_LOSSY_GROUPS,
+                                 executor=label).set(float(n_lossy))
+            self._phase_counts["agg_extrema_lossy_groups"] = n_lossy
+
+    def _note_extrema_errors(self, totals: list) -> dict:
+        """Publish the device's running fail-stop counts of the retractable
+        calls as the increase since the last fetch; returns the kinds that
+        are non-zero (the caller fail-stops on any)."""
+        label = self.mem_name or self.identity
+        for i, (kind, total) in enumerate(zip(EXTREMA_KINDS, totals)):
+            GLOBAL_METRICS.counter(
+                HASH_AGG_EXTREMA_ERRORS, executor=label, kind=kind).inc(
+                    total - self._extrema_errs_seen[i])
+            self._extrema_errs_seen[i] = total
+        return {k: n for k, n in zip(EXTREMA_KINDS, totals) if n}
 
     def _maybe_rebuild_at_barrier(self) -> None:
         """Barrier-time growth: the table is examined between epochs, when
@@ -797,7 +911,8 @@ class HashAggExecutor(Executor):
             dirty=state.dirty.at[tgt].set(True, mode="drop"),
             prev_exists=state.prev_exists.at[tgt].set(True, mode="drop"),
             prev_emit=tuple(prev_emit),
-        ), (overflow + jnp.stack([n_un, n_fb])).astype(overflow.dtype)
+        ), overflow.at[:2].add(
+            jnp.stack([n_un, n_fb]).astype(overflow.dtype))
 
     def _clean_spilled(self, wm) -> None:
         """Watermark state cleaning of EVICTED ranges: spilled keys below
@@ -909,16 +1024,32 @@ class HashAggExecutor(Executor):
 
     def _flush_persist_view(self):
         """The state rows that changed this epoch (computed pre-flush)."""
-        return self._persist_view(self.state)
+        return self._persist_view(self.state, n_slots=self._dirty_width())
 
-    def _persist_view_impl(self, st: AggState):
+    def _dirty_width(self) -> Optional[int]:
+        """Dirty slots the barrier's flush and persist view lay out: a
+        power of two that holds the count the watchdog fetch brought,
+        never narrower than before; None (the whole capacity) where no
+        fetch brought one."""
+        if self._dirty_slots_known is None:
+            return None
+        if self._dirty_slots_known > self._flush_slots:
+            # twice the count: the next width is needed only when an
+            # interval dirties twice the groups of the one that set this
+            while self._flush_slots < 2 * self._dirty_slots_known:
+                self._flush_slots *= 2
+        return min(self._flush_slots, self.capacity)
+
+    def _persist_view_impl(self, st: AggState,
+                           n_slots: Optional[int] = None):
         # persisted row = keys ++ raw agg states ++ row_count; same
         # cumsum-compaction as the flush step. Pure in `st` so the
         # sharded subclass can run it per shard under shard_map.
-        C = st.table.capacity
+        R = st.table.capacity if n_slots is None else n_slots
         exists_now = st.row_count > 0
         d_slot, n_dirty = compact_mask(st.dirty)
-        is_dirty = jnp.arange(C, dtype=jnp.int32) < n_dirty
+        d_slot = d_slot[:R]
+        is_dirty = jnp.arange(R, dtype=jnp.int32) < n_dirty
         exists = exists_now[d_slot]
         existed = st.prev_exists[d_slot]
         vis = is_dirty & (exists | existed)
@@ -1129,7 +1260,9 @@ class HashAggExecutor(Executor):
                 flushed = self._applied_since_flush
                 if flushed:
                     self._applied_since_flush = False
-                    self.state, cols, ops, vis = self._flush(self.state)
+                    self.state, cols, ops, vis = self._flush(
+                        self.state, n_slots=self._dirty_width())
+                    self._dirty_slots_known = None
                     yield StreamChunk(
                         tuple(Column(c) for c in cols), ops, vis, self.schema)
                 if (self.cleaning_watermark_key is not None
@@ -1161,4 +1294,11 @@ class HashAggExecutor(Executor):
                     pos = self.group_key_indices.index(wm.col_idx)
                     if pos == self.cleaning_watermark_key:
                         self._pending_clean_wm = wm.val
-                    self._held_wms[pos] = wm.with_idx(pos)
+                    if self._applied_since_flush or self._pending_chunks:
+                        self._held_wms[pos] = wm.with_idx(pos)
+                    else:
+                        # nothing buffered since the last flush, nothing
+                        # to overtake: a resumed source's re-stated
+                        # watermark reaches the consumers before the
+                        # first chunk, as it had before the crash
+                        yield wm.with_idx(pos)

@@ -12,7 +12,10 @@ totals. This module is that subsystem for the TPU port:
     busy vs. align-wait seconds, dispatch fanout, plus the interval
     phase split (apply / persist / align) the EpochTrace shows, and for
     an actor whose chain holds a sharded executor what crossed the mesh
-    (mesh_rows / mesh_rows_max_shard / mesh_shuffle_bytes);
+    (mesh_rows / mesh_rows_max_shard / mesh_shuffle_bytes), and what its
+    hash aggs emitted and its sorted joins persisted (agg_emit_rows /
+    agg_extrema_lossy_groups / join_persist_delete_rows /
+    join_persist_insert_rows);
   * `ChannelObs` — queue depth + blocked-put (backpressure) seconds on
     every exchange channel feeding an actor;
   * `StreamingStats` — the per-coordinator registrar: `build_graph`
@@ -230,7 +233,7 @@ class ActorObs:
         "actor_id", "debug", "apply_ns", "persist_ns", "input_wait_ns",
         "fence_ns", "_row_acc", "row_count", "chunks_in", "chunks_out",
         "dispatch", "busy_seconds", "align_seconds", "keys",
-        "_occupancy", "registry", "children", "mesh",
+        "_occupancy", "registry", "children", "mesh", "counted",
     )
 
     def __init__(self, registry: MetricsRegistry, actor_id: int,
@@ -249,6 +252,9 @@ class ActorObs:
         self.children = []            # ExecutorObs, chain-walk order
         self.mesh = []                # the chain's sharded executors
         #                               (stream/mesh_shuffle.py)
+        self.counted = []             # the chain's executors that count
+        #                               rows per interval
+        #                               (take_phase_counts)
         self.keys = []
         if debug:
             labels = dict(actor=str(actor_id), executor=executor_label)
@@ -315,6 +321,12 @@ class ActorObs:
                 (ex.take_mesh_interval() for ex in self.mesh),
                 key=lambda iv: (iv["mesh_rows_max_shard"]
                                 / max(1, iv["mesh_rows"]))))
+        for ex in self.counted:
+            # rows the chain's hash aggs flushed downstream and its sorted
+            # joins wrote durably this interval: host numbers, from the
+            # fetches those executors make at the barrier anyway
+            for k, n in ex.take_phase_counts().items():
+                phases[k] = phases.get(k, 0) + n
         if self.debug:
             if self._row_acc is not None:
                 self.row_count.inc(int(np.asarray(self._row_acc)))
@@ -456,6 +468,8 @@ class StreamingStats:
         for ex in _iter_chain(root):
             if hasattr(ex, "take_mesh_interval"):
                 obs.mesh.append(ex)
+            if hasattr(ex, "take_phase_counts"):
+                obs.counted.append(ex)
             if hasattr(ex, "barrier_queue") and hasattr(ex, "obs"):
                 # sources: barrier-queue wait is align (idle) time
                 ex.obs = obs

@@ -191,10 +191,16 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
             st, cols, ops, vis = self._flush_impl(state)
             return st, cols, ops, vis
 
-        self._flush = jit_state(shard_map(
+        flush_mesh = shard_map(
             flush_sharded, in_specs=(shard,),
-            out_specs=(shard, shard, shard, shard), **mesh_kw),
-            donate_argnums=(0,), name="sharded_agg_flush")
+            out_specs=(shard, shard, shard, shard), **mesh_kw)
+        # called as the one-chip flush is; the mesh watchdog brings no
+        # dirty count, so `n_slots` is None and every shard lays its
+        # dirty slots out as wide as its table
+        self._flush = jit_state(
+            lambda state, n_slots=None: flush_mesh(state),
+            static_argnames=("n_slots",), donate_argnums=(0,),
+            name="sharded_agg_flush")
 
         def evict_sharded(state, wm):
             return self._evict_impl(state, wm)
@@ -253,7 +259,8 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         # per-shard watchdog accumulators replace the parent's scalars
         sharding = NamedSharding(mesh, P(VNODE_AXIS))
         self._overflow_dev = jax.device_put(
-            jnp.zeros((self.n_shards, 2), dtype=jnp.int32), sharding)
+            jnp.zeros((self.n_shards, self._overflow_width),
+                      dtype=jnp.int32), sharding)
         self._occ_dev = jax.device_put(
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
         self._dropped_dev = jax.device_put(

@@ -16,16 +16,19 @@ TPU re-design — why not the reference's chained hash multimap:
     throughput = row_capacity x barrier_rate — the measured q7/q8 ceiling.
 
 Here each side's state is a *dense, sorted* struct-of-arrays: rows
-[0, n) sorted ascending by a 63-bit hash of the join key (exact key
-equality re-checked on every candidate, so hash collisions only cost a
-wasted compare — they can never produce a wrong match). Everything is
+[0, n) sorted ascending by a 63-bit ORDER HASH: its high 39 bits are a
+hash of the join key, its low 24 a hash of the row's pk (`order_hash`).
+The rows of one key are still one contiguous range, and inside it a row
+stands where its pk puts it (exact key equality re-checked on every
+candidate, so hash collisions only cost a wasted compare — they can never
+produce a wrong match). Everything is
 sort / searchsorted / cumsum / gather — static shapes, zero
 data-dependent control flow:
 
-  probe   lo/hi = searchsorted(other.khash, h) — each chunk row's matches
-          are a CONTIGUOUS RANGE. Ranges are expanded into a fixed match
-          buffer [M] with cumsum offsets + one locating searchsorted
-          (no loop, unlike the chain walk).
+  probe   lo/hi = searchsorted(other.khash, key range of h) — each chunk
+          row's matches are a CONTIGUOUS RANGE. Ranges are expanded into a
+          fixed match buffer [M] with cumsum offsets + one locating
+          searchsorted (no loop, unlike the chain walk).
   evict   rows with clean-col < watermark are dropped DURING the same
           merge program that inserts new rows — per chunk, not per
           barrier. State capacity therefore bounds the LIVE set only;
@@ -33,10 +36,16 @@ data-dependent control flow:
   insert  incoming rows are sorted by hash and merged into the kept rows
           with two searchsorteds (stable: state rows stay before new rows
           of equal hash) + scatters — O(C + N) bandwidth, no table sort.
-  delete  a retraction finds its victim row via its own side's range +
-          exact (key, pk) compare; one victim per retraction (within-chunk
-          insert/delete runs on the same pk are netted first: a run's
-          first delete and last insert are the ones that take effect).
+  delete  a retraction searches its own side for its (key, pk) order hash
+          and takes the row whose (key, pk) compare equal: a handful of
+          candidates however many rows share the key (q5 as published
+          joins on the window alone, `num >= maxn` is its condition: every
+          count of a window shares one key, tens of thousands of rows,
+          and a search by key alone expanded every one of them for every
+          retraction, past any match buffer); one victim per retraction
+          (within-chunk insert/delete runs on the same pk are netted
+          first: a run's first delete and last insert are the ones that
+          take effect).
 
 `append_only=(left, right)` statically removes the retraction machinery
 from a side's program — the common windowed-join case compiles to the
@@ -130,10 +139,25 @@ def key_hash(key_cols: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return (h >> jnp.uint64(1)).astype(jnp.int64)
 
 
+# The order hash's low bits, taken from the pk: a key keeps 39 bits (a
+# 2^19-row pool probes a foreign key's range once in 2^20 probes, and the
+# exact compare rejects it), a retraction meets a foreign row of its key
+# once in 2^24 rows that share the key.
+_PK_MASK = (1 << 24) - 1
+_KEY_MASK = ((1 << 63) - 1) & ~_PK_MASK
+
+
+def order_hash(h: jnp.ndarray, pk_cols: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """The hash a side's store is ordered by: the key hash `h` with its low
+    24 bits replaced by a hash of the pk columns."""
+    return (h & _KEY_MASK) | (key_hash(pk_cols) & _PK_MASK)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class SortedSideState:
-    """One side's store: dense prefix [0, n), ascending by khash."""
+    """One side's store: dense prefix [0, n), ascending by khash (the
+    rows' `order_hash`)."""
 
     khash: jnp.ndarray                 # int64 [C], sentinel beyond n
     cols: tuple[jnp.ndarray, ...]      # per input column [C]
@@ -322,6 +346,9 @@ class SortedJoinExecutor(Executor):
         self._snap = [self.sides[LEFT], self.sides[RIGHT]]
         self._src_iotas: dict[int, jnp.ndarray] = {}
         self._flush_dirty = [False, False]
+        # rows this barrier interval's durable flushes wrote, for the
+        # actor's phase dict (take_phase_counts)
+        self._phase_counts: dict = {}
         # Donation: ONLY the error accumulator (arg 2). The side states
         # must NOT be donated: `_snap` keeps the last-persisted side as
         # the durable diff base by ALIASING the live arrays (_rebase), so
@@ -415,6 +442,7 @@ class SortedJoinExecutor(Executor):
         signs = op_sign(chunk.ops)
         row_ids = jnp.arange(N, dtype=jnp.int32)
         h = key_hash(key_cols)
+        h_own = order_hash(h, [chunk.columns[p].data for p in pk_idx])
 
         # ---- within-chunk pk-run netting ----
         if append_only:
@@ -440,8 +468,10 @@ class SortedJoinExecutor(Executor):
             is_ins = jnp.zeros(N, dtype=bool).at[order].set(eff_ins_s)
 
         # ---- probe the other side: contiguous hash ranges ----
-        lo = jnp.searchsorted(other.khash, h, side="left").astype(jnp.int32)
-        hi = jnp.searchsorted(other.khash, h, side="right").astype(jnp.int32)
+        lo = jnp.searchsorted(other.khash, h & _KEY_MASK,
+                              side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(other.khash, h | _PK_MASK,
+                              side="right").astype(jnp.int32)
         # int64 offsets: a hot-key chunk's total candidate-match count can
         # exceed 2^31 (120k-row key run probed by a 20k-row chunk); an int32
         # cumsum would wrap negative and silently drop every match while
@@ -574,8 +604,10 @@ class SortedJoinExecutor(Executor):
             keep = live
 
         if not append_only:
-            dlo = jnp.searchsorted(own.khash, h, side="left").astype(jnp.int32)
-            dhi = jnp.searchsorted(own.khash, h, side="right").astype(jnp.int32)
+            dlo = jnp.searchsorted(own.khash, h_own,
+                                   side="left").astype(jnp.int32)
+            dhi = jnp.searchsorted(own.khash, h_own,
+                                   side="right").astype(jnp.int32)
             dlens = jnp.where(is_del, (dhi - dlo).astype(jnp.int64), 0)
             doffs = jnp.cumsum(dlens)
             dtot = doffs[N - 1]
@@ -600,7 +632,7 @@ class SortedJoinExecutor(Executor):
             n_del_miss = jnp.int32(0)
 
         # merge: kept state rows + new rows, both in hash order
-        ins_h = jnp.where(is_ins, h, _HSENTINEL)
+        ins_h = jnp.where(is_ins, h_own, _HSENTINEL)
         iorder = jnp.argsort(ins_h, stable=True)          # new rows first
         nh = ins_h[iorder]                                 # [N] sorted
         n_new = jnp.sum(is_ins.astype(jnp.int32))
@@ -736,6 +768,16 @@ class SortedJoinExecutor(Executor):
             GLOBAL_METRICS.counter(
                 JOIN_PERSIST_ROWS, executor=self.mem_name or self.identity,
                 side=("left", "right")[s], op=op).inc(n)
+            key = f"join_persist_{op}_rows"
+            self._phase_counts[key] = self._phase_counts.get(key, 0) + n
+
+    def take_phase_counts(self) -> dict:
+        """This barrier interval's share of `join_persist_rows_total`, both
+        sides together (`join_persist_delete_rows`,
+        `join_persist_insert_rows`), for the actor's phase dict; empty where
+        no durable flush ran."""
+        counts, self._phase_counts = self._phase_counts, {}
+        return counts
 
     def _publish_live_rows(self, n_left: int, n_right: int) -> None:
         """How full the pools are, from the counts the watchdog's barrier
@@ -815,11 +857,18 @@ class SortedJoinExecutor(Executor):
                 [] if st is None else [r for _, r in st.iter_all()])
         for s in (LEFT, RIGHT):
             self._recover_reset(s, rows_by_side[s])
-        batch = 1 << 12
+        # An apply costs by the pool's capacity whatever the chunk carries
+        # (0.35 s at 2^19 on a v5e): 2^14 rows a batch, not 2^12, replays
+        # q7's 236 k rows in 15 applies, not 58. Never wider than a pool.
+        batch = min(1 << 14, *self.capacity)
         # generous match buffer: a replay batch probes the FULL restored
         # other side; overflow here would silently corrupt degrees, and
-        # the barrier watchdog fail-stops on the counter if it ever trips
-        mf = max(self.match_factor, 64)
+        # the barrier watchdog fail-stops on the counter if it ever trips.
+        # Only LEFT rows find anything to match (RIGHT replays into an
+        # empty LEFT), so the LEFT side's factor decides: a RIGHT side
+        # whose every row probes a whole window of LEFT rows (q5's
+        # `num >= maxn`: a factor in the thousands) is not paid here
+        mf = max(self.match_factors[LEFT], 64)
         # flag read by the sharded dispatch: replay rows are already in
         # join-input schema, so chain preludes (raw-chunk transforms)
         # and the mesh ingest log must not see them
@@ -833,10 +882,13 @@ class SortedJoinExecutor(Executor):
                     arrays = [np.asarray([r[k] for r in part],
                                          dtype=f.data_type.np_dtype)
                               for k, f in enumerate(sch)]
-                    cap = 1 << max(1, (len(part) - 1).bit_length())
+                    # every batch at ONE capacity, the last short one too:
+                    # a tail at its own power of two is one more apply
+                    # program a side, met or not by the luck of the row
+                    # count (seconds of a timed recovery each)
                     out = self._apply(
                         self.sides[s], self.sides[1 - s], self._errs_dev,
-                        StreamChunk.from_numpy(sch, arrays, capacity=cap),
+                        StreamChunk.from_numpy(sch, arrays, capacity=batch),
                         jnp.int64(NO_WATERMARK), side=s, match_factor=mf)
                     self.sides[s] = out[0]
                     self.sides[1 - s] = replace(self.sides[1 - s],
@@ -892,11 +944,13 @@ class SortedJoinExecutor(Executor):
 
     def _mem_kh_cut_impl(self, side_state: SortedSideState, frac_num: int):
         """Key-hash value at the frac_num/4 quantile of the live prefix
-        (the store is SORTED by khash, so a quantile is one gather)."""
+        (the store is SORTED by khash, so a quantile is one gather), cut
+        back to the start of its key's range: a threshold never parts the
+        rows of one key."""
         idx = jnp.clip(side_state.n * frac_num // 4 - 1, 0,
                        side_state.capacity - 1)
-        return jnp.where(side_state.n > 0, side_state.khash[idx],
-                         jnp.int64(-1))
+        return jnp.where(side_state.n > 0,
+                         side_state.khash[idx] & _KEY_MASK, jnp.int64(-1))
 
     def _mem_cc_range(self, s: int) -> tuple[int, int]:
         parts = [self._mem_cc_range_prog(sl, side=s)

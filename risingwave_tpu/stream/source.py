@@ -116,17 +116,36 @@ class SourceExecutor(Executor):
             token = self._tokens.popleft()
             await asyncio.to_thread(token.block_until_ready)
 
-    def _recover_offset(self) -> None:
+    def _recover_offset(self) -> bool:
+        """Seek every owned split to its committed offset; True if any
+        split resumed from one."""
         if self.state_table is None:
-            return
+            return False
         # keyed by SPLIT ID: split ids are stable across rebuilds while
         # actor ids are not (rescale/recovery reallocate them) — a
         # re-assigned split finds its committed offset wherever it lands
         # (reference: state_table_handler.rs keyed by split id)
+        resumed = False
         for sid, conn in self.splits:
             row = self.state_table.get_row((sid,))
             if row is not None:
                 conn.seek(row[1])
+                resumed = True
+        return resumed
+
+    def _watermark(self):
+        """The watermark message of the owned splits' current offsets
+        (safe frontier = MIN over them: a lagging split may still hold
+        earlier rows), or None while it has not advanced."""
+        wm = min(c.current_watermark()
+                 for _, c in self.splits) - self.watermark_lag_us
+        if self._last_wm is not None and wm <= self._last_wm:
+            return None
+        self._last_wm = wm
+        from ..common.types import DataType
+        from .message import Watermark
+        return Watermark(self.splits[0][1].watermark_col,
+                         DataType.TIMESTAMP, wm)
 
     def _commit_offset(self, barrier: Barrier) -> None:
         self._update_split_metrics()
@@ -214,12 +233,25 @@ class SourceExecutor(Executor):
         # recover on the FIRST observed barrier whatever its kind: a
         # rescale/MV-on-MV rebuild joins a running epoch stream where the
         # Initial barrier happened long ago
-        self._recover_offset()
+        resumed = self._recover_offset()
         # the first barrier can already carry mutations (a split
         # discovered between build and the first injection must not be
         # dropped — the enumerator will never re-announce it)
         self._apply_mutation(barrier)
         yield barrier
+        if resumed and self.emit_watermarks:
+            # A resumed source re-states the watermark of its committed
+            # offsets BEFORE its first chunk. The one it sent after the
+            # last committed chunk was held by its consumers, not stored
+            # (a join evicts by it during its NEXT apply): without it the
+            # first chunk after a restart is applied with no cleaning
+            # watermark, a windowed join's pool then holds one interval
+            # more than it ever does in steady state, and q7's 2^19 pool
+            # crossed its growth threshold there by the luck of the
+            # checkpoint count (a 2^20 recompile inside a timed recovery).
+            wm = self._watermark()
+            if wm is not None:
+                yield wm
 
         sent_this_interval = 0
         while True:
@@ -291,16 +323,9 @@ class SourceExecutor(Executor):
                 sent_this_interval += rows_host
             yield chunk
             if self.emit_watermarks:
-                # safe frontier = MIN over owned splits (a lagging split
-                # may still hold earlier rows)
-                wm = min(c.current_watermark()
-                         for _, c in self.splits) - self.watermark_lag_us
-                if self._last_wm is None or wm > self._last_wm:
-                    self._last_wm = wm
-                    from ..common.types import DataType
-                    from .message import Watermark
-                    yield Watermark(self.splits[0][1].watermark_col,
-                                    DataType.TIMESTAMP, wm)
+                wm = self._watermark()
+                if wm is not None:
+                    yield wm
             # let barriers/other actors in
             await asyncio.sleep(0)
 

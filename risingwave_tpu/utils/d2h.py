@@ -87,10 +87,19 @@ def fetch_columns(arrays) -> list[np.ndarray]:
     return unpack_fetched(fetch_flat(flat), metas)
 
 
+# The narrowest prefix a group is sliced to. Below it every count has ONE
+# bucket, 0 included: a payload's eager slice / concatenate programs are
+# keyed by the bucket of each of its groups, and small counts (a join side
+# that re-states five windows' maxima, then six, then deletes none) walked
+# through 0, 1, 8, 16 beside a large neighbour, each new combination a
+# compile inside a measured window (17 a window in NEXMark q5 as published,
+# 0.2 s each on a chip whose cache had not met them). 64 rows of a payload
+# are a few KB.
+_MIN_BUCKET = 64
+
+
 def _bucket(n: int, cap: int) -> int:
-    if n <= 0:
-        return 0
-    return min(1 << (n - 1).bit_length(), cap)
+    return min(max(1 << max(n - 1, 0).bit_length(), _MIN_BUCKET), cap)
 
 
 def prepare_prefix_groups(groups):

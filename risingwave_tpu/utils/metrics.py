@@ -282,6 +282,25 @@ D2H_FETCHES = GLOBAL_METRICS.counter("d2h_fetch_count")
 HASH_PROBE_FALLBACK_ROWS = GLOBAL_METRICS.counter(
     "hash_probe_fallback_rows_total")
 
+# Hash agg (stream/hash_agg.py), labelled only, `executor` = the name the
+# memory manager registered the agg under (its `identity` outside a flow);
+# all from the agg's one per-barrier watchdog fetch:
+# - `hash_agg_emit_rows_total{executor}`: rows the barrier flushes sent
+#   downstream (inserts, deletes and both halves of every update pair).
+# - `hash_agg_extrema_lossy_groups{executor}`: live groups of a retractable
+#   MIN/MAX whose top-K value buffer has dropped an insert: exact only
+#   while the buffer does not drain (ops/extrema.py). A gauge; goes when
+#   the memory manager unregisters the agg.
+# - `hash_agg_extrema_errors_total{executor,kind=underflow|dropped_delete|
+#   negative_residue}`: the three fail-stop counts of that buffer's bound
+#   (a lossy buffer emptied under live rows; more than K distinct deleted
+#   values of one group in one chunk; a delete of an untracked value of a
+#   group that is not lossy). Any increase fail-stops the epoch before its
+#   checkpoint commits.
+HASH_AGG_EMIT_ROWS = "hash_agg_emit_rows_total"
+HASH_AGG_EXTREMA_LOSSY_GROUPS = "hash_agg_extrema_lossy_groups"
+HASH_AGG_EXTREMA_ERRORS = "hash_agg_extrema_errors_total"
+
 # Sorted join (stream/sorted_join.py), labelled only, `executor` = the
 # name the memory manager registered the join under (its `identity` when
 # it runs outside a flow):

@@ -49,7 +49,12 @@ class EpochTrace:
     # whose chain holds a sharded (mesh) executor adds "mesh_rows",
     # "mesh_rows_max_shard" (rows the shards received from the in-mesh
     # shuffle: all of them, the fullest shard's) and "mesh_shuffle_bytes"
-    # (stream/mesh_shuffle.py); a one-device actor has the three keys only.
+    # (stream/mesh_shuffle.py). An actor whose chain holds a hash agg adds
+    # "agg_emit_rows" (rows its barrier flush sent downstream) and, with a
+    # retractable MIN/MAX, "agg_extrema_lossy_groups"; one that holds a
+    # sorted join adds "join_persist_delete_rows" /
+    # "join_persist_insert_rows" (rows its durable flush wrote). Counts,
+    # not nanoseconds: only the keys that end in "_ns" are times.
     phases: dict = field(default_factory=dict)
     sync_ns: int = 0        # inline store sync duration (pipelining off)
     # checkpoint-pipeline phases (annotated AFTER the span closes — the
@@ -114,6 +119,16 @@ class EpochTrace:
                 line += (f" [mesh rows {ph['mesh_rows']}, max shard "
                          f"{ph['mesh_rows_max_shard']}, shuffle "
                          f"{ph['mesh_shuffle_bytes']} B]")
+            if "agg_emit_rows" in ph:
+                line += f" [agg emitted {ph['agg_emit_rows']} rows"
+                if "agg_extrema_lossy_groups" in ph:
+                    line += (f", {ph['agg_extrema_lossy_groups']} lossy "
+                             f"min/max groups")
+                line += "]"
+            if "join_persist_delete_rows" in ph:
+                line += (f" [join persisted -"
+                         f"{ph['join_persist_delete_rows']} +"
+                         f"{ph['join_persist_insert_rows']} rows]")
         return line
 
     def render(self) -> str:
@@ -324,8 +339,8 @@ class RecoveryRing:
 
 
 def _phase_args(ph: dict) -> dict:
-    """An actor's phase dict as chrome-trace args: times in ms, the mesh
-    counts as they are."""
+    """An actor's phase dict as chrome-trace args: times in ms, the mesh,
+    agg and join counts as they are."""
     return {k: v / 1e6 if k.endswith("_ns") else v for k, v in ph.items()}
 
 

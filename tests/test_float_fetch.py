@@ -100,6 +100,29 @@ def test_prefix_groups_trim_float_columns_bit_exactly():
     np.testing.assert_array_equal(_bits(d), _bits(np.asarray(f64))[:5])
 
 
+@pytest.mark.parametrize("counts,packed_rows", [
+    ((0, 1), 128), ((5, 6), 128), ((64, 0), 128),      # one shape below 64
+    ((65, 3), 192), ((300, 64), 576), ((1000, 9), 1088),  # then powers of 2
+    ((5000, 5000), 2 * 4096),                          # never past the array
+])
+def test_small_prefix_counts_share_one_packed_shape(counts, packed_rows):
+    """A payload's eager slice / concatenate programs are keyed by the
+    bucket of each group: every count up to 64 (0 too) is one bucket, so a
+    group that walks through 0, 1, 5, 6 rows beside a large neighbour meets
+    no new program; the rows that come back are the exact prefixes."""
+    from risingwave_tpu.utils.d2h import (finish_prefix_groups,
+                                          prepare_prefix_groups)
+    k = jnp.arange(4096, dtype=jnp.int64)
+    groups = [([k], counts[0]), ([k + 7], counts[1])]
+    flat, metas, meta = prepare_prefix_groups(groups)
+    assert flat[0].shape == (packed_rows,)
+    (a,), (b,) = finish_prefix_groups(
+        tuple(None if f is None else np.asarray(f) for f in flat),
+        metas, meta)
+    np.testing.assert_array_equal(a, np.arange(min(counts[0], 4096)))
+    np.testing.assert_array_equal(b, np.arange(min(counts[1], 4096)) + 7)
+
+
 def test_identity_bits_are_the_ieee_bits_on_this_backend():
     f64 = _F64_BITS.view(np.float64)
     f32 = _F32_BITS.view(np.float32)

@@ -278,7 +278,7 @@ async def test_retractable_underflow_fail_stop():
     ]
     src = ScriptSource(SCHEMA, msgs)
     agg = HashAggExecutor(src, [0], [agg_max(1)], capacity=64, minput_k=2)
-    with pytest.raises(RuntimeError, match="overflow"):
+    with pytest.raises(RuntimeError, match=r"lost its bound.*'underflow': 1"):
         async for _ in agg.execute():
             pass
 
@@ -637,3 +637,29 @@ async def test_recover_beyond_constructor_capacity():
     assert agg2.capacity >= 128
     rows2 = emitted_rows(out2)
     assert (OP_UPDATE_INSERT, (5, 2)) in rows2
+
+
+@pytest.mark.parametrize("watchdog,groups,want_capacity", [
+    (1, 3, 2 * 128),            # FLUSH_MIN_SLOTS dirty slots, two rows each
+    (1, 300, 2 * 1024),         # the power of two that holds twice 300
+    (None, 3, 2 * 4096),        # no watchdog fetch, no count: the capacity
+], ids=["few", "grown", "transfer_free"])
+async def test_flush_chunk_is_as_wide_as_the_dirty_groups(
+        watchdog, groups, want_capacity):
+    """The barrier flush hands its consumer a chunk as wide as a power of
+    two over the groups the interval touched (the count rides the watchdog
+    fetch), not as wide as the table; the width never shrinks again."""
+    rows = [(OP_INSERT, k, k) for k in range(groups)]
+    msgs = [barrier(1, 0, BarrierKind.INITIAL),
+            chunk(rows, cap=512), barrier(2, 1),
+            chunk([(OP_INSERT, 0, 7)], cap=512), barrier(3, 2)]
+    agg = HashAggExecutor(ScriptSource(SCHEMA, msgs), [0],
+                          [count_star(), agg_sum(1)], capacity=4096,
+                          watchdog_interval=watchdog)
+    out = [m async for m in agg.execute()]
+    chunks = [m for m in out if isinstance(m, StreamChunk)]
+    assert [c.capacity for c in chunks] == [want_capacity] * 2
+    assert sorted(r[1] for r in chunks[0].to_rows()) == [
+        (k, 1, k) for k in range(groups)]
+    assert chunks[1].to_rows() == [(OP_UPDATE_DELETE, (0, 1, 0)),
+                                   (OP_UPDATE_INSERT, (0, 2, 7))]

@@ -166,6 +166,95 @@ def test_q7_hash_agg_apply(q7_executors, one_chip,
     fits_one_chip(compiled)
 
 
+@pytest.fixture(scope="module")
+def q5full_executors():
+    """The plan of NEXMark q5 as published (`benchmark/queries/q5full.py`'s
+    own DDL, at cut widths), deployed; no data is run."""
+    from benchmark.queries import q5full
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.plan.build import _iter_executor_chain
+    cfg = {"generator": {"inter_event_us": 2, "emit_watermarks": 1},
+           "hop_slide_us": 2_000_000, "hop_size_us": 10_000_000,
+           "session_set": {"streaming_agg_capacity": AGG_CAP,
+                           "streaming_join_capacity": JOIN_CAP,
+                           "streaming_join_match_factor": 2048}}
+
+    async def deploy():
+        s = Session()
+        for stmt in q5full.ddl(cfg, {"chunk_size": {"bid": CHUNK},
+                                     "chunks_per_interval": {"bid": 1}}, 7):
+            await s.execute(stmt)
+        return [ex for roots
+                in s.catalog.mvs["q5full"].deployment.roots.values()
+                for root in roots for ex in _iter_executor_chain(root)]
+
+    return asyncio.run(deploy())
+
+
+@pytest.mark.parametrize("program", ["apply", "flush", "persist_view",
+                                     "watchdog_pack"])
+def test_q5full_retractable_max_programs(q5full_executors, one_chip,
+                                         no_persistent_cache, program):
+    """The max-of-counts agg of published q5: its state is the top-32
+    value buffer per group (`[C, 32]` int64 values, int32 counts, the lossy
+    flag), its input chunk the count agg's flush: at most 2 x capacity rows
+    wide, in a real run as wide as the power of two that holds the dirty
+    groups. The apply sorts 64-bit keys along both axes, gathers the touched
+    groups' buffers and scatters into `[M + 1, 32]` candidate buffers; flush
+    and persist view stop at `n_slots` dirty slots: what the chip's compiler
+    makes of that is met here, not in the cell's first run."""
+    from risingwave_tpu.stream.hash_agg import (
+        FLUSH_MIN_SLOTS, HashAggExecutor)
+    agg, = [ex for ex in q5full_executors
+            if isinstance(ex, HashAggExecutor) and any(ex._retractable)]
+    assert agg.capacity == AGG_CAP and agg.minput_k == 32
+    vals, cnts, lossy = agg.state.agg_states[0]
+    assert (vals.shape, vals.dtype, cnts.dtype, lossy.dtype) == (
+        (AGG_CAP, 32), jnp.int64, jnp.int32, jnp.bool_)
+    state = abstract(agg.state, one_chip)
+    ov = abstract(agg._overflow_dev, one_chip)
+    assert ov.shape == (5,) and ov.dtype == jnp.int32
+    lowered = {
+        "apply": lambda: agg._apply._jitted.lower(
+            state, ov, abstract_chunk(agg.input.schema, 2 * AGG_CAP,
+                                      one_chip)),
+        "flush": lambda: agg._flush._jitted.lower(
+            state, n_slots=FLUSH_MIN_SLOTS),
+        "persist_view": lambda: agg._persist_view._jitted.lower(
+            state, n_slots=FLUSH_MIN_SLOTS),
+        "watchdog_pack": lambda: agg._watchdog_pack._jitted.lower(
+            state, ov, abstract(agg._occ_dev, one_chip)),
+    }[program]()
+    fits_one_chip(lowered.compile())
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_q5full_join_apply_with_the_published_condition(
+        q5full_executors, one_chip, no_persistent_cache, side):
+    """The join of published q5: one equi key (the window), `num >= maxn`
+    evaluated inside the apply, both sides retracting. The left apply takes
+    the count agg's flush chunk against a max side that is unique per
+    window (factor 2); the right apply takes the MAX agg's 256-row chunk
+    and expands every count of the re-stated windows: 2048 x 256 = 2^19
+    candidates, the buffer the cell runs with."""
+    from risingwave_tpu.stream.align import LEFT, RIGHT
+    from risingwave_tpu.stream.hash_agg import FLUSH_MIN_SLOTS
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+    join, = [ex for ex in q5full_executors
+             if isinstance(ex, SortedJoinExecutor)]
+    assert join.condition is not None and join.match_factors == (2, 2048)
+    s, width = {"left": (LEFT, 2 * AGG_CAP),
+                "right": (RIGHT, 2 * FLUSH_MIN_SLOTS)}[side]
+    compiled = join._apply._jitted.lower(
+        abstract(join.sides[s], one_chip),
+        abstract(join.sides[1 - s], one_chip),
+        abstract(join._errs_dev, one_chip),
+        abstract_chunk(join.inputs[s].schema, width, one_chip),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip),
+        side=s, match_factor=join.match_factors[s]).compile()
+    fits_one_chip(compiled)
+
+
 def test_float_column_diff_lanes_compile(one_chip, no_persistent_cache):
     """A sorted-join side holding an f64 and an f32 column: the diff
     compiles (its row gathers move the floats as they are; nothing
