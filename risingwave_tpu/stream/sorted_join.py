@@ -22,20 +22,29 @@ The rows of one key are still one contiguous range, and inside it a row
 stands where its pk puts it (exact key equality re-checked on every
 candidate, so hash collisions only cost a wasted compare — they can never
 produce a wrong match). Everything is
-sort / searchsorted / cumsum / gather — static shapes, zero
-data-dependent control flow:
+sort / searchsorted / cumsum / scatter / gather — static shapes, zero
+data-dependent control flow. A binary search is only ever asked
+CHUNK-many questions (of a pool); a rank with a question per pool slot or
+per match-buffer slot has ascending questions, so it is counted instead:
+a histogram of the searched array's bounds and a prefix sum
+(`_rank_of_ascending`) — O(N + C), where a search costs a C-index gather
+for each of its log N steps:
 
   probe   lo/hi = searchsorted(other.khash, key range of h) — each chunk
           row's matches are a CONTIGUOUS RANGE. Ranges are expanded into a
-          fixed match buffer [M] with cumsum offsets + one locating
-          searchsorted (no loop, unlike the chain walk).
+          fixed match buffer [M] with cumsum offsets; the slot -> range
+          map is the counted rank of the offsets (`_range_owner`; no
+          loop, unlike the chain walk).
   evict   rows with clean-col < watermark are dropped DURING the same
           merge program that inserts new rows — per chunk, not per
           barrier. State capacity therefore bounds the LIVE set only;
           epoch churn is unlimited. This is what lifts the q7/q8 cap.
   insert  incoming rows are sorted by hash and merged into the kept rows
-          with two searchsorteds (stable: state rows stay before new rows
-          of equal hash) + scatters — O(C + N) bandwidth, no table sort.
+          by ONE searchsorted of the new hashes into the pool, which gives
+          each new row its rank among the kept rows and, counted, each
+          pool row its rank among the new ones (`_merge_ranks`; stable:
+          state rows stay before new rows of equal hash) + scatters —
+          O(C + N) bandwidth, no table sort.
   delete  a retraction searches its own side for its (key, pk) order hash
           and takes the row whose (key, pk) compare equal: a handful of
           candidates however many rows share the key (q5 as published
@@ -210,13 +219,45 @@ def grow_sorted_arrays(khash, cols, valids, new_capacity: int):
     return kh, cols2, valids2
 
 
-def _count_le(sorted_arr: jnp.ndarray, dead_cum: jnp.ndarray,
-              vals: jnp.ndarray, side: str) -> jnp.ndarray:
-    """Count of LIVE entries of `sorted_arr` </<= vals, where `dead_cum`
-    is the inclusive prefix-sum of the dead mask over the same array."""
-    idx = jnp.searchsorted(sorted_arr, vals, side=side)
+def _rank_of_ascending(idx: jnp.ndarray, weight, size: int) -> jnp.ndarray:
+    """rank[t] = sum of `weight` over the entries with idx <= t, for t in
+    [0, size): a histogram and a prefix sum. It is what a search of `size`
+    ASCENDING queries through the array whose bounds are `idx` returns,
+    at the cost of len(idx) + size instead of size x log(len(idx)) — for
+    a rank whose queries outnumber the searched rows. An idx at or past
+    `size` counts toward no position (dropped: the buffer keeps its
+    power-of-two length)."""
+    hist = jnp.zeros(size, dtype=jnp.int32).at[idx].add(weight, mode="drop")
+    return jnp.cumsum(hist)
+
+
+def _range_owner(offs: jnp.ndarray, size: int) -> jnp.ndarray:
+    """Which range each slot j of a [size] expansion buffer belongs to:
+    #{i : offs[i] <= j}, `offs` the inclusive prefix sums of the range
+    lengths (`searchsorted(offs, arange(size), side="right")`, counted).
+    Empty ranges repeat their offset and add up; an offset at or past
+    `size` — int64: a hot key's total can pass 2^31 — is clipped to
+    `size` BEFORE the cast and owns no slot."""
+    return _rank_of_ascending(jnp.minimum(offs, size).astype(jnp.int32),
+                              1, size)
+
+
+def _merge_ranks(khash: jnp.ndarray, dead_cum: jnp.ndarray,
+                 nh: jnp.ndarray, is_new: jnp.ndarray):
+    """The two ranks of the stable merge of the sorted new hashes `nh`
+    (real where `is_new`, sentinel padding behind) into the sorted pool
+    `khash`, whose dead rows `dead_cum` counts (inclusive prefix sum of
+    the dead mask):
+      new_lt[t]  = # new rows with hash <  khash[t]     ([C])
+      kept_le[r] = # LIVE pool rows with hash <= nh[r]  ([N])
+    ONE search — the N new hashes into the pool — gives both: nh[r] <
+    khash[t] exactly when idx[r] <= t, so new_lt is the rank of t among
+    the idx (pool rows stay before new rows of equal hash either way)."""
+    idx = jnp.searchsorted(khash, nh, side="right").astype(jnp.int32)
     dead_before = jnp.where(idx > 0, dead_cum[jnp.clip(idx - 1, 0)], 0)
-    return (idx - dead_before).astype(jnp.int32)
+    new_lt = _rank_of_ascending(idx, is_new.astype(jnp.int32),
+                                khash.shape[0])
+    return new_lt, idx - dead_before
 
 
 class SortedJoinExecutor(Executor):
@@ -480,7 +521,7 @@ class SortedJoinExecutor(Executor):
         offs = jnp.cumsum(lens)
         total = offs[N - 1]
         j = jnp.arange(M, dtype=jnp.int64)
-        src = jnp.searchsorted(offs, j, side="right").astype(jnp.int32)
+        src = _range_owner(offs, M)
         srcc = jnp.clip(src, 0, N - 1)
         prev = jnp.where(srcc > 0, offs[jnp.clip(srcc - 1, 0)], 0)
         pos = jnp.clip(lo[srcc] + (j - prev), 0, Co - 1).astype(jnp.int32)
@@ -611,7 +652,7 @@ class SortedJoinExecutor(Executor):
             dlens = jnp.where(is_del, (dhi - dlo).astype(jnp.int64), 0)
             doffs = jnp.cumsum(dlens)
             dtot = doffs[N - 1]
-            dsrc = jnp.searchsorted(doffs, j, side="right").astype(jnp.int32)
+            dsrc = _range_owner(doffs, M)
             dsrcc = jnp.clip(dsrc, 0, N - 1)
             dprev = jnp.where(dsrcc > 0, doffs[jnp.clip(dsrcc - 1, 0)], 0)
             dpos = jnp.clip(dlo[dsrcc] + (j - dprev), 0,
@@ -640,13 +681,12 @@ class SortedJoinExecutor(Executor):
         kept_rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
         n_kept = kept_rank[C - 1] + 1
         # state row t -> kept_rank + (# new rows with hash < khash[t])
-        new_lt = jnp.searchsorted(nh, own.khash, side="left").astype(jnp.int32)
-        pos_t = kept_rank + new_lt
         # new row r -> r + (# kept state rows with hash <= nh[r])
-        kept_le = _count_le(own.khash, dead_cum, nh, side="right")
         rr = jnp.arange(N, dtype=jnp.int32)
-        pos_r = rr + kept_le
         new_ok = rr < n_new
+        new_lt, kept_le = _merge_ranks(own.khash, dead_cum, nh, new_ok)
+        pos_t = kept_rank + new_lt
+        pos_r = rr + kept_le
         n_after = n_kept + n_new
         n_row_overflow = jnp.maximum(n_after - C, 0)
         n_after = jnp.minimum(n_after, C)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from ..common.chunk import StreamChunk, op_sign
 from ..ops.hash_table import stable_lexsort
-from .sorted_join import _HSENTINEL, _count_le, key_hash
+from .sorted_join import _HSENTINEL, _merge_ranks, _range_owner, key_hash
 
 
 def sorted_store_apply(khash, cols, valids, n, errs, chunk: StreamChunk,
@@ -65,7 +65,7 @@ def sorted_store_apply(khash, cols, valids, n, errs, chunk: StreamChunk,
     doffs = jnp.cumsum(dlens)
     dtot = doffs[N - 1]
     j = jnp.arange(M, dtype=jnp.int64)
-    dsrc = jnp.searchsorted(doffs, j, side="right").astype(jnp.int32)
+    dsrc = _range_owner(doffs, M)
     dsrcc = jnp.clip(dsrc, 0, N - 1)
     dprev = jnp.where(dsrcc > 0, doffs[jnp.clip(dsrcc - 1, 0)], 0)
     dpos = jnp.clip(dlo[dsrcc] + (j - dprev), 0, C - 1).astype(jnp.int32)
@@ -87,12 +87,11 @@ def sorted_store_apply(khash, cols, valids, n, errs, chunk: StreamChunk,
     dead_cum = jnp.cumsum((~keep).astype(jnp.int32))
     kept_rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
     n_kept = kept_rank[C - 1] + 1
-    new_lt = jnp.searchsorted(nh, khash, side="left").astype(jnp.int32)
-    pos_t = kept_rank + new_lt
-    kept_le = _count_le(khash, dead_cum, nh, side="right")
     rr = jnp.arange(N, dtype=jnp.int32)
-    pos_r = rr + kept_le
     new_ok = rr < n_new
+    new_lt, kept_le = _merge_ranks(khash, dead_cum, nh, new_ok)
+    pos_t = kept_rank + new_lt
+    pos_r = rr + kept_le
     n_after = n_kept + n_new
     n_row_overflow = jnp.maximum(n_after - C, 0)
     n_after = jnp.minimum(n_after, C)

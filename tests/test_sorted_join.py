@@ -308,7 +308,12 @@ async def test_differential_vs_reference_random(kd):
 
 
 @key_dtypes
-def test_differential_lockstep_apply(kd):
+@pytest.mark.parametrize("match_factor", [
+    2,    # the default: M = 32 candidate slots for a 16-row chunk
+    64,   # q5full's shape: M = 1024 > the pool's 256, so the match
+    #       buffer's slot -> range ranks map M-many slots to few rows
+])
+def test_differential_lockstep_apply(kd, match_factor):
     """Deterministic differential: apply the SAME chunk sequence directly
     through the join's _apply and through the reference (no async
     interleaving) — per-chunk outputs and live state multisets must match
@@ -322,7 +327,8 @@ def test_differential_lockstep_apply(kd):
     sj = SortedJoinExecutor(
         ScriptSource(ls, []), ScriptSource(rs, []),
         left_key_indices=[0], right_key_indices=[0],
-        left_pk_indices=[1], right_pk_indices=[1], capacity=256)
+        left_pk_indices=[1], right_pk_indices=[1], capacity=256,
+        match_factor=match_factor)
     next_pk = [0, 1_000_000]
 
     def sj_live(s):
@@ -354,6 +360,133 @@ def test_differential_lockstep_apply(kd):
         assert changelog_counter([out_s]) == want
         assert sj_live(side) == Counter(ref.live[side])
     assert int(np.asarray(sj._errs_dev).sum()) == 0
+
+
+# ------------------------------------------------------- ranks by counting
+
+_SENT = np.iinfo(np.int64).max
+
+
+def _merge_case(name):
+    """(pool hashes [C] sorted with sentinel padding, keep mask [C], new
+    hashes [N] sorted with sentinel padding, n_new)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    C, N, n, n_new = 64, 16, 40, 9
+    if name == "n_new_zero":
+        n_new = 0
+    elif name == "n_new_all":
+        n_new = N
+    elif name == "pool_empty":
+        n = 0
+    elif name == "pool_full":
+        n = C
+    elif name == "chunk_wider_than_pool":
+        C, N, n, n_new = 8, 32, 5, 27
+    # few distinct values: ties inside the pool, inside the chunk and
+    # between the two are the rule, not the exception
+    hi = 12 if name != "no_ties" else 1 << 40
+    khash = np.full(C, _SENT, dtype=np.int64)
+    khash[:n] = np.sort(rng.integers(0, hi, n))
+    nh = np.full(N, _SENT, dtype=np.int64)
+    nh[:n_new] = np.sort(rng.integers(0, hi, n_new))
+    if name == "new_above_every_pool_row" and n_new:
+        nh[:n_new] = np.sort(rng.integers(hi, 2 * hi, n_new))
+    keep = (np.arange(C) < n) & (rng.random(C) < 0.7)
+    return khash, keep, nh, n_new
+
+
+def _range_case(name):
+    """(range lengths [N] int64, M)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    N, M = 16, 64
+    lens = rng.integers(0, 5, N).astype(np.int64)
+    if name == "empty_ranges_repeat":
+        lens[rng.random(N) < 0.6] = 0
+        lens[:3] = 0                       # leading empties: offset 0, thrice
+    elif name == "all_empty":
+        lens[:] = 0
+    elif name == "total_over_M":
+        lens = rng.integers(3, 12, N).astype(np.int64)
+    elif name == "offset_past_2_31":
+        lens[5] = (1 << 31) + 7            # the hot-key case: int64 offsets
+        lens[9] = 1 << 33
+    elif name == "exactly_M":
+        lens[:] = M // N
+    elif name == "slots_outnumber_rows":
+        M = 1024                           # q5full's shape: M >> N
+    return lens, M
+
+
+@pytest.mark.parametrize("name", [
+    "ties", "no_ties", "n_new_zero", "n_new_all", "pool_empty", "pool_full",
+    "chunk_wider_than_pool", "new_above_every_pool_row"])
+def test_merge_ranks_equal_the_searches(name):
+    """`_merge_ranks` against the two binary searches it replaced, value
+    for value: stored rows stay before new rows of equal hash, sentinel
+    padding on both arrays counts for nothing."""
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import _merge_ranks
+    khash, keep, nh, n_new = _merge_case(name)
+    dead_cum = np.cumsum(~keep).astype(np.int32)
+    new_lt, kept_le = _merge_ranks(jnp.asarray(khash), jnp.asarray(dead_cum),
+                                   jnp.asarray(nh),
+                                   jnp.arange(len(nh)) < n_new)
+    assert new_lt.dtype == jnp.int32 and kept_le.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        np.asarray(new_lt), np.searchsorted(nh, khash, side="left"))
+    want_le = (keep[None, :] & (khash[None, :] <= nh[:, None])).sum(axis=1)
+    np.testing.assert_array_equal(np.asarray(kept_le), want_le)
+    # and the merge they drive is a permutation onto [0, n_kept + n_new)
+    tgt = np.concatenate([
+        (np.cumsum(keep) - 1 + np.asarray(new_lt))[keep],
+        (np.arange(len(nh)) + np.asarray(kept_le))[:n_new]])
+    assert sorted(tgt.tolist()) == list(range(int(keep.sum()) + n_new))
+
+
+@pytest.mark.parametrize("name", [
+    "random", "empty_ranges_repeat", "all_empty", "total_over_M",
+    "offset_past_2_31", "exactly_M", "slots_outnumber_rows"])
+def test_range_owner_equals_the_search(name):
+    """`_range_owner` against `searchsorted(offs, arange(M), 'right')`: a
+    row with an empty range repeats its offset (duplicates add up), an
+    offset at or past M — or past 2^31 — owns no slot, and what the apply
+    derives from it (`total`, hence the overflow count) is untouched."""
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import _range_owner
+    lens, M = _range_case(name)
+    offs = np.cumsum(lens)
+    got = _range_owner(jnp.asarray(offs), M)
+    assert got.dtype == jnp.int32 and got.shape == (M,)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.searchsorted(offs, np.arange(M), side="right"))
+    # slot j < min(total, M) lies inside its owner's range
+    n_live = int(min(offs[-1], M))
+    owner = np.asarray(got)[:n_live]
+    start = np.concatenate([[0], offs[:-1]])
+    assert (owner < len(lens)).all()
+    assert ((start[owner] <= np.arange(n_live))
+            & (np.arange(n_live) < offs[owner])).all()
+
+
+def test_match_overflow_count_is_total_minus_buffer():
+    """20 left rows of one key probe 5 stored right rows: 100 candidates
+    into a 64-slot buffer. The overflow counter reads the 36 that did not
+    fit, and the 64 that did are all emitted."""
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import NO_WATERMARK
+    sj = SortedJoinExecutor(
+        ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
+        left_key_indices=[0], right_key_indices=[0],
+        left_pk_indices=[1], right_pk_indices=[1], capacity=64)
+    wm = jnp.int64(NO_WATERMARK)
+    rc = chunk(R_SCHEMA, [(OP_INSERT, 1, 100 + i) for i in range(5)], cap=32)
+    sj.sides[1], _, _, _, _, sj._errs_dev, _ = sj._apply(
+        sj.sides[1], sj.sides[0], sj._errs_dev, rc, wm, side=1)
+    lc = chunk(L_SCHEMA, [(OP_INSERT, 1, i) for i in range(20)], cap=32)
+    _, _, _, _, vis, errs, _ = sj._apply(
+        sj.sides[0], sj.sides[1], sj._errs_dev, lc, wm, side=0)
+    assert np.asarray(errs).tolist() == [36, 0, 0]
+    assert int(np.asarray(vis).sum()) == 64
 
 
 # ------------------------------------------------------- FLOAT64 key values
@@ -1077,3 +1210,40 @@ def test_lane_diff_program_has_no_sort_and_no_loop():
     assert "scatter" in text and "gather" in text
     assert text.count("stablehlo.sort") == 0
     assert text.count("stablehlo.while") == 0
+
+
+@pytest.mark.parametrize("append_only", [True, False],
+                         ids=["append_only_side", "retracting_side"])
+def test_apply_program_searches_only_with_chunk_many_queries(append_only):
+    """A count on the CPU, of the apply as jax lowers it: every binary
+    search left in it (a `stablehlo.while` of `searchsorted`) asks
+    CHUNK-many questions — the probe's lo / hi, a retraction's dlo / dhi,
+    the merge's one search of the new hashes. The ranks that have a
+    question per POOL slot (`new_lt`) or per MATCH-BUFFER slot (`src`,
+    `dsrc`) are a histogram and a prefix sum, so no loop carries a tensor
+    of either length."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import (NO_WATERMARK,
+                                                   _empty_sorted_side)
+    N, C, factor = 16, 1 << 12, 32           # M = 512: three distinct lengths
+    sj = SortedJoinExecutor(
+        ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
+        left_key_indices=[0], right_key_indices=[0],
+        left_pk_indices=[1], right_pk_indices=[1], capacity=C,
+        match_factor=factor, append_only=(append_only, append_only))
+    side = _empty_sorted_side(C, (jnp.int64,) * 2)
+    c = chunk(L_SCHEMA, [(OP_INSERT, 1, 1)], cap=N)
+    text = jax.jit(sj._apply_impl, static_argnames=("side",)).lower(
+        side, side, jnp.zeros(3, jnp.int32), c, jnp.int64(NO_WATERMARK),
+        side=0).as_text()
+    loops = re.findall(r"stablehlo\.while\(([^\n]*)", text)
+    # jax lowers `searchsorted` once per (shapes, side): the text holds the
+    # 'left' body and the 'right' body, both of chunk-many queries into
+    # the pool
+    assert len(loops) == 2, loops
+    for sig in loops:
+        # the bounds a search narrows are its i32 tensors: one per query
+        assert set(re.findall(r"tensor<(\d+)xi32>", sig)) == {str(N)}, sig
+    assert f"tensor<{C}xi32>" in text and f"tensor<{N * factor}xi32>" in text
