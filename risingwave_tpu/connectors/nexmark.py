@@ -3,10 +3,19 @@
 Reference: src/connector/src/source/nexmark/ (wraps the public `nexmark`
 crate); workloads defined by ci/scripts/sql/nexmark/q*.sql. This is a
 re-implementation of the *public Nexmark benchmark generator model* (person/
-auction/bid event interleaving 1:3:46 per 50 events, hot-key skew ratios
-from the spec) as a pure function `event_index -> row`, vectorized in jnp so
-a whole chunk is generated on device per call — the source never bottlenecks
-the TPU executors it feeds.
+auction/bid event interleaving 1:3:46 per 50 events, a hot auction / bidder
+per bucket of 100 ids) as a pure function `event_index -> row`, vectorized
+in jnp so a whole chunk is generated on device per call — the source never
+bottlenecks the TPU executors it feeds.
+
+Key skew: a bid goes to its bucket's hot auction with probability
+1 - 1/hot_auction_ratio and to the hot bidder with 1 - 1/hot_bidder_ratio.
+The DEFAULTS are 100 / 100 (99% / 99% hot: the bucket width 100 doubling as
+the modulus, what this connector always did, and what every older cell,
+test and oracle was written against). The spec's skew — 50% / 75%, NEXMark's
+hotAuctionRatio 2 and hotBidderRatio 4 — is the source options
+`hot_auction_ratio=2, hot_bidder_ratio=4`; the bucket width stays 100
+either way.
 
 Randomness is a counter-based splitmix64 of the event id: deterministic,
 seekable (exactly-once source recovery = remember the next event index,
@@ -32,6 +41,8 @@ AUCTION_PROPORTION = 3
 BID_PROPORTION = 46
 TOTAL_PROPORTION = 50
 
+# width of the bucket of ids a hot auction / bidder is the first of; also
+# the DEFAULT probability modulus (NexmarkConfig.hot_*_ratio)
 HOT_AUCTION_RATIO = 100
 HOT_BIDDER_RATIO = 100
 HOT_SELLER_RATIO = 4
@@ -136,6 +147,9 @@ class NexmarkConfig:
     inter_event_us: int = 10                   # logical event spacing
     num_active_people: int = 1000
     in_flight_auctions: int = 100
+    # a bid is cold with probability 1/ratio (the spec's: 2 and 4)
+    hot_auction_ratio: int = HOT_AUCTION_RATIO
+    hot_bidder_ratio: int = HOT_BIDDER_RATIO
 
 
 def _ids_so_far(global_id):
@@ -163,13 +177,14 @@ def gen_bid_columns(start_index: jnp.ndarray, n: int, cfg: NexmarkConfig,
     global_id = group * TOTAL_PROPORTION + PERSON_PROPORTION + AUCTION_PROPORTION + off
     n_persons, n_auctions = _ids_so_far(global_id)
 
-    # auction: hot (1 per HOT_AUCTION_RATIO chance of cold) -> recent hot id
-    hot = _rand(global_id, 1, HOT_AUCTION_RATIO) > 0
+    # auction: hot (1 per cfg.hot_auction_ratio chance of cold) -> the
+    # first id of the current bucket of HOT_AUCTION_RATIO
+    hot = _rand(global_id, 1, cfg.hot_auction_ratio) > 0
     hot_auction = ((n_auctions - 1) // HOT_AUCTION_RATIO) * HOT_AUCTION_RATIO
     cold_auction = n_auctions - 1 - _rand(global_id, 2, cfg.in_flight_auctions)
     auction = FIRST_AUCTION_ID + jnp.where(hot, hot_auction, jnp.maximum(cold_auction, 0))
 
-    hot_b = _rand(global_id, 3, HOT_BIDDER_RATIO) > 0
+    hot_b = _rand(global_id, 3, cfg.hot_bidder_ratio) > 0
     hot_bidder = ((n_persons - 1) // HOT_BIDDER_RATIO) * HOT_BIDDER_RATIO + 1
     cold_bidder = n_persons - 1 - _rand(global_id, 4, cfg.num_active_people)
     bidder = FIRST_PERSON_ID + jnp.where(hot_b, hot_bidder, jnp.maximum(cold_bidder, 0))

@@ -1315,9 +1315,13 @@ class Session:
         if "splits" in opts:
             args["splits"] = int(opts.pop("splits"))
         cfg = {}
-        for k in ("inter_event_us", "base_time_us"):
+        for k in ("inter_event_us", "base_time_us", "hot_auction_ratio",
+                  "hot_bidder_ratio"):
             if k in opts:
                 cfg[k] = int(opts.pop(k))
+        for k in ("hot_auction_ratio", "hot_bidder_ratio"):
+            if cfg.get(k, 1) < 1:
+                raise BindError(f"{k} must be at least 1")
         if cfg:
             args["cfg"] = cfg
         if "emit_watermarks" in opts:

@@ -322,9 +322,10 @@ class ActorObs:
                 key=lambda iv: (iv["mesh_rows_max_shard"]
                                 / max(1, iv["mesh_rows"]))))
         for ex in self.counted:
-            # rows the chain's hash aggs flushed downstream and its sorted
-            # joins wrote durably this interval: host numbers, from the
-            # fetches those executors make at the barrier anyway
+            # rows the chain's hash aggs flushed downstream, its sorted
+            # joins wrote durably, matched and hold this interval: host
+            # numbers, from the fetches those executors make at the
+            # barrier anyway (summed where a chain holds two of a kind)
             for k, n in ex.take_phase_counts().items():
                 phases[k] = phases.get(k, 0) + n
         if self.debug:

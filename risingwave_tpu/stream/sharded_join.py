@@ -231,6 +231,9 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
         self._dropped_dev = jax.device_put(
             jnp.zeros(self.n_shards, dtype=jnp.int32), sharding)
         self._shuffle_obs_dev = self._fresh_shuffle_obs()
+        # the fused programs do not count what a chunk asks of its match
+        # buffer: `join_match_*` are the one-chip join's
+        self._match_dev = None
         self.sides = [self._sharded_empty(s) for s in (LEFT, RIGHT)]
         self._snap = list(self.sides)
         # one packed fetch per barrier: summed errs + shuffle drops + the
@@ -431,7 +434,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
         n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max, n_l, n_r = (
             int(x) for x in vals)
         self._publish_shuffle(rows, rows_max, fill)
-        self._publish_live_rows(n_l, n_r)
+        self._publish_live_rows(n_l, n_r, shards=self.n_shards)
         self._fail_on_shuffle_drops(n_drop)
         if n_mo:
             raise RuntimeError(

@@ -116,7 +116,9 @@ from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
 from ..ops.hash_table import pack_rows, stable_lexsort
 from ..ops.jit_state import jit_state
-from ..utils.metrics import GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_PERSIST_ROWS
+from ..utils.metrics import (
+    GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_MATCH_BUFFER_PEAK, JOIN_MATCH_ROWS,
+    JOIN_PERSIST_ROWS)
 from .align import LEFT, RIGHT, barrier_align
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -398,6 +400,12 @@ class SortedJoinExecutor(Executor):
                                 static_argnames=("side", "match_factor"),
                                 donate_argnums=(2,),
                                 name="sorted_join_apply")
+        # the stream's applies: the same program, which also folds what
+        # the chunk asked of its match buffer into `_match_dev`
+        self._apply_counted = jit_state(
+            self._apply_counted_impl,
+            static_argnames=("side", "match_factor"), donate_argnums=(2, 3),
+            name="sorted_join_apply")
         self._evict = jit_state(self._evict_impl, static_argnames=("side",),
                                 name="sorted_join_evict")
         self._diff = jit_state(self._diff_impl, name="sorted_join_diff")
@@ -412,9 +420,18 @@ class SortedJoinExecutor(Executor):
         zero = jnp.zeros((), dtype=jnp.int32)
         self._n_dev = [zero, zero]
         self._dirty = [False, False]
+        # this barrier interval's [rows emitted by LEFT chunks, by RIGHT
+        # chunks, most equi-key candidates one LEFT chunk found, one RIGHT
+        # chunk]: folded by the stream's applies, fetched and zeroed by
+        # the watchdog pack. None = not counted (the mesh join)
+        self._match_dev = jnp.zeros(4, dtype=jnp.int32)
+        # rows of the match buffer each side's last chunk was given
+        self._match_width = [0, 0]
         self._watchdog_pack = jit_state(
-            lambda errs, nl, nr: jnp.concatenate([errs, jnp.stack([nl, nr])]),
-            name="sorted_join_watchdog_pack")
+            lambda errs, nl, nr, match: (
+                jnp.concatenate([errs, jnp.stack([nl, nr]), match]),
+                jnp.zeros_like(match)),
+            donate_argnums=(3,), name="sorted_join_watchdog_pack")
         self._key_wms: list[dict[int, int]] = [{}, {}]
         self._emitted_key_wm: dict[int, int] = {}
         # watermark value a side's state is already clean to (skip
@@ -453,11 +470,34 @@ class SortedJoinExecutor(Executor):
     def _apply_impl(self, own: SortedSideState, other: SortedSideState,
                     errs: jnp.ndarray, chunk: StreamChunk, wm_own, side: int,
                     match_factor: Optional[int] = None):
+        """`_apply_core` without its candidate count: (own', other_degree',
+        out_cols, out_ops, out_vis, errs', n_own)."""
+        return self._apply_core(own, other, errs, chunk, wm_own, side,
+                                match_factor)[:7]
+
+    def _apply_counted_impl(self, own: SortedSideState,
+                            other: SortedSideState, errs: jnp.ndarray,
+                            match: jnp.ndarray, chunk: StreamChunk, wm_own,
+                            side: int, match_factor: Optional[int] = None):
+        """`_apply_impl` + `match'`: the rows this chunk emitted added to
+        `match[side]`, its equi-key candidates (what it asked of the match
+        buffer, before key equality and the condition; saturating at
+        2^31 - 1) folded into the peak `match[2 + side]`."""
+        *out, total = self._apply_core(own, other, errs, chunk, wm_own,
+                                       side, match_factor)
+        n_emit = jnp.sum(out[4], dtype=jnp.int32)
+        peak = jnp.minimum(total, jnp.iinfo(jnp.int32).max).astype(jnp.int32)
+        match = match.at[side].add(n_emit).at[2 + side].max(peak)
+        return (*out, match)
+
+    def _apply_core(self, own: SortedSideState, other: SortedSideState,
+                    errs: jnp.ndarray, chunk: StreamChunk, wm_own, side: int,
+                    match_factor: Optional[int] = None):
         """Probe `other`, emit matches (+ outer-join NULL rows and degree
         transitions), evict+update `own` in one program.
 
         Returns (own', other_degree', out_cols, out_ops, out_vis, errs',
-        n_own). Output rows are laid out in up to three segments:
+        n_own, candidates). Output rows are laid out in up to three segments:
         [0, M)       inner matches
         [M, 2M)      other-side NULL-row transitions   (outer only)
         [2M, 2M+N)   own-side unmatched NULL rows      (own outer only)
@@ -718,7 +758,8 @@ class SortedJoinExecutor(Executor):
                                degree, src2, n_after.astype(jnp.int32))
         errs = errs + jnp.stack(
             [n_match_overflow, n_del_miss, n_row_overflow]).astype(jnp.int32)
-        return own2, other_degree, tuple(cols), ops_out, emit, errs, own2.n
+        return (own2, other_degree, tuple(cols), ops_out, emit, errs, own2.n,
+                total)
 
     # ------------------------------------------------------------- evict
     def _evict_impl(self, own: SortedSideState, wm, kh, side: int):
@@ -814,18 +855,45 @@ class SortedJoinExecutor(Executor):
     def take_phase_counts(self) -> dict:
         """This barrier interval's share of `join_persist_rows_total`, both
         sides together (`join_persist_delete_rows`,
-        `join_persist_insert_rows`), for the actor's phase dict; empty where
-        no durable flush ran."""
+        `join_persist_insert_rows`; absent where no durable flush ran), and
+        what the watchdog's fetch brought (`_publish_match`,
+        `_publish_live_rows`; absent where it made none), for the actor's
+        phase dict."""
         counts, self._phase_counts = self._phase_counts, {}
         return counts
 
-    def _publish_live_rows(self, n_left: int, n_right: int) -> None:
+    def _publish_live_rows(self, n_left: int, n_right: int,
+                           shards: int = 1) -> None:
         """How full the pools are, from the counts the watchdog's barrier
-        fetch brings anyway."""
+        fetch brings anyway; the fuller side's `join_live_rows` over its
+        `join_capacity` (all `shards` together) goes into the phase dict."""
         for side, n in (("left", n_left), ("right", n_right)):
             GLOBAL_METRICS.gauge(
                 JOIN_LIVE_ROWS, executor=self.mem_name or self.identity,
                 side=side).set(float(n))
+        n, cap = max(zip((n_left, n_right), self.capacity),
+                     key=lambda nc: nc[0] / nc[1])
+        self._phase_counts.update(join_live_rows=n,
+                                  join_capacity=cap * shards)
+
+    def _publish_match(self, rows, peaks) -> None:
+        """What this interval's chunks asked of their match buffers, from
+        the watchdog's fetch: `join_match_rows` (emitted rows, both sides),
+        and of the side that came nearest its buffer's end
+        `join_match_peak` (the most equi-key candidates one chunk found)
+        over `join_match_width` (that buffer's rows: factor x chunk width;
+        more candidates than that fail-stop the epoch)."""
+        label = self.mem_name or self.identity
+        for s, side in enumerate(("left", "right")):
+            GLOBAL_METRICS.counter(JOIN_MATCH_ROWS, executor=label,
+                                   side=side).inc(rows[s])
+            GLOBAL_METRICS.gauge(JOIN_MATCH_BUFFER_PEAK, executor=label,
+                                 side=side).set(float(peaks[s]))
+        peak, width = max(zip(peaks, self._match_width),
+                          key=lambda pw: pw[0] / max(1, pw[1]))
+        self._phase_counts.update(join_match_rows=sum(rows),
+                                  join_match_peak=peak,
+                                  join_match_width=width)
 
     def _persist(self, barrier: Barrier) -> None:
         for s in (LEFT, RIGHT):
@@ -1198,11 +1266,14 @@ class SortedJoinExecutor(Executor):
 
     # --------------------------------------------------------- watchdog
     def _check_watchdog(self) -> None:
-        vals = np.asarray(self._watchdog_pack(
-            self._errs_dev, self._n_dev[LEFT], self._n_dev[RIGHT]))
-        n_mo, n_miss, n_ro = (int(x) for x in vals[:3])
-        self._n_known = [int(vals[3]), int(vals[4])]
+        packed, self._match_dev = self._watchdog_pack(
+            self._errs_dev, self._n_dev[LEFT], self._n_dev[RIGHT],
+            self._match_dev)
+        vals = [int(x) for x in np.asarray(packed)]
+        n_mo, n_miss, n_ro = vals[:3]
+        self._n_known = vals[3:5]
         self._publish_live_rows(*self._n_known)
+        self._publish_match(vals[5:7], vals[7:9])
         if n_mo:
             raise RuntimeError(
                 f"sorted-join match-buffer overflow ({n_mo} matches "
@@ -1225,10 +1296,17 @@ class SortedJoinExecutor(Executor):
                     self._mem_check_reload(s, msg)
                 wm = jnp.int64(self._pending_clean[s])
                 self._cleaned_to[s] = self._pending_clean[s]
+                mf = self.match_factors[s]
+                args = (self.sides[s], self.sides[1 - s], self._errs_dev)
+                if self._match_dev is None:
+                    out = self._apply(*args, msg, wm, side=s, match_factor=mf)
+                else:
+                    *out, self._match_dev = self._apply_counted(
+                        *args, self._match_dev, msg, wm, side=s,
+                        match_factor=mf)
+                    self._match_width[s] = mf * msg.capacity
                 (self.sides[s], oth_degree, cols, ops, vis, self._errs_dev,
-                 self._n_dev[s]) = self._apply(
-                    self.sides[s], self.sides[1 - s], self._errs_dev, msg,
-                    wm, side=s, match_factor=self.match_factors[s])
+                 self._n_dev[s]) = out
                 self.sides[1 - s] = replace(self.sides[1 - s],
                                             degree=oth_degree)
                 self._dirty[s] = True

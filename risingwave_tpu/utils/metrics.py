@@ -311,8 +311,19 @@ HASH_AGG_EXTREMA_ERRORS = "hash_agg_extrema_errors_total"
 #   shards together on a mesh), from the barrier watchdog's fetch; over the
 #   pool's capacity it is the fill. Goes when the memory manager
 #   unregisters the join.
+# - `join_match_rows_total{executor,side}`: rows the applies of the side's
+#   chunks emitted (matches that passed key equality and the condition,
+#   and an outer join's NULL rows), and
+#   `join_match_buffer_peak{executor,side}`: the most equi-key candidates
+#   one chunk of the side found in the last barrier interval — what it
+#   asked of its match buffer (`match_factor` x the chunk's width; more
+#   than that fail-stops the epoch). Both counted inside the apply and
+#   brought by the barrier watchdog's fetch; the mesh join publishes
+#   neither.
 JOIN_PERSIST_ROWS = "join_persist_rows_total"
 JOIN_LIVE_ROWS = "join_live_rows"
+JOIN_MATCH_ROWS = "join_match_rows_total"
+JOIN_MATCH_BUFFER_PEAK = "join_match_buffer_peak"
 
 # HBM memory manager (memory/manager.py): exact accounted device-state
 # bytes vs. the configured budget, plus eviction/reload activity. The

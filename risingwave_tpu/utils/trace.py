@@ -53,8 +53,12 @@ class EpochTrace:
     # "agg_emit_rows" (rows its barrier flush sent downstream) and, with a
     # retractable MIN/MAX, "agg_extrema_lossy_groups"; one that holds a
     # sorted join adds "join_persist_delete_rows" /
-    # "join_persist_insert_rows" (rows its durable flush wrote). Counts,
-    # not nanoseconds: only the keys that end in "_ns" are times.
+    # "join_persist_insert_rows" (rows its durable flush wrote), from its
+    # watchdog fetch "join_live_rows" / "join_capacity" (the fuller pool)
+    # and, on one chip, "join_match_rows" (rows its applies emitted) and
+    # "join_match_peak" / "join_match_width" (the most equi-key candidates
+    # one chunk found, of the side nearest its match buffer's width).
+    # Counts, not nanoseconds: only the keys that end in "_ns" are times.
     phases: dict = field(default_factory=dict)
     sync_ns: int = 0        # inline store sync duration (pipelining off)
     # checkpoint-pipeline phases (annotated AFTER the span closes — the
@@ -129,6 +133,14 @@ class EpochTrace:
                 line += (f" [join persisted -"
                          f"{ph['join_persist_delete_rows']} +"
                          f"{ph['join_persist_insert_rows']} rows]")
+            if "join_live_rows" in ph:
+                line += (f" [join holds {ph['join_live_rows']} of "
+                         f"{ph['join_capacity']} rows")
+                if "join_match_rows" in ph:
+                    line += (f", matched {ph['join_match_rows']} rows, "
+                             f"peak {ph['join_match_peak']} of "
+                             f"{ph['join_match_width']} candidates")
+                line += "]"
         return line
 
     def render(self) -> str:

@@ -267,7 +267,12 @@ async def test_phase_keys_carry_the_cascades_counts():
         await s.tick(1)
         got = _phases(s)
         want = _cascade_counts(k * 2048, (k + 1) * 2048, cfg)
-        assert got == {
+        # what the join's watchdog fetch adds since PR 34 is q4's to test
+        # (tests/test_q4_published.py)
+        match = {"join_live_rows", "join_capacity", "join_match_rows",
+                 "join_match_peak", "join_match_width"}
+        assert match <= set(got)
+        assert {k_: v for k_, v in got.items() if k_ not in match} == {
             "agg_emit_rows": 2 * want["count_emit"] + want["max_emit"],
             "agg_extrema_lossy_groups": 0,
             "join_persist_delete_rows": want["join_deletes"],

@@ -255,6 +255,84 @@ def test_q5full_join_apply_with_the_published_condition(
     fits_one_chip(compiled)
 
 
+@pytest.fixture(scope="module")
+def q4_executors():
+    """The plan of NEXMark q4 as published (`benchmark/queries/q4.py`'s own
+    DDL, at cut widths; NEXMark's 46 : 3 of bids to auctions), deployed; no
+    data is run."""
+    from benchmark.queries import q4
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.plan.build import _iter_executor_chain
+    cfg = {"generator": {"inter_event_us": 100, "emit_watermarks": 0,
+                         "hot_auction_ratio": 2, "hot_bidder_ratio": 4},
+           "session_set": {"streaming_agg_capacity": AGG_CAP,
+                           "streaming_join_capacity": JOIN_CAP,
+                           "streaming_join_match_factor": 16}}
+
+    async def deploy():
+        s = Session()
+        for stmt in q4.ddl(cfg, {"chunk_size": {"bid": 46 * 64,
+                                                "auction": 3 * 64},
+                                 "chunks_per_interval": {"bid": 1,
+                                                         "auction": 1}}, 7):
+            await s.execute(stmt)
+        return [ex for roots in s.catalog.mvs["q4"].deployment.roots.values()
+                for root in roots for ex in _iter_executor_chain(root)]
+
+    return asyncio.run(deploy())
+
+
+@pytest.mark.parametrize("program", ["auction_apply", "bid_apply",
+                                     "watchdog_pack", "avg_apply",
+                                     "avg_persist_view"])
+def test_q4_join_and_float_avg_programs(q4_executors, one_chip,
+                                        no_persistent_cache, program):
+    """q4 as published on one chip: the stream's applies of a join nothing
+    cleans — each also folds the rows it emitted and its equi-key candidates
+    into the int32[4] the watchdog pack fetches and zeroes (factor 16 on
+    both sides: upstream's sources declare no key) — and the retractable
+    FLOAT64 SUM / COUNT under the AVG, an f64 being two f32 on the chip: its
+    apply, and the persist view that hands the sum to the d2h pack."""
+    from risingwave_tpu.stream.align import LEFT, RIGHT
+    from risingwave_tpu.stream.hash_agg import (
+        FLUSH_MIN_SLOTS, HashAggExecutor)
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+    join, = [ex for ex in q4_executors if isinstance(ex, SortedJoinExecutor)]
+    avg, = [ex for ex in q4_executors if isinstance(ex, HashAggExecutor)
+            and len(ex.group_key_indices) == 1]
+    assert join.match_factors == (16, 16) and join.capacity[LEFT] == JOIN_CAP
+    assert [c.ret_type.name for c in avg.agg_calls] == ["FLOAT64", "INT64"]
+
+    def apply(s, width):
+        return join._apply_counted._jitted.lower(
+            abstract(join.sides[s], one_chip),
+            abstract(join.sides[1 - s], one_chip),
+            abstract(join._errs_dev, one_chip),
+            abstract(join._match_dev, one_chip),
+            abstract_chunk(join.inputs[s].schema, width, one_chip),
+            jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip),
+            side=s, match_factor=join.match_factors[s])
+
+    n = abstract(join._n_dev[0], one_chip)
+    state = abstract(avg.state, one_chip)
+    compiled = {
+        "auction_apply": lambda: apply(LEFT, 3 * 64),
+        "bid_apply": lambda: apply(RIGHT, 46 * 64),
+        "watchdog_pack": lambda: join._watchdog_pack._jitted.lower(
+            abstract(join._errs_dev, one_chip), n, n,
+            abstract(join._match_dev, one_chip)),
+        "avg_apply": lambda: avg._apply._jitted.lower(
+            state, abstract(avg._overflow_dev, one_chip),
+            abstract_chunk(avg.input.schema, 2 * FLUSH_MIN_SLOTS, one_chip)),
+        "avg_persist_view": lambda: avg._persist_view._jitted.lower(
+            state, n_slots=FLUSH_MIN_SLOTS),
+    }[program]().compile()
+    fits_one_chip(compiled)
+    if program.startswith("avg"):
+        assert "bitcast-convert" not in "".join(
+            ln for ln in compiled.as_text().splitlines() if "f64" in ln)
+
+
 def test_float_column_diff_lanes_compile(one_chip, no_persistent_cache):
     """A sorted-join side holding an f64 and an f32 column: the diff
     compiles (its row gathers move the floats as they are; nothing
