@@ -198,6 +198,11 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
             return prog(own, other, errs, chunk, wm)
         self._apply = apply_dispatch
 
+        def replay_dispatch(*args, **kwargs):
+            own2, odeg, _, _, _, errs2, n = apply_dispatch(*args, **kwargs)
+            return own2, odeg, errs2, n
+        self._replay = replay_dispatch
+
         def set_mesh_preludes(side, fns, chain=None):
             assert self.mesh_shuffle_applies == 0, \
                 "mesh preludes must install before the first fused " \

@@ -22,8 +22,13 @@ The rows of one key are still one contiguous range, and inside it a row
 stands where its pk puts it (exact key equality re-checked on every
 candidate, so hash collisions only cost a wasted compare — they can never
 produce a wrong match). Everything is
-sort / searchsorted / cumsum / scatter / gather — static shapes, zero
-data-dependent control flow. A binary search is only ever asked
+sort / searchsorted / cumsum / scatter / gather over static shapes, zero
+data-dependent control flow. In the per-chunk programs (apply, evict) a
+scatter or a gather has CHUNK-many or match-buffer-many indices, never
+one per pool slot: what moves a whole column of a pool moves it by
+log-step shifts and selects (`ops/monotone_move.py`, `insert` below); only
+the barrier's lane diff (Durability, below) still indexes every slot. A
+binary search is only ever asked
 CHUNK-many questions (of a pool); a rank with a question per pool slot or
 per match-buffer slot has ascending questions, so it is counted instead:
 a histogram of the searched array's bounds and a prefix sum
@@ -43,8 +48,17 @@ for each of its log N steps:
           by ONE searchsorted of the new hashes into the pool, which gives
           each new row its rank among the kept rows and, counted, each
           pool row its rank among the new ones (`_merge_ranks`; stable:
-          state rows stay before new rows of equal hash) + scatters —
-          O(C + N) bandwidth, no table sort.
+          state rows stay before new rows of equal hash). The merge is
+          no permutation: a kept pool row slides LEFT over the dropped
+          rows before it, then RIGHT past the new rows that sort before
+          it, and never overtakes another — two monotone moves, each
+          log2 stages of "shift every lane by 2^k, select" at memory
+          speed (`compact`, `expand`; `_merge_sorted`). The N new rows
+          land in the gaps the same way (`expand` by their rank among
+          the kept rows) — O(C log C) elementwise bandwidth, no table
+          sort, no index per pool slot or per new row (a scatter of one
+          pool column cost 67 ns a SLOT on a v5e: 87% of q4's device
+          time at 2^22, PERF.md §6, PR 35).
   delete  a retraction searches its own side for its (key, pk) order hash
           and takes the row whose (key, pk) compare equal: a handful of
           candidates however many rows share the key (q5 as published
@@ -116,6 +130,7 @@ from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
 from ..ops.hash_table import pack_rows, stable_lexsort
 from ..ops.jit_state import jit_state
+from ..ops.monotone_move import compact, expand
 from ..utils.metrics import (
     GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_MATCH_BUFFER_PEAK, JOIN_MATCH_ROWS,
     JOIN_PERSIST_ROWS)
@@ -192,6 +207,19 @@ class SortedSideState:
     def capacity(self) -> int:
         return self.khash.shape[0]
 
+    def lanes(self) -> tuple[list, list]:
+        """Every per-row lane, `khash` first, and the padding each holds
+        behind n: what a merge or an eviction moves, row by row."""
+        nk = len(self.cols)
+        return ([self.khash, *self.cols, *self.valids, self.degree, self.src],
+                [_HSENTINEL] + [0] * nk + [False] * nk + [0, -1])
+
+    @classmethod
+    def from_lanes(cls, lanes: Sequence[jnp.ndarray], n) -> "SortedSideState":
+        nk = (len(lanes) - 3) // 2
+        return cls(lanes[0], tuple(lanes[1:1 + nk]),
+                   tuple(lanes[1 + nk:1 + 2 * nk]), lanes[-2], lanes[-1], n)
+
 
 def _empty_sorted_side(capacity: int, col_dtypes: Sequence) -> SortedSideState:
     return SortedSideState(
@@ -260,6 +288,55 @@ def _merge_ranks(khash: jnp.ndarray, dead_cum: jnp.ndarray,
     new_lt = _rank_of_ascending(idx, is_new.astype(jnp.int32),
                                 khash.shape[0])
     return new_lt, idx - dead_before
+
+
+def _merge_sorted(keep: jnp.ndarray, drops: bool, n_new,
+                  pool: Sequence[jnp.ndarray], fills: Sequence,
+                  new: Sequence):
+    """The stable merge of the chunk's new rows into the pool rows with
+    `keep`: the state the merge leaves, every lane dense and in hash
+    order, padding included. `pool` are the pool's lanes [C], the order
+    hash first, `fills` their padding; `new` the new rows' lanes [N] in
+    the same order, sorted by hash, the first `n_new` real and sentinel
+    hashes behind (None: a new row gets that lane's fill). `drops` says
+    statically whether `keep` can leave a gap in the live prefix (a side
+    that neither cleans nor retracts only appends: its kept rows need no
+    compaction).
+
+    No row overtakes another on its way. A kept pool row slides left
+    over the dropped rows before it (`compact`), then right past the new
+    rows that sort before it (`expand` by `new_lt`, at most N); new row r
+    slides right past the kept pool rows that sort at or before it
+    (`expand` by `kept_le`), into the gaps the pool rows left. Returns
+    (lanes', n', rows dropped past the capacity)."""
+    C, N = pool[0].shape[0], new[0].shape[0]
+    dead_cum = jnp.cumsum((~keep).astype(jnp.int32))
+    n_kept = C - dead_cum[C - 1]
+    new_ok = jnp.arange(N, dtype=jnp.int32) < n_new
+    new_lt, kept_le = _merge_ranks(pool[0], dead_cum, new[0], new_ok)
+    pool, fills = list(pool), list(fills)
+    occupied = keep
+    if drops:
+        # the ranks were taken where the rows stood: `new_lt` moves along
+        *pool, new_lt = compact(keep, pool + [new_lt], fills + [0])
+        occupied = jnp.arange(C, dtype=jnp.int32) < n_kept
+    pool, occupied = expand(occupied, new_lt, N, pool, fills)
+
+    def pool_wide(x, fill):
+        # a new row past the pool's length lands past its capacity
+        return x[:C] if N >= C else jnp.pad(x, (0, C - N),
+                                            constant_values=fill)
+
+    given = [i for i, nx in enumerate(new) if nx is not None]
+    landed, _ = expand(
+        pool_wide(new_ok, False), pool_wide(kept_le, 0), C - 1,
+        [pool_wide(new[i].astype(pool[i].dtype), fills[i]) for i in given],
+        [fills[i] for i in given])
+    for i, nx in zip(given, landed):
+        pool[i] = jnp.where(occupied, pool[i], nx)
+    n_after = n_kept + n_new
+    return (pool, jnp.minimum(n_after, C).astype(jnp.int32),
+            jnp.maximum(n_after - C, 0))
 
 
 class SortedJoinExecutor(Executor):
@@ -400,6 +477,15 @@ class SortedJoinExecutor(Executor):
                                 static_argnames=("side", "match_factor"),
                                 donate_argnums=(2,),
                                 name="sorted_join_apply")
+        # what `recover()` and the spill reload replay stored rows through:
+        # the same program with only the state for outputs, so the
+        # compiler drops what served the emitted rows alone (the match
+        # buffer's gathers); counted under the apply's name like the
+        # stream's form below
+        self._replay = jit_state(self._replay_impl,
+                                 static_argnames=("side", "match_factor"),
+                                 donate_argnums=(2,),
+                                 name="sorted_join_apply")
         # the stream's applies: the same program, which also folds what
         # the chunk asked of its match buffer into `_match_dev`
         self._apply_counted = jit_state(
@@ -474,6 +560,15 @@ class SortedJoinExecutor(Executor):
         out_cols, out_ops, out_vis, errs', n_own)."""
         return self._apply_core(own, other, errs, chunk, wm_own, side,
                                 match_factor)[:7]
+
+    def _replay_impl(self, own: SortedSideState, other: SortedSideState,
+                     errs: jnp.ndarray, chunk: StreamChunk, wm_own,
+                     side: int, match_factor: Optional[int] = None):
+        """`_apply_core` for rows that were emitted when they first came:
+        (own', other_degree', errs', n_own)."""
+        out = self._apply_core(own, other, errs, chunk, wm_own, side,
+                               match_factor)
+        return out[0], out[1], out[5], out[6]
 
     def _apply_counted_impl(self, own: SortedSideState,
                             other: SortedSideState, errs: jnp.ndarray,
@@ -715,47 +810,16 @@ class SortedJoinExecutor(Executor):
         # merge: kept state rows + new rows, both in hash order
         ins_h = jnp.where(is_ins, h_own, _HSENTINEL)
         iorder = jnp.argsort(ins_h, stable=True)          # new rows first
-        nh = ins_h[iorder]                                 # [N] sorted
-        n_new = jnp.sum(is_ins.astype(jnp.int32))
-        dead_cum = jnp.cumsum((~keep).astype(jnp.int32))
-        kept_rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
-        n_kept = kept_rank[C - 1] + 1
-        # state row t -> kept_rank + (# new rows with hash < khash[t])
-        # new row r -> r + (# kept state rows with hash <= nh[r])
-        rr = jnp.arange(N, dtype=jnp.int32)
-        new_ok = rr < n_new
-        new_lt, kept_le = _merge_ranks(own.khash, dead_cum, nh, new_ok)
-        pos_t = kept_rank + new_lt
-        pos_r = rr + kept_le
-        n_after = n_kept + n_new
-        n_row_overflow = jnp.maximum(n_after - C, 0)
-        n_after = jnp.minimum(n_after, C)
-
-        tgt_t = jnp.where(keep & (pos_t < C), pos_t, C)
-        tgt_r = jnp.where(new_ok & (pos_r < C), pos_r, C)
-        new_khash = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
-        new_khash = new_khash.at[tgt_t].set(own.khash, mode="drop")
-        new_khash = new_khash.at[tgt_r].set(nh, mode="drop")
-        out_cols = []
-        out_valids = []
-        for ci, (sc, sv) in enumerate(zip(own.cols, own.valids)):
-            col = chunk.columns[ci]
-            c2 = jnp.zeros(C, dtype=sc.dtype).at[tgt_t].set(sc, mode="drop")
-            c2 = c2.at[tgt_r].set(col.data[iorder].astype(sc.dtype), mode="drop")
-            v2 = jnp.zeros(C, dtype=bool).at[tgt_t].set(sv, mode="drop")
-            v2 = v2.at[tgt_r].set(col.valid_mask()[iorder], mode="drop")
-            out_cols.append(c2)
-            out_valids.append(v2)
-        degree = jnp.zeros(C, dtype=jnp.int32).at[tgt_t].set(
-            own.degree, mode="drop")
-        if any_outer:
-            degree = degree.at[tgt_r].set(match_cnt[iorder], mode="drop")
-        # provenance travels with the kept rows; merged-in rows land on
-        # the -1 fill
-        src2 = jnp.full(C, -1, dtype=jnp.int32).at[tgt_t].set(
-            own.src, mode="drop")
-        own2 = SortedSideState(new_khash, tuple(out_cols), tuple(out_valids),
-                               degree, src2, n_after.astype(jnp.int32))
+        moved, n_after, n_row_overflow = _merge_sorted(
+            keep, self.clean_cols[side] is not None or not append_only,
+            jnp.sum(is_ins.astype(jnp.int32)), *own.lanes(),
+            [ins_h[iorder]]
+            + [c.data[iorder] for c in chunk.columns[:len(own.cols)]]
+            + [c.valid_mask()[iorder] for c in chunk.columns[:len(own.cols)]]
+            # provenance travels with the kept rows; merged-in rows land
+            # on the -1 fill
+            + [match_cnt[iorder] if any_outer else None, None])
+        own2 = SortedSideState.from_lanes(moved, n_after)
         errs = errs + jnp.stack(
             [n_match_overflow, n_del_miss, n_row_overflow]).astype(jnp.int32)
         return (own2, other_degree, tuple(cols), ops_out, emit, errs, own2.n,
@@ -775,20 +839,8 @@ class SortedJoinExecutor(Executor):
         if cc is not None:
             drop = drop | (own.cols[cc] < wm)
         keep = live & ~drop
-        rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
-        tgt = jnp.where(keep, rank, C)
-        kh = jnp.full(C, _HSENTINEL, dtype=jnp.int64).at[tgt].set(
-            own.khash, mode="drop")
-        cols = tuple(jnp.zeros(C, dtype=c.dtype).at[tgt].set(c, mode="drop")
-                     for c in own.cols)
-        valids = tuple(jnp.zeros(C, dtype=bool).at[tgt].set(v, mode="drop")
-                       for v in own.valids)
-        degree = jnp.zeros(C, dtype=jnp.int32).at[tgt].set(own.degree,
-                                                           mode="drop")
-        src = jnp.full(C, -1, dtype=jnp.int32).at[tgt].set(own.src,
-                                                           mode="drop")
-        n2 = jnp.sum(keep.astype(jnp.int32))
-        return SortedSideState(kh, cols, valids, degree, src, n2)
+        return SortedSideState.from_lanes(
+            compact(keep, *own.lanes()), jnp.sum(keep.astype(jnp.int32)))
 
     def _evict_side(self, s: int, wm, kh) -> None:
         self.sides[s] = self._evict(self.sides[s], wm, kh, side=s)
@@ -965,9 +1017,10 @@ class SortedJoinExecutor(Executor):
                 [] if st is None else [r for _, r in st.iter_all()])
         for s in (LEFT, RIGHT):
             self._recover_reset(s, rows_by_side[s])
-        # An apply costs by the pool's capacity whatever the chunk carries
-        # (0.35 s at 2^19 on a v5e): 2^14 rows a batch, not 2^12, replays
-        # q7's 236 k rows in 15 applies, not 58. Never wider than a pool.
+        # An apply passes over the whole pool whatever the chunk carries
+        # (0.35 s at 2^19 on a v5e before PR 35's moves, milliseconds
+        # since): 2^14 rows a batch, not 2^12, replays q7's 236 k rows in
+        # 15 applies, not 58. Never wider than a pool.
         batch = min(1 << 14, *self.capacity)
         # generous match buffer: a replay batch probes the FULL restored
         # other side; overflow here would silently corrupt degrees, and
@@ -994,15 +1047,13 @@ class SortedJoinExecutor(Executor):
                     # a tail at its own power of two is one more apply
                     # program a side, met or not by the luck of the row
                     # count (seconds of a timed recovery each)
-                    out = self._apply(
+                    (self.sides[s], degree, self._errs_dev,
+                     self._n_dev[s]) = self._replay(
                         self.sides[s], self.sides[1 - s], self._errs_dev,
                         StreamChunk.from_numpy(sch, arrays, capacity=batch),
                         jnp.int64(NO_WATERMARK), side=s, match_factor=mf)
-                    self.sides[s] = out[0]
                     self.sides[1 - s] = replace(self.sides[1 - s],
-                                                degree=out[1])
-                    self._errs_dev = out[5]
-                    self._n_dev[s] = out[6]
+                                                degree=degree)
         finally:
             self._state_replay = False
         for s in (LEFT, RIGHT):
@@ -1180,14 +1231,11 @@ class SortedJoinExecutor(Executor):
             ch = StreamChunk(tuple(cols),
                              jnp.full(cap, OP_INSERT, dtype=jnp.int8),
                              jnp.asarray(np.arange(cap) < len(part)), sch)
-            out = self._apply(self.sides[t], self.sides[1 - t],
-                              self._errs_dev, ch,
-                              jnp.int64(self._pending_clean[t]), side=t,
-                              match_factor=mf)
-            self.sides[t] = out[0]
-            self.sides[1 - t] = replace(self.sides[1 - t], degree=out[1])
-            self._errs_dev = out[5]
-            self._n_dev[t] = out[6]
+            (self.sides[t], degree, self._errs_dev,
+             self._n_dev[t]) = self._replay(
+                self.sides[t], self.sides[1 - t], self._errs_dev, ch,
+                jnp.int64(self._pending_clean[t]), side=t, match_factor=mf)
+            self.sides[1 - t] = replace(self.sides[1 - t], degree=degree)
         self._dirty[t] = True
         self._flush_dirty[t] = True
 

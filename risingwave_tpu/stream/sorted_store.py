@@ -6,7 +6,9 @@ Rows live in a dense prefix [0, n) of fixed-capacity arrays sorted by a
 63-bit hash of the STREAM KEY (retractions address rows by it), maintained
 with the same searchsorted/merge machinery as sorted_join.py's own-side
 update: per chunk, one jitted program nets within-chunk pk runs, finds
-delete victims by (hash, pk) match, and merge-inserts the survivors —
+delete victims by (hash, pk) match, and merge-inserts the survivors
+through the join's own `_merge_sorted` (kept rows and new rows move by
+log-step shifts, `ops/monotone_move.py`: no index per stored row) —
 static shapes, no data-dependent control flow.
 
 Reference analogue: the row-holding state tables behind
@@ -22,7 +24,7 @@ import jax.numpy as jnp
 
 from ..common.chunk import StreamChunk, op_sign
 from ..ops.hash_table import stable_lexsort
-from .sorted_join import _HSENTINEL, _merge_ranks, _range_owner, key_hash
+from .sorted_join import _HSENTINEL, _merge_sorted, _range_owner, key_hash
 
 
 def sorted_store_apply(khash, cols, valids, n, errs, chunk: StreamChunk,
@@ -82,37 +84,15 @@ def sorted_store_apply(khash, cols, valids, n, errs, chunk: StreamChunk,
     # merge inserts (stable, state rows before equal-hash new rows)
     ins_h = jnp.where(is_ins, h, _HSENTINEL)
     iorder = jnp.argsort(ins_h, stable=True)
-    nh = ins_h[iorder]
-    n_new = jnp.sum(is_ins.astype(jnp.int32))
-    dead_cum = jnp.cumsum((~keep).astype(jnp.int32))
-    kept_rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    n_kept = kept_rank[C - 1] + 1
-    rr = jnp.arange(N, dtype=jnp.int32)
-    new_ok = rr < n_new
-    new_lt, kept_le = _merge_ranks(khash, dead_cum, nh, new_ok)
-    pos_t = kept_rank + new_lt
-    pos_r = rr + kept_le
-    n_after = n_kept + n_new
-    n_row_overflow = jnp.maximum(n_after - C, 0)
-    n_after = jnp.minimum(n_after, C)
-    tgt_t = jnp.where(keep & (pos_t < C), pos_t, C)
-    tgt_r = jnp.where(new_ok & (pos_r < C), pos_r, C)
-    kh2 = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
-    kh2 = kh2.at[tgt_t].set(khash, mode="drop")
-    kh2 = kh2.at[tgt_r].set(nh, mode="drop")
-    cols2, valids2 = [], []
-    for ci, (sc, sv) in enumerate(zip(cols, valids)):
-        col = chunk.columns[ci]
-        c2 = jnp.zeros(C, dtype=sc.dtype).at[tgt_t].set(sc, mode="drop")
-        c2 = c2.at[tgt_r].set(col.data[iorder].astype(sc.dtype),
-                              mode="drop")
-        v2 = jnp.zeros(C, dtype=bool).at[tgt_t].set(sv, mode="drop")
-        v2 = v2.at[tgt_r].set(col.valid_mask()[iorder], mode="drop")
-        cols2.append(c2)
-        valids2.append(v2)
+    nk = len(cols)
+    moved, n_after, n_row_overflow = _merge_sorted(
+        keep, True, jnp.sum(is_ins.astype(jnp.int32)),
+        [khash, *cols, *valids], [_HSENTINEL] + [0] * nk + [False] * nk,
+        [ins_h[iorder]] + [c.data[iorder] for c in chunk.columns[:nk]]
+        + [c.valid_mask()[iorder] for c in chunk.columns[:nk]])
     errs = errs + jnp.stack([n_row_overflow, n_del_miss]).astype(jnp.int32)
-    return (kh2, tuple(cols2), tuple(valids2),
-            n_after.astype(jnp.int32), errs)
+    return (moved[0], tuple(moved[1:1 + nk]), tuple(moved[1 + nk:]), n_after,
+            errs)
 
 
 def segment_starts(sorted_group_ids: jnp.ndarray):

@@ -68,3 +68,57 @@ class InnerJoinReference:
 
     def joined(self) -> Counter:
         return join_rows(self.live[0], self.live[1], *self.keys)
+
+
+# --------------------------------------------------------------------------
+# The scatter form of the sorted pool's moves: what `ops/monotone_move.py`
+# and `sorted_join._merge_sorted` replaced (PR 35), kept as their reference.
+# Every row gets an index (`out.at[tgt].set(x, mode="drop")`); the ranks of
+# the merge are the two binary searches, in numpy.
+
+def compact_by_scatter(keep, lanes, fills):
+    import jax.numpy as jnp
+    C = keep.shape[0]
+    tgt = jnp.where(keep, jnp.cumsum(keep.astype(jnp.int32)) - 1, C)
+    return [jnp.full(C, f, dtype=x.dtype).at[tgt].set(x, mode="drop")
+            for x, f in zip(lanes, fills)]
+
+
+def expand_by_scatter(occupied, amount, lanes, fills):
+    """(lanes', occupied'): entry t to t + amount[t], past the end dropped."""
+    import jax.numpy as jnp
+    C = occupied.shape[0]
+    tgt = jnp.where(occupied, jnp.arange(C, dtype=jnp.int32) + amount, C)
+    moved = [jnp.full(C, f, dtype=x.dtype).at[tgt].set(x, mode="drop")
+             for x, f in zip(lanes, fills)]
+    return moved, jnp.zeros(C, dtype=bool).at[tgt].set(True, mode="drop")
+
+
+def merge_by_scatter(khash, keep, nh, n_new, lanes, fills, new_lanes):
+    """`_merge_sorted`'s contract, the way `_apply_core` did it before:
+    kept pool row t to kept_rank[t] + #{new hashes < khash[t]}, new row r
+    to r + #{kept pool hashes <= nh[r]}, one scatter a lane for each.
+    Returns (khash', lanes', n', rows dropped past the capacity)."""
+    import jax.numpy as jnp
+    import numpy as np
+    khash, keep, nh = np.asarray(khash), np.asarray(keep), np.asarray(nh)
+    C, N = len(khash), len(nh)
+    n_new = int(n_new)
+    new_lt = np.searchsorted(nh[:n_new], khash, side="left")
+    kept_le = np.searchsorted(khash[keep], nh, side="right")
+    pos_t = np.cumsum(keep) - 1 + new_lt
+    pos_r = np.arange(N) + kept_le
+    tgt_t = jnp.asarray(np.where(keep & (pos_t < C), pos_t, C))
+    tgt_r = jnp.asarray(np.where((np.arange(N) < n_new) & (pos_r < C),
+                                 pos_r, C))
+    sentinel = np.iinfo(np.int64).max
+    out = []
+    for x, f, nx in zip([jnp.asarray(khash), *lanes], [sentinel, *fills],
+                        [jnp.asarray(nh), *new_lanes]):
+        y = jnp.full(C, f, dtype=x.dtype).at[tgt_t].set(x, mode="drop")
+        if nx is not None:
+            y = y.at[tgt_r].set(nx.astype(x.dtype), mode="drop")
+        out.append(y)
+    n_after = int(keep.sum()) + n_new
+    return out[0], out[1:], min(n_after, C), max(n_after - C, 0)
+

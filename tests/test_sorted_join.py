@@ -8,6 +8,7 @@ inserts/deletes/update pairs, NULL keys, and watermark cleaning.
 """
 
 import asyncio
+import functools
 from collections import Counter
 
 import numpy as np
@@ -1198,18 +1199,84 @@ def test_lane_diff_row_that_leaves_and_returns_is_a_pair():
     assert sorted(r for _, r in lt.iter_all()) == [(1, 10), (2, 20)]
 
 
+def _long_index_ops(text):
+    """Of a lowered program's text: the lengths of every scatter's updates,
+    every gather's indices and every sort's operands, by op."""
+    import re
+    found = {"scatter": [], "gather": [], "sort": []}
+    for op, types in re.findall(
+            r'"stablehlo\.(scatter|gather|sort)"\(.*?\}[>)] : \(([^)]*)\) ->',
+            text, flags=re.DOTALL):
+        tensors = re.findall(r"tensor<(\d+)(?:x\d+)*x\w+>", types)
+        # scatter: (operand.., indices, updates..); gather: (operand,
+        # indices); sort: (operands..): the last is as long as the index
+        found[op].append(int(tensors[-1]))
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered_join_programs(C, N, factor):
+    """{name: stablehlo text} of the join's capacity-priced programs at a
+    capacity C no other length of theirs equals."""
+    import jax
+    import jax.numpy as jnp
+    from risingwave_tpu.stream.sorted_join import (NO_WATERMARK,
+                                                   _empty_sorted_side)
+    side = _empty_sorted_side(C, (jnp.int64,) * 2)
+    c = chunk(L_SCHEMA, [(OP_INSERT, 1, 1)], cap=N)
+    texts = {}
+    for append_only in (True, False):
+        sj = SortedJoinExecutor(
+            ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
+            left_key_indices=[0], right_key_indices=[0],
+            left_pk_indices=[1], right_pk_indices=[1], capacity=C,
+            match_factor=factor, append_only=(append_only, append_only),
+            clean_watermark_cols=(None, None) if append_only else (1, 1))
+        name = "apply_append_only" if append_only else "apply_retracting"
+        texts[name] = jax.jit(sj._apply_impl, static_argnames=("side",)).lower(
+            side, side, jnp.zeros(3, jnp.int32), c, jnp.int64(NO_WATERMARK),
+            side=0).as_text()
+    texts["evict"] = jax.jit(sj._evict_impl, static_argnames=("side",)).lower(
+        side, jnp.int64(0), jnp.int64(-1), side=0).as_text()
+    texts["diff"] = jax.jit(SortedJoinExecutor._diff_impl).lower(
+        side, side).as_text()
+    return texts
+
+
 def test_lane_diff_program_has_no_sort_and_no_loop():
     """A count on the CPU, of the program as jax lowers it (before any
     backend rewrites it): the diff is elementwise passes, prefix sums,
-    scatters and gathers, whatever the capacity."""
-    import jax
-    import jax.numpy as jnp
-    from risingwave_tpu.stream.sorted_join import _empty_sorted_side
-    side = _empty_sorted_side(1 << 12, (jnp.int64,) * 5)
-    text = jax.jit(SortedJoinExecutor._diff_impl).lower(side, side).as_text()
-    assert "scatter" in text and "gather" in text
+    scatters and gathers, whatever the capacity. (Its scatters and gathers
+    ARE capacity-long: the one join program PR 35 left so, see
+    `test_join_programs_index_no_pool_slot`.)"""
+    C = 1 << 12
+    text = _lowered_join_programs(C, 16, 32)["diff"]
+    found = _long_index_ops(text)
+    assert C in found["scatter"] and C in found["gather"]
     assert text.count("stablehlo.sort") == 0
     assert text.count("stablehlo.while") == 0
+
+
+@pytest.mark.parametrize("program", ["apply_append_only", "apply_retracting",
+                                     "evict"])
+def test_join_programs_index_no_pool_slot(program):
+    """A count on the CPU, of the per-chunk programs as jax lowers them:
+    what moves a whole pool column moves it by log-step shifts and selects
+    (`ops/monotone_move.py`) — no scatter whose updates, no gather whose
+    indices and no sort whose operand are CAPACITY-long. What is left of
+    those ops is chunk-long or match-buffer-long. (The barrier's lane diff
+    is not among them yet: PERF.md §6, PR 35.)"""
+    N, C, factor = 16, 1 << 12, 32           # M = 512: three distinct lengths
+    text = _lowered_join_programs(C, N, factor)[program]
+    found = _long_index_ops(text)
+    for op, lengths in found.items():
+        assert C not in lengths, (op, lengths)
+    if program.startswith("apply"):
+        # the new rows' scatters, the match buffer's gathers, the one sort
+        # of the chunk's hashes: the parser sees them
+        assert N in found["scatter"] and N * factor in found["gather"]
+        assert set(found["sort"]) == {N}
+    assert f"tensor<{C}xi64>" in text and "stablehlo.dynamic_slice" in text
 
 
 @pytest.mark.parametrize("append_only", [True, False],
@@ -1220,30 +1287,30 @@ def test_apply_program_searches_only_with_chunk_many_queries(append_only):
     CHUNK-many questions — the probe's lo / hi, a retraction's dlo / dhi,
     the merge's one search of the new hashes. The ranks that have a
     question per POOL slot (`new_lt`) or per MATCH-BUFFER slot (`src`,
-    `dsrc`) are a histogram and a prefix sum, so no loop carries a tensor
-    of either length."""
+    `dsrc`) are a histogram and a prefix sum, so no SEARCH carries a
+    tensor of either length. The only other loops are the moves of the
+    pool's rows (PR 35): one loop over a move's table of steps, whose body
+    is one stage of shifts and selects."""
     import re
-    import jax
-    import jax.numpy as jnp
-    from risingwave_tpu.stream.sorted_join import (NO_WATERMARK,
-                                                   _empty_sorted_side)
     N, C, factor = 16, 1 << 12, 32           # M = 512: three distinct lengths
-    sj = SortedJoinExecutor(
-        ScriptSource(L_SCHEMA, []), ScriptSource(R_SCHEMA, []),
-        left_key_indices=[0], right_key_indices=[0],
-        left_pk_indices=[1], right_pk_indices=[1], capacity=C,
-        match_factor=factor, append_only=(append_only, append_only))
-    side = _empty_sorted_side(C, (jnp.int64,) * 2)
-    c = chunk(L_SCHEMA, [(OP_INSERT, 1, 1)], cap=N)
-    text = jax.jit(sj._apply_impl, static_argnames=("side",)).lower(
-        side, side, jnp.zeros(3, jnp.int32), c, jnp.int64(NO_WATERMARK),
-        side=0).as_text()
+    name = "apply_append_only" if append_only else "apply_retracting"
+    text = _lowered_join_programs(C, N, factor)[name]
     loops = re.findall(r"stablehlo\.while\(([^\n]*)", text)
+    # a move carries the pool-wide mask of the stage; a search does not
+    moves = [sig for sig in loops if f"tensor<{C}xi1>" in sig]
+    searches = [sig for sig in loops if f"tensor<{C}xi1>" not in sig]
     # jax lowers `searchsorted` once per (shapes, side): the text holds the
     # 'left' body and the 'right' body, both of chunk-many queries into
     # the pool
-    assert len(loops) == 2, loops
-    for sig in loops:
+    assert len(searches) == 2, loops
+    for sig in searches:
         # the bounds a search narrows are its i32 tensors: one per query
         assert set(re.findall(r"tensor<(\d+)xi32>", sig)) == {str(N)}, sig
+    # kept rows and new rows `expand`; a side with dead rows `compact`s first
+    assert len(moves) == (2 if append_only else 3), loops
+    for sig in moves:
+        # pool-wide lanes and the table of steps, nothing match-buffer-long
+        assert f"tensor<{N * factor}x" not in sig, sig
+        assert set(re.findall(r"tensor<(\d+)xi32>", sig)) - {str(C)} <= {
+            str((C - 1).bit_length()), str(N.bit_length())}, sig
     assert f"tensor<{C}xi32>" in text and f"tensor<{N * factor}xi32>" in text
