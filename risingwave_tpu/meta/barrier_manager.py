@@ -37,6 +37,9 @@ from ..serving.manager import ServingManager
 from ..state.store import StateStore
 from ..stream.message import Barrier, BarrierKind, Mutation
 from ..utils.faults import FAULTS, FaultInjected
+from ..utils.trace import (
+    SPAN_LOG, SpanScope, current_scope, set_scope, span,
+)
 
 
 @dataclass
@@ -51,6 +54,21 @@ class _UploadJob:
     """One checkpoint handed to the background uploader."""
     prev_epoch: int          # the epoch being made durable
     curr_epoch: int          # barrier whose trace gets the phase spans
+    enqueued_ns: int = 0     # where its `flush.queue` span starts
+
+
+async def _off_loop(fn, *args):
+    """`asyncio.to_thread`, and as the span `flush.loop_wait` how long its
+    result then waited for the event loop: the actors' host work runs on
+    the same loop as the uploader's continuations."""
+    def run():
+        out = fn(*args)
+        return out, time.monotonic_ns()
+    out, t_done = await asyncio.to_thread(run)
+    sc = current_scope()
+    if sc is not None:
+        sc.leaf("flush.loop_wait", t_done, time.monotonic_ns())
+    return out
 
 
 class BarrierCoordinator:
@@ -503,7 +521,7 @@ class BarrierCoordinator:
         barrier = Barrier(epoch, kind, mutation, (), time.monotonic_ns())
         self._epochs[curr] = EpochState(barrier, set(self.actor_ids))
         self._prev_epoch = curr
-        self.tracer.begin(curr)
+        self.tracer.begin(curr, spans=self.stats.level > 0)
         self._ensure_watchdog()
         for q in self.source_queues:
             await q.put(barrier)
@@ -539,7 +557,7 @@ class BarrierCoordinator:
             # participates in the protocol (it reports collected at once)
             st.done.set()
         self._prev_epoch = curr
-        self.tracer.begin(curr)
+        self.tracer.begin(curr, spans=self.stats.level > 0)
         self._ensure_watchdog()
         for q in self.source_queues:
             await q.put(barrier)
@@ -790,7 +808,8 @@ class BarrierCoordinator:
         self._inflight += 1
         self._m_inflight.set(self._inflight)
         self._upload_q.put_nowait(
-            _UploadJob(barrier.epoch.prev, barrier.epoch.curr))
+            _UploadJob(barrier.epoch.prev, barrier.epoch.curr,
+                       time.monotonic_ns()))
         if self._uploader_task is None or self._uploader_task.done():
             self._uploader_task = asyncio.get_running_loop().create_task(
                 self._upload_worker(), name="epoch-uploader")
@@ -817,12 +836,31 @@ class BarrierCoordinator:
         seal the shared buffer, build+upload the SST off the loop, then
         swap the manifest on the loop. A failure parks the error for the
         next inject_barrier (fail-stop: recovery replays from the last
-        committed epoch, exactly like an actor death)."""
+        committed epoch, exactly like an actor death).
+
+        Each job is one `flush` span with its stages as children, behind
+        the `flush.queue` span of its wait in the queue (utils/trace.py):
+        the job's SpanScope is the scope in force, and `asyncio.to_thread`
+        copies it, so a stage's `fetch_flat` on the worker thread records
+        its `d2h_wait` under the stage."""
         store = self.store
         while True:
             if self._upload_q.empty():
                 return        # respawned by the next enqueue; no parked task
             job = self._upload_q.get_nowait()
+            root_sid = SPAN_LOG.anchors(job.curr_epoch)[0]
+            # no scope where the epoch records no spans (metric_level=off)
+            scope = SpanScope("uploader") if root_sid else None
+            set_scope(scope)
+            if scope is not None:
+                taken_ns = time.monotonic_ns()
+                scope.leaf("flush.queue", job.enqueued_ns, taken_ns)
+                flush = scope.open(taken_ns)
+
+            def end_flush(t1: int = 0) -> None:
+                if scope is not None:
+                    scope.close(flush, "flush", t1)
+                    scope.flush(job.curr_epoch, root_sid)
             try:
                 if self.workers:
                     # cluster commit: the epoch is durable once EVERY
@@ -832,12 +870,14 @@ class BarrierCoordinator:
                     # barrier-complete reports carry their synced SSTs)
                     t0 = time.monotonic_ns()
                     sst_ids: list[int] = []
-                    for handle in list(self.workers.values()):
-                        sst_ids.extend(await handle.wait_sealed(
-                            job.prev_epoch))
+                    with span("flush.upload"):
+                        for handle in list(self.workers.values()):
+                            sst_ids.extend(await handle.wait_sealed(
+                                job.prev_epoch))
                     t2 = time.monotonic_ns()
-                    self.store.commit_remote(job.prev_epoch,
-                                             sorted(sst_ids))
+                    with span("flush.commit"):
+                        self.store.commit_remote(job.prev_epoch,
+                                                 sorted(sst_ids))
                     t3 = time.monotonic_ns()
                     self.committed_epochs.append(job.prev_epoch)
                     self.logstore.on_commit(job.prev_epoch)
@@ -855,20 +895,25 @@ class BarrierCoordinator:
                     self.upload_busy_ns += t3 - t0
                     self._m_upload.observe((t2 - t0) / 1e9)
                     self._m_commit.observe((t3 - t2) / 1e9)
+                    end_flush(t3)
                     self.tracer.annotate(job.curr_epoch, upload_ns=t2 - t0,
-                                         commit_ns=t3 - t2)
+                                         commit_ns=t3 - t2,
+                                         committed_at_ns=t3)
                     self._inflight -= 1
                     self._m_inflight.set(self._inflight)
                     self._slot_free.set()
                     self._upload_q.task_done()
                     continue
                 t0 = time.monotonic_ns()
-                for stages in store.take_deferred(job.prev_epoch):
+                for table_id, stages in store.take_deferred(
+                        job.prev_epoch, tagged=True):
                     for wait, cont in stages:
-                        payload = (await asyncio.to_thread(wait)
-                                   if wait is not None else None)
-                        cont(payload)
-                batch = store.seal(job.prev_epoch)
+                        with span(f"flush.stage:{table_id}"):
+                            payload = (await _off_loop(wait)
+                                       if wait is not None else None)
+                            cont(payload)
+                with span("flush.seal"):
+                    batch = store.seal(job.prev_epoch)
                 t1 = time.monotonic_ns()
                 if FAULTS.active:
                     # chaos harness: an injected store fault takes the
@@ -881,17 +926,21 @@ class BarrierCoordinator:
                         raise FaultInjected(
                             f"injected upload_fail at epoch "
                             f"{job.prev_epoch}")
-                await asyncio.to_thread(store.upload_sealed, batch)
+                with span("flush.upload"):
+                    await _off_loop(store.upload_sealed, batch)
                 t2 = time.monotonic_ns()
-                res = store.commit_sealed(batch)
+                with span("flush.commit"):
+                    res = store.commit_sealed(batch)
                 t3 = time.monotonic_ns()
+                end_flush(t3)
                 self.committed_epochs.append(job.prev_epoch)
                 # annotate BEFORE the commit listener: on a compute node
                 # the listener ships this epoch's closed span to meta
                 # piggybacked on the sealed report, and the span must
                 # already carry its checkpoint-pipeline phases
                 self.tracer.annotate(job.curr_epoch, seal_ns=t1 - t0,
-                                     upload_ns=t2 - t1, commit_ns=t3 - t2)
+                                     upload_ns=t2 - t1, commit_ns=t3 - t2,
+                                     committed_at_ns=t3)
                 if self.commit_listener is not None:
                     self.commit_listener(
                         job.prev_epoch,
@@ -909,6 +958,10 @@ class BarrierCoordinator:
                 raise
             except BaseException as e:  # noqa: BLE001 — park for injection
                 self._upload_failure = e
+                if scope is not None and scope.cur:
+                    # the failed flush's spans, for the post-mortem
+                    scope.cur = flush[0]
+                    end_flush()
             self._inflight -= 1
             self._m_inflight.set(self._inflight)
             self._slot_free.set()

@@ -226,10 +226,11 @@ class StateStore:
         else:
             self._run_stages(stages)
 
-    def take_deferred(self, epoch: int) -> list[tuple]:
+    def take_deferred(self, epoch: int, tagged: bool = False) -> list:
         """Pop every stage list registered for epochs <= epoch, in
-        registration order."""
-        taken = [st for e, st, _t in self._deferred if e <= epoch]
+        registration order; `tagged`: as (table_id, stages) pairs."""
+        taken = [(t, st) if tagged else st
+                 for e, st, t in self._deferred if e <= epoch]
         self._deferred = [t for t in self._deferred if t[0] > epoch]
         return taken
 

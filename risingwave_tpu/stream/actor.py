@@ -12,19 +12,24 @@ of the chain and splits each barrier interval into apply (chunk compute +
 dispatch), persist (the barrier-yielding poll — the chain's flush/commit
 work), and align (input-channel waits reported by the exchange inputs +
 the epoch fence). The split rides to the EpochTracer at collect time, so
-`\trace` answers "who held epoch N and doing what". At metric_level=off
-`self.obs` is None and the loop is the uninstrumented one.
+`\trace` answers "who held epoch N and doing what". Each poll and the
+fence are also spans (`actor.apply` / `actor.persist` / `actor.fence`,
+utils/trace.py): the actor's `SpanScope` is the scope in force in its task,
+so a `StateJit` call or a d2h fetch deep in the chain's generators records
+itself as the poll's child, and the barrier hands the interval's spans to
+the log under its epoch. At metric_level=off `self.obs` is None, no scope
+is in force and the loop is the uninstrumented one.
 """
 
 from __future__ import annotations
 
 import asyncio
-import time
 from contextlib import aclosing
 from typing import Optional, Protocol
 
 from ..common.chunk import StreamChunk
 from ..utils.faults import FAULTS, FaultInjected
+from ..utils.trace import set_scope
 from .exchange import Dispatcher
 from .executor import Executor
 from .message import Barrier
@@ -68,18 +73,20 @@ class Actor:
 
     async def _run_inner(self) -> None:
         last_token = None
-        mono = time.monotonic_ns
         # the chain is closed HERE, where it is dropped: an actor that
         # stops or dies never resumes the generator, and an executor's
         # `finally` (sockets, tasks) would otherwise run whenever the
         # loop finalizes the collected generator — later, as a detached
         # task nobody awaits
         async with aclosing(self.consumer.execute()) as it:
+            scoped = None       # the obs whose scope is in force
             while True:
                 obs = self.obs
+                if obs is not scoped:
+                    scoped = obs
+                    set_scope(obs.scope if obs is not None else None)
                 if obs is not None:
-                    t_poll = mono()
-                    w0 = obs.input_wait_ns
+                    poll = obs.begin_poll()
                 try:
                     msg = await it.__anext__()
                 except StopAsyncIteration:
@@ -88,10 +95,10 @@ class Actor:
                     # re-instrumented while parked in the poll (SET
                     # metric_level): restart the span at the switch point so
                     # this very message already reports under the new level
-                    obs = self.obs
+                    obs = scoped = self.obs
+                    set_scope(obs.scope if obs is not None else None)
                     if obs is not None:
-                        t_poll = mono()
-                        w0 = obs.input_wait_ns
+                        poll = obs.begin_poll()
                 if isinstance(msg, StreamChunk):
                     if msg.columns:
                         last_token = msg.columns[0].data
@@ -100,8 +107,7 @@ class Actor:
                     if obs is not None:
                         # poll span minus the channel-recv wait accrued inside
                         # it = actual chunk compute + dispatch time
-                        waited = obs.input_wait_ns - w0
-                        obs.apply_ns += max(0, mono() - t_poll - waited)
+                        obs.end_poll(poll, barrier=False)
                         obs.note_chunk_out(msg,
                                            dispatcher_fanout(self.dispatcher))
                 elif isinstance(msg, Barrier):
@@ -120,8 +126,7 @@ class Actor:
                         # the barrier-yielding poll is the chain's barrier
                         # work: every executor's flush/persist/commit runs
                         # inside it before the barrier emerges
-                        waited = obs.input_wait_ns - w0
-                        obs.persist_ns += max(0, mono() - t_poll - waited)
+                        obs.end_poll(poll, barrier=True)
                     # Epoch fence: the barrier is only reported collected once
                     # every device program of the epoch has actually executed
                     # (the chain dispatches asynchronously) — the last chunk
@@ -138,17 +143,19 @@ class Actor:
                         tokens = ([last_token]
                                   if last_token is not None else [])
                         tokens.extend(gather_fence_tokens(self.consumer))
-                    t_fence = mono() if obs is not None else 0
+                    if obs is not None:
+                        fence = obs.begin_fence()
                     for tok in tokens:
                         if hasattr(tok, "block_until_ready"):
                             await asyncio.to_thread(tok.block_until_ready)
                     last_token = None
                     if obs is not None:
-                        obs.fence_ns += mono() - t_fence
+                        obs.end_fence(fence)
                         phases = obs.on_barrier()
                         ph = getattr(self.collector, "collect_phases", None)
                         if ph is not None:
                             ph(self.actor_id, barrier, phases)
+                        obs.flush_spans(barrier.epoch.curr)
                     stop = barrier.is_stop(self.actor_id)
                     if stop:
                         # BEFORE the collect: whoever stops a deployment
@@ -163,8 +170,7 @@ class Actor:
                     if self.dispatcher is not None:
                         await self.dispatcher.dispatch(msg)
                     if obs is not None:
-                        waited = obs.input_wait_ns - w0
-                        obs.apply_ns += max(0, mono() - t_poll - waited)
+                        obs.end_poll(poll, barrier=False)
 
     def spawn(self) -> asyncio.Task:
         return asyncio.create_task(self.run(), name=f"actor-{self.actor_id}")

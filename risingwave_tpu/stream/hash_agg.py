@@ -51,6 +51,7 @@ from ..ops.hash_table import (
 )
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
+from ..utils.d2h import fetch_small
 from ..utils.metrics import (
     GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EXTREMA_ERRORS,
     HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_PROBE_FALLBACK_ROWS,
@@ -557,7 +558,7 @@ class HashAggExecutor(Executor):
         replays from the last committed epoch (SURVEY.md §3.5). Capacity
         provisioning + barrier-time growth make this a last-resort
         watchdog."""
-        vals = np.asarray(self._watchdog_pack(
+        vals = fetch_small(self._watchdog_pack(
             self.state, self._overflow_dev, self._occ_dev))
         self._note_probe_fallback(int(vals[2]))
         self._note_flush_counts(int(vals[3]), int(vals[4]))
@@ -973,7 +974,7 @@ class HashAggExecutor(Executor):
         cell: dict = {}
 
         def wait_counts():
-            return np.asarray(counts_dev) if counts_dev is not None else None
+            return fetch_small(counts_dev) if counts_dev is not None else None
 
         def cont_prepare(counts):
             groups, i = [], 0

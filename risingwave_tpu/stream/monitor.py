@@ -10,7 +10,9 @@ totals. This module is that subsystem for the TPU port:
   * `MetricLevel` — off | info | debug (SET metric_level ...);
   * `ActorObs` — one bundle of instruments per actor: row/chunk counts,
     busy vs. align-wait seconds, dispatch fanout, plus the interval
-    phase split (apply / persist / align) the EpochTrace shows, and for
+    phase split (apply / persist / align, and their parts dispatch /
+    apply_wait / persist_wait / fence / input_wait) the EpochTrace shows, the
+    `SpanScope` the interval's spans wait in (utils/trace.py), and for
     an actor whose chain holds a sharded executor what crossed the mesh
     (mesh_rows / mesh_rows_max_shard / mesh_shuffle_bytes), and what its
     hash aggs emitted and its sorted joins persisted (agg_emit_rows /
@@ -41,6 +43,7 @@ from typing import Optional
 import numpy as np
 
 from ..utils.metrics import GLOBAL_METRICS, MetricsRegistry
+from ..utils.trace import SPAN_LOG, SpanScope, TraceAnnotation
 
 
 class MetricLevel(enum.IntEnum):
@@ -234,6 +237,7 @@ class ActorObs:
         "fence_ns", "_row_acc", "row_count", "chunks_in", "chunks_out",
         "dispatch", "busy_seconds", "align_seconds", "keys",
         "_occupancy", "registry", "children", "mesh", "counted",
+        "apply_wait_ns", "persist_wait_ns", "scope",
     )
 
     def __init__(self, registry: MetricsRegistry, actor_id: int,
@@ -246,6 +250,11 @@ class ActorObs:
         self.persist_ns = 0
         self.input_wait_ns = 0
         self.fence_ns = 0
+        self.apply_wait_ns = 0        # d2h waits inside chunk polls
+        self.persist_wait_ns = 0      # d2h waits inside the barrier poll
+        # the interval's spans until the barrier names their epoch; its
+        # `dispatch_ns` is the interval's fourth new phase cell
+        self.scope = SpanScope(actor_id)
         self._row_acc = None          # lazy device scalar (sum of chunk
         #                               cardinalities this interval)
         self._occupancy = []          # (executor_label, part, gauge, fn)
@@ -281,11 +290,55 @@ class ActorObs:
             self.row_count = self.chunks_in = self.chunks_out = None
             self.dispatch = self.busy_seconds = self.align_seconds = None
 
+    # ------------------------------------------------------ the actor's polls
+    def begin_poll(self) -> tuple:
+        """One poll of the chain starts: its span is in force from here, so
+        what the chain dispatches and fetches inside it is its child."""
+        t0 = time.monotonic_ns()
+        anno = TraceAnnotation("rw:actor.poll")
+        anno.__enter__()
+        return (t0, self.input_wait_ns, self.scope.wait_ns,
+                self.scope.open(t0), anno)
+
+    def end_poll(self, poll: tuple, barrier: bool) -> None:
+        """The poll yielded a chunk / watermark (apply) or the barrier
+        (persist: every executor's flush / persist / commit ran inside
+        it). The poll less the channel-recv waits accrued inside it is the
+        phase's time; its d2h waits are the phase's `*_wait_ns`."""
+        t0, w0, d0, handle, anno = poll
+        now = time.monotonic_ns()
+        busy = max(0, now - t0 - (self.input_wait_ns - w0))
+        waited = self.scope.wait_ns - d0
+        if barrier:
+            self.persist_ns += busy
+            self.persist_wait_ns += waited
+        else:
+            self.apply_ns += busy
+            self.apply_wait_ns += waited
+        anno.__exit__(None, None, None)
+        self.scope.close(handle, "actor.persist" if barrier
+                         else "actor.apply", now)
+
+    def begin_fence(self) -> tuple:
+        anno = TraceAnnotation("rw:actor.fence")
+        anno.__enter__()
+        return time.monotonic_ns(), anno
+
+    def end_fence(self, fence: tuple) -> None:
+        t0, anno = fence
+        now = time.monotonic_ns()
+        anno.__exit__(None, None, None)
+        self.fence_ns += now - t0
+        self.scope.leaf("actor.fence", t0, now)
+
     # ------------------------------------------------------ hot-path notes
     def add_input_wait(self, ns: int) -> None:
         """Exchange inputs (ChannelInput/Merge) report channel recv
-        waits here — the align component of the phase split."""
+        waits here — the align component of the phase split — as each
+        ends: a child span of the poll that waited."""
         self.input_wait_ns += ns
+        now = time.monotonic_ns()
+        self.scope.leaf("actor.input_wait", now - ns, now)
 
     def note_chunk_in(self) -> None:
         if self.chunks_in is not None:
@@ -301,6 +354,12 @@ class ActorObs:
                              else self._row_acc + card)
 
     # --------------------------------------------------------- barrier flush
+    def flush_spans(self, epoch: int) -> None:
+        """The interval's spans learn their epoch when its barrier arrives,
+        as its phases do; what no poll was in force for hangs off the
+        epoch's `collect`."""
+        self.scope.flush(epoch, SPAN_LOG.anchors(epoch)[1])
+
     def on_barrier(self) -> dict:
         """Close the interval: fetch the accumulated row count (the
         epoch fence already blocked on this interval's programs, so the
@@ -308,9 +367,15 @@ class ActorObs:
         counters, refresh occupancy gauges, and return the phase split
         for the epoch trace."""
         align_ns = self.input_wait_ns + self.fence_ns
+        scope = self.scope
         phases = {"apply_ns": self.apply_ns,
                   "persist_ns": self.persist_ns,
-                  "align_ns": align_ns}
+                  "align_ns": align_ns,
+                  "input_wait_ns": self.input_wait_ns,
+                  "fence_ns": self.fence_ns,
+                  "dispatch_ns": scope.dispatch_ns,
+                  "apply_wait_ns": self.apply_wait_ns,
+                  "persist_wait_ns": self.persist_wait_ns}
         if self.mesh:
             # what crossed the mesh this interval (rows received in all
             # and by the fullest shard, all_to_all bytes), as the
@@ -342,6 +407,8 @@ class ActorObs:
                     pass
         self.apply_ns = self.persist_ns = 0
         self.input_wait_ns = self.fence_ns = 0
+        self.apply_wait_ns = self.persist_wait_ns = 0
+        scope.dispatch_ns = scope.wait_ns = 0
         self._row_acc = None
         return phases
 

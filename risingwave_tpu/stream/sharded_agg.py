@@ -61,6 +61,7 @@ from ..expr.agg import AggCall
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
+from ..utils.d2h import fetch_small
 from .executor import Executor
 from .hash_agg import AggState, HashAggExecutor
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
@@ -524,7 +525,7 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         cell: dict = {}
 
         def wait_counts():
-            return np.asarray(counts_dev) if counts_dev is not None else None
+            return fetch_small(counts_dev) if counts_dev is not None else None
 
         def cont_prepare(counts):
             groups, i = [], 0
@@ -636,7 +637,7 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         return 0
 
     def _check_watchdog(self) -> None:
-        vals = np.asarray(self._watchdog_pack(self._overflow_dev,
+        vals = fetch_small(self._watchdog_pack(self._overflow_dev,
                                               self._occ_dev,
                                               self._dropped_dev,
                                               self._shuffle_obs_dev))[0]

@@ -42,6 +42,7 @@ from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
+from ..utils.d2h import fetch_small
 from .align import LEFT, RIGHT
 from .executor import Executor
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
@@ -332,7 +333,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
         cell: dict = {}
 
         def wait_counts():
-            return np.asarray(counts_dev) if counts_dev is not None else None
+            return fetch_small(counts_dev) if counts_dev is not None else None
 
         def cont_prepare(counts):
             if counts is None:
@@ -433,7 +434,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
 
     # --------------------------------------------------------- watchdog
     def _check_watchdog(self) -> None:
-        vals = np.asarray(self._watchdog_pack_sh(
+        vals = fetch_small(self._watchdog_pack_sh(
             self._errs_dev, self._dropped_dev, self._shuffle_obs_dev,
             *self._n_dev))
         n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max, n_l, n_r = (

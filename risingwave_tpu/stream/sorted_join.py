@@ -131,6 +131,7 @@ from ..memory.spill import HostSpill
 from ..ops.hash_table import pack_rows, stable_lexsort
 from ..ops.jit_state import jit_state
 from ..ops.monotone_move import compact, expand
+from ..utils.d2h import fetch_small
 from ..utils.metrics import (
     GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_MATCH_BUFFER_PEAK, JOIN_MATCH_ROWS,
     JOIN_PERSIST_ROWS)
@@ -480,18 +481,19 @@ class SortedJoinExecutor(Executor):
         # what `recover()` and the spill reload replay stored rows through:
         # the same program with only the state for outputs, so the
         # compiler drops what served the emitted rows alone (the match
-        # buffer's gathers); counted under the apply's name like the
-        # stream's form below
+        # buffer's gathers). Each of the three has a name of its own: the
+        # name is what tells their programs apart in a trace
+        # (ops/jit_state.py PROGRAMS)
         self._replay = jit_state(self._replay_impl,
                                  static_argnames=("side", "match_factor"),
                                  donate_argnums=(2,),
-                                 name="sorted_join_apply")
+                                 name="sorted_join_replay")
         # the stream's applies: the same program, which also folds what
         # the chunk asked of its match buffer into `_match_dev`
         self._apply_counted = jit_state(
             self._apply_counted_impl,
             static_argnames=("side", "match_factor"), donate_argnums=(2, 3),
-            name="sorted_join_apply")
+            name="sorted_join_apply_counted")
         self._evict = jit_state(self._evict_impl, static_argnames=("side",),
                                 name="sorted_join_evict")
         self._diff = jit_state(self._diff_impl, name="sorted_join_diff")
@@ -969,7 +971,7 @@ class SortedJoinExecutor(Executor):
         st = self.state_tables[s]
         del_cols, n_del, ins_cols, n_ins = self._diff(self.sides[s],
                                                       self._snap[s])
-        counts = np.asarray(jnp.stack([n_del, n_ins]))
+        counts = fetch_small(jnp.stack([n_del, n_ins]))
         nd, ni = int(counts[0]), int(counts[1])
         self._count_persisted(s, nd, ni)
         if not nd and not ni:
@@ -1317,7 +1319,7 @@ class SortedJoinExecutor(Executor):
         packed, self._match_dev = self._watchdog_pack(
             self._errs_dev, self._n_dev[LEFT], self._n_dev[RIGHT],
             self._match_dev)
-        vals = [int(x) for x in np.asarray(packed)]
+        vals = [int(x) for x in fetch_small(packed)]
         n_mo, n_miss, n_ro = vals[:3]
         self._n_known = vals[3:5]
         self._publish_live_rows(*self._n_known)

@@ -11,9 +11,13 @@ rule in one place.
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .trace import TraceAnnotation, current_scope
 
 
 def pack_for_fetch(arrays):
@@ -73,11 +77,37 @@ def fetch_flat(flat):
     therefore bottom out here or in a bare np.asarray of a dispatched
     buffer."""
     from .metrics import D2H_BYTES, D2H_FETCHES
-    host = tuple(None if f is None else np.asarray(f) for f in flat)
+    host = _in_wait_span(
+        lambda: tuple(None if f is None else np.asarray(f) for f in flat),
+        lambda host: sum(h.nbytes for h in host if h is not None))
     for h in host:
         if h is not None:
             D2H_FETCHES.inc()
             D2H_BYTES.inc(h.nbytes)
+    return host
+
+
+def fetch_small(dev) -> np.ndarray:
+    """Blocking d2h of one small device array (an executor's barrier
+    watchdog counters, a flush's counts): `np.asarray` inside a `d2h_wait`
+    span. The wait is for the device to REACH the program that packed it,
+    not for the few bytes. Not counted in d2h_bytes_total, which is the
+    persist payloads'."""
+    return _in_wait_span(lambda: np.asarray(dev), lambda host: host.nbytes)
+
+
+def _in_wait_span(fetch, nbytes):
+    """`fetch()` as a `d2h_wait` span under the span in force — the actor's
+    poll on the loop thread, a flush stage on the uploader's worker thread
+    (asyncio.to_thread copies the context) — with `nbytes(host)` as its
+    count; the bare fetch where no scope is in force."""
+    sc = current_scope()
+    if sc is None:
+        return fetch()
+    t0 = time.monotonic_ns()
+    with TraceAnnotation("rw:d2h_wait"):
+        host = fetch()
+    sc.wait(t0, time.monotonic_ns(), nbytes(host))
     return host
 
 

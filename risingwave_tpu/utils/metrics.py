@@ -364,6 +364,12 @@ SERVING_TIMEOUTS = GLOBAL_METRICS.counter("serving_timeouts_total")
 # barrier_stall_threshold_ms; the one-shot report rides stdout/logs.
 BARRIER_STALLS = GLOBAL_METRICS.counter("barrier_stalls_total")
 
+# Span log (utils/trace.py): spans the process-wide log let go of — whole
+# epochs dropped from its old end when it outgrew its bound, and spans an
+# actor recorded past its per-interval cap. 0 in a healthy run: a reader
+# of the log that finds an epoch missing looks here first.
+TRACE_SPANS_DROPPED = GLOBAL_METRICS.counter("trace_spans_dropped_total")
+
 # Mesh-parallel fragment execution (parallel/exchange.py +
 # stream/sharded_*.py, host half in stream/mesh_shuffle.py). The series:
 # - `mesh_shuffle_dropped_rows_total`: rows the in-mesh all_to_all

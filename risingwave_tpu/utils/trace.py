@@ -25,15 +25,260 @@ inject), so per-worker sub-blocks line up under meta's span without
 any cross-host clock agreement. `traces_to_json` / `traces_to_chrome`
 export the same stitched data machine-readably (the chrome form loads
 in Perfetto: one pid per worker, one tid per actor).
+
+The span tree of one checkpoint. At metric_level >= info (the default)
+every epoch also records a tree of `Span`s on `time.monotonic_ns()`, all
+carrying the barrier's `epoch.curr`; `off` records none. They live in the
+process-wide `SPAN_LOG` (bounded: whole epochs go from its old end,
+counted in `trace_spans_dropped_total`), hang off their `EpochTrace` while
+it is in the ring, ride `to_dict` / `from_dict` as offsets from inject,
+and all but the five marked * are also entered as a
+`jax.profiler.TraceAnnotation` named `rw:<name>` (a poll as
+`rw:actor.poll`: it learns its name when it ends), so a kept xplane shows
+them over the device lines. Self time = a span less what its children
+cover.
+
+  span (parent)                       recorded in                bounds
+  ----------------------------------  -------------------------  ------------------------------
+  checkpoint* (root)                  EpochTracer.begin ->       inject -> manifest swap (->
+                                      annotate                   collected, where nothing flushes)
+  collect* (checkpoint)               EpochTracer.begin -> end   inject -> every actor collected:
+                                                                 what `latencies_ns` holds
+  actor.apply, actor.persist          stream/actor.py            one poll of the chain: a chunk's,
+    (collect)                                                    the barrier's. Less its input
+                                                                 waits: `apply_ns`, `persist_ns`
+  actor.input_wait* (the poll)        ActorObs.add_input_wait    a channel / barrier-queue wait
+  dispatch:<StateJit.name>            ops/jit_state.py           host time to enqueue one program,
+    (the poll)                                                   a full device queue's block
+                                                                 included; one span a call
+  d2h_wait (the poll or flush.stage)  utils/d2h.py               host blocked until the device
+                                                                 reaches and ships a buffer;
+                                                                 `count` = its bytes
+  actor.fence (collect)               stream/actor.py            block_until_ready of the epoch's
+                                                                 tokens
+  flush.queue* (checkpoint)           meta/barrier_manager.py    enqueued -> the uploader takes it:
+                                                                 the wait behind the predecessor
+  flush (checkpoint)                  _upload_worker             job taken -> manifest swapped
+  flush.stage:<table> (flush)         _upload_worker             one (wait, cont) of a deferred
+                                                                 flush: `wait` (worker thread) is a
+                                                                 d2h_wait child, the rest is host
+                                                                 encode + state-table write
+  flush.seal, flush.upload,           _upload_worker             store.seal; upload_sealed (cluster
+    flush.commit (flush)                                         mode: every worker's sealed
+                                                                 report); commit_sealed
+  flush.loop_wait* (flush.stage,      _upload_worker             a worker thread's result waiting
+    flush.upload)                                                for the event loop to come back
+                                                                 to the uploader's task
+
+An interval's spans learn their epoch when its barrier reaches the actor,
+as `phases` does, so the work of an interval (and the wait for its
+barrier) may START before that epoch's inject; everything ends inside its
+parent. An input wait happens inside a poll of the chain, so it is the
+poll's child and not its sibling.
+
+`EpochTrace.phases[actor]`, the interval sums an actor reports with its
+collect (only the keys that end in `_ns` are times):
+
+  key               what
+  ----------------  ----------------------------------------------------
+  apply_ns          chunk polls less their input waits
+  persist_ns        the barrier-yielding poll less its input waits
+  align_ns          input_wait_ns + fence_ns (kept: older readers)
+  input_wait_ns     channel recv / barrier-queue waits
+  fence_ns          the epoch fence
+  dispatch_ns       the `dispatch:*` spans inside polls: enqueueing
+                    programs, part of apply_ns + persist_ns
+  apply_wait_ns     the `d2h_wait` spans inside chunk polls (a hash
+                    agg's watchdog fetch: its barrier work ends in the
+                    chunk its flush emits), part of apply_ns
+  persist_wait_ns   the `d2h_wait` spans inside the barrier poll: the
+                    loop thread blocked on a fetch, part of persist_ns
+  mesh_* / agg_* / join_*   row and byte counts (see `EpochTrace.phases`)
 """
 
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import contextvars
+import itertools
+import threading
 import time
 import traceback
 from collections import deque
 from dataclasses import dataclass, field
+from typing import NamedTuple
+
+from jax.profiler import TraceAnnotation
+
+from .metrics import TRACE_SPANS_DROPPED
+
+mono = time.monotonic_ns
+
+
+class Span(NamedTuple):
+    epoch: int
+    name: str
+    parent: int         # sid of the parent span; 0 = the epoch's root
+    owner: object       # actor id, "coord" or "uploader"
+    t0_ns: int
+    t1_ns: int
+    sid: int
+    count: int = 0      # a d2h_wait's bytes; 0 elsewhere
+
+
+_next_sid = itertools.count(1).__next__
+
+
+class SpanLog:
+    """The process-wide span store: epoch -> {sid: Span}, oldest epoch
+    first. `EpochTracer` keeps 64 epochs and a benchmark window commits
+    more, so what a reader selects by epoch lives here. Bounded by spans:
+    past `max_spans` whole epochs go from the old end (a tree is under 100
+    spans at bench widths, so the default holds 300 checkpoints and more)."""
+
+    def __init__(self, max_spans: int = 1 << 15):
+        self.max_spans = max_spans
+        self._epochs: dict[int, dict[int, Span]] = {}
+        # epoch -> (sid of `checkpoint`, sid of `collect`)
+        self._anchors: dict[int, tuple] = {}
+        self._n = 0
+        self._lock = threading.Lock()
+
+    def open(self, epoch: int, root_sid: int, collect_sid: int) -> dict:
+        """Start `epoch`; the dict returned is the log's own (an
+        `EpochTrace` holds on to it while it is in the ring)."""
+        with self._lock:
+            self._anchors[epoch] = (root_sid, collect_sid)
+            return self._epochs.setdefault(epoch, {})
+
+    def anchors(self, epoch: int) -> tuple:
+        return self._anchors.get(epoch, (0, 0))
+
+    def put(self, *spans: Span) -> None:
+        """Add spans; one whose sid the epoch already has replaces it (the
+        root is written at collect and again at the manifest swap)."""
+        with self._lock:
+            for sp in spans:
+                d = self._epochs.get(sp.epoch)
+                if d is None:
+                    d = self._epochs[sp.epoch] = {}
+                if sp.sid not in d:
+                    self._n += 1
+                d[sp.sid] = sp
+            while self._n > self.max_spans and len(self._epochs) > 1:
+                epoch = next(iter(self._epochs))
+                gone = self._epochs.pop(epoch)
+                self._anchors.pop(epoch, None)
+                self._n -= len(gone)
+                TRACE_SPANS_DROPPED.inc(len(gone))
+
+    def spans(self, epoch: int) -> list:
+        """The epoch's spans, or [] where the log does not have it."""
+        with self._lock:
+            return list(self._epochs.get(epoch, {}).values())
+
+    def epochs(self) -> list:
+        return list(self._epochs)
+
+
+SPAN_LOG = SpanLog()
+
+# The scope in force: one per actor (its task sets it; tasks and threads
+# spawned under it copy the context and so share the object) and one per
+# flush job of the uploader. None = record nothing (metric_level = off,
+# or code that runs under no actor).
+_SCOPE: contextvars.ContextVar = contextvars.ContextVar(
+    "rw_span_scope", default=None)
+# spans one scope holds before its flush: an interval of thousands of
+# small chunks keeps its first ones and counts the rest as dropped
+MAX_PENDING = 4096
+
+
+current_scope = _SCOPE.get
+set_scope = _SCOPE.set
+
+
+class SpanScope:
+    """Where spans wait for their epoch. `cur` is the sid of the span in
+    force (0 = none: a child then hangs off the parent `flush` is given);
+    `dispatch_ns` / `wait_ns` sum the dispatch and d2h_wait spans recorded
+    under a span in force."""
+
+    __slots__ = ("owner", "cur", "cur_t0", "pending", "dispatch_ns",
+                 "wait_ns", "dropped")
+
+    def __init__(self, owner):
+        self.owner = owner
+        self.cur = 0
+        self.cur_t0 = 0         # where the span in force started
+        self.pending: list = []
+        self.dispatch_ns = 0
+        self.wait_ns = 0
+        self.dropped = 0
+
+    def _add(self, name, parent, t0, t1, sid, count) -> None:
+        if len(self.pending) < MAX_PENDING:
+            self.pending.append((name, parent, t0, t1, sid, count))
+        else:
+            self.dropped += 1
+
+    def leaf(self, name: str, t0: int, t1: int, count: int = 0) -> None:
+        """A finished span under the span in force, or under the parent
+        `flush` is given where it began before that one did (a join's
+        other input, waiting since an earlier poll)."""
+        self._add(name, self.cur if t0 >= self.cur_t0 else 0, t0, t1,
+                  _next_sid(), count)
+
+    def dispatch(self, name: str, t0: int, t1: int) -> None:
+        if self.cur:
+            self.dispatch_ns += t1 - t0
+        self._add(name, self.cur, t0, t1, _next_sid(), 0)
+
+    def wait(self, t0: int, t1: int, nbytes: int) -> None:
+        if self.cur:
+            self.wait_ns += t1 - t0
+        self._add("d2h_wait", self.cur, t0, t1, _next_sid(), nbytes)
+
+    def open(self, t0: int = 0) -> tuple:
+        """Put a new span in force; `close` it with the handle."""
+        handle = (_next_sid(), self.cur, t0 or mono(), self.cur_t0)
+        self.cur, self.cur_t0 = handle[0], handle[2]
+        return handle
+
+    def close(self, handle: tuple, name: str, t1: int = 0) -> None:
+        sid, prev, t0, prev_t0 = handle
+        self.cur, self.cur_t0 = prev, prev_t0
+        self._add(name, prev, t0, t1 or mono(), sid, 0)
+
+    def flush(self, epoch: int, parent: int, log: SpanLog = None) -> None:
+        """Hand the waiting spans to the log under `epoch`; those with no
+        span in force when recorded become children of `parent`."""
+        if self.dropped:
+            TRACE_SPANS_DROPPED.inc(self.dropped)
+            self.dropped = 0
+        if self.pending:
+            pending, self.pending = self.pending, []
+            (log or SPAN_LOG).put(*(
+                Span(epoch, name, par or parent, self.owner, t0, t1, sid,
+                     count)
+                for name, par, t0, t1, sid, count in pending))
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """`name` as a child of the span in force, and as `rw:<name>` in the
+    profiler's trace; nothing where no scope is in force."""
+    sc = _SCOPE.get()
+    if sc is None:
+        yield
+        return
+    h = sc.open()
+    try:
+        with TraceAnnotation("rw:" + name):
+            yield
+    finally:
+        sc.close(h, name)
 
 
 @dataclass
@@ -58,8 +303,17 @@ class EpochTrace:
     # and, on one chip, "join_match_rows" (rows its applies emitted) and
     # "join_match_peak" / "join_match_width" (the most equi-key candidates
     # one chunk found, of the side nearest its match buffer's width).
-    # Counts, not nanoseconds: only the keys that end in "_ns" are times.
+    # Counts, not nanoseconds: only the keys that end in "_ns" are times
+    # (the module docstring lists them: apply / persist / align and their
+    # parts input_wait / fence / dispatch / apply_wait / persist_wait).
     phases: dict = field(default_factory=dict)
+    # the epoch's span tree (module docstring): sid -> Span, the very dict
+    # SPAN_LOG holds for the epoch, so spans that arrive after the span
+    # closed (the background flush) are seen here too. Empty at
+    # metric_level = off.
+    span_map: dict = field(default_factory=dict, repr=False)
+    root_sid: int = 0
+    collect_sid: int = 0
     sync_ns: int = 0        # inline store sync duration (pipelining off)
     # checkpoint-pipeline phases (annotated AFTER the span closes — the
     # uploader commits in the background, off the barrier critical path)
@@ -79,6 +333,11 @@ class EpochTrace:
     # meets again in `stitch_chrome_traces` via matching span ids.
     links: list = field(default_factory=list)
 
+    @property
+    def spans(self) -> list:
+        """The epoch's spans, by start time."""
+        return sorted(self.span_map.values(), key=lambda sp: sp.t0_ns)
+
     def to_dict(self) -> dict:
         """Wire form of the span (sealed-push piggyback + format=json):
         every time is an OFFSET from inject_ns, so the dict is
@@ -94,6 +353,10 @@ class EpochTrace:
             "commit_ns": int(self.commit_ns),
             "total_ns": int(self.total_ns),
             "links": [dict(ln) for ln in self.links],
+            "spans": [[sp.name, sp.parent, sp.owner,
+                       int(sp.t0_ns - self.inject_ns),
+                       int(sp.t1_ns - self.inject_ns), sp.sid, sp.count]
+                      for sp in self.spans],
         }
 
     @classmethod
@@ -109,6 +372,14 @@ class EpochTrace:
         t.commit_ns = int(d.get("commit_ns", 0))
         t.total_ns = int(d.get("total_ns", 0))
         t.links = [dict(ln) for ln in d.get("links", ())]
+        for name, parent, owner, t0, t1, sid, count in d.get("spans", ()):
+            t.span_map[int(sid)] = Span(t.epoch, str(name), int(parent),
+                                        owner, int(t0), int(t1), int(sid),
+                                        int(count))
+            if name == "checkpoint":
+                t.root_sid = int(sid)
+            elif name == "collect":
+                t.collect_sid = int(sid)
         return t
 
     @staticmethod
@@ -119,6 +390,13 @@ class EpochTrace:
             line += (f" (apply {ph.get('apply_ns', 0) / 1e6:.1f}ms, "
                      f"persist {ph.get('persist_ns', 0) / 1e6:.1f}ms, "
                      f"align {ph.get('align_ns', 0) / 1e6:.1f}ms)")
+            if "dispatch_ns" in ph:
+                line += (f" (of which dispatch "
+                         f"{ph['dispatch_ns'] / 1e6:.1f}ms, d2h wait "
+                         f"{ph['apply_wait_ns'] / 1e6:.1f} + "
+                         f"{ph['persist_wait_ns'] / 1e6:.1f}ms; fence "
+                         f"{ph['fence_ns'] / 1e6:.1f}ms, input wait "
+                         f"{ph['input_wait_ns'] / 1e6:.1f}ms)")
             if "mesh_rows" in ph:
                 line += (f" [mesh rows {ph['mesh_rows']}, max shard "
                          f"{ph['mesh_rows_max_shard']}, shuffle "
@@ -143,6 +421,49 @@ class EpochTrace:
                 line += "]"
         return line
 
+    def _dispatch_by_actor(self) -> dict:
+        """actor -> {program name: (calls, ns)} from the dispatch spans:
+        one span a call, summed per name."""
+        out: dict = {}
+        for sp in self.span_map.values():
+            if sp.name.startswith("dispatch:"):
+                per = out.setdefault(sp.owner, {})
+                n, ns = per.get(sp.name[9:], (0, 0))
+                per[sp.name[9:]] = (n + 1, ns + sp.t1_ns - sp.t0_ns)
+        return out
+
+    def _flush_lines(self) -> list:
+        """The background flush from its spans: inject -> commit, the
+        wait in the uploader's queue, each stage with the part of it that
+        waited for the device."""
+        by_name = {sp.name: sp for sp in self.span_map.values()
+                   if sp.owner in ("coord", "uploader")}
+        if not {"checkpoint", "collect", "flush.queue",
+                "flush"} <= set(by_name):
+            return []
+        def ms(sp):
+            return (sp.t1_ns - sp.t0_ns) / 1e6
+
+        flush_sid = by_name["flush"].sid
+        waits: dict = {}        # stage sid -> [d2h wait ns, loop wait ns]
+        for sp in self.span_map.values():
+            if sp.owner == "uploader" and sp.name in ("d2h_wait",
+                                                      "flush.loop_wait"):
+                w = waits.setdefault(sp.parent, [0, 0])
+                w[sp.name != "d2h_wait"] += sp.t1_ns - sp.t0_ns
+        lines = [f"  inject -> commit {ms(by_name['checkpoint']):.1f}ms: "
+                 f"collect {ms(by_name['collect']):.1f}ms, flush queued "
+                 f"{ms(by_name['flush.queue']):.1f}ms, flush "
+                 f"{ms(by_name['flush']):.1f}ms"]
+        for sp in self.spans:
+            if sp.parent == flush_sid:
+                lines.append(
+                    f"    {sp.name} {ms(sp):.1f}ms"
+                    + (f" (d2h wait {waits[sp.sid][0] / 1e6:.1f}ms, waiting "
+                       f"for the loop {waits[sp.sid][1] / 1e6:.1f}ms)"
+                       if sp.sid in waits else ""))
+        return lines
+
     def render(self) -> str:
         head = (f"epoch {self.epoch}: total {self.total_ns / 1e6:.1f}ms, "
                 f"sync {self.sync_ns / 1e6:.1f}ms")
@@ -151,9 +472,16 @@ class EpochTrace:
                      f"upload {self.upload_ns / 1e6:.1f}ms, "
                      f"commit {self.commit_ns / 1e6:.1f}ms]")
         lines = [head]
+        lines += self._flush_lines()
+        by_actor = self._dispatch_by_actor()
         for actor_id, dt in sorted(self.collects, key=lambda x: x[1]):
             lines.append(self._actor_line(
                 actor_id, dt, self.phases.get(actor_id)))
+            if actor_id in by_actor:
+                lines.append("    " + ", ".join(
+                    f"{name} {n}x {ns / 1e6:.1f}ms" for name, (n, ns)
+                    in sorted(by_actor[actor_id].items(),
+                              key=lambda kv: -kv[1][1])))
         # stitched per-worker sub-blocks: one timeline, offsets
         # anchored at each worker's inject receipt (= meta's push)
         for wid in sorted(self.worker_spans):
@@ -202,8 +530,13 @@ class EpochTracer:
              f"rebuilt_actors={r['actors']}")
             for r in self.recoveries]
 
-    def begin(self, epoch: int) -> None:
-        self._open[epoch] = EpochTrace(epoch, time.monotonic_ns())
+    def begin(self, epoch: int, spans: bool = True) -> None:
+        """`spans` False (metric_level = off): the epoch records no span
+        tree."""
+        t = self._open[epoch] = EpochTrace(epoch, time.monotonic_ns())
+        if spans:
+            t.root_sid, t.collect_sid = _next_sid(), _next_sid()
+            t.span_map = SPAN_LOG.open(epoch, t.root_sid, t.collect_sid)
 
     def collect(self, epoch: int, actor_id: int) -> None:
         t = self._open.get(epoch)
@@ -223,15 +556,25 @@ class EpochTracer:
     def end(self, epoch: int, sync_ns: int = 0) -> None:
         t = self._open.pop(epoch, None)
         if t is not None:
-            t.total_ns = time.monotonic_ns() - t.inject_ns
+            now = time.monotonic_ns()
+            t.total_ns = now - t.inject_ns
             t.sync_ns = sync_ns
             self._ring.append(t)
+            if t.root_sid:
+                # the root ends here unless a background flush follows:
+                # `annotate` then writes it again, to the manifest swap
+                SPAN_LOG.put(
+                    Span(epoch, "checkpoint", 0, "coord", t.inject_ns, now,
+                         t.root_sid),
+                    Span(epoch, "collect", t.root_sid, "coord",
+                         t.inject_ns, now, t.collect_sid))
 
     def annotate(self, epoch: int, *, seal_ns: int = 0, upload_ns: int = 0,
-                 commit_ns: int = 0) -> None:
+                 commit_ns: int = 0, committed_at_ns: int = 0) -> None:
         """Attach checkpoint-pipeline phase durations to an epoch whose
         span already closed — the background uploader reports these after
-        the barrier completed (which is the whole point of the pipeline)."""
+        the barrier completed (which is the whole point of the pipeline).
+        `committed_at_ns` (the manifest swap) ends the `checkpoint` span."""
         t = self._open.get(epoch)
         if t is None:
             for cand in reversed(self._ring):
@@ -240,6 +583,9 @@ class EpochTracer:
                     break
         if t is not None:
             t.seal_ns, t.upload_ns, t.commit_ns = seal_ns, upload_ns, commit_ns
+            if t.root_sid and committed_at_ns:
+                SPAN_LOG.put(Span(epoch, "checkpoint", 0, "coord",
+                                  t.inject_ns, committed_at_ns, t.root_sid))
 
     def add_links(self, epoch: int, links) -> None:
         """Attach cross-engine broker link records to an epoch span —
@@ -387,6 +733,12 @@ def traces_to_chrome(traces) -> list:
     epoch-level span). All timestamps are µs offsets from the OLDEST
     exported epoch's inject, each epoch anchored at its inject time;
     worker events anchor at the inject push, i.e. the same origin.
+    The epoch's span tree (module docstring) goes on the same tracks at
+    its real offsets from inject: an actor's polls, with their dispatch /
+    d2h_wait / input-wait children nested by time, on the actor's tid,
+    `checkpoint` / `collect` / `flush.queue` / `flush` and the flush's
+    stages on tid 0 (they replace the seal / upload / commit slices laid
+    end to end, which an epoch without spans still gets).
     Cross-engine broker links add a "broker i/o" track per epoch plus
     chrome flow events ("s"/"f" with matching ids) so Perfetto draws an
     arrow from a sink delivery to the downstream engine's ingest once
@@ -394,6 +746,13 @@ def traces_to_chrome(traces) -> list:
     events = []
     base = 0
     for i, t in enumerate(sorted(traces, key=lambda t: t.epoch)):
+        spans = t.spans
+        # an interval's work may start before its barrier's inject: leave
+        # it room, so that no timestamp runs back into the epoch before
+        base += max([t.inject_ns - sp.t0_ns for sp in spans]
+                    + [-sp[3] for w in t.worker_spans.values()
+                       for sp in w.get("spans", ())] + [0])
+
         def ev(name, pid, tid, ts_ns, dur_ns, **args):
             events.append({
                 "name": name, "ph": "X", "cat": "epoch",
@@ -404,7 +763,12 @@ def traces_to_chrome(traces) -> list:
 
         ev(f"epoch {t.epoch}", 0, 0, 0, t.total_ns,
            sync_ms=t.sync_ns / 1e6)
-        if t.seal_ns or t.upload_ns or t.commit_ns:
+        for sp in spans:
+            ev(sp.name, 0, sp.owner if isinstance(sp.owner, int) else 0,
+               sp.t0_ns - t.inject_ns, sp.t1_ns - sp.t0_ns, sid=sp.sid,
+               parent=sp.parent, **({"bytes": sp.count} if sp.count else {}))
+        flushed = any(sp.name == "flush" for sp in spans)
+        if (t.seal_ns or t.upload_ns or t.commit_ns) and not flushed:
             off = t.total_ns
             for nm, dur in (("seal", t.seal_ns),
                             ("upload", t.upload_ns),
@@ -425,6 +789,10 @@ def traces_to_chrome(traces) -> list:
                 ev(f"w{wid} collect actor {actor_id}", wid,
                    actor_id, 0, dt,
                    **_phase_args(ph))
+            for name, parent, owner, t0, t1, sid, _count in w.get(
+                    "spans", ()):
+                ev(name, wid, owner if isinstance(owner, int) else 0,
+                   t0, t1 - t0, sid=sid, parent=parent)
         # cross-engine links: one slice per delivery/ingest on the
         # broker i/o track + a flow event INSIDE it (flow events bind
         # to their enclosing slice by pid/tid/ts)
@@ -450,6 +818,8 @@ def traces_to_chrome(traces) -> list:
         base += max(t.total_ns + t.seal_ns + t.upload_ns + t.commit_ns,
                     max((w.get("total_ns", 0)
                          for w in t.worker_spans.values()), default=0),
+                    max((sp.t1_ns - t.inject_ns for sp in spans),
+                        default=0),
                     1_000_000)
     return events
 

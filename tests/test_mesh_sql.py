@@ -574,14 +574,16 @@ async def test_one_device_q7_has_three_phase_keys_and_no_mesh_counts(
     for phases in run["epochs"]:
         assert phases
         for p in phases.values():
-            # the three times, and on the agg's and the join's actors the
-            # row counts of PRs 30 and 34 (utils/trace.py); nothing of the
-            # mesh
+            # the three times and their five parts (PR 36), and on the
+            # agg's and the join's actors the row counts of PRs 30 and 34
+            # (utils/trace.py); nothing of the mesh
             assert set(p) - {"agg_emit_rows", "join_persist_delete_rows",
                              "join_persist_insert_rows", "join_live_rows",
                              "join_capacity", "join_match_rows",
                              "join_match_peak", "join_match_width"} \
-                == {"apply_ns", "persist_ns", "align_ns"}
+                == {"apply_ns", "persist_ns", "align_ns", "input_wait_ns",
+                    "fence_ns", "dispatch_ns", "apply_wait_ns",
+                    "persist_wait_ns"}
     assert run["totals_delta"] == [0, 0, 0]
     assert run["labels_added"] == set()
     assert "mesh" not in run["rendered"]

@@ -395,7 +395,8 @@ async def test_a_pool_that_fills_past_its_growth_mark_is_a_statejit_compile():
     await s.tick(2)
     assert join.capacity == [1 << 13, 1 << 14] and join.rebuilds == 1
     after = drive.compiles_by_program()
-    assert after["sorted_join_apply"] > before["sorted_join_apply"]
+    assert (after["sorted_join_apply_counted"]
+            > before["sorted_join_apply_counted"])
     _assert_is_the_oracles(
         _read(s), {"auction": 5 * AUCTIONS, "bid": 5 * BIDS}, _config())
     await s.drop_all()

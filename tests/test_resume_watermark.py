@@ -77,14 +77,14 @@ async def test_the_first_apply_after_a_restart_runs_with_a_cleaning_watermark(
     await _deploy(s)
     await s.tick(6)
     held = _join(s)._n_known[0]
-    compiled = _join(s)._apply.compiles     # per program NAME, by process
+    compiled = _join(s)._replay.compiles    # per program NAME, by process
     assert held > 2 * CHUNK
     await s.crash()
     del s
     s2 = Session(store=HummockStateStore.open(LocalFsObjectStore(root)))
     await s2.recover()
     join = _join(s2)
-    assert join._apply.compiles - compiled == 2, "one replay program a side"
+    assert join._replay.compiles - compiled == 2, "one replay program a side"
     await s2.tick(1)
     assert min(int(x) for x in join._cleaned_to) > NO_WATERMARK
     assert join._n_known[0] < held + CHUNK

@@ -140,6 +140,32 @@ def test_q7_sorted_join_apply(q7_executors, one_chip,
     fits_one_chip(compiled)
 
 
+def test_the_program_id_is_read_from_the_serialized_executable(
+        q7_executors, one_chip, no_persistent_cache):
+    """The `<id>` of a device trace's `jit_traced(<id>)` is the varint
+    field 9 of the second message of libtpu's serialized executable
+    (found on a v5e, PERF.md): held here to what THIS libtpu writes, so
+    that a new one that moves it fails a test and not three metrics. Two
+    programs, two ids; the same program, the same id."""
+    from risingwave_tpu.ops.jit_state import program_id_of_serialized
+    agg = q7_executors["HashAggExecutor"]
+    chunk = abstract_chunk(agg.input.schema, CHUNK, one_chip)
+    args = (abstract(agg.state, one_chip),
+            abstract(agg._overflow_dev, one_chip))
+    ids = []
+    for jitted, a in ((agg._apply._jitted, args + (chunk,)),
+                      (agg._apply._jitted, args + (chunk,)),
+                      (agg._watchdog_pack._jitted,
+                       args + (abstract(agg._occ_dev, one_chip),))):
+        ser = bytes(jitted.lower(*a).compile().runtime_executable()
+                    .serialize())
+        ids.append(program_id_of_serialized(ser))
+    assert all(isinstance(i, int) and i >= 1 << 32 for i in ids), ids
+    assert ids[0] == ids[1] != ids[2]
+    assert program_id_of_serialized(b"") is None
+    assert program_id_of_serialized(ser[:100]) is None
+
+
 def test_q7_sorted_join_durable_diff(q7_executors, one_chip,
                                                     no_persistent_cache):
     """The durable diff by the provenance lane — the program the volatile
