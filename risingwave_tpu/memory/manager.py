@@ -231,10 +231,11 @@ class MemoryManager:
     def on_barrier(self, epoch: int) -> None:
         """Barrier-collection hook: refresh gauges; under an exceeded
         budget, ask the worst offenders to evict. Runs synchronously on
-        the event loop with every executor idle between epochs — eviction
-        dispatches device programs and (rarely) blocks on a packed d2h
-        fetch, exactly the per-barrier transfer discipline the watchdogs
-        already follow."""
+        the event loop with no barrier in flight (meta/barrier_manager.py
+        skips the pulse otherwise), so no executor is parked inside its
+        barrier handling — eviction dispatches device programs and
+        (rarely) blocks the loop on a packed d2h fetch: off the barrier
+        path, and counted in `d2h_wait_on_loop_seconds_total`."""
         if not self._participants:
             return
         self.reload_guard.on_barrier()

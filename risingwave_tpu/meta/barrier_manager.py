@@ -708,11 +708,15 @@ class BarrierCoordinator:
         self._stalls_reported.discard(barrier.epoch.curr)
         if not self._epochs:
             self._stop_watchdog()
-        # budget check at barrier collection: the epoch is complete and
-        # every executor idle, so eviction device work cannot race an
-        # in-flight apply; runs synchronously (no awaits) so no actor
-        # interleaves mid-eviction
-        self.memory.on_barrier(barrier.epoch.curr)
+        # budget check at barrier collection: the epoch is complete and,
+        # with no other barrier in flight (rounds inject one at a time), no
+        # executor is inside its barrier handling — parked in an awaited
+        # fetch with counts in hand that an eviction would put out of date
+        # (utils/d2h.py off_loop); runs synchronously (no awaits) so no
+        # actor interleaves mid-eviction. A pulse that finds another
+        # barrier in flight is skipped: the next one comes with its collect
+        if not self._epochs:
+            self.memory.on_barrier(barrier.epoch.curr)
         # serving caches advance to the sealed epoch in the same
         # synchronous window (a wanted-but-absent cache pays its one
         # full build scan here, before incremental maintenance takes
@@ -830,9 +834,11 @@ class BarrierCoordinator:
 
     async def _upload_worker(self) -> None:
         """Drains the checkpoint queue STRICTLY in order: per epoch, run
-        the executors' deferred flush stages (blocking d2h waits on a
-        worker thread, count-dependent dispatch continuations back on the
-        loop — dispatching from two threads concurrently deadlocks jax),
+        the executors' deferred flushes (each ONE pure d2h wait on a
+        worker thread and a host-only continuation back on the loop: the
+        uploader dispatches nothing to the device — the actors enqueued
+        every pack at their barrier, ahead of the next interval's
+        programs, utils/d2h.py),
         seal the shared buffer, build+upload the SST off the loop, then
         swap the manifest on the loop. A failure parks the error for the
         next inject_barrier (fail-stop: recovery replays from the last
@@ -905,13 +911,10 @@ class BarrierCoordinator:
                     self._upload_q.task_done()
                     continue
                 t0 = time.monotonic_ns()
-                for table_id, stages in store.take_deferred(
-                        job.prev_epoch, tagged=True):
-                    for wait, cont in stages:
-                        with span(f"flush.stage:{table_id}"):
-                            payload = (await _off_loop(wait)
-                                       if wait is not None else None)
-                            cont(payload)
+                for table_id, wait, cont in store.take_deferred(
+                        job.prev_epoch):
+                    with span(f"flush.stage:{table_id}"):
+                        cont(await _off_loop(wait))
                 with span("flush.seal"):
                     batch = store.seal(job.prev_epoch)
                 t1 = time.monotonic_ns()

@@ -13,6 +13,7 @@ but everything stays in RAM. The durable LSM variant is state/hummock.py.
 
 from __future__ import annotations
 
+import asyncio
 import bisect
 import heapq
 from dataclasses import dataclass
@@ -186,27 +187,30 @@ class StateStore:
 
     Deferred-flush protocol (the async-checkpoint hook): a stateful
     executor's barrier-time persist splits into a device-dispatch half
-    (runs at the barrier) and a staged host half registered here via
-    `defer_flush(epoch, *stages)`, each stage a `(wait, cont)` pair:
+    (the ACTOR runs it at the barrier: the views, the counts it awaits,
+    the count-dependent prefix slicing/packing — `utils/d2h.py`
+    `defer_prefix_flush`) and ONE host half registered here via
+    `defer_flush(epoch, wait, cont)`:
 
-      * `wait()` -> payload: a PURE device wait / host computation (an
-        `np.asarray` of an already-dispatched buffer, `utils/d2h.py
-        fetch_flat`). The background uploader runs it on a worker
-        thread. It MUST NOT dispatch jax ops — a second thread
-        dispatching concurrently with the event loop deadlocks jax.
-      * `cont(payload)`: runs on the event loop; may dispatch follow-up
-        device ops (count-dependent prefix slicing/packing) and write/
-        commit state tables.
+      * `wait()` -> payload: a PURE device wait (`utils/d2h.py
+        fetch_flat` of the buffer the actor packed). The background
+        uploader runs it on a worker thread. It MUST NOT dispatch jax
+        ops — a second thread dispatching concurrently with the event
+        loop deadlocks jax.
+      * `cont(payload)`: runs on the event loop and is HOST-ONLY: unpack,
+        write and commit state tables. A device op dispatched here would
+        queue behind the next interval's programs, and the flush would
+        wait a whole collect for it.
 
     With `defer_enabled` False (the default — unit tests driving
-    executors directly, inline-sync mode) all stages run immediately in
-    order, which is exactly the pre-pipeline behavior. The barrier
-    coordinator's background uploader enables deferral and drains the
-    queue before sealing each epoch, so the stream never waits for the
-    d2h + encode + ingest cost."""
+    executors directly, inline-sync mode) the pair runs at once, the
+    wait on a worker thread and awaited. The barrier coordinator's
+    background uploader enables deferral and drains the queue before
+    sealing each epoch, so the stream never waits for the d2h + encode +
+    ingest cost."""
 
     def __init__(self):
-        # FIFO of (epoch, stages, table_id); epoch = the shared-buffer
+        # FIFO of (epoch, wait, cont, table_id); epoch = the shared-buffer
         # epoch the flush writes into (must run before that epoch seals);
         # table_id attributes the flush to its owning executor's primary
         # state table so per-fragment recovery can discard exactly the
@@ -215,23 +219,19 @@ class StateStore:
         self._deferred: list[tuple] = []
         self.defer_enabled = False
 
-    @staticmethod
-    def _run_stages(stages) -> None:
-        for wait, cont in stages:
-            cont(wait() if wait is not None else None)
-
-    def defer_flush(self, epoch: int, *stages, table_id=None) -> None:
+    async def defer_flush(self, epoch: int, wait, cont,
+                          table_id=None) -> None:
         if self.defer_enabled:
-            self._deferred.append((epoch, stages, table_id))
+            self._deferred.append((epoch, wait, cont, table_id))
         else:
-            self._run_stages(stages)
+            cont(await asyncio.to_thread(wait))
 
-    def take_deferred(self, epoch: int, tagged: bool = False) -> list:
-        """Pop every stage list registered for epochs <= epoch, in
-        registration order; `tagged`: as (table_id, stages) pairs."""
-        taken = [(t, st) if tagged else st
-                 for e, st, t in self._deferred if e <= epoch]
-        self._deferred = [t for t in self._deferred if t[0] > epoch]
+    def take_deferred(self, epoch: int) -> list:
+        """Pop every flush registered for epochs <= epoch, in
+        registration order, as (table_id, wait, cont)."""
+        taken = [(t, wait, cont)
+                 for e, wait, cont, t in self._deferred if e <= epoch]
+        self._deferred = [d for d in self._deferred if d[0] > epoch]
         return taken
 
     def discard_staged_tables(self, table_ids) -> None:
@@ -245,7 +245,7 @@ class StateStore:
         re-reads its tables at the committed view and re-stages the
         replayed intervals itself."""
         ids = set(table_ids)
-        self._deferred = [t for t in self._deferred if t[2] not in ids]
+        self._deferred = [d for d in self._deferred if d[3] not in ids]
         self._discard_staged(ids)
 
     def _discard_staged(self, table_ids: set) -> None:
@@ -259,8 +259,8 @@ class StateStore:
             del buf[k]
 
     def run_deferred(self, epoch: int) -> None:
-        for stages in self.take_deferred(epoch):
-            self._run_stages(stages)
+        for _, wait, cont in self.take_deferred(epoch):
+            cont(wait())
 
     @staticmethod
     def _count_write_keys(batch: WriteBatch) -> None:

@@ -26,6 +26,7 @@ from ..common.chunk import StreamChunk, OP_INSERT, op_sign
 from ..ops.hash_table import HashTable, lookup, lookup_or_insert
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
+from ..utils.d2h import fetch_small, off_loop
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier
 
@@ -95,8 +96,8 @@ class AppendOnlyDedupExecutor(StatefulUnaryExecutor):
             self.table, self.fresh, self._errs_dev, chunk)
         return StreamChunk(chunk.columns, chunk.ops, keep, chunk.schema)
 
-    def check_watchdog(self) -> None:
-        n = int(np.asarray(self._errs_dev))
+    async def check_watchdog(self) -> None:
+        n = int(await off_loop(fetch_small, self._errs_dev))
         if n:
             raise RuntimeError(
                 f"dedup overflow or append-only violation ({n} rows, "

@@ -30,7 +30,7 @@ from ..common.chunk import (
 from ..common.floatbits import float_identity_bits
 from ..common.types import DataType, Field, Schema
 from ..ops.jit_state import jit_state
-from ..utils.d2h import fetch_small
+from ..utils.d2h import fetch_small, off_loop
 from .executor import Executor
 from .align import LEFT, RIGHT, barrier_align
 from .message import Barrier, BarrierKind, Watermark
@@ -226,8 +226,8 @@ class DynamicFilterExecutor(GrowableSortedStore, Executor):
                     self._dirty = False
                     yield StreamChunk(out_cols, ops, vis, self.schema)
                 if self.watchdog_interval:
-                    vals = fetch_small(self._wd_pack(self._errs_dev,
-                                                    self.n))
+                    vals = await off_loop(
+                        fetch_small, self._wd_pack(self._errs_dev, self.n))
                     if int(vals[0]) or int(vals[1]):
                         raise RuntimeError(
                             f"dynamic filter state errors "

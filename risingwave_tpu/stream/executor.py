@@ -70,8 +70,10 @@ class StatefulUnaryExecutor(Executor):
 
       first/INITIAL barrier  -> init_epoch + recover, no flush
       data chunk             -> on_chunk (device dispatch, no transfers)
-      barrier                -> watchdog fail-stop BEFORE the checkpoint
-                                commits, then flush -> persist -> emit
+      barrier                -> watchdog fail-stop (awaited: the loop
+                                is free while the device reaches the
+                                pack) BEFORE the checkpoint commits,
+                                then flush -> persist -> emit
 
     Subclasses implement the hooks; `watchdog_interval` must be 1 (check
     every barrier) or None (transfer-free mode, no d2h fetch ever — see
@@ -94,8 +96,10 @@ class StatefulUnaryExecutor(Executor):
         """Apply a chunk; return an output chunk to emit now (or None)."""
         raise NotImplementedError
 
-    def check_watchdog(self) -> None:
-        """Fetch device error counters; raise to fail-stop pre-commit."""
+    async def check_watchdog(self) -> None:
+        """Fetch device error counters; raise to fail-stop pre-commit.
+        The pack is dispatched here, its wait awaited off the loop
+        (`utils/d2h.py` `off_loop(fetch_small, pack)`)."""
 
     def flush(self) -> Optional[StreamChunk]:
         """Barrier-time changelog emission (None = nothing to emit)."""
@@ -135,7 +139,7 @@ class StatefulUnaryExecutor(Executor):
                 stopping = msg.mutation is not None and msg.is_stop_any()
                 if self.watchdog_interval and (
                         stopping or self._applied_since_flush):
-                    self.check_watchdog()
+                    await self.check_watchdog()
                 flushed = None
                 if self._applied_since_flush:
                     self._applied_since_flush = False

@@ -42,7 +42,7 @@ from ..common.floatbits import float_identity_bits
 from ..common.types import DataType, Field, Schema
 from ..ops.hash_table import stable_lexsort
 from ..ops.jit_state import jit_state
-from ..utils.d2h import fetch_small
+from ..utils.d2h import fetch_small, off_loop
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier, Watermark
 from .sorted_join import _HSENTINEL, key_hash
@@ -373,8 +373,9 @@ class GeneralOverWindowExecutor(GrowableSortedStore,
 
     _SECONDARY = ("em_hash", "em_cols", "em_valids")
 
-    def check_watchdog(self) -> None:
-        vals = fetch_small(self._wd_pack(self._errs_dev, self.n))
+    async def check_watchdog(self) -> None:
+        vals = await off_loop(fetch_small,
+                              self._wd_pack(self._errs_dev, self.n))
         if int(vals[0]):
             raise RuntimeError(
                 f"over-window store overflow ({int(vals[0])} rows "

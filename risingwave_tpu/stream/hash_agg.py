@@ -51,7 +51,7 @@ from ..ops.hash_table import (
 )
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
-from ..utils.d2h import fetch_small
+from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from ..utils.metrics import (
     GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EXTREMA_ERRORS,
     HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_PROBE_FALLBACK_ROWS,
@@ -546,19 +546,21 @@ class HashAggExecutor(Executor):
         occ, _ = self._live_zombie(self.state)
         return int(occ)
 
-    def _check_watchdog(self) -> None:
-        """ONE small blocking fetch of the device-accumulated counters
+    async def _check_watchdog(self) -> None:
+        """ONE small fetch of the device-accumulated counters
         (`_watchdog_pack_impl`) — called per BARRIER, never per chunk. The
         counters accumulate on device across the epoch; fetching them per
         chunk gates throughput on d2h copy latency, so the fetch is a plain
-        blocking np.asarray of a few scalars, once per barrier.
+        np.asarray of a few scalars, once per barrier: the pack dispatched
+        here, the wait — for the device to reach it, behind the interval's
+        applies — awaited off the loop (utils/d2h.py `off_loop`).
 
         Overflow fail-stops BEFORE this epoch's checkpoint commits, so a
         chunk the table dropped rows from is never made durable; recovery
         replays from the last committed epoch (SURVEY.md §3.5). Capacity
         provisioning + barrier-time growth make this a last-resort
         watchdog."""
-        vals = fetch_small(self._watchdog_pack(
+        vals = await off_loop(fetch_small, self._watchdog_pack(
             self.state, self._overflow_dev, self._occ_dev))
         self._note_probe_fallback(int(vals[2]))
         self._note_flush_counts(int(vals[3]), int(vals[4]))
@@ -931,32 +933,28 @@ class HashAggExecutor(Executor):
             self._apply_evict_deletes(keys_np, len(dead))
 
     # ------------------------------------------------------- persistence
-    def _persist(self, barrier: Barrier) -> None:
-        """Overlap-friendly durable flush: the packed persist/evict views
-        are DISPATCHED here (device work queues behind the epoch's applies,
-        into fresh non-donated buffers), and the blocking work hands off
-        to the store as a staged deferred flush — inline by default,
-        drained by the barrier coordinator's background uploader in
-        pipelined mode, so the stream resumes as soon as the dispatch is
-        queued. Stage waits are PURE (np.asarray of dispatched buffers,
-        thread-safe); the count-dependent prefix slicing/packing happens
-        in the stage continuations, which always run on the event loop.
+    def _persist_views(self, barrier: Barrier):
+        """Dispatch-only first half of the barrier's durable flush: the
+        packed persist / evict views queue behind the epoch's applies,
+        into fresh non-donated buffers, BEFORE the flush resets `dirty` and
+        the evict zeroes the groups. Returns what `_persist` finishes:
+        `(rows, evict)`, each None or `(device arrays, count)`.
 
-        d2h discipline (a blocking fetch has a fixed per-call cost and
-        serialises with dispatch): dirty rows are compacted to the buffer
-        prefix, and the whole payload — ops, vis, every column, evict
-        keys — ships in TWO calls (counts, then one packed payload,
-        utils/d2h.py)."""
+        d2h discipline (a fetch has a fixed per-call cost): dirty rows are
+        compacted to the buffer prefix, and the whole payload — ops, vis,
+        every column, evict keys — ships as one packed payload
+        (utils/d2h.py) after at most one fetch of counts."""
         if self.state_table is None:
-            return
-        from ..utils.d2h import (fetch_flat, finish_prefix_groups,
-                                 prepare_prefix_groups)
-        st = self.state_table
-        dev_rows = n_dirty = None
+            return None
+        rows = evict = None
         if self._applied_since_flush:
             cols, ops, vis, n_dirty = self._flush_persist_view()
-            dev_rows = [ops, vis] + list(cols)
-        dev_evict = n_ev = None
+            # the watchdog's fetch already brought this very count (its
+            # pack and the view read the same state); without one the
+            # device's is fetched with the evict count
+            known = self._dirty_slots_known
+            rows = ([ops, vis] + list(cols),
+                    n_dirty if known is None else known)
         if (self.cleaning_watermark_key is not None
                 and self._pending_clean_wm is not None):
             # evicted groups leave the durable table in the SAME epoch their
@@ -965,54 +963,44 @@ class HashAggExecutor(Executor):
             # these tombstones override any insert staged above)
             keys_dev, n_ev = self._evict_keys(self.state,
                                               self._pending_clean_wm)
-            dev_evict = list(keys_dev)
-        count_parts = [jnp.ravel(x) for x in (n_dirty, n_ev)
-                       if x is not None]
-        counts_dev = (jnp.concatenate(count_parts) if count_parts
-                      else None)
+            evict = (list(keys_dev), n_ev)
+        return rows, evict
+
+    async def _persist(self, barrier: Barrier, views) -> None:
+        """Second half, after everything else the barrier dispatches: the
+        counts the host lacks are awaited, the payload's prefixes packed
+        (dispatched HERE, by the actor, ahead of the next interval's
+        programs), and the rest handed to the store as one deferred stage —
+        a pure wait for that pack and the host-only write + commit; inline
+        by default, drained by the barrier coordinator's background
+        uploader in pipelined mode (`defer_prefix_flush`)."""
+        if views is None:
+            return
+        st = self.state_table
+        on_dev = [jnp.ravel(p[1]) for p in views
+                  if p is not None and not isinstance(p[1], int)]
         new_epoch = barrier.epoch.curr
-        cell: dict = {}
 
-        def wait_counts():
-            return fetch_small(counts_dev) if counts_dev is not None else None
+        def plan(counts):
+            fetched = iter(() if counts is None else counts)
+            nd, nev = (0 if p is None else p[1] if isinstance(p[1], int)
+                       else int(next(fetched)) for p in views)
+            groups = [(p[0], n) for p, n in zip(views, (nd, nev)) if n]
 
-        def cont_prepare(counts):
-            groups, i = [], 0
-            cell["nd"] = cell["nev"] = 0
-            if dev_rows is not None:
-                cell["nd"] = int(counts[i])
-                i += 1
-                if cell["nd"]:
-                    groups.append((dev_rows, cell["nd"]))
-            if dev_evict is not None:
-                cell["nev"] = int(counts[i])
-                i += 1
-                if cell["nev"]:
-                    groups.append((dev_evict, cell["nev"]))
-            if groups:
-                cell["prep"] = prepare_prefix_groups(groups)
-
-        def wait_flat():
-            prep = cell.get("prep")
-            return fetch_flat(prep[0]) if prep is not None else None
-
-        def cont_apply(host_flat):
-            prep = cell.get("prep")
-            if prep is not None:
-                outs = finish_prefix_groups(host_flat, prep[1], prep[2])
-                oi = 0
-                if cell["nd"]:
-                    host = outs[oi]
-                    oi += 1
+            def write(outs):
+                outs = iter(outs)
+                if nd:
+                    host = next(outs)
                     st.write_chunk_columns(host[0], host[2:], host[1])
-                if cell["nev"]:
-                    self._apply_evict_deletes(outs[oi], cell["nev"])
-            st.commit(new_epoch)
+                if nev:
+                    self._apply_evict_deletes(next(outs), nev)
+                st.commit(new_epoch)
 
-        st.store.defer_flush(barrier.epoch.prev,
-                             (wait_counts, cont_prepare),
-                             (wait_flat, cont_apply),
-                             table_id=st.table_id)
+            return groups, write
+
+        await defer_prefix_flush(
+            st.store, barrier.epoch.prev, st.table_id,
+            jnp.concatenate(on_dev) if on_dev else None, plan)
 
     def _apply_evict_deletes(self, keys_np, n: int) -> None:
         width = sum(self._call_persist_width(j)
@@ -1252,12 +1240,12 @@ class HashAggExecutor(Executor):
                 # below keeping occupancy bounded.
                 if self.watchdog_interval and (
                         stopping or self._applied_since_flush):
-                    self._check_watchdog()
+                    await self._check_watchdog()
                 # LRU epoch stamp BEFORE the flush resets dirty (one
                 # segment_max per interval; no-op while eviction is off)
                 if self._mem_lru_on and self._applied_since_flush:
                     self._mem_stamp(msg.epoch.curr)
-                self._persist(msg)
+                views = self._persist_views(msg)
                 flushed = self._applied_since_flush
                 if flushed:
                     self._applied_since_flush = False
@@ -1279,6 +1267,9 @@ class HashAggExecutor(Executor):
                         # with a same-capacity rehash (compiles once, no
                         # host roundtrip) to keep occupancy == live set.
                         self.state = self._rehash(self.state, self.capacity)
+                # last of the barrier's dispatches, so the device has the
+                # flush and the evict to run while the counts travel
+                await self._persist(msg, views)
                 if flushed:
                     self._maybe_rebuild_at_barrier()
                 # held watermarks follow the interval's flushed updates

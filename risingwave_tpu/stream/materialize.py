@@ -30,6 +30,7 @@ from ..common.chunk import (
     StreamChunk, OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT,
 )
 from ..state.state_table import StateTable
+from ..utils.d2h import fetch_small, off_loop
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
 
@@ -60,6 +61,9 @@ class MaterializeExecutor(Executor):
         first = True
         async for msg in self.input.execute():
             if isinstance(msg, StreamChunk):
+                # the chunk is on the device until the program that makes
+                # it has run: that wait is taken off the loop
+                await off_loop(fetch_small, msg.vis)
                 self._apply(msg)
                 yield msg
             elif isinstance(msg, Barrier):

@@ -43,7 +43,7 @@ from ..ops.hash_table import stable_lexsort
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier, Watermark
 from ..ops.jit_state import jit_state
-from ..utils.d2h import fetch_small
+from ..utils.d2h import fetch_small, off_loop
 from .sorted_join import _HSENTINEL, key_hash
 from .sorted_store import GrowableSortedStore, sorted_store_apply
 
@@ -240,8 +240,9 @@ class RetractableTopNExecutor(GrowableSortedStore,
 
     _SECONDARY = ("top_hash", "top_cols", "top_valids")
 
-    def check_watchdog(self) -> None:
-        vals = fetch_small(self._wd_pack(self._errs_dev, self.n))
+    async def check_watchdog(self) -> None:
+        vals = await off_loop(fetch_small,
+                              self._wd_pack(self._errs_dev, self.n))
         if int(vals[0]):
             raise RuntimeError(
                 f"retractable TopN overflow ({int(vals[0])} rows dropped; "

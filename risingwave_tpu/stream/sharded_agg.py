@@ -61,7 +61,7 @@ from ..expr.agg import AggCall
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
-from ..utils.d2h import fetch_small
+from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from .executor import Executor
 from .hash_agg import AggState, HashAggExecutor
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
@@ -484,31 +484,38 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
             self.rebuilds += 1
             self._occ_known = 0  # refreshed by the next watchdog fetch
 
-    def _persist(self, barrier) -> None:
-        """Durable flush of the SHARDED state: the per-shard persist
-        view compacts each shard's dirty rows to its LOCAL prefix; all
-        shards' prefixes ship in TWO d2h calls (counts, then one packed
-        buffer — same per-call d2h discipline as the parent's). Like the
-        parent's, the device views dispatch AT the barrier and the
-        blocking fetch + writes + commit defer to the store (drained by
-        the background uploader in pipelined mode)."""
+    def _persist_views(self, barrier):
+        """The parent's, over the SHARDED state: the per-shard persist
+        view compacts each shard's dirty rows to its LOCAL prefix; the
+        evict view is the parent's. Returns `(dev, evict)`: the view's
+        `(cols, ops, vis, n_dirty per shard)` and `(key arrays, count)`,
+        each None where the barrier has none."""
         # stamp the interval's replay point with the epoch this barrier
         # seals; the coordinator drops it when that epoch commits
         self.ingest_log.seal(barrier.epoch.prev)
         if self.state_table is None:
-            return
-        from ..utils.d2h import (fetch_flat, finish_prefix_groups,
-                                 prepare_prefix_groups)
-        st = self.state_table
-        dev = None
+            return None
+        dev = evict = None
         if self._applied_since_flush:
             dev = self._persist_view_sh(self.state)
-        dev_evict = n_ev = None
         if (self.cleaning_watermark_key is not None
                 and self._pending_clean_wm is not None):
             keys_dev, n_ev = self._evict_keys(self.state,
                                               self._pending_clean_wm)
-            dev_evict = list(keys_dev)
+            evict = (list(keys_dev), n_ev)
+        return dev, evict
+
+    async def _persist(self, barrier, views) -> None:
+        """All shards' prefixes ship in TWO d2h calls (counts, then one
+        packed buffer — same per-call d2h discipline as the parent's).
+        Like the parent's, the counts are awaited and the prefixes packed
+        by the actor AT the barrier; the pure wait for that pack, the
+        writes and the commit defer to the store (drained by the
+        background uploader in pipelined mode)."""
+        if views is None:
+            return
+        dev, evict = views
+        st = self.state_table
         # int32 before the concatenate: joining a mesh-sharded int64[S]
         # with a replicated int64[1] ABORTS the TPU compiler (check failure
         # "Unsupported conversion from vmreg/vreg to U64", libtpu 0.0.34;
@@ -516,60 +523,44 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         count_parts = []
         if dev is not None:                            # n_dirty per shard
             count_parts.append(jnp.ravel(dev[3]).astype(jnp.int32))
-        if dev_evict is not None:
-            count_parts.append(jnp.ravel(n_ev).astype(jnp.int32))
-        counts_dev = (jnp.concatenate(count_parts) if count_parts
-                      else None)
+        if evict is not None:
+            count_parts.append(jnp.ravel(evict[1]).astype(jnp.int32))
         new_epoch = barrier.epoch.curr
         C, S = self.capacity, self.n_shards
-        cell: dict = {}
 
-        def wait_counts():
-            return fetch_small(counts_dev) if counts_dev is not None else None
-
-        def cont_prepare(counts):
-            groups, i = [], 0
-            cell["n_rows_groups"] = 0
-            cell["nev"] = 0
+        def plan(counts):
+            groups, i, nev = [], 0, 0
             if dev is not None:
                 cols, ops, vis, _ = dev
                 # every shard's prefix at the largest shard's bucket, the
                 # empty ones too: the packed shapes repeat (d2h.py)
-                nd_max = int(max(counts[i:i + S]))
+                nd_max = int(max(counts[:S]))
                 for sh in range(S if nd_max else 0):
                     lo = sh * C
                     groups.append((
                         [ops[lo:lo + C], vis[lo:lo + C]]
                         + [c[lo:lo + C] for c in cols],
-                        int(counts[i + sh]), nd_max))
-                cell["n_rows_groups"] = len(groups)
-                i += S
-            if dev_evict is not None:
-                cell["nev"] = int(counts[i])
-                if cell["nev"]:
-                    groups.append((dev_evict, cell["nev"]))
-            if groups:
-                cell["prep"] = prepare_prefix_groups(groups)
+                        int(counts[sh]), nd_max))
+                i = S
+            n_rows_groups = len(groups)
+            if evict is not None:
+                nev = int(counts[i])
+                if nev:
+                    groups.append((evict[0], nev))
 
-        def wait_flat():
-            prep = cell.get("prep")
-            return fetch_flat(prep[0]) if prep is not None else None
-
-        def cont_apply(host_flat):
-            prep = cell.get("prep")
-            if prep is not None:
-                outs = finish_prefix_groups(host_flat, prep[1], prep[2])
-                for seg in outs[:cell["n_rows_groups"]]:
+            def write(outs):
+                for seg in outs[:n_rows_groups]:
                     if len(seg[0]):
                         st.write_chunk_columns(seg[0], seg[2:], seg[1])
-                if cell["nev"]:
-                    self._apply_evict_deletes(outs[-1], cell["nev"])
-            st.commit(new_epoch)
+                if nev:
+                    self._apply_evict_deletes(outs[-1], nev)
+                st.commit(new_epoch)
 
-        st.store.defer_flush(barrier.epoch.prev,
-                             (wait_counts, cont_prepare),
-                             (wait_flat, cont_apply),
-                             table_id=st.table_id)
+            return groups, write
+
+        await defer_prefix_flush(
+            st.store, barrier.epoch.prev, st.table_id,
+            jnp.concatenate(count_parts) if count_parts else None, plan)
 
     def recover(self, barrier_epoch: int) -> None:
         """Rebuild SHARDED device state: rows partition by
@@ -636,11 +627,10 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
     def memory_evict(self, target_bytes: int, epoch: int) -> int:
         return 0
 
-    def _check_watchdog(self) -> None:
-        vals = fetch_small(self._watchdog_pack(self._overflow_dev,
-                                              self._occ_dev,
-                                              self._dropped_dev,
-                                              self._shuffle_obs_dev))[0]
+    async def _check_watchdog(self) -> None:
+        vals = (await off_loop(fetch_small, self._watchdog_pack(
+            self._overflow_dev, self._occ_dev, self._dropped_dev,
+            self._shuffle_obs_dev)))[0]
         n_un, occ, n_drop, fill = (int(vals[0]), int(vals[1]),
                                    int(vals[2]), int(vals[3]))
         self._note_probe_fallback(int(vals[4]))

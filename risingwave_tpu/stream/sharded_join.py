@@ -42,7 +42,7 @@ from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
-from ..utils.d2h import fetch_small
+from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from .align import LEFT, RIGHT
 from .executor import Executor
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
@@ -290,7 +290,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
             st.src[lo:lo + C],
             st.n[sh].reshape(()))
 
-    def _persist(self, barrier) -> None:
+    async def _persist(self, barrier) -> None:
         """Durable flush of the sharded sides: per-shard diffs (each
         shard's slice is a valid local sorted state whose `src` lane holds
         shard-local positions, so the parent's diff program applies
@@ -298,13 +298,12 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
         shipped in TWO d2h calls — one counts fetch, one packed buffer
         (the per-call fetch tax would otherwise multiply by 2·S·sides).
         The diff programs dispatch AT the barrier (against non-donated
-        snapshot bases); the blocking fetches run as PURE waits on the
-        uploader thread, with the count-dependent slicing/packing done in
-        a loop-side continuation (two threads dispatching concurrently
+        snapshot bases), the counts are awaited and the count-dependent
+        slicing/packing dispatched by the actor, still at the barrier;
+        the wait for that pack runs as a PURE wait on the uploader's
+        thread and the writes in its host-only continuation
+        (`defer_prefix_flush`; two threads dispatching concurrently
         deadlocks jax)."""
-        from ..common.chunk import OP_DELETE, OP_INSERT
-        from ..utils.d2h import (fetch_flat, finish_prefix_groups,
-                                 prepare_prefix_groups)
         # stamp the interval's replay point with the epoch this barrier
         # seals; the coordinator drops it when that epoch commits
         self.ingest_log.seal(barrier.epoch.prev)
@@ -325,20 +324,9 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                 pending.append((s, st, diffs))
                 self._rebase(s)
                 self._flush_dirty[s] = False
-        counts_dev = (jnp.stack(
-            [x for _, _, diffs in pending
-             for d in diffs for x in (d[1], d[3])])
-            if pending else None)
         new_epoch = barrier.epoch.curr
-        cell: dict = {}
 
-        def wait_counts():
-            return fetch_small(counts_dev) if counts_dev is not None else None
-
-        def cont_prepare(counts):
-            if counts is None:
-                return
-            cell["counts"] = counts
+        def plan(counts):
             groups, ci = [], 0
             for _, _, diffs in pending:
                 # the shards of one side at the largest shard's bucket, so
@@ -350,41 +338,27 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                     ci += 2
                     groups.append((list(d[0]), nd, nd_max))
                     groups.append((list(d[2]), ni, ni_max))
-            cell["prep"] = prepare_prefix_groups(groups)
 
-        def wait_flat():
-            prep = cell.get("prep")
-            return fetch_flat(prep[0]) if prep is not None else None
-
-        def cont_apply(host_flat):
-            prep = cell.get("prep")
-            if prep is not None:
-                fetched = finish_prefix_groups(host_flat, prep[1], prep[2])
-                counts = cell["counts"]
+            def write(fetched):
                 gi = ci = 0
                 for s, st, diffs in pending:
-                    for d in diffs:
+                    for _ in diffs:
                         nd, ni = int(counts[ci]), int(counts[ci + 1])
                         ci += 2
                         self._count_persisted(s, nd, ni)
-                        del_cols = fetched[gi]
-                        ins_cols = fetched[gi + 1]
+                        self._write_diff(st, nd, fetched[gi], ni,
+                                         fetched[gi + 1])
                         gi += 2
-                        if nd:
-                            st.write_chunk_columns(
-                                np.full(nd, OP_DELETE, dtype=np.int8),
-                                del_cols, np.ones(nd, dtype=bool))
-                        if ni:
-                            st.write_chunk_columns(
-                                np.full(ni, OP_INSERT, dtype=np.int8),
-                                ins_cols, np.ones(ni, dtype=bool))
-            for st in tables:
-                st.commit(new_epoch)
+                for st in tables:
+                    st.commit(new_epoch)
 
-        tables[0].store.defer_flush(barrier.epoch.prev,
-                                    (wait_counts, cont_prepare),
-                                    (wait_flat, cont_apply),
-                                    table_id=tables[0].table_id)
+            return groups, write
+
+        await defer_prefix_flush(
+            tables[0].store, barrier.epoch.prev, tables[0].table_id,
+            jnp.stack([x for _, _, diffs in pending
+                       for d in diffs for x in (d[1], d[3])])
+            if pending else None, plan)
 
     def _src_iota(self, capacity: int) -> jnp.ndarray:
         return jax.device_put(
@@ -433,8 +407,8 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
         return [int(vals[:S].max()), int(vals[S:].max())]
 
     # --------------------------------------------------------- watchdog
-    def _check_watchdog(self) -> None:
-        vals = fetch_small(self._watchdog_pack_sh(
+    async def _check_watchdog(self) -> None:
+        vals = await off_loop(fetch_small, self._watchdog_pack_sh(
             self._errs_dev, self._dropped_dev, self._shuffle_obs_dev,
             *self._n_dev))
         n_mo, n_miss, n_ro, n_drop, fill, rows, rows_max, n_l, n_r = (

@@ -48,7 +48,7 @@ from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
 from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
-from ..utils.d2h import fetch_small
+from ..utils.d2h import fetch_small, off_loop
 from .sharded_agg import MeshIngestLog
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 from .sorted_join import _HSENTINEL
@@ -372,13 +372,13 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
         setattr(self, self._SEC_COUNT, nn)
         return StreamChunk(out_cols, ops, vis, self.schema)
 
-    def check_watchdog(self) -> None:
+    async def check_watchdog(self) -> None:
         # the drain must run BEFORE the fetch so this interval's shuffle
         # drops / store overflow fail-stop the SAME epoch
         self._drain_pending()
-        vals = fetch_small(self._watchdog_pack(
+        vals = (await off_loop(fetch_small, self._watchdog_pack(
             self._errs_dev, self.n, self._dropped_dev,
-            self._shuffle_obs_dev))[0]
+            self._shuffle_obs_dev)))[0]
         (n_ovf, n_miss, max_n, n_drop, fill, rows,
          rows_max) = (int(x) for x in vals)
         self._publish_shuffle(rows, rows_max, fill)

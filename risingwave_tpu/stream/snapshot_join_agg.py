@@ -49,6 +49,7 @@ from ..common.chunk import (
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall, AggKind
 from ..ops.jit_state import jit_state
+from ..utils.d2h import fetch_small, off_loop
 from .align import LEFT, RIGHT, barrier_align
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -322,8 +323,8 @@ class SnapshotJoinAggExecutor(Executor):
         return cur, cur_valid, jnp.ones((), dtype=bool), out
 
     # ------------------------------------------------------- housekeeping
-    def _check_watchdog(self):
-        errs = [int(x) for x in np.asarray(self._errs)]
+    async def _check_watchdog(self):
+        errs = [int(x) for x in await off_loop(fetch_small, self._errs)]
         if errs[0]:
             raise RuntimeError(
                 f"snapshot-join-agg fact store overflow ({errs[0]} rows "
@@ -364,12 +365,14 @@ class SnapshotJoinAggExecutor(Executor):
                  jnp.zeros(Cd - self._dkeys.shape[0], dtype=jnp.int64)])
 
     # ----------------------------------------------------------- persist
-    def _persist(self, barrier: Barrier) -> None:
-        for s, (st, n_dev) in enumerate(
-                zip(self.state_tables, (self._fn, self._dn))):
+    async def _persist(self, barrier: Barrier) -> None:
+        # the one wait for the device (it has reached the appends once the
+        # counts are here; the column slices below only travel)
+        counts = await off_loop(fetch_small, jnp.stack([self._fn, self._dn]))
+        for s, st in enumerate(self.state_tables):
             if st is None:
                 continue
-            n = int(np.asarray(n_dev))
+            n = int(counts[s])
             lo = self._persist_cursor[s]
             if n > lo:
                 pos = np.arange(lo, n, dtype=np.int64)
@@ -463,7 +466,7 @@ class SnapshotJoinAggExecutor(Executor):
                     # watchdog's barrier d2h, pay one here instead of
                     # overflowing (and surface any pending errors —
                     # they must never be swallowed in this mode)
-                    self._check_watchdog()
+                    await self._check_watchdog()
                     self._maybe_grow()
                     self._applied_rows_upper = int(np.asarray(self._fn))
                     self._applied_dim_upper = int(np.asarray(self._dn))
@@ -483,17 +486,17 @@ class SnapshotJoinAggExecutor(Executor):
                 if self._dirty:
                     self._dirty = False
                     if self.watchdog_interval:
-                        self._check_watchdog()
+                        await self._check_watchdog()
                         self._maybe_grow()
                     (self._prev, self._prev_valid, self._emitted,
                      out) = self._flush(
                         self._fcols, self._fvalids, self._fn,
                         self._dkeys, self._dn, self._prev,
                         self._prev_valid, self._emitted)
-                    self._persist(barrier)
+                    await self._persist(barrier)
                     yield out
                 elif stopping and self.watchdog_interval:
-                    self._check_watchdog()
+                    await self._check_watchdog()
                     for st in self.state_tables:
                         if st is not None:
                             st.commit(barrier.epoch.curr)

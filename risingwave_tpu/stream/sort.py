@@ -25,6 +25,7 @@ import numpy as np
 from ..common.chunk import Column, StreamChunk, OP_INSERT, op_sign
 from ..ops.hash_table import stable_lexsort
 from ..state.state_table import StateTable
+from ..utils.d2h import fetch_small, off_loop
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier, Watermark
 from ..ops.jit_state import jit_state
@@ -107,8 +108,8 @@ class SortExecutor(StatefulUnaryExecutor):
             return wm
         return None
 
-    def check_watchdog(self) -> None:
-        n = int(np.asarray(self._errs_dev))
+    async def check_watchdog(self) -> None:
+        n = int(await off_loop(fetch_small, self._errs_dev))
         if n:
             raise RuntimeError(
                 f"sort buffer overflow or append-only violation ({n} "

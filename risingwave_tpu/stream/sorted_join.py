@@ -131,7 +131,10 @@ from ..memory.spill import HostSpill
 from ..ops.hash_table import pack_rows, stable_lexsort
 from ..ops.jit_state import jit_state
 from ..ops.monotone_move import compact, expand
-from ..utils.d2h import fetch_small
+from ..utils.d2h import (
+    fetch_flat, fetch_small, finish_prefix_groups, off_loop,
+    prepare_prefix_groups,
+)
 from ..utils.metrics import (
     GLOBAL_METRICS, JOIN_LIVE_ROWS, JOIN_MATCH_BUFFER_PEAK, JOIN_MATCH_ROWS,
     JOIN_PERSIST_ROWS)
@@ -949,45 +952,50 @@ class SortedJoinExecutor(Executor):
                                   join_match_peak=peak,
                                   join_match_width=width)
 
-    def _persist(self, barrier: Barrier) -> None:
+    async def _persist(self, barrier: Barrier) -> None:
+        """Write both sides' changed rows to their StateTables, before the
+        barrier leaves the join.
+
+        d2h discipline: a fetch has a fixed per-call cost, so a side's
+        diff ships in TWO calls — one for its two counts, one for every
+        changed row as one packed payload (utils/d2h.py), never a fetch
+        per column. Both sides' diffs are dispatched first; every wait is
+        awaited off the loop (`off_loop`), the count-dependent packing and
+        the writes stay on it."""
+        diffs = []
         for s in (LEFT, RIGHT):
-            st = self.state_tables[s]
-            if st is None:
-                continue
-            if self._flush_dirty[s]:
-                self._persist_diff_write(s)
+            if self.state_tables[s] is not None and self._flush_dirty[s]:
+                del_cols, n_del, ins_cols, n_ins = self._diff(
+                    self.sides[s], self._snap[s])
+                diffs.append((s, del_cols, ins_cols,
+                              jnp.stack([n_del, n_ins])))
                 self._rebase(s)
                 self._flush_dirty[s] = False
-            st.commit(barrier.epoch.curr)
+        packs = []
+        for s, del_cols, ins_cols, counts_dev in diffs:
+            counts = await off_loop(fetch_small, counts_dev)
+            nd, ni = int(counts[0]), int(counts[1])
+            self._count_persisted(s, nd, ni)
+            if nd or ni:
+                packs.append((s, nd, ni, prepare_prefix_groups(
+                    [(list(del_cols), nd), (list(ins_cols), ni)])))
+        for s, nd, ni, (flat, metas, meta) in packs:
+            dels, inss = finish_prefix_groups(
+                await off_loop(fetch_flat, flat), metas, meta)
+            self._write_diff(self.state_tables[s], nd, dels, ni, inss)
+        for st in self.state_tables:
+            if st is not None:
+                st.commit(barrier.epoch.curr)
 
-    def _persist_diff_write(self, s: int) -> None:
-        """Write side s's changed rows to its StateTable.
-
-        d2h discipline: a blocking fetch has a fixed per-call cost and
-        serialises with dispatch, so the whole diff ships in TWO calls —
-        one for the two counts, one for every changed row as one packed
-        payload (utils/d2h.py), never a fetch per column."""
-        from ..utils.d2h import fetch_prefix_groups
-        st = self.state_tables[s]
-        del_cols, n_del, ins_cols, n_ins = self._diff(self.sides[s],
-                                                      self._snap[s])
-        counts = fetch_small(jnp.stack([n_del, n_ins]))
-        nd, ni = int(counts[0]), int(counts[1])
-        self._count_persisted(s, nd, ni)
-        if not nd and not ni:
-            return
-        dels, inss = fetch_prefix_groups(
-            [(list(del_cols), nd), (list(ins_cols), ni)])
-        # deletes strictly before inserts: an updated row (same pk,
-        # new values) diffs as delete(old)+insert(new) on one key
-        if nd:
-            st.write_chunk_columns(
-                np.full(nd, OP_DELETE, dtype=np.int8),
-                dels, np.ones(nd, dtype=bool))
-        if ni:
-            st.write_chunk_columns(
-                np.full(ni, OP_INSERT, dtype=np.int8),
-                inss, np.ones(ni, dtype=bool))
+    @staticmethod
+    def _write_diff(st, nd: int, dels, ni: int, inss) -> None:
+        """A side's fetched diff into its StateTable, deletes strictly
+        before inserts: an updated row (same pk, new values) diffs as
+        delete(old)+insert(new) on one key."""
+        for op, n, cols in ((OP_DELETE, nd, dels), (OP_INSERT, ni, inss)):
+            if n:
+                st.write_chunk_columns(np.full(n, op, dtype=np.int8), cols,
+                                       np.ones(n, dtype=bool))
 
     def _recover_reset(self, s: int, rows: list) -> None:
         """Size a side for recovery and reset it to empty (the sharded
@@ -1315,11 +1323,11 @@ class SortedJoinExecutor(Executor):
             self.rebuilds += 1
 
     # --------------------------------------------------------- watchdog
-    def _check_watchdog(self) -> None:
+    async def _check_watchdog(self) -> None:
         packed, self._match_dev = self._watchdog_pack(
             self._errs_dev, self._n_dev[LEFT], self._n_dev[RIGHT],
             self._match_dev)
-        vals = [int(x) for x in fetch_small(packed)]
+        vals = [int(x) for x in await off_loop(fetch_small, packed)]
         n_mo, n_miss, n_ro = vals[:3]
         self._n_known = vals[3:5]
         self._publish_live_rows(*self._n_known)
@@ -1394,9 +1402,9 @@ class SortedJoinExecutor(Executor):
                 # watchdog BEFORE the durable commit: errors fail-stop
                 # this epoch's checkpoint
                 if self.watchdog_interval and (stopping or dirty_any):
-                    self._check_watchdog()
+                    await self._check_watchdog()
                     self._maybe_grow()
-                self._persist(barrier)
+                await self._persist(barrier)
                 yield barrier
             else:
                 wm: Watermark = msg

@@ -7,10 +7,19 @@ payload — an int64 buffer holding every integer/bool/f32 column
 (narrower ints widened, f32 as its bits) plus, only when the payload
 has f64 columns, one float64 buffer. These helpers keep the pack/unpack
 rule in one place.
+
+The rule of the barrier path: the event-loop thread never WAITS for the
+device, and no other thread DISPATCHES to it. An actor at its barrier
+dispatches its packs on the loop and awaits each pure wait
+(`fetch_small`, `fetch_flat`) through `off_loop`, on a worker thread;
+the checkpoint uploader runs pure waits and host-only continuations.
+`d2h_wait_on_loop_seconds_total` is the time a fetch held the loop all
+the same (recovery, the memory manager's eviction and reload).
 """
 
 from __future__ import annotations
 
+import asyncio
 import time
 
 import jax
@@ -69,13 +78,13 @@ def unpack_fetched(flat, metas) -> list[np.ndarray]:
 
 def fetch_flat(flat):
     """Blocking d2h of an already-packed `flat` pair — a PURE WAIT
-    (`np.asarray` on concrete arrays; no op dispatch), so it is the ONE
-    d2h primitive safe to run on a worker thread while the event-loop
-    thread keeps dispatching. Dispatching eager jax ops from two threads
-    concurrently deadlocks (observed: a background slice gather vs. the
-    loop blocked in `_value`); every deferred-flush wait phase must
-    therefore bottom out here or in a bare np.asarray of a dispatched
-    buffer."""
+    (`np.asarray` on concrete arrays; no op dispatch), so it and
+    `fetch_small` are the d2h primitives safe to run on a worker thread
+    (`off_loop`) while the event-loop thread keeps dispatching.
+    Dispatching eager jax ops from two threads concurrently deadlocks
+    (observed: a background slice gather vs. the loop blocked in
+    `_value`); every awaited or deferred wait must therefore bottom out
+    here or in `fetch_small`."""
     from .metrics import D2H_BYTES, D2H_FETCHES
     host = _in_wait_span(
         lambda: tuple(None if f is None else np.asarray(f) for f in flat),
@@ -96,18 +105,37 @@ def fetch_small(dev) -> np.ndarray:
     return _in_wait_span(lambda: np.asarray(dev), lambda host: host.nbytes)
 
 
+async def off_loop(fetch, dev):
+    """`fetch(dev)` — `fetch_small` or `fetch_flat`, a pure wait — on a
+    worker thread, awaited: the caller's task is parked, the event loop
+    runs the other actors and the uploader's continuations meanwhile.
+    `dev` is dispatched by the caller, on the loop, before this is called.
+    `asyncio.to_thread` copies the context, so the `d2h_wait` span still
+    lands under the span in force (the actor's poll). A task cancelled
+    here unwinds at once; the thread finishes its wait on its own and the
+    result is dropped."""
+    return await asyncio.to_thread(fetch, dev)
+
+
 def _in_wait_span(fetch, nbytes):
     """`fetch()` as a `d2h_wait` span under the span in force — the actor's
-    poll on the loop thread, a flush stage on the uploader's worker thread
-    (asyncio.to_thread copies the context) — with `nbytes(host)` as its
-    count; the bare fetch where no scope is in force."""
+    poll or a flush stage, on a worker thread either way (asyncio.to_thread
+    copies the context) — with `nbytes(host)` as its count. A fetch made ON
+    the event-loop thread holds every actor and the uploader for as long
+    as it waits: its seconds go to `d2h_wait_on_loop_seconds_total`."""
     sc = current_scope()
-    if sc is None:
+    on_loop = asyncio._get_running_loop() is not None
+    if sc is None and not on_loop:
         return fetch()
     t0 = time.monotonic_ns()
     with TraceAnnotation("rw:d2h_wait"):
         host = fetch()
-    sc.wait(t0, time.monotonic_ns(), nbytes(host))
+    t1 = time.monotonic_ns()
+    if on_loop:
+        from .metrics import D2H_WAIT_ON_LOOP_SECONDS
+        D2H_WAIT_ON_LOOP_SECONDS.inc((t1 - t0) / 1e9)
+    if sc is not None:
+        sc.wait(t0, t1, nbytes(host))
     return host
 
 
@@ -137,7 +165,9 @@ def prepare_prefix_groups(groups):
     arrays to the pow2 bucket of its host-known prefix length and pack
     everything into ONE packed `flat` payload (pack_for_fetch). Returns
     (flat, metas, group_meta) for `finish_prefix_groups`. MUST run on
-    the event-loop thread — it dispatches device ops (see fetch_flat).
+    the event-loop thread, by the actor at its barrier: it dispatches
+    device ops (see fetch_flat), and a pack the uploader enqueued would
+    sit in the device's queue behind the next interval's programs.
 
     A group is `(arrays, n)` or `(arrays, n, bucket_n)`: sliced to the
     bucket of `bucket_n >= n`, trimmed to `n` on the host. The sharded
@@ -177,3 +207,30 @@ def fetch_prefix_groups(groups) -> list:
     per-epoch lengths would pay at every single barrier."""
     flat, metas, meta = prepare_prefix_groups(groups)
     return finish_prefix_groups(fetch_flat(flat), metas, meta)
+
+
+async def defer_prefix_flush(store, epoch: int, table_id, counts_dev,
+                             plan) -> None:
+    """The barrier's half of a checkpoint's deferred flush, run by the
+    ACTOR before its barrier leaves the executor: the device counts are
+    awaited (`counts_dev`: one small array the caller dispatched, or None
+    where the host knows them), `plan(counts)` turns them into
+    `(groups, write)`, the groups' prefixes are packed — enqueued here,
+    ahead of the next interval's programs — and what is left goes to the
+    store as ONE stage: a pure `fetch_flat` of that pack and the host-only
+    `write(fetched groups)`, which must end in the tables' commit.
+    Dispatch everything else the barrier dispatches BEFORE this is
+    awaited: the device then has work while the counts travel."""
+    counts = (None if counts_dev is None
+              else await off_loop(fetch_small, counts_dev))
+    groups, write = plan(counts)
+    prep = prepare_prefix_groups(groups) if groups else None
+
+    def wait():
+        return None if prep is None else fetch_flat(prep[0])
+
+    def cont(host_flat):
+        write([] if prep is None
+              else finish_prefix_groups(host_flat, prep[1], prep[2]))
+
+    await store.defer_flush(epoch, wait, cont, table_id=table_id)

@@ -274,6 +274,10 @@ CHECKPOINT_BACKPRESSURE_SECONDS = GLOBAL_METRICS.counter(
 # durable bench's d2h_bytes_per_s comes from here.
 D2H_BYTES = GLOBAL_METRICS.counter("d2h_bytes_total")
 D2H_FETCHES = GLOBAL_METRICS.counter("d2h_fetch_count")
+# seconds a `d2h_wait` (utils/d2h.py) was taken ON the event-loop thread,
+# where it holds every actor and the uploader: 0 on the barrier path
+D2H_WAIT_ON_LOOP_SECONDS = GLOBAL_METRICS.counter(
+    "d2h_wait_on_loop_seconds_total")
 
 # Hash-table probe (ops/hash_table._probe): rows that needed more than the
 # fingerprint lane and one key verify. Counted on the device; HashAgg

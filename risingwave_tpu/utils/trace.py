@@ -51,18 +51,24 @@ cover.
   dispatch:<StateJit.name>            ops/jit_state.py           host time to enqueue one program,
     (the poll)                                                   a full device queue's block
                                                                  included; one span a call
-  d2h_wait (the poll or flush.stage)  utils/d2h.py               host blocked until the device
-                                                                 reaches and ships a buffer;
-                                                                 `count` = its bytes
+  d2h_wait (the poll or flush.stage)  utils/d2h.py               a worker thread blocked until the
+                                                                 device reaches and ships a buffer,
+                                                                 the actor's task (or the uploader's)
+                                                                 parked on it and the event loop
+                                                                 free; `count` = its bytes. One taken
+                                                                 ON the loop thread also counts in
+                                                                 d2h_wait_on_loop_seconds_total
   actor.fence (collect)               stream/actor.py            block_until_ready of the epoch's
                                                                  tokens
   flush.queue* (checkpoint)           meta/barrier_manager.py    enqueued -> the uploader takes it:
                                                                  the wait behind the predecessor
   flush (checkpoint)                  _upload_worker             job taken -> manifest swapped
-  flush.stage:<table> (flush)         _upload_worker             one (wait, cont) of a deferred
-                                                                 flush: `wait` (worker thread) is a
-                                                                 d2h_wait child, the rest is host
-                                                                 encode + state-table write
+  flush.stage:<table> (flush)         _upload_worker             the ONE (wait, cont) of a table's
+                                                                 deferred flush: `wait` (worker
+                                                                 thread) is its one d2h_wait child,
+                                                                 for the pack the actor enqueued at
+                                                                 its barrier; the rest is host-only
+                                                                 unpack + state-table write
   flush.seal, flush.upload,           _upload_worker             store.seal; upload_sealed (cluster
     flush.commit (flush)                                         mode: every worker's sealed
                                                                  report); commit_sealed
@@ -92,7 +98,8 @@ collect (only the keys that end in `_ns` are times):
                     agg's watchdog fetch: its barrier work ends in the
                     chunk its flush emits), part of apply_ns
   persist_wait_ns   the `d2h_wait` spans inside the barrier poll: the
-                    loop thread blocked on a fetch, part of persist_ns
+                    actor parked on an awaited fetch (the loop is not
+                    held), part of persist_ns
   mesh_* / agg_* / join_*   row and byte counts (see `EpochTrace.phases`)
 """
 
