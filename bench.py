@@ -72,6 +72,10 @@ QUERY_BUDGET_S = {"q1": 60.0, "q5": 150.0, "q7": 150.0, "q8": 170.0,
 BASELINE_CHUNKS = {"q1": (16, 131072), "q5": (8, 131072),
                    "q7": (8, 131072), "q8": (8, 393216),
                    "q17": (64, 8192)}
+# q17's data: TPC-H by clause 4.2.3 (connectors/tpch.py) at scale factor
+# 0.005, a 1,000-part universe, under a seed at which three parts pass the
+# brand and container filter
+TPCH_SF, TPCH_SEED = 0.005, 22
 # Target duration of the timed measurement region per query.
 MEASURE_S = 8.0
 # Per-PHASE deadlines (fractions of the query budget): a stalled setup
@@ -204,12 +208,13 @@ def _numpy_q17(part_cols, li_chunks) -> float:
     the work a vectorized CPU engine pays for the same retraction
     semantics (every lineitem shifts its part's threshold, so all rows
     of affected parts re-evaluate)."""
-    from risingwave_tpu.connectors.tpch import NUM_PARTS
     from risingwave_tpu.common.types import GLOBAL_DICT
+    NUM_PARTS = round(TPCH_SF * 200_000)
     t0 = time.perf_counter()
     want_b = GLOBAL_DICT.get_or_insert("Brand#23")
     want_c = GLOBAL_DICT.get_or_insert("MED BOX")
-    pk, pb, pc = part_cols[0], part_cols[1], part_cols[2]
+    # p_partkey, p_brand, p_container of part's 9 columns
+    pk, pb, pc = part_cols[0], part_cols[3], part_cols[6]
     # part keys are an unbounded serial (only the first NUM_PARTS are
     # ever referenced by lineitems) — size EVERY per-part array by the
     # same bound so the masks line up
@@ -224,7 +229,8 @@ def _numpy_q17(part_cols, li_chunks) -> float:
     all_ep = np.empty(0, dtype=np.int64)
     answer = 0.0
     for cols, vis in li_chunks:
-        lpk, q, ep = cols[1][vis], cols[2][vis], cols[3][vis]
+        # l_partkey, l_quantity, l_extendedprice of lineitem's 16
+        lpk, q, ep = cols[1][vis], cols[4][vis], cols[5][vis]
         np.add.at(sumq, lpk, q)
         np.add.at(cnt, lpk, 1)
         all_pk = np.concatenate([all_pk, lpk])
@@ -261,9 +267,11 @@ def _baseline_main(query: str, n_chunks: int, chunk_size: int) -> None:
         dt = _numpy_q8(pch, ach)
     elif query == "q17":
         from risingwave_tpu.connectors import TpchGenerator
-        g = TpchGenerator("part", chunk_size=1024)
+        g = TpchGenerator("part", chunk_size=1024, scale_factor=TPCH_SF,
+                          seed=TPCH_SEED)
         part_cols = [np.asarray(c.data) for c in g.next_chunk().columns]
-        gl = TpchGenerator("lineitem", chunk_size=chunk_size)
+        gl = TpchGenerator("lineitem", chunk_size=chunk_size,
+                           scale_factor=TPCH_SF, seed=TPCH_SEED)
         chunks = []
         for _ in range(n_chunks):
             c = gl.next_chunk()
@@ -992,9 +1000,11 @@ async def bench_q17(progress: dict) -> None:
         "SET streaming_watchdog = 0",
         f"SET streaming_join_capacity = {1 << 20}",
         f"SET streaming_agg_capacity = {1 << 16}",
-        ("CREATE SOURCE part WITH (connector='tpch', table='part', "
+        (f"CREATE SOURCE part WITH (connector='tpch', table='part', "
+         f"scale_factor={TPCH_SF}, seed={TPCH_SEED}, "
          "chunk_size=1024, rate_limit=1024, primary_key='p_partkey')"),
-        ("CREATE SOURCE lineitem WITH (connector='tpch', "
+        (f"CREATE SOURCE lineitem WITH (connector='tpch', "
+         f"scale_factor={TPCH_SF}, seed={TPCH_SEED}, "
          f"table='lineitem', chunk_size={CS}, rate_limit={16 * CS})"),
         ("CREATE SINK q17 AS "
          "SELECT sum(L.l_extendedprice) / 7.0 AS avg_yearly "

@@ -20,12 +20,13 @@ the engine's Expr IR, and emits a `StreamGraph` directly:
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
+from fractions import Fraction
 from typing import Optional
 
 from ..common.types import DataType, Schema
 from ..expr.agg import AggCall, AggKind
-from ..expr.ir import Expr, call, col, lit
+from ..expr.ir import Expr, FuncCall, InputRef, call, col, lit
 from ..plan import Exchange, Fragment, Node, StreamGraph
 from . import sql as ast
 
@@ -677,7 +678,7 @@ class StreamPlanner:
         aggregate sub-plan of /root/reference/e2e_test/tpch q17.
         """
         from ..common.types import Field
-        from ..expr.ir import InputRef, input_refs, remap_inputs
+        from ..expr.ir import input_refs, remap_inputs
 
         if not self.cfg("streaming_snapshot_fuse", 1):
             return None
@@ -815,7 +816,13 @@ class StreamPlanner:
         sub_agg_calls: list[AggCall] = []
         decomp_sub = make_decomp(sub_agg_calls, ascan)
         a_fields, a_items, key_item = [], [], None
+        # item -> (ratio, L column) where the item is `ratio * avg(column)`
+        # over an integer column: a threshold NUMERIC states exactly
+        a_ratio_avg = {}
         for j, it in enumerate(asel.items):
+            ra = _ratio_times_avg(it.expr, ascan)
+            if ra is not None:
+                a_ratio_avg[j] = ra
             name = it.alias or auto_name(it.expr, j)
             if not contains_agg(it.expr):
                 try:
@@ -892,11 +899,28 @@ class StreamPlanner:
         if fact_link is None or dim_link is None or dim_link != fact_key:
             return None
 
-        sub_items = [e for e in a_items if e is not None]
-        sub_idx = {}
-        for j, e in enumerate(a_items):
-            if e is not None:
-                sub_idx[j] = len(sub_idx)
+        # sub items are numbered as the residue comes to read them: an item
+        # nothing reads (a FLOAT64 threshold folded away below) is never
+        # computed, nor are the aggregate calls only it used
+        sub_items: list = []
+        sub_pos: dict = {}
+
+        def sub_ref(key, make):
+            if key not in sub_pos:
+                sub_pos[key] = len(sub_items)
+                sub_items.append(make())
+            e = sub_items[sub_pos[key]]
+            return col(nl + sub_pos[key], e.ret_type)
+
+        def to_residue(b):
+            exact = _exact_avg_compare(
+                b, nl, a_off, a_ratio_avg, fscope, sub_agg_calls, sub_ref)
+            if exact is not None:
+                return exact
+            return remap_inputs(b, {
+                i: i if i < nl else sub_ref(
+                    i - a_off, lambda: a_items[i - a_off]).index
+                for i in sorted(input_refs(b))})
 
         def combine(es):
             if not es:
@@ -906,14 +930,14 @@ class StreamPlanner:
                 e = call("and", e, r)
             return e
 
-        residue = combine(residues)
-        if residue is not None:
-            refs = input_refs(residue)
-            residue = remap_inputs(residue, {
-                i: (i if i < nl else nl + sub_idx[i - a_off])
-                for i in refs})
+        residue = combine([to_residue(b) for b in residues])
         fact_filter = combine(fact_filters)
         dim_filter = combine(dim_filters)
+        used_calls = sorted(set().union(
+            *(input_refs(e) for e in sub_items)) if sub_items else ())
+        cmap = {o: n for n, o in enumerate(used_calls)}
+        sub_items = [remap_inputs(e, cmap) for e in sub_items]
+        sub_agg_calls = [sub_agg_calls[o] for o in used_calls]
 
         # ---- final (global) aggregates over L columns only
         final_agg_calls: list[AggCall] = []
@@ -932,18 +956,50 @@ class StreamPlanner:
         df, _, dinfo = self.plan_rel(dim_rel)
         if not (linfo.append_only and dinfo.append_only):
             return None
+        # the fact store reserves `capacity` rows for every column it is
+        # given and persists every one: hand it the columns the plan reads
+        # (q17: 3 of lineitem's 16), the dim side its key and filter columns
+        keep_f = {fact_key}
+        keep_f |= {c.arg for c in sub_agg_calls + final_agg_calls
+                   if c.arg is not None}
+        for e in (sub_filter, fact_filter):
+            if e is not None:
+                keep_f |= input_refs(e)
+        if residue is not None:
+            keep_f |= {i for i in input_refs(residue) if i < nl}
+        keep_d = {dim_pk} | (input_refs(dim_filter)
+                             if dim_filter is not None else set())
+        keep_f, keep_d = sorted(keep_f), sorted(keep_d)
+        fmap = {o: n for n, o in enumerate(keep_f)}
+        dmap = {o: n for n, o in enumerate(keep_d)}
+        rmap = {**fmap, **{nl + k: len(keep_f) + k
+                           for k in range(len(sub_items))}}
+        remap = lambda e, m: None if e is None else remap_inputs(e, m)
+        recall = lambda c: c if c.arg is None else replace(c, arg=fmap[c.arg])
+        inputs = []
+        for fid_, keep, sch in ((lf, keep_f, fscope.schema),
+                                (df, keep_d, dscope.schema)):
+            inp = Exchange(fid_)
+            if len(keep) < len(sch) \
+                    and not self._push_prune_upstream(fid_, keep, sch):
+                inp = Node("project", dict(
+                    exprs=[col(i, sch[i].data_type) for i in keep],
+                    names=[sch[i].name for i in keep]), inputs=(inp,))
+            inputs.append(inp)
         wd = 1 if self.cfg("streaming_watchdog", 1) else None
         node = Node("snapshot_join_agg", dict(
-            fact_key=fact_key, dim_key=dim_pk,
-            sub_agg_calls=sub_agg_calls, sub_items=sub_items,
-            residue=residue, final_agg_calls=final_agg_calls,
+            fact_key=fmap[fact_key], dim_key=dmap[dim_pk],
+            sub_agg_calls=[recall(c) for c in sub_agg_calls],
+            sub_items=sub_items, residue=remap(residue, rmap),
+            final_agg_calls=[recall(c) for c in final_agg_calls],
             final_items=final_items, out_names=names, out_types=types,
-            fact_filter=fact_filter, sub_filter=sub_filter,
-            dim_filter=dim_filter,
+            fact_filter=remap(fact_filter, fmap),
+            sub_filter=remap(sub_filter, fmap),
+            dim_filter=remap(dim_filter, dmap),
             capacity=self.cfg("streaming_join_capacity", 1 << 17),
             dim_capacity=self.cfg("streaming_agg_capacity", 1 << 16),
             durable=self.durable(), watchdog_interval=wd),
-            inputs=(Exchange(lf), Exchange(df)))
+            inputs=tuple(inputs))
         f = self.graph.add(Fragment(self.fid(), node, dispatch="simple"))
         return (f.fid, names, types, (), False, frozenset())
 
@@ -1736,6 +1792,90 @@ def auto_name(e, j: int) -> str:
     if isinstance(e, ast.Func):
         return e.name
     return f"expr{j}"
+
+_INTS = (DataType.INT16, DataType.INT32, DataType.INT64)
+
+
+def _ratio_times_avg(e, scope: Scope):
+    """(ratio, column) where the AST `e` is `avg(column)` over an integer
+    column times or over numeric literals (`0.2 * avg(q)`, `avg(q) / 5`),
+    the ratio as the exact fraction the literals' decimal text states
+    (`0.2` is 1/5, not the float64 nearest to it); else None."""
+    def number(x):
+        if isinstance(x, ast.Lit) and not isinstance(x.value, bool) \
+                and isinstance(x.value, (int, float)):
+            return Fraction(repr(x.value))
+        return None
+
+    if isinstance(e, ast.Func) and e.name == "avg" and len(e.args) == 1 \
+            and isinstance(e.args[0], ast.ColRef):
+        try:
+            i, t = scope.resolve(e.args[0])
+        except BindError:
+            return None
+        return (Fraction(1), i) if t in _INTS else None
+    if not isinstance(e, ast.BinOp) or e.op not in ("multiply", "divide"):
+        return None
+    c, inner = number(e.right), _ratio_times_avg(e.left, scope)
+    if e.op == "multiply" and inner is None:
+        c, inner = number(e.left), _ratio_times_avg(e.right, scope)
+    if c is None or inner is None or c <= 0:
+        return None
+    return (inner[0] * c if e.op == "multiply" else inner[0] / c), inner[1]
+
+
+def _exact_avg_compare(b, nl: int, a_off: int, ratio_avg: dict,
+                       fscope: Scope, sub_agg_calls: list, sub_ref):
+    """`x < c * avg(y)` (any of the four orderings, either way round)
+    with `x` an integer L column, `y` an integer L column and `c` a
+    decimal literal, as an INTEGER comparison: upstream evaluates the
+    right side in NUMERIC, exactly, so with c = num / den a row with
+    `den * x * count = num * sum` is a tie and `<` leaves it out. In
+    FLOAT64 the product `0.2 * (sum / count)` rounds, and on a TPU,
+    where an f64 is two f32, it can land on the other side of `x`. For
+    an integer x, `x < r` is `x < ceil(r)` and `x <= r` is `x <=
+    floor(r)`: one INT64 threshold a group, `ceil` or `floor` of `num *
+    sum / (den * count)` by floor division. Returns the rewritten
+    conjunct over [L columns ++ sub items], or None where `b` is not of
+    that shape."""
+    flip = {"less_than": "greater_than",
+            "less_than_or_equal": "greater_than_or_equal",
+            "greater_than": "less_than",
+            "greater_than_or_equal": "less_than_or_equal"}
+    if not (isinstance(b, FuncCall) and b.name in flip
+            and all(isinstance(a, InputRef) for a in b.args)):
+        return None
+    (x, thr), op = b.args, b.name
+    if x.index >= nl:
+        (thr, x), op = b.args, flip[b.name]
+    j = thr.index - a_off
+    if not (x.index < nl and j in ratio_avg
+            and fscope.schema[x.index].data_type in _INTS):
+        return None
+    ratio, y = ratio_avg[j]
+    if not 0 < ratio.numerator < 1 << 20 \
+            or not ratio.denominator < 1 << 20:
+        return None
+    up = op in ("less_than", "greater_than_or_equal")
+
+    def threshold():
+        sub_agg_calls.append(AggCall(AggKind.SUM, y, DataType.INT64,
+                                     True))
+        sub_agg_calls.append(AggCall(AggKind.COUNT, y, DataType.INT64,
+                                     True))
+        s_ = len(sub_agg_calls) - 2
+        num = call("multiply", lit(ratio.numerator),
+                   col(s_, DataType.INT64))
+        den = call("multiply", lit(ratio.denominator),
+                   col(s_ + 1, DataType.INT64))
+        # INT64 `divide` floors, and is NULL over no rows (count 0)
+        if up:
+            return call("neg", call("divide", call("neg", num), den))
+        return call("divide", num, den)
+
+    return call(op, col(x.index, x.ret_type),
+                sub_ref(("exact", j, up), threshold))
+
 
 def _now_conjunct(conj, scope):
     """`col OP now()` (either side) -> (col_index, dynamic-filter op)."""

@@ -1324,6 +1324,14 @@ class Session:
                 raise BindError(f"{k} must be at least 1")
         if cfg:
             args["cfg"] = cfg
+        if connector == "tpch":
+            # which data: the scale factor sizes the key universe, the seed
+            # is a dynamic argument of the generator's program
+            args["scale_factor"] = float(opts.pop("scale_factor", 1))
+            args["seed"] = int(opts.pop("seed", 0))
+            if args["scale_factor"] <= 0 or args["seed"] < 0:
+                raise BindError(
+                    "tpch: scale_factor must be positive, seed not negative")
         if "emit_watermarks" in opts:
             v = opts.pop("emit_watermarks")
             args["emit_watermarks"] = v in (True, 1, "1", "true", "t", "on")

@@ -459,7 +459,8 @@ class Parser:
             k = self.next().val
             self.expect("op", "=")
             t = self.next()
-            opts[k] = int(t.val) if t.kind == "num" else t.val
+            opts[k] = t.val if t.kind != "num" else (
+                float(t.val) if "." in t.val else int(t.val))
             if not self.accept("op", ","):
                 break
         self.expect("op", ")")

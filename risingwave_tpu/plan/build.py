@@ -806,7 +806,9 @@ def _build_source(args, inputs, ctx: ActorCtx, key):
         if args.get("connector") == "tpch":
             from ..connectors.tpch import TpchGenerator
             return TpchGenerator(args["table"],
-                                 chunk_size=args.get("chunk_size", 8192))
+                                 chunk_size=args.get("chunk_size", 8192),
+                                 scale_factor=args.get("scale_factor", 1.0),
+                                 seed=args.get("seed", 0))
         cfg = (NexmarkConfig(**args.get("cfg", {}))
                if args.get("cfg") else None)
         return NexmarkGenerator(args["table"],

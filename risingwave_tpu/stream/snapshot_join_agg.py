@@ -49,7 +49,9 @@ from ..common.chunk import (
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall, AggKind
 from ..ops.jit_state import jit_state
-from ..utils.d2h import fetch_small, off_loop
+from ..utils.d2h import (
+    _bucket, fetch_flat, fetch_small, off_loop, pack_for_fetch,
+    unpack_fetched)
 from .align import LEFT, RIGHT, barrier_align
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -108,6 +110,7 @@ class SnapshotJoinAggExecutor(Executor):
         self.dim_capacity = int(dim_capacity)
         self.state_tables = tuple(state_tables) if state_tables \
             else (None, None)
+        self._durable = any(st is not None for st in self.state_tables)
         if watchdog_interval not in (None, 1):
             raise ValueError(
                 "watchdog_interval must be 1 (check before every "
@@ -147,11 +150,22 @@ class SnapshotJoinAggExecutor(Executor):
                                      name="snapshot_join_agg_append_dim")
         self._flush = jit_state(self._flush_impl, donate_argnums=(5, 6, 7),
                                 name="snapshot_join_agg_flush")
+        # the barrier's two fetches, each one program: the five counters,
+        # then the rows appended since the last checkpoint
+        self._counts = jit_state(
+            lambda errs, fn, dn: jnp.concatenate(
+                [errs, jnp.stack([fn, dn])]),
+            name="snapshot_join_agg_counts")
+        self._persist_pack = jit_state(
+            self._persist_pack_impl, static_argnames=("wf", "wd"),
+            name="snapshot_join_agg_persist_pack")
         self._dirty = False
         # host upper bounds for growth triggers (no d2h on the hot path)
         self._applied_rows_upper = 0
         self._applied_dim_upper = 0
         self._persist_cursor = [0, 0]
+        # what the last counts fetch brought, for the actor's phase dict
+        self._phase_counts: dict = {}
 
     # ------------------------------------------------------------- state
     def _init_stores(self):
@@ -323,8 +337,13 @@ class SnapshotJoinAggExecutor(Executor):
         return cur, cur_valid, jnp.ones((), dtype=bool), out
 
     # ------------------------------------------------------- housekeeping
-    async def _check_watchdog(self):
-        errs = [int(x) for x in await off_loop(fetch_small, self._errs)]
+    async def _fetch_counts(self) -> tuple:
+        """The barrier's one wait for the device to reach the appends: the
+        error counters and the two row counts in one awaited fetch. Raises
+        what the watchdog raises; returns (fact rows, dim rows) and leaves
+        them for the phase dict."""
+        *errs, n, nd = (int(x) for x in await off_loop(
+            fetch_small, self._counts(self._errs, self._fn, self._dn)))
         if errs[0]:
             raise RuntimeError(
                 f"snapshot-join-agg fact store overflow ({errs[0]} rows "
@@ -337,12 +356,22 @@ class SnapshotJoinAggExecutor(Executor):
             raise RuntimeError(
                 "snapshot-join-agg saw retractions on an append-only "
                 "input — the planner must not fuse retracting inputs")
+        self._phase_counts = dict(snapshot_rows=n,
+                                  snapshot_capacity=self.capacity,
+                                  snapshot_dim_rows=nd)
+        return n, nd
 
-    def _maybe_grow(self):
-        """Double the fact store while the live count crowds capacity
-        (watchdog mode reads the true device count; the jitted programs
-        re-trace at the new static shape)."""
-        n = int(np.asarray(self._fn))
+    def take_phase_counts(self) -> dict:
+        """Rows the two stores hold and the fact store's capacity, as the
+        barrier's counts fetch read them (absent where the interval made
+        none), for the actor's phase dict."""
+        counts, self._phase_counts = self._phase_counts, {}
+        return counts
+
+    def _maybe_grow(self, n: int, nd: int) -> None:
+        """Double a store while its live count (from the counts fetch)
+        crowds its capacity; the jitted programs re-trace at the new
+        static shape."""
         grew = False
         while n > 0.7 * self.capacity:
             self.capacity *= 2
@@ -353,7 +382,6 @@ class SnapshotJoinAggExecutor(Executor):
                 [a, jnp.zeros(C - a.shape[0], dtype=a.dtype)])
             self._fcols = tuple(pad(c) for c in self._fcols)
             self._fvalids = tuple(pad(v) for v in self._fvalids)
-        nd = int(np.asarray(self._dn))
         grew_d = False
         while nd > 0.7 * self.dim_capacity:
             self.dim_capacity *= 2
@@ -365,34 +393,66 @@ class SnapshotJoinAggExecutor(Executor):
                  jnp.zeros(Cd - self._dkeys.shape[0], dtype=jnp.int64)])
 
     # ----------------------------------------------------------- persist
-    async def _persist(self, barrier: Barrier) -> None:
-        # the one wait for the device (it has reached the appends once the
-        # counts are here; the column slices below only travel)
-        counts = await off_loop(fetch_small, jnp.stack([self._fn, self._dn]))
+    def _persist_pack_impl(self, fcols, fvalids, dkeys, lo_f, lo_d, *,
+                           wf: int, wd: int):
+        """Rows [lo_f, lo_f + wf) of every fact column, their validity as
+        one bitmask a row, and keys [lo_d, lo_d + wd) of the dim store, as
+        the (int64, float64) pair `fetch_flat` takes; the host trims by
+        the true counts."""
+        win = lambda a, lo, w: jax.lax.dynamic_slice(a, (lo,), (w,))
+        vbits = jnp.zeros(wf, dtype=jnp.int64)
+        for k, v in enumerate(fvalids):
+            vbits |= win(v, lo_f, wf).astype(jnp.int64) << k
+        flat, _ = pack_for_fetch(
+            [win(c, lo_f, wf) for c in fcols]
+            + [vbits, win(dkeys, lo_d, wd)])
+        return flat
+
+    def _dispatch_persist(self, n: int, nd: int):
+        """Enqueue the pack of the rows the stores gained since the last
+        checkpoint (host-known windows, pow2-bucketed widths so that equal
+        intervals share one program). Returns what `_persist` needs, or None
+        where nothing is durable."""
+        if not self._durable:
+            return None
+        lo_f, lo_d = self._persist_cursor
+        wf = _bucket(n - lo_f, self.capacity)
+        wd = _bucket(nd - lo_d, self.dim_capacity)
+        # a window that would pass the store's end starts earlier: the
+        # host skips what the last checkpoint wrote
+        start_f = min(lo_f, self.capacity - wf)
+        start_d = min(lo_d, self.dim_capacity - wd)
+        flat = self._persist_pack(
+            self._fcols, self._fvalids, self._dkeys,
+            jnp.int32(start_f), jnp.int32(start_d), wf=wf, wd=wd)
+        return flat, (wf, lo_f - start_f, n), (wd, lo_d - start_d, nd)
+
+    async def _persist(self, barrier: Barrier, pack) -> None:
+        if pack is None:
+            return
+        flat, (wf, off_f, n), (wd, off_d, nd) = pack
+        metas = ([(wf, f.data_type.np_dtype) for f in self._fact_schema]
+                 + [(wf, np.dtype(np.int64)), (wd, np.dtype(np.int64))])
+        *fact, dkeys = unpack_fetched(
+            await off_loop(fetch_flat, flat), metas)
+        lo_f, lo_d = self._persist_cursor
+        # `fact` ends with the per-cell validity, a packed bitmask column
+        # (NULL cells must survive recovery — their data lanes are
+        # undefined)
+        rows = ([np.arange(lo_f, n, dtype=np.int64)]
+                + [c[off_f:off_f + n - lo_f] for c in fact],
+                [np.arange(lo_d, nd, dtype=np.int64),
+                 dkeys[off_d:off_d + nd - lo_d]])
         for s, st in enumerate(self.state_tables):
             if st is None:
                 continue
-            n = int(counts[s])
-            lo = self._persist_cursor[s]
-            if n > lo:
-                pos = np.arange(lo, n, dtype=np.int64)
-                if s == LEFT:
-                    # per-cell validity rides as a packed bitmask column
-                    # (NULL cells must survive recovery — their data
-                    # lanes are undefined)
-                    vbits = np.zeros(n - lo, dtype=np.int64)
-                    for k, v in enumerate(self._fvalids):
-                        vbits |= np.asarray(
-                            v[lo:n]).astype(np.int64) << k
-                    cols = [pos] + [np.asarray(c[lo:n])
-                                    for c in self._fcols] + [vbits]
-                else:
-                    cols = [pos, np.asarray(self._dkeys[lo:n])]
+            k = rows[s][0].shape[0]
+            if k:
                 st.write_chunk_columns(
-                    np.full(n - lo, OP_INSERT, dtype=np.int8), cols,
-                    np.ones(n - lo, dtype=bool))
-                self._persist_cursor[s] = n
+                    np.full(k, OP_INSERT, dtype=np.int8), rows[s],
+                    np.ones(k, dtype=bool))
             st.commit(barrier.epoch.curr)
+        self._persist_cursor = [n, nd]
 
     def recover(self) -> None:
         if all(st is None for st in self.state_tables):
@@ -466,10 +526,10 @@ class SnapshotJoinAggExecutor(Executor):
                     # watchdog's barrier d2h, pay one here instead of
                     # overflowing (and surface any pending errors —
                     # they must never be swallowed in this mode)
-                    await self._check_watchdog()
-                    self._maybe_grow()
-                    self._applied_rows_upper = int(np.asarray(self._fn))
-                    self._applied_dim_upper = int(np.asarray(self._dn))
+                    n, nd = await self._fetch_counts()
+                    self._maybe_grow(n, nd)
+                    self._applied_rows_upper = n
+                    self._applied_dim_upper = nd
                 self._dirty = True
             elif kind == "barrier":
                 barrier: Barrier = msg
@@ -485,18 +545,23 @@ class SnapshotJoinAggExecutor(Executor):
                     and barrier.is_stop_any()
                 if self._dirty:
                     self._dirty = False
-                    if self.watchdog_interval:
-                        await self._check_watchdog()
-                        self._maybe_grow()
+                    pack = None
+                    if self.watchdog_interval or self._durable:
+                        n, nd = await self._fetch_counts()
+                        self._maybe_grow(n, nd)
+                        # before the flush: the device reaches the small
+                        # pack first and the rows travel and are written
+                        # while it recomputes the snapshot
+                        pack = self._dispatch_persist(n, nd)
                     (self._prev, self._prev_valid, self._emitted,
                      out) = self._flush(
                         self._fcols, self._fvalids, self._fn,
                         self._dkeys, self._dn, self._prev,
                         self._prev_valid, self._emitted)
-                    await self._persist(barrier)
+                    await self._persist(barrier, pack)
                     yield out
                 elif stopping and self.watchdog_interval:
-                    await self._check_watchdog()
+                    await self._fetch_counts()
                     for st in self.state_tables:
                         if st is not None:
                             st.commit(barrier.epoch.curr)

@@ -309,7 +309,10 @@ class EpochTrace:
     # watchdog fetch "join_live_rows" / "join_capacity" (the fuller pool)
     # and, on one chip, "join_match_rows" (rows its applies emitted) and
     # "join_match_peak" / "join_match_width" (the most equi-key candidates
-    # one chunk found, of the side nearest its match buffer's width).
+    # one chunk found, of the side nearest its match buffer's width); one
+    # that holds a snapshot join-agg adds "snapshot_rows" /
+    # "snapshot_capacity" (its fact store) and "snapshot_dim_rows", from
+    # the counts fetch its barrier makes.
     # Counts, not nanoseconds: only the keys that end in "_ns" are times
     # (the module docstring lists them: apply / persist / align and their
     # parts input_wait / fence / dispatch / apply_wait / persist_wait).
@@ -426,6 +429,10 @@ class EpochTrace:
                              f"peak {ph['join_match_peak']} of "
                              f"{ph['join_match_width']} candidates")
                 line += "]"
+            if "snapshot_rows" in ph:
+                line += (f" [snapshot holds {ph['snapshot_rows']} of "
+                         f"{ph['snapshot_capacity']} rows, "
+                         f"{ph['snapshot_dim_rows']} dim keys]")
         return line
 
     def _dispatch_by_actor(self) -> dict:

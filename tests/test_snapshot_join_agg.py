@@ -37,13 +37,30 @@ def _executors(session, mv_name, klass):
     return out
 
 
+# a 200-part universe (TPC-H at SF 0.001) under a seed at which four parts
+# are Brand#23 (the tests here filter on the brand alone)
+SF, SEED = 0.001, 15
+
+
 async def _mk_sources(s):
     await s.execute(
         "CREATE SOURCE part WITH (connector='tpch', table='part', "
+        f"scale_factor={SF}, seed={SEED}, "
         "chunk_size=512, rate_limit=512, primary_key='p_partkey')")
     await s.execute(
         "CREATE SOURCE lineitem WITH (connector='tpch', "
-        "table='lineitem', chunk_size=512, rate_limit=1024)")
+        f"table='lineitem', scale_factor={SF}, seed={SEED}, "
+        "chunk_size=512, rate_limit=1024)")
+
+
+def _prefix(table, n):
+    """Rows [0, n) of the engine's own generator, column name -> array."""
+    from risingwave_tpu.connectors import TpchGenerator
+    g = TpchGenerator(table, chunk_size=max(256, n), scale_factor=SF,
+                      seed=SEED)
+    c = g.next_chunk()
+    return {f.name: np.asarray(col.data)[:n]
+            for f, col in zip(g.schema, c.columns)}
 
 
 async def test_q17_shape_lowers_to_fused_executor():
@@ -80,20 +97,16 @@ def _source_offsets(session, mv_name):
 
 
 def _q17ish_oracle(part_n, li_n):
-    from risingwave_tpu.connectors import TpchGenerator
     from risingwave_tpu.common.types import GLOBAL_DICT
 
-    def prefix(table, n_):
-        g = TpchGenerator(table, chunk_size=max(256, n_))
-        c = g.next_chunk()
-        return [np.asarray(col.data)[:n_] for col in c.columns]
-
-    p = prefix("part", part_n)
-    li = prefix("lineitem", li_n)
+    p = _prefix("part", part_n)
+    li = _prefix("lineitem", li_n)
     wb = GLOBAL_DICT.get_or_insert("Brand#23")
-    ok = {int(k) for k, b in zip(p[0], p[1]) if int(b) == wb}
+    ok = {int(k) for k, b in zip(p["p_partkey"], p["p_brand"])
+          if int(b) == wb}
     by = {}
-    for pk, q, ep in zip(li[1], li[2], li[3]):
+    for pk, q, ep in zip(li["l_partkey"], li["l_quantity"],
+                         li["l_extendedprice"]):
         by.setdefault(int(pk), []).append((int(q), int(ep)))
     total, n = 0, 0
     for pk, rows in by.items():
@@ -157,24 +170,17 @@ async def test_sub_where_group_existence():
     await s.tick(3)
     got = s.query("SELECT n FROM ge")[0][0]
     offs = _source_offsets(s, "ge")
-    from risingwave_tpu.connectors import TpchGenerator
-
-    def prefix(table, n_):
-        g = TpchGenerator(table, chunk_size=max(256, n_))
-        c = g.next_chunk()
-        return [np.asarray(col.data)[:n_] for col in c.columns]
-
-    p = prefix("part", offs["part"])
-    li = prefix("lineitem", offs["lineitem"])
-    parts_seen = {int(k) for k in p[0]}
+    p = _prefix("part", offs["part"])
+    li = _prefix("lineitem", offs["lineitem"])
+    parts_seen = {int(k) for k in p["p_partkey"]}
     has_high = {}
-    for pk, q in zip(li[1], li[2]):
+    for pk, q in zip(li["l_partkey"], li["l_quantity"]):
         if int(q) > 48:
             has_high[int(pk)] = has_high.get(int(pk), 0) + 1
-    exp = sum(1 for pk, q in zip(li[1], li[2])
+    exp = sum(1 for pk, q in zip(li["l_partkey"], li["l_quantity"])
               if int(pk) in parts_seen and int(pk) in has_high
               and int(q) < has_high[int(pk)] + 100)
-    n_total = sum(1 for pk in li[1] if int(pk) in parts_seen)
+    n_total = sum(1 for pk in li["l_partkey"] if int(pk) in parts_seen)
     assert 0 < exp < n_total, "oracle not discriminating"
     assert got == exp, f"group existence violated: got {got}, want {exp}"
     await s.drop_all()
@@ -200,23 +206,19 @@ async def test_fused_handles_sub_where_and_no_residue():
     assert len(got) == 1
     n, sq = got[0]
     # oracle on the committed prefix
-    from risingwave_tpu.connectors import TpchGenerator
     from risingwave_tpu.common.types import GLOBAL_DICT
     offs = _source_offsets(s, "g1")
-    def prefix(table, n_):
-        g = TpchGenerator(table, chunk_size=max(256, n_))
-        c = g.next_chunk()
-        return [np.asarray(col.data)[:n_] for col in c.columns]
-    p = prefix("part", offs["part"])
-    li = prefix("lineitem", offs["lineitem"])
+    p = _prefix("part", offs["part"])
+    li = _prefix("lineitem", offs["lineitem"])
     wb = GLOBAL_DICT.get_or_insert("Brand#23")
-    ok = {int(k) for k, b in zip(p[0], p[1]) if int(b) == wb}
+    ok = {int(k) for k, b in zip(p["p_partkey"], p["p_brand"])
+          if int(b) == wb}
     mq = {}
-    for pk, q in zip(li[1], li[2]):
+    for pk, q in zip(li["l_partkey"], li["l_quantity"]):
         if int(q) > 3:
             mq[int(pk)] = min(mq.get(int(pk), 10**9), int(q))
     exp_n = exp_sq = 0
-    for pk, q in zip(li[1], li[2]):
+    for pk, q in zip(li["l_partkey"], li["l_quantity"]):
         if int(pk) in ok and int(pk) in mq and int(q) <= mq[int(pk)]:
             exp_n += 1
             exp_sq += int(q)

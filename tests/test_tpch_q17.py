@@ -7,23 +7,19 @@ against a float aggregate.
 Reference: /root/reference/e2e_test/tpch/ (q17), ci q17.sql.
 """
 
-import numpy as np
-
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.state.storage_table import StorageTable
 from risingwave_tpu.stream.source import SourceExecutor
 
-Q17 = (
-    "CREATE MATERIALIZED VIEW q17 AS "
-    "SELECT sum(L.l_extendedprice) / 7.0 AS avg_yearly "
-    "FROM lineitem L "
-    "JOIN part P ON P.p_partkey = L.l_partkey "
-    "JOIN (SELECT l_partkey AS agg_partkey, "
-    "             0.2 * avg(l_quantity) AS avg_quantity "
-    "      FROM lineitem GROUP BY l_partkey) A "
-    "  ON A.agg_partkey = L.l_partkey "
-    " AND L.l_quantity < A.avg_quantity "
-    "WHERE P.p_brand = 'Brand#23' AND P.p_container = 'MED BOX'")
+from benchmark.queries import q17
+from benchmark.reference import tpch
+
+Q17 = q17.STATEMENT.format(brand="Brand#23", container="MED BOX")
+# TPC-H at SF 0.001 (200 parts, all of them in the first part chunk) under a
+# seed at which two parts are Brand#23 in a MED BOX and lineitems of theirs
+# count from the second tick on: the published filter is not vacuous
+SF, SEED = 0.001, 15
+GEN = f"scale_factor={SF}, seed={SEED}"
 
 
 def _committed_offsets(session, mv_name):
@@ -44,24 +40,19 @@ def _committed_offsets(session, mv_name):
     return out
 
 
-def _prefix(table, n):
-    from risingwave_tpu.connectors import TpchGenerator
-    gen = TpchGenerator(table, chunk_size=max(256, n))
-    c = gen.next_chunk()
-    return [np.asarray(col.data)[:n] for col in c.columns]
-
-
 def _oracle(part_n, li_n, container: bool = True):
-    from risingwave_tpu.common.types import GLOBAL_DICT
-    p = _prefix("part", part_n)
-    li = _prefix("lineitem", li_n)
-    want_brand = GLOBAL_DICT.get_or_insert("Brand#23")
-    want_cont = GLOBAL_DICT.get_or_insert("MED BOX")
-    parts_ok = {int(k) for k, b, c in zip(p[0], p[1], p[2])
-                if int(b) == want_brand
-                and (not container or int(c) == want_cont)}
+    """avg_yearly (cents / 7.0) over the benchmark's numpy rows, the
+    threshold in floats as the statement words it."""
+    p = tpch.part(0, part_n, seed=SEED)
+    li = tpch.lineitem(0, li_n, seed=SEED, scale_factor=SF)
+    if container:
+        r = q17.small_quantity_revenue(p, li, "Brand#23", "MED BOX")
+        return r["cents"] / 7.0
+    parts_ok = {int(k) for k, b in zip(p["p_partkey"], p["p_brand"])
+                if tpch.BRANDS[int(b)] == "Brand#23"}
     by_part: dict[int, list] = {}
-    for pk, q, ep in zip(li[1], li[2], li[3]):
+    for pk, q, ep in zip(li["l_partkey"], li["l_quantity"],
+                         li["l_extendedprice"]):
         by_part.setdefault(int(pk), []).append((int(q), int(ep)))
     total = 0
     for pk, rows in by_part.items():
@@ -76,10 +67,10 @@ async def test_q17_streaming_golden():
     s = Session()
     await s.execute("SET streaming_join_capacity = 32768")
     await s.execute(
-        "CREATE SOURCE part WITH (connector='tpch', table='part', "
+        f"CREATE SOURCE part WITH (connector='tpch', table='part', {GEN}, "
         "chunk_size=256, rate_limit=256, primary_key='p_partkey')")
     await s.execute(
-        "CREATE SOURCE lineitem WITH (connector='tpch', "
+        f"CREATE SOURCE lineitem WITH (connector='tpch', {GEN}, "
         "table='lineitem', chunk_size=512, rate_limit=1024)")
     await s.execute(Q17)
     await s.tick(5)
@@ -100,15 +91,14 @@ async def test_q17_survives_crash_recovery(tmp_path):
     store = HummockStateStore(LocalFsObjectStore(str(tmp_path / "d")))
     s = Session(store=store)
     await s.execute("SET streaming_join_capacity = 32768")
-    # brand-only filter: the full brand+container predicate passes ~1/400
-    # parts, so at unit-test volumes ZERO rows qualify and sum() is NULL
-    # (SQL semantics) — a vacuous recovery check. (The exact q17 text is
-    # covered by the golden test above.)
+    # brand-only filter: four of the 200 parts pass, where the published
+    # brand + container predicate passes two (the exact q17 text is covered
+    # by the golden test above and by tests/test_q17_published.py)
     await s.execute(
-        "CREATE SOURCE part WITH (connector='tpch', table='part', "
+        f"CREATE SOURCE part WITH (connector='tpch', table='part', {GEN}, "
         "chunk_size=512, rate_limit=512, primary_key='p_partkey')")
     await s.execute(
-        "CREATE SOURCE lineitem WITH (connector='tpch', "
+        f"CREATE SOURCE lineitem WITH (connector='tpch', {GEN}, "
         "table='lineitem', chunk_size=256, rate_limit=512)")
     await s.execute(Q17.replace(
         " AND P.p_container = 'MED BOX'", ""))

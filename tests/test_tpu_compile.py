@@ -359,6 +359,70 @@ def test_q4_join_and_float_avg_programs(q4_executors, one_chip,
             ln for ln in compiled.as_text().splitlines() if "f64" in ln)
 
 
+@pytest.fixture(scope="module")
+def q17_snapshot():
+    """TPC-H Q17 as `benchmark/queries/q17.py` states it, deployed (no data
+    is run): the fused snapshot join-agg executor."""
+    from benchmark.queries import q17
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.plan.build import _iter_executor_chain
+    from risingwave_tpu.stream.snapshot_join_agg import (
+        SnapshotJoinAggExecutor)
+    cfg = {"generator": {"scale_factor": 1, "brand": "Brand#23",
+                         "container": "MED BOX"},
+           "session_set": {"streaming_join_capacity": JOIN_CAP,
+                           "streaming_agg_capacity": 256}}
+
+    async def deploy():
+        s = Session()
+        for stmt in q17.ddl(cfg, {"chunk_size": {"lineitem": 30 * 64,
+                                                 "part": 64},
+                                  "chunks_per_interval": {"lineitem": 1,
+                                                          "part": 1}}, 7):
+            await s.execute(stmt)
+        snap, = [ex for roots in s.catalog.mvs["q17"].deployment.roots
+                 .values() for root in roots
+                 for ex in _iter_executor_chain(root)
+                 if isinstance(ex, SnapshotJoinAggExecutor)]
+        return snap
+
+    return asyncio.run(deploy())
+
+
+@pytest.mark.parametrize("program", ["append_fact", "flush", "persist_pack",
+                                     "gen_lineitem"])
+def test_q17_snapshot_join_agg_programs(q17_snapshot, one_chip,
+                                        no_persistent_cache, program):
+    """`q17.sat` on one chip: the lineitem store's append, the barrier's
+    snapshot recompute with the threshold as one INT64 a group (floor
+    division of int64 on the chip), the persist pack's dynamic-offset window,
+    and the spec-following generator with its seed a dynamic argument. At the
+    cell's 2^23 rows the flush compiled in 135 s by hand (PERF.md, PR 38)."""
+    from risingwave_tpu.connectors import tpch
+    snap = q17_snapshot
+    assert snap.capacity == JOIN_CAP and len(snap._fcols) == 3
+    A = lambda tree: abstract(tree, one_chip)  # noqa: E731
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt,  # noqa: E731
+                                             sharding=one_chip)
+    gen = tpch.TpchGenerator("lineitem", chunk_size=30 * 64)
+    compiled = {
+        "append_fact": lambda: snap._append_fact._jitted.lower(
+            A(snap._fcols), A(snap._fvalids), A(snap._fn), A(snap._errs),
+            abstract_chunk(snap.inputs[0].schema, 30 * 64, one_chip)),
+        "flush": lambda: snap._flush._jitted.lower(
+            A(snap._fcols), A(snap._fvalids), A(snap._fn), A(snap._dkeys),
+            A(snap._dn), A(snap._prev), A(snap._prev_valid),
+            A(snap._emitted)),
+        "persist_pack": lambda: snap._persist_pack._jitted.lower(
+            A(snap._fcols), A(snap._fvalids), A(snap._dkeys),
+            scalar(jnp.int32), scalar(jnp.int32), wf=2048, wd=64),
+        "gen_lineitem": lambda: tpch.gen_lineitem_columns.lower(
+            scalar(jnp.uint64), scalar(jnp.int64), A(gen._vocab_ids),
+            n=30 * 64, n_parts=200_000, n_suppliers=10_000),
+    }[program]().compile()
+    fits_one_chip(compiled)
+
+
 def test_float_column_diff_lanes_compile(one_chip, no_persistent_cache):
     """A sorted-join side holding an f64 and an f32 column: the diff
     compiles (its row gathers move the floats as they are; nothing
