@@ -8,7 +8,13 @@ Reference: the join-against-own-aggregate sub-plan of
 """
 
 import numpy as np
+import pytest
 
+from risingwave_tpu.common.chunk import (
+    OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, StreamChunk)
+from risingwave_tpu.common.types import DataType, schema
+from risingwave_tpu.expr.agg import AggCall, AggKind
+from risingwave_tpu.expr.ir import call, col
 from risingwave_tpu.frontend import Session
 from risingwave_tpu.stream.snapshot_join_agg import SnapshotJoinAggExecutor
 from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
@@ -226,3 +232,184 @@ async def test_fused_handles_sub_where_and_no_residue():
         f"got ({n}, {sq}) want ({exp_n}, {exp_sq})"
     assert exp_n > 0, "oracle vacuous"
     await s.drop_all()
+
+
+# --------------------------------------------------------------------------
+# the barrier program itself: membership and the final aggregate against a
+# numpy statement of the query, on hand-built stores
+
+class _Input:
+    def __init__(self, schema):
+        self.schema = schema
+
+    def fence_tokens(self):
+        return []
+
+
+def _flush_executor(agg=AggKind.SUM, x_type=DataType.INT64, capacity=64,
+                    dim_capacity=8):
+    """SELECT <agg>(x) FROM L JOIN P ON P.pk = L.k
+       JOIN (SELECT k, sum(q) AS s FROM L GROUP BY k) A
+         ON A.k = L.k AND L.q < A.s"""
+    final = AggCall(agg, 2, DataType.INT64 if agg is AggKind.COUNT
+                    else x_type, True)
+    return SnapshotJoinAggExecutor(
+        _Input(schema(("k", DataType.INT64), ("q", DataType.INT64),
+                      ("x", x_type))),
+        _Input(schema(("pk", DataType.INT64))),
+        fact_key=0, dim_key=0,
+        sub_agg_calls=[AggCall(AggKind.SUM, 1, DataType.INT64, True)],
+        sub_items=[col(0, DataType.INT64)],
+        residue=call("less_than", col(1, DataType.INT64),
+                     col(3, DataType.INT64)),
+        final_agg_calls=[final], final_items=[col(0, final.ret_type)],
+        out_names=["v"], out_types=[final.ret_type],
+        capacity=capacity, dim_capacity=dim_capacity)
+
+
+def _numpy_statement(facts, parts, agg):
+    """The query over every row so far: facts as (k | None, q, x) triples,
+    parts as keys. None where no row is aggregated (SQL NULL; 0 for a
+    count)."""
+    keyed = [r for r in facts if r[0] is not None]
+    k = np.asarray([r[0] for r in keyed], dtype=np.int64)
+    q = np.asarray([r[1] for r in keyed], dtype=np.int64)
+    x = np.asarray([r[2] for r in keyed])
+    s = np.asarray([q[k == key].sum() for key in k], dtype=np.int64)
+    sel = x[np.isin(k, np.asarray(parts, dtype=np.int64)) & (q < s)]
+    if agg is AggKind.COUNT:
+        return len(sel)
+    if not len(sel):
+        return None
+    return {AggKind.SUM: np.sum, AggKind.MIN: np.min,
+            AggKind.MAX: np.max}[agg](sel).item()
+
+
+# part 5: the quantities sum to 4, so its second line does not pass `<`
+_LINES = [(5, 0, 10), (5, 4, 20), (7, 2, 40), (7, 3, 80), (7, 9, 160),
+          (8, 1, 320), (8, 1, 640)]
+# case -> (executor options, [(lineitems, part keys) of each barrier])
+FLUSH_CASES = {
+    # a part no lineitem names, a lineitem no part names
+    "unnamed_part": ({}, [(_LINES, [5, 99])]),
+    # member flips between two flushes: the diff chunk is U- / U+
+    "part_one_barrier_later": ({}, [(_LINES, [5]), ([], [7]), ([], [])]),
+    # a NULL l_partkey whose data lane reads a live part's key (5): joins
+    # nothing though it passes `<`, and its quantity stays out of part 5's
+    "null_partkey": ({}, [(_LINES + [(None, 50, 1280), (None, 0, 2560)],
+                           [5, 7])]),
+    "empty_stores": ({}, [([], []), (_LINES, []), ([], [8])]),
+    "part_below_and_above": ({}, [(_LINES, [1, 7, 1000])]),
+    "part_store_full": ({}, [(_LINES, [1, 2, 3, 5, 6, 8, 9, 10])]),
+    # dead lanes of both stores read key 0: a live lineitem of part 0 is no
+    # member until part 0 is there
+    "dead_lanes_share_a_key": ({}, [(_LINES + [(0, 1, 1280), (0, 2, 2560)],
+                                     [7]), ([], [0])]),
+    # the planner promises a unique key; two marks over a run still read > 0
+    "duplicate_part_key": ({}, [(_LINES, [7, 7, 5])]),
+    "fact_store_full": (dict(capacity=8), [(_LINES + [(9, 1, 1)], [5, 9]),
+                                           ([], [7])]),
+    "count_final": (dict(agg=AggKind.COUNT), [(_LINES, [5]), ([], [8])]),
+    "min_final": (dict(agg=AggKind.MIN), [(_LINES, [7, 8]), ([], [5])]),
+    "max_final": (dict(agg=AggKind.MAX), [(_LINES, [5, 8]), ([], [7])]),
+    "float64_sum": (dict(x_type=DataType.FLOAT64),
+                    [([(k, q, x * 0.1 + 1e-9) for k, q, x in _LINES],
+                      [5, 7]), ([], [8])]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLUSH_CASES))
+def test_flush_against_a_numpy_statement(case):
+    opts, barriers = FLUSH_CASES[case]
+    ex = _flush_executor(**opts)
+    agg = ex.final_agg_calls[0].kind
+    fsch, dsch = (i.schema for i in ex.inputs)
+    facts, parts, shown = [], [], "nothing yet"
+    for new_facts, new_parts in barriers:
+        if new_facts:
+            key_there = np.asarray([r[0] is not None for r in new_facts])
+            cols = [[5 if r[0] is None else r[0] for r in new_facts],
+                    [r[1] for r in new_facts], [r[2] for r in new_facts]]
+            (ex._fcols, ex._fvalids, ex._fn, ex._errs) = ex._append_fact(
+                ex._fcols, ex._fvalids, ex._fn, ex._errs,
+                StreamChunk.from_numpy(fsch, cols, capacity=16,
+                                       valids=[key_there, None, None]))
+        if new_parts:
+            ex._dkeys, ex._dn, ex._errs = ex._append_dim(
+                ex._dkeys, ex._dn, ex._errs,
+                StreamChunk.from_numpy(dsch, [new_parts], capacity=16))
+        facts += new_facts
+        parts += new_parts
+        ex._prev, ex._prev_valid, ex._emitted, out = ex._flush(
+            ex._fcols, ex._fvalids, ex._fn, ex._dkeys, ex._dn,
+            ex._prev, ex._prev_valid, ex._emitted)
+        assert not np.asarray(ex._errs).any()
+        assert (int(ex._fn), int(ex._dn)) == (len(facts), len(parts))
+        want = _numpy_statement(facts, parts, agg)
+        if shown == "nothing yet":
+            expect = [(OP_INSERT, want)]
+        elif shown == want:
+            expect = []
+        else:
+            expect = [(OP_UPDATE_DELETE, shown), (OP_UPDATE_INSERT, want)]
+        got = [(op, v) for op, (v,) in out.to_rows()]
+        if isinstance(want, float):
+            assert [op for op, _ in got] == [op for op, _ in expect]
+            np.testing.assert_allclose([v for _, v in got],
+                                       [v for _, v in expect], rtol=1e-12)
+        else:
+            assert got == expect, (len(facts), len(parts))
+        shown = want
+    if case == "part_store_full":
+        assert len(parts) == ex.dim_capacity
+    if case == "fact_store_full":
+        assert len(facts) == ex.capacity
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (loop bodies,
+    pjit, cond branches) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_no_capacity_wide_pass_asks_a_tiny_target():
+    """Three ops the barrier program must not grow back (2.63 + 0.86 s of a
+    5.49 s checkpoint at 2^23, PERF.md PR 39): a search loop that carries
+    one query per fact row, a gather of as many indices out of the dim
+    store, a scatter-add of every row onto one address."""
+    import jax
+    C, Cd = 4096, 16
+    ex = _flush_executor(capacity=C, dim_capacity=Cd)
+    jaxpr = jax.make_jaxpr(ex._flush_impl)(
+        ex._fcols, ex._fvalids, ex._fn, ex._dkeys, ex._dn, ex._prev,
+        ex._prev_valid, ex._emitted).jaxpr
+    rows = lambda v: v.aval.shape[:1]  # noqa: E731
+    loops = 0
+    for e in _eqns(jaxpr):
+        name = e.primitive.name
+        if name == "scan":
+            n = e.params["num_consts"]
+            carried = e.invars[n:n + e.params["num_carry"]]
+        elif name == "while":
+            carried = e.invars[e.params["cond_nconsts"]
+                               + e.params["body_nconsts"]:]
+        else:
+            carried = ()
+        loops += bool(carried)
+        assert all(rows(v) != (C,) for v in carried), \
+            f"a loop carries {C} queries: {e}"
+        if name == "gather":
+            operand, indices = e.invars[:2]
+            assert not (rows(operand) == (Cd,) and rows(indices) == (C,)), \
+                f"{C} indices gathered from the {Cd}-key dim store: {e}"
+        if name.startswith("scatter"):
+            assert e.invars[0].aval.size > 1, \
+                f"a scatter onto one address: {e}"
+    # the two searches of the dim keys in the sorted fact keys are there
+    assert loops == 2

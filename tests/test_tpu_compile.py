@@ -397,7 +397,8 @@ def test_q17_snapshot_join_agg_programs(q17_snapshot, one_chip,
     snapshot recompute with the threshold as one INT64 a group (floor
     division of int64 on the chip), the persist pack's dynamic-offset window,
     and the spec-following generator with its seed a dynamic argument. At the
-    cell's 2^23 rows the flush compiled in 135 s by hand (PERF.md, PR 38)."""
+    cell's 2^23 rows the flush compiled in 158 s by hand (the parent's beside
+    it in 146 s; PERF.md, PR 39)."""
     from risingwave_tpu.connectors import tpch
     snap = q17_snapshot
     assert snap.capacity == JOIN_CAP and len(snap._fcols) == 3
