@@ -15,7 +15,11 @@ the modulus, what this connector always did, and what every older cell,
 test and oracle was written against). The spec's skew — 50% / 75%, NEXMark's
 hotAuctionRatio 2 and hotBidderRatio 4 — is the source options
 `hot_auction_ratio=2, hot_bidder_ratio=4`; the bucket width stays 100
-either way.
+either way. An auction goes to a hot seller with probability 3/4 (NEXMark's
+hotSellersRatio 4) — the first person of the current bucket of
+`hot_seller_bucket` persons: DEFAULT 4 (the modulus doubling as the width,
+what this connector always did); the public generator's bucket is 100, the
+source option `hot_seller_bucket=100`.
 
 Randomness is a counter-based splitmix64 of the event id: deterministic,
 seekable (exactly-once source recovery = remember the next event index,
@@ -150,6 +154,9 @@ class NexmarkConfig:
     # a bid is cold with probability 1/ratio (the spec's: 2 and 4)
     hot_auction_ratio: int = HOT_AUCTION_RATIO
     hot_bidder_ratio: int = HOT_BIDDER_RATIO
+    # persons per hot seller (the spec's: 100); the probability modulus
+    # stays HOT_SELLER_RATIO
+    hot_seller_bucket: int = HOT_SELLER_RATIO
 
 
 def _ids_so_far(global_id):
@@ -236,7 +243,8 @@ def gen_auction_columns(start_index: jnp.ndarray, n: int, cfg: NexmarkConfig,
     date_time = _event_time(global_id, cfg)
     expires = date_time + (_rand(global_id, 25, 100) + 1) * 1_000_000
     hot = _rand(global_id, 26, HOT_SELLER_RATIO) > 0
-    hot_seller = ((n_persons - 1) // HOT_SELLER_RATIO) * HOT_SELLER_RATIO
+    hot_seller = ((n_persons - 1) // cfg.hot_seller_bucket
+                  ) * cfg.hot_seller_bucket
     cold_seller = n_persons - 1 - _rand(global_id, 27, cfg.num_active_people)
     seller = FIRST_PERSON_ID + jnp.where(hot, hot_seller, jnp.maximum(cold_seller, 0))
     category = FIRST_CATEGORY_ID + _rand(global_id, 28, 5)

@@ -1316,10 +1316,11 @@ class Session:
             args["splits"] = int(opts.pop("splits"))
         cfg = {}
         for k in ("inter_event_us", "base_time_us", "hot_auction_ratio",
-                  "hot_bidder_ratio"):
+                  "hot_bidder_ratio", "hot_seller_bucket"):
             if k in opts:
                 cfg[k] = int(opts.pop(k))
-        for k in ("hot_auction_ratio", "hot_bidder_ratio"):
+        for k in ("hot_auction_ratio", "hot_bidder_ratio",
+                  "hot_seller_bucket"):
             if cfg.get(k, 1) < 1:
                 raise BindError(f"{k} must be at least 1")
         if cfg:

@@ -77,6 +77,9 @@ class StateTable:
         self._mem: list[Union[dict[bytes, Optional[tuple]],
                               ColumnarSegment]] = []
         self.epoch: Optional[int] = None
+        # rows `write_chunk_rows` has staged, ever: the row form's share of
+        # this table's writes (a columnar segment counts none)
+        self.row_path_rows = 0
         self._all_i64 = all(
             np.dtype(f.data_type.np_dtype).kind in "i" and
             np.dtype(f.data_type.np_dtype).itemsize == 8 for f in schema)
@@ -170,6 +173,7 @@ class StateTable:
         from ..common.chunk import OP_INSERT, OP_UPDATE_INSERT
         if not rows:
             return
+        self.row_path_rows += len(rows)
         vnodes = self._vnodes_of_batch([r for _, r in rows])
         seg = self._row_segment()
         for (op, row), vn in zip(rows, vnodes):

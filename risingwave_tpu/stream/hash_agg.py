@@ -24,6 +24,7 @@ where the buffer can no longer know the extremum.
 
 from __future__ import annotations
 
+import asyncio
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional, Sequence
@@ -52,9 +53,11 @@ from ..ops.hash_table import (
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
 from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
+from ..utils.trace import span
 from ..utils.metrics import (
-    GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EXTREMA_ERRORS,
-    HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_PROBE_FALLBACK_ROWS,
+    GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EVICT_GROUPS,
+    HASH_AGG_EXTREMA_ERRORS, HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_AGG_PURGES,
+    HASH_PROBE_FALLBACK_ROWS,
 )
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -67,6 +70,23 @@ EXTREMA_KINDS = ("underflow", "dropped_delete", "negative_residue")
 
 # the narrowest barrier flush: this many dirty slots (twice the rows)
 FLUSH_MIN_SLOTS = 128
+
+# A table that is mostly zombies (groups the watermark cleaned, or that
+# emptied) is purged at its own capacity once occupancy PLUS the most slots
+# one interval has claimed would pass this share of it. Lower than
+# `needs_rebuild`'s 0.7, which is a mark for keys that arrive a few at a
+# time: `lookup_or_insert` places a whole chunk's new keys by the bucket
+# fills it found BEFORE the chunk, so a chunk of fresh keys overflows a
+# bucket by the tail of its arrivals in one bucket, long before the mean
+# fill is near 16 (32,768 fresh keys into 2^19 slots: first unplaced rows
+# between 0.62 and 0.69 full, measured; memory_maintain's `_mem_cap_for`
+# keeps to the same 0.35 for the same reason). A purge costs the same
+# whenever it comes, an overflow costs the epoch.
+ZOMBIE_PURGE_MARK = 0.35
+
+# rows one call of the recovery's replay program takes (never more than
+# the table has slots)
+RECOVER_BATCH = 1 << 14
 
 
 @jax.tree_util.register_pytree_node_class
@@ -197,6 +217,11 @@ class HashAggExecutor(Executor):
         self.watchdog_interval = watchdog_interval
         self.rebuilds = 0
         self._occ_known = 0
+        # the most slots one barrier interval has claimed, and the groups
+        # alive after this barrier's evict (None: no watchdog fetch at it);
+        # both from the watchdog fetch, for `_maybe_rebuild_at_barrier`
+        self._claim_peak = 0
+        self._live_known: Optional[int] = None
         self._applied_since_flush = False
         # ---- HBM memory manager hooks (memory/manager.py) ----
         # LRU hotness is an int64 epoch stamp PER SLOT, advanced at each
@@ -229,6 +254,9 @@ class HashAggExecutor(Executor):
                                      donate_argnums=(0,),
                                      name="hash_agg_mem_rehash")
         self._mem_reloads: dict[int, object] = {}
+        self._recover_rows = jit_state(self._recover_impl,
+                                       donate_argnums=(0, 1),
+                                       name="hash_agg_recover")
         # device-accumulated watchdog counters, int32 [2]: rows the table
         # could not place or fold plus the retractable calls' errors
         # (fail-stop), rows whose probe went past the fingerprint lane and
@@ -259,12 +287,15 @@ class HashAggExecutor(Executor):
         self._dirty_slots_known: Optional[int] = None
         self._flush_slots = FLUSH_MIN_SLOTS
 
-    def _watchdog_pack_impl(self, state: AggState, ov, occ):
+    def _watchdog_pack_impl(self, state: AggState, ov, occ, wm):
         """The barrier's one fetch: [overflow, occupied, probe fallback,
         rows the flush that follows will emit, lossy groups, dirty slots,
-        then the extrema errors by kind where a call is retractable]. The
-        emit count repeats `_flush_impl`'s visibility per slot, without its
-        compaction: both read the same state, nothing runs between."""
+        live groups, live groups the pending cleaning watermark `wm` is
+        about to evict (`_evict_keys_impl`'s mask; 0 where the executor
+        cleans nothing), then the extrema errors by kind where a call is
+        retractable]. The emit count repeats `_flush_impl`'s visibility per
+        slot, without its compaction: both read the same state, nothing
+        runs between."""
         exists = state.row_count > 0
         unchanged = state.prev_exists & exists
         n_lossy = jnp.int32(0)
@@ -277,14 +308,23 @@ class HashAggExecutor(Executor):
         n_emit = (jnp.sum((moved & state.prev_exists).astype(jnp.int32))
                   + jnp.sum((moved & exists).astype(jnp.int32)))
         n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
+        n_live = jnp.sum(exists.astype(jnp.int32))
+        n_evict = jnp.int32(0)
+        if self.cleaning_watermark_key is not None:
+            below = state.table.keys[self.cleaning_watermark_key] < wm
+            n_evict = jnp.sum((exists & state.table.occupied
+                               & below).astype(jnp.int32))
         return jnp.concatenate([
-            jnp.stack([ov[0], occ, ov[1], n_emit, n_lossy, n_dirty]),
+            jnp.stack([ov[0], occ, ov[1], n_emit, n_lossy, n_dirty,
+                       n_live, n_evict]),
             ov[2:]])
 
     def take_phase_counts(self) -> dict:
-        """This barrier interval's `agg_emit_rows` (and, with a retractable
-        MIN/MAX, `agg_extrema_lossy_groups`) for the actor's phase dict:
-        host numbers the watchdog fetch brought; empty where it made none."""
+        """This barrier interval's `agg_emit_rows` and `agg_evict_groups`
+        (and, with a retractable MIN/MAX, `agg_extrema_lossy_groups`) for
+        the actor's phase dict: host numbers the watchdog fetch brought,
+        absent where it made none; `agg_purges` where the barrier purged
+        the table's zombies."""
         counts, self._phase_counts = self._phase_counts, {}
         return counts
 
@@ -448,9 +488,10 @@ class HashAggExecutor(Executor):
         return state2, tuple(out_cols), out_ops, out_vis
 
     def _live_zombie_impl(self, state: AggState):
+        """int32 [2]: occupied slots, live groups — one small fetch."""
         occ = jnp.sum(state.table.occupied.astype(jnp.int32))
         live = jnp.sum((state.row_count > 0).astype(jnp.int32))
-        return occ, live
+        return jnp.stack([occ, live])
 
     def _evict_keys_impl(self, state: AggState, watermark):
         """Compacted group keys of live groups below the cleaning watermark —
@@ -534,17 +575,52 @@ class HashAggExecutor(Executor):
         )
 
     # --------------------------------------------------------- rebuild
-    def _rebuild(self, new_capacity: int) -> int:
-        """Purge zombies / grow via the device-side rehash.
-        Returns the rebuilt occupancy (one readback — rebuilds are rare)."""
+    def _rehash_to(self, new_capacity: int) -> None:
+        """Dispatch the device-side rehash: zombies dropped, the survivors
+        re-inserted into a fresh table of `new_capacity` slots."""
         self.state = self._rehash(self.state, new_capacity)
         self.capacity = new_capacity
         self.rebuilds += 1
         # slot geometry changed: restamp lazily (everything hot, and one
         # interval later the LRU discriminates again)
         self._slot_epoch = None
-        occ, _ = self._live_zombie(self.state)
-        return int(occ)
+
+    def _rebuild(self, new_capacity: int) -> int:
+        """Purge zombies / grow, and read the rebuilt occupancy back ON the
+        calling thread: the memory manager's reload path (by design on the
+        loop). The barrier's own rebuild awaits that readback off the loop
+        (`_maybe_rebuild_at_barrier`)."""
+        self._rehash_to(new_capacity)
+        return int(fetch_small(self._live_zombie(self.state))[0])
+
+    def _precompile_purge(self):
+        """Compile (lower + compile, nothing runs) the two programs of a
+        same-capacity purge for the table as it stands, where zombies are
+        this executor's steady state: it cleans by watermark and watches
+        its occupancy. The first purge comes many barriers after warm-up;
+        compiled there it is a compile inside somebody's timed window.
+        After a growth the programs of the new capacity compile when first
+        needed, as the growth itself does.
+
+        On a worker thread, started at the INITIAL barrier and awaited at
+        the end of the first barrier after it (`execute`), not on the way:
+        no purge is due before an interval has run, and a recovery does
+        not hold its replay for the two largest executables of the
+        operator, which it will not call for many checkpoints. Only this
+        pair: executables loaded side by side on the v5e's host each took
+        three to four times as long (PERF.md section 6, PR 40), so the
+        replay and the first apply stay where they are asked for, on the
+        loop. Returns the task, or None where there is nothing to
+        compile."""
+        if self.cleaning_watermark_key is None or not self.watchdog_interval:
+            return None
+        state, capacity = self.state, self.capacity
+
+        def compile_both():
+            self._live_zombie.precompile(state)
+            self._rehash.precompile(state, capacity)
+
+        return asyncio.ensure_future(asyncio.to_thread(compile_both))
 
     async def _check_watchdog(self) -> None:
         """ONE small fetch of the device-accumulated counters
@@ -560,12 +636,14 @@ class HashAggExecutor(Executor):
         replays from the last committed epoch (SURVEY.md §3.5). Capacity
         provisioning + barrier-time growth make this a last-resort
         watchdog."""
+        wm = self._pending_clean_wm
         vals = await off_loop(fetch_small, self._watchdog_pack(
-            self.state, self._overflow_dev, self._occ_dev))
+            self.state, self._overflow_dev, self._occ_dev,
+            jnp.int64(np.iinfo(np.int64).min if wm is None else wm)))
         self._note_probe_fallback(int(vals[2]))
-        self._note_flush_counts(int(vals[3]), int(vals[4]))
+        self._note_flush_counts(int(vals[3]), int(vals[4]), int(vals[7]))
         self._dirty_slots_known = int(vals[5])
-        ext = self._note_extrema_errors([int(v) for v in vals[6:]])
+        ext = self._note_extrema_errors([int(v) for v in vals[8:]])
         n_un = int(vals[0])
         if ext:
             raise RuntimeError(
@@ -578,7 +656,12 @@ class HashAggExecutor(Executor):
                 f"hash-agg table overflow mid-epoch ({n_un} rows, "
                 f"capacity {self.capacity}); recovery must replay the "
                 f"epoch with a larger table")
-        self._occ_known = int(vals[1])
+        occ = int(vals[1])
+        # the slots this interval claimed: what the next one may ask for
+        self._claim_peak = max(self._claim_peak, occ - self._occ_known)
+        self._occ_known = occ
+        # the groups alive once this barrier's evict has run
+        self._live_known = int(vals[6]) - int(vals[7])
 
     def _note_probe_fallback(self, total: int) -> None:
         """Publish the device's running fallback-row count as the increase
@@ -586,14 +669,19 @@ class HashAggExecutor(Executor):
         HASH_PROBE_FALLBACK_ROWS.inc(total - self._probe_fallback_seen)
         self._probe_fallback_seen = total
 
-    def _note_flush_counts(self, n_emit: int, n_lossy: int) -> None:
-        """What the flush after this watchdog fetch emits, and how many
-        groups of a retractable MIN/MAX are lossy: into the registry, and
-        kept for the epoch trace."""
+    def _note_flush_counts(self, n_emit: int, n_lossy: int,
+                           n_evict: int) -> None:
+        """What the flush after this watchdog fetch emits, how many live
+        groups the barrier's evict cleans, and how many groups of a
+        retractable MIN/MAX are lossy: into the registry, and kept for the
+        epoch trace."""
         label = self.mem_name or self.identity
         GLOBAL_METRICS.counter(HASH_AGG_EMIT_ROWS, executor=label).inc(
             n_emit)
-        self._phase_counts = {"agg_emit_rows": n_emit}
+        GLOBAL_METRICS.counter(HASH_AGG_EVICT_GROUPS, executor=label).inc(
+            n_evict)
+        self._phase_counts = {"agg_emit_rows": n_emit,
+                              "agg_evict_groups": n_evict}
         if any(self._retractable):
             GLOBAL_METRICS.gauge(HASH_AGG_EXTREMA_LOSSY_GROUPS,
                                  executor=label).set(float(n_lossy))
@@ -611,18 +699,53 @@ class HashAggExecutor(Executor):
             self._extrema_errs_seen[i] = total
         return {k: n for k, n in zip(EXTREMA_KINDS, totals) if n}
 
-    def _maybe_rebuild_at_barrier(self) -> None:
-        """Barrier-time growth: the table is examined between epochs, when
-        occupancy knowledge from the barrier watchdog fetch is safe to act
-        on. Crossing the high watermark purges zombies (dead windows/
-        groups) or doubles capacity; both re-jit the apply step, which is
-        why it never happens mid-epoch."""
-        if self._occ_known <= 0.7 * self.capacity:
+    async def _maybe_rebuild_at_barrier(self) -> None:
+        """Barrier-time purge and growth: the table is examined between
+        epochs, on what the barrier's watchdog fetch brought. Past
+        `needs_rebuild`'s 0.7 it is rebuilt — at twice the capacity where
+        the LIVE set crowds it (a re-jit of the apply step, which is why
+        this never happens mid-epoch), else at its own. Before that, a
+        table that one more interval like the fullest so far would take
+        past ZOMBIE_PURGE_MARK is purged at its own capacity if that frees
+        at least half of what is occupied: groups that die as fast as they
+        come (a DISTINCT per window under a watermark) never reach 0.7, and
+        no chunk of fresh keys meets a crowded table. The purge is the span
+        `agg.purge`: the rehash's dispatch and the awaited readback of the
+        rebuilt occupancy (the wait is for the device to finish the
+        rehash; the loop is not held)."""
+        occ, cap = self._occ_known, self.capacity
+        grow_mark = occ > 0.7 * cap
+        if not grow_mark and occ + self._claim_peak <= ZOMBIE_PURGE_MARK * cap:
             return
-        occ, live = self._live_zombie(self.state)
-        rebuild, cap = needs_rebuild(int(occ), int(live), self.capacity)
-        if rebuild:
-            self._occ_known = self._rebuild(cap)
+
+        def plan(occ: int, live: int) -> Optional[int]:
+            """The capacity to rebuild at, or None."""
+            if grow_mark:
+                rebuild, new_cap = needs_rebuild(occ, live, cap)
+                return new_cap if rebuild else None
+            return cap if occ - live >= live else None
+
+        live = self._live_known
+        if live is not None and plan(occ, live) is None:
+            return
+        with span("agg.purge"):
+            if live is None:
+                # no watchdog fetch at this barrier (nothing applied since
+                # the last flush, yet the evict made zombies)
+                occ, live = (int(v) for v in await off_loop(
+                    fetch_small, self._live_zombie(self.state)))
+            new_cap = plan(occ, live)
+            if new_cap is None:
+                return
+            self._rehash_to(new_cap)
+            self._occ_known = int((await off_loop(
+                fetch_small, self._live_zombie(self.state)))[0])
+        if new_cap == cap:
+            GLOBAL_METRICS.counter(
+                HASH_AGG_PURGES,
+                executor=self.mem_name or self.identity).inc()
+            self._phase_counts["agg_purges"] = (
+                self._phase_counts.get("agg_purges", 0) + 1)
 
     # ------------------------------------------------- HBM memory manager
     def state_bytes(self) -> int:
@@ -842,8 +965,25 @@ class HashAggExecutor(Executor):
                 cap *= 2
             self._occ_known = self._rebuild(cap)
         B = 1 << max(0, (n - 1).bit_length())
-        pad = rows + [rows[0]] * (B - n)
-        active = jnp.asarray(np.arange(B) < n)
+        reload = self._mem_reloads.get(B)
+        if reload is None:
+            reload = jit_state(self._mem_reload_impl, donate_argnums=(0, 1),
+                               name=f"hash_agg_mem_reload{B}")
+            self._mem_reloads[B] = reload
+        self.state, self._overflow_dev = reload(
+            self.state, self._overflow_dev, *self._durable_rows_to_cols(
+                rows, B))
+        self._applied_since_flush = True
+        self._occ_known += n
+
+    def _durable_rows_to_cols(self, rows: list, width: int) -> tuple:
+        """Durable-layout rows (`_durable_cols_at`: keys ++ raw agg states
+        ++ row_count) as device columns `width` long, the tail a repeat of
+        the first row under a false `active`: (key_cols, call_cols,
+        row_count, active), what `_scatter_rows` takes."""
+        n = len(rows)
+        pad = rows + [rows[0]] * (width - n)
+        active = jnp.asarray(np.arange(width) < n)
         nk = len(self.group_key_indices)
         key_cols = tuple(
             jnp.asarray(np.asarray([r[j] for r in pad],
@@ -871,19 +1011,14 @@ class HashAggExecutor(Executor):
                 off += 1
         row_count = jnp.asarray(np.asarray([r[off] for r in pad],
                                            dtype=np.int64))
-        reload = self._mem_reloads.get(B)
-        if reload is None:
-            reload = jit_state(self._mem_reload_impl, donate_argnums=(0, 1),
-                               name=f"hash_agg_mem_reload{B}")
-            self._mem_reloads[B] = reload
-        self.state, self._overflow_dev = reload(
-            self.state, self._overflow_dev, key_cols, tuple(call_cols),
-            row_count, active)
-        self._applied_since_flush = True
-        self._occ_known += n
+        return key_cols, tuple(call_cols), row_count, active
 
-    def _mem_reload_impl(self, state: AggState, overflow, key_cols,
-                         call_cols, row_count, active):
+    def _scatter_rows(self, state: AggState, key_cols, call_cols, row_count,
+                      active, dirty: bool):
+        """Insert the active durable-layout rows into `state`, each group
+        as already emitted (`prev_exists`, `prev_emit` of its state), marked
+        `dirty` or not: (state', rows no slot was found for, probe
+        fallbacks)."""
         table, slots, n_un, n_fb = lookup_or_insert_counted(
             state.table, key_cols, active)
         C = table.capacity
@@ -904,18 +1039,31 @@ class HashAggExecutor(Executor):
                     cs.astype(state.agg_states[j].dtype), mode="drop"))
             prev_emit.append(state.prev_emit[j].at[tgt].set(
                 self._call_emit(j, cs), mode="drop"))
-        # dirty=True: re-persists the rows (idempotent upsert), keeps the
-        # LRU stamp hot, and the flush's no-change skip still emits no
-        # changelog because prev_emit matches
         return AggState(
             table=table,
             agg_states=tuple(agg_states),
             row_count=state.row_count.at[tgt].set(row_count, mode="drop"),
-            dirty=state.dirty.at[tgt].set(True, mode="drop"),
+            dirty=(state.dirty.at[tgt].set(True, mode="drop") if dirty
+                   else state.dirty),
             prev_exists=state.prev_exists.at[tgt].set(True, mode="drop"),
             prev_emit=tuple(prev_emit),
-        ), overflow.at[:2].add(
+        ), n_un, n_fb
+
+    def _mem_reload_impl(self, state: AggState, overflow, key_cols,
+                         call_cols, row_count, active):
+        # dirty=True: re-persists the rows (idempotent upsert), keeps the
+        # LRU stamp hot, and the flush's no-change skip still emits no
+        # changelog because prev_emit matches
+        state, n_un, n_fb = self._scatter_rows(
+            state, key_cols, call_cols, row_count, active, dirty=True)
+        return state, overflow.at[:2].add(
             jnp.stack([n_un, n_fb]).astype(overflow.dtype))
+
+    def _recover_impl(self, state: AggState, unplaced, key_cols, call_cols,
+                      row_count, active):
+        state, n_un, _ = self._scatter_rows(
+            state, key_cols, call_cols, row_count, active, dirty=False)
+        return state, unplaced + n_un
 
     def _clean_spilled(self, wm) -> None:
         """Watermark state cleaning of EVICTED ranges: spilled keys below
@@ -1093,56 +1241,24 @@ class HashAggExecutor(Executor):
     def _state_from_rows(self, rows: list, capacity: int) -> AggState:
         """One LOCAL AggState of `capacity` holding exactly `rows` (the
         durable-row layout of _flush_persist_view). The sharded subclass
-        calls this per shard and concatenates along the mesh axis."""
+        calls this per shard and concatenates along the mesh axis.
+
+        Replayed in batches of ONE width through one jitted program
+        (`hash_agg_recover`), the last short batch too: the rows a crash
+        leaves differ from run to run, and a replay shaped by their count
+        was a chain of eager programs compiled anew inside every timed
+        recovery (as the sorted join's replay, PR 30)."""
+        state = self._empty_state(capacity)
         if not rows:
-            return self._empty_state(capacity)
-        nk = len(self.group_key_indices)
-        key_cols = [
-            jnp.asarray(np.asarray([r[j] for r in rows],
-                                   dtype=np.dtype(self._key_dtypes[j])))
-            for j in range(nk)]
-        active = jnp.ones(len(rows), dtype=bool)
-        table, slots, n_un = lookup_or_insert(
-            HashTable.empty(capacity, self._key_dtypes), key_cols, active)
-        assert int(n_un) == 0
-        st = self._empty_state(capacity)
-        agg_states = []
-        off = nk
-        for j, spec in enumerate(self.specs):
-            if self._retractable[j]:
-                K = self.minput_k
-                e_vals, e_cnts, e_lossy = st.agg_states[j]
-                vals = np.asarray([[r[off + k] for k in range(K)]
-                                   for r in rows])
-                cnts = np.asarray([[r[off + K + k] for k in range(K)]
-                                   for r in rows], dtype=np.int32)
-                lossy = np.asarray([bool(r[off + 2 * K]) for r in rows])
-                agg_states.append((
-                    e_vals.at[slots].set(
-                        jnp.asarray(vals, dtype=spec.state_dtype)),
-                    e_cnts.at[slots].set(jnp.asarray(cnts)),
-                    e_lossy.at[slots].set(jnp.asarray(lossy)),
-                ))
-                off += 2 * K + 1
-            else:
-                vals = jnp.asarray(np.asarray([r[off] for r in rows]))
-                agg_states.append(st.agg_states[j].at[slots].set(
-                    vals.astype(st.agg_states[j].dtype)))
-                off += 1
-        counts = jnp.asarray(np.asarray([r[off] for r in rows],
-                                        dtype=np.int64))
-        emits = tuple(
-            st.prev_emit[j].at[slots].set(
-                self._call_emit(j, agg_states[j])[slots])
-            for j in range(len(self.specs)))
-        return AggState(
-            table=table,
-            agg_states=tuple(agg_states),
-            row_count=st.row_count.at[slots].set(counts),
-            dirty=jnp.zeros(capacity, dtype=bool),
-            prev_exists=st.prev_exists.at[slots].set(True),
-            prev_emit=emits,
-        )
+            return state
+        batch = min(RECOVER_BATCH, capacity)
+        unplaced = jnp.zeros((), dtype=jnp.int32)
+        for i in range(0, len(rows), batch):
+            state, unplaced = self._recover_rows(
+                state, unplaced,
+                *self._durable_rows_to_cols(rows[i:i + batch], batch))
+        assert int(unplaced) == 0, "recovered rows overflowed the table"
+        return state
 
     # ---------------------------------------------------- multi-chunk apply
     def _apply_chunk_now(self, chunk: StreamChunk) -> None:
@@ -1220,6 +1336,7 @@ class HashAggExecutor(Executor):
     # ----------------------------------------------------------- stream
     async def execute(self):
         first = True
+        purge_ahead = None      # the task of `_precompile_purge`
         async for msg in self.input.execute():
             if isinstance(msg, StreamChunk):
                 self._enqueue_chunk(msg)
@@ -1230,9 +1347,11 @@ class HashAggExecutor(Executor):
                     if self.state_table is not None:
                         self.state_table.init_epoch(msg.epoch.curr)
                         self.recover(msg.epoch.curr)
+                    purge_ahead = self._precompile_purge()
                     yield msg
                     continue
                 stopping = msg.mutation is not None and msg.is_stop_any()
+                self._live_known = None
                 # watchdog_interval=None => NO fetch ever (not even at
                 # stop): a blocking d2h fetch serialises with dispatch.
                 # Correctness in that mode rests on CPU-backend tests
@@ -1270,12 +1389,20 @@ class HashAggExecutor(Executor):
                 # last of the barrier's dispatches, so the device has the
                 # flush and the evict to run while the counts travel
                 await self._persist(msg, views)
-                if flushed:
-                    self._maybe_rebuild_at_barrier()
                 # held watermarks follow the interval's flushed updates
                 for held in self._held_wms.values():
                     yield held
                 self._held_wms.clear()
+                # in the poll that yields the barrier: the consumers have
+                # their chunk and their watermarks to work on meanwhile
+                if purge_ahead is not None:
+                    # whatever is left of it is set-up, not a later
+                    # interval's; the barrier's own programs are on the
+                    # device meanwhile
+                    await purge_ahead
+                    purge_ahead = None
+                if flushed:
+                    await self._maybe_rebuild_at_barrier()
                 yield msg
             else:
                 # watermarks on group-key columns pass through re-indexed,

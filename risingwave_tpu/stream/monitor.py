@@ -236,7 +236,7 @@ class ActorObs:
         "actor_id", "debug", "apply_ns", "persist_ns", "input_wait_ns",
         "fence_ns", "_row_acc", "row_count", "chunks_in", "chunks_out",
         "dispatch", "busy_seconds", "align_seconds", "keys",
-        "_occupancy", "registry", "children", "mesh", "counted",
+        "_occupancy", "registry", "children", "mesh", "counted", "tables",
         "apply_wait_ns", "persist_wait_ns", "scope",
     )
 
@@ -264,6 +264,8 @@ class ActorObs:
         self.counted = []             # the chain's executors that count
         #                               rows per interval
         #                               (take_phase_counts)
+        self.tables = []              # [StateTable, rows seen] of the
+        #                               chain's executors (row_path_rows)
         self.keys = []
         if debug:
             labels = dict(actor=str(actor_id), executor=executor_label)
@@ -393,6 +395,16 @@ class ActorObs:
             # barrier anyway (summed where a chain holds two of a kind)
             for k, n in ex.take_phase_counts().items():
                 phases[k] = phases.get(k, 0) + n
+        # rows the chain's state tables took in row form since the last
+        # barrier (state/state_table.py `row_path_rows`: a Python tuple and
+        # an encoded key a row). A deferred flush writes behind its
+        # barrier, so an interval reads what the store drained during it.
+        for seen in self.tables:
+            total = seen[0].row_path_rows
+            if total != seen[1]:
+                phases["row_path_rows"] = (phases.get("row_path_rows", 0)
+                                           + total - seen[1])
+                seen[1] = total
         if self.debug:
             if self._row_acc is not None:
                 self.row_count.inc(int(np.asarray(self._row_acc)))
@@ -420,6 +432,15 @@ class ActorObs:
                                     **labels)
         self._occupancy.append((executor_label, part, gauge, fn))
         self.keys.append(("stream_executor_hash_occupancy", labels))
+
+
+def _state_tables_of(ex) -> list:
+    """The StateTables an executor writes: `state_table` (aggs, sources),
+    `state_tables` (a join's two sides), `table` (materialize)."""
+    from ..state.state_table import StateTable
+    found = [getattr(ex, "state_table", None), getattr(ex, "table", None),
+             *(getattr(ex, "state_tables", None) or ())]
+    return [t for t in found if isinstance(t, StateTable)]
 
 
 def _iter_chain(root):
@@ -538,6 +559,8 @@ class StreamingStats:
                 obs.mesh.append(ex)
             if hasattr(ex, "take_phase_counts"):
                 obs.counted.append(ex)
+            obs.tables.extend([t, t.row_path_rows]
+                              for t in _state_tables_of(ex))
             if hasattr(ex, "barrier_queue") and hasattr(ex, "obs"):
                 # sources: barrier-queue wait is align (idle) time
                 ex.obs = obs

@@ -472,7 +472,11 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
 
         return jax.tree_util.tree_map(expand, local)
 
-    def _maybe_rebuild_at_barrier(self) -> None:
+    def _precompile_purge(self):
+        # the mesh purge is `sharded_agg_purge`, compiled when first needed
+        return None
+
+    async def _maybe_rebuild_at_barrier(self) -> None:
         # static per-shard capacity in v1 (growth would need a global
         # re-layout), but zombie PURGING is mesh-safe: when the watchdog's
         # max-shard occupancy crosses the threshold, rebuild at the same

@@ -301,7 +301,13 @@ HASH_PROBE_FALLBACK_ROWS = GLOBAL_METRICS.counter(
 #   values of one group in one chunk; a delete of an untracked value of a
 #   group that is not lossy). Any increase fail-stops the epoch before its
 #   checkpoint commits.
+# - `hash_agg_evict_groups_total{executor}`: live groups the barrier's
+#   watermark cleaning zeroed (they stay as zombie slots until a purge).
+# - `hash_agg_purges_total{executor}`: same-capacity rebuilds that dropped
+#   the zombies (`_maybe_rebuild_at_barrier`; not from the fetch).
 HASH_AGG_EMIT_ROWS = "hash_agg_emit_rows_total"
+HASH_AGG_EVICT_GROUPS = "hash_agg_evict_groups_total"
+HASH_AGG_PURGES = "hash_agg_purges_total"
 HASH_AGG_EXTREMA_LOSSY_GROUPS = "hash_agg_extrema_lossy_groups"
 HASH_AGG_EXTREMA_ERRORS = "hash_agg_extrema_errors_total"
 

@@ -51,6 +51,11 @@ cover.
   dispatch:<StateJit.name>            ops/jit_state.py           host time to enqueue one program,
     (the poll)                                                   a full device queue's block
                                                                  included; one span a call
+  agg.purge (the barrier poll)        stream/hash_agg.py         a hash agg's zombie purge (or
+                                                                 growth): the rehash's dispatch and
+                                                                 the awaited readback of the rebuilt
+                                                                 occupancy, its `dispatch:hash_agg_*`
+                                                                 and `d2h_wait` children
   d2h_wait (the poll or flush.stage)  utils/d2h.py               a worker thread blocked until the
                                                                  device reaches and ships a buffer,
                                                                  the actor's task (or the uploader's)
@@ -302,8 +307,12 @@ class EpochTrace:
     # "mesh_rows_max_shard" (rows the shards received from the in-mesh
     # shuffle: all of them, the fullest shard's) and "mesh_shuffle_bytes"
     # (stream/mesh_shuffle.py). An actor whose chain holds a hash agg adds
-    # "agg_emit_rows" (rows its barrier flush sent downstream) and, with a
-    # retractable MIN/MAX, "agg_extrema_lossy_groups"; one that holds a
+    # "agg_emit_rows" (rows its barrier flush sent downstream),
+    # "agg_evict_groups" (live groups its watermark cleaning zeroed),
+    # "agg_purges" (same-capacity rebuilds that dropped the zombies; only
+    # where one ran) and, with a retractable MIN/MAX,
+    # "agg_extrema_lossy_groups"; any actor whose state tables took rows in
+    # row form adds "row_path_rows" (stream/monitor.py); one that holds a
     # sorted join adds "join_persist_delete_rows" /
     # "join_persist_insert_rows" (rows its durable flush wrote), from its
     # watchdog fetch "join_live_rows" / "join_capacity" (the fuller pool)
@@ -416,7 +425,13 @@ class EpochTrace:
                 if "agg_extrema_lossy_groups" in ph:
                     line += (f", {ph['agg_extrema_lossy_groups']} lossy "
                              f"min/max groups")
+                if ph.get("agg_evict_groups"):
+                    line += f", evicted {ph['agg_evict_groups']} groups"
+                if "agg_purges" in ph:
+                    line += f", {ph['agg_purges']} zombie purge(s)"
                 line += "]"
+            if "row_path_rows" in ph:
+                line += f" [{ph['row_path_rows']} state rows in row form]"
             if "join_persist_delete_rows" in ph:
                 line += (f" [join persisted -"
                          f"{ph['join_persist_delete_rows']} +"

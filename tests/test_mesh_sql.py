@@ -575,9 +575,11 @@ async def test_one_device_q7_has_three_phase_keys_and_no_mesh_counts(
         assert phases
         for p in phases.values():
             # the three times and their five parts (PR 36), and on the
-            # agg's and the join's actors the row counts of PRs 30 and 34
-            # (utils/trace.py); nothing of the mesh
-            assert set(p) - {"agg_emit_rows", "join_persist_delete_rows",
+            # agg's and the join's actors the row counts of PRs 30, 34 and
+            # 40 (utils/trace.py); nothing of the mesh
+            assert set(p) - {"agg_emit_rows", "agg_evict_groups",
+                             "agg_purges", "row_path_rows",
+                             "join_persist_delete_rows",
                              "join_persist_insert_rows", "join_live_rows",
                              "join_capacity", "join_match_rows",
                              "join_match_peak", "join_match_width"} \

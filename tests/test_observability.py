@@ -274,9 +274,10 @@ async def test_trace_phases_rendered():
     t = s.coord.tracer.recent()[-1]
     assert t.phases, "info level must record phase splits"
     for ph in t.phases.values():
-        assert set(ph) == {"apply_ns", "persist_ns", "align_ns",
-                           "input_wait_ns", "fence_ns", "dispatch_ns",
-                           "apply_wait_ns", "persist_wait_ns"}
+        # the MV's actor also counts the rows its table took in row form
+        assert set(ph) - {"row_path_rows"} == {
+            "apply_ns", "persist_ns", "align_ns", "input_wait_ns",
+            "fence_ns", "dispatch_ns", "apply_wait_ns", "persist_wait_ns"}
         assert ph["align_ns"] == ph["input_wait_ns"] + ph["fence_ns"]
     txt = t.render()
     assert "apply" in txt and "persist" in txt and "align" in txt

@@ -269,8 +269,11 @@ async def test_phase_keys_carry_the_cascades_counts():
         want = _cascade_counts(k * 2048, (k + 1) * 2048, cfg)
         # what the join's watchdog fetch adds since PR 34 is q4's to test
         # (tests/test_q4_published.py)
+        # and the aggs' evictions and the tables' row-form writes since PR
+        # 40 are q8's (tests/test_q8_published.py)
         match = {"join_live_rows", "join_capacity", "join_match_rows",
-                 "join_match_peak", "join_match_width"}
+                 "join_match_peak", "join_match_width", "agg_evict_groups",
+                 "row_path_rows"}
         assert match <= set(got)
         assert {k_: v for k_, v in got.items() if k_ not in match} == {
             "agg_emit_rows": 2 * want["count_emit"] + want["max_emit"],

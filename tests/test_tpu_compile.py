@@ -156,7 +156,9 @@ def test_the_program_id_is_read_from_the_serialized_executable(
     for jitted, a in ((agg._apply._jitted, args + (chunk,)),
                       (agg._apply._jitted, args + (chunk,)),
                       (agg._watchdog_pack._jitted,
-                       args + (abstract(agg._occ_dev, one_chip),))):
+                       args + (abstract(agg._occ_dev, one_chip),
+                               jax.ShapeDtypeStruct((), jnp.int64,
+                                                    sharding=one_chip)))):
         ser = bytes(jitted.lower(*a).compile().runtime_executable()
                     .serialize())
         ids.append(program_id_of_serialized(ser))
@@ -249,7 +251,8 @@ def test_q5full_retractable_max_programs(q5full_executors, one_chip,
         "persist_view": lambda: agg._persist_view._jitted.lower(
             state, n_slots=FLUSH_MIN_SLOTS),
         "watchdog_pack": lambda: agg._watchdog_pack._jitted.lower(
-            state, ov, abstract(agg._occ_dev, one_chip)),
+            state, ov, abstract(agg._occ_dev, one_chip),
+            jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)),
     }[program]()
     fits_one_chip(lowered.compile())
 
@@ -357,6 +360,82 @@ def test_q4_join_and_float_avg_programs(q4_executors, one_chip,
     if program.startswith("avg"):
         assert "bitcast-convert" not in "".join(
             ln for ln in compiled.as_text().splitlines() if "f64" in ln)
+
+
+@pytest.fixture(scope="module")
+def q8_executors():
+    """The plan of NEXMark q8 as published (`benchmark/queries/q8.py`'s own
+    DDL, at cut widths; NEXMark's 1 : 3 of persons to auctions), deployed;
+    no data is run."""
+    from benchmark.queries import q8
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.plan.build import _iter_executor_chain
+    cfg = {"generator": {"inter_event_us": 100, "emit_watermarks": 1,
+                         "watermark_lag_us": 0, "hot_seller_bucket": 100},
+           "window_us": W,
+           "session_set": {"streaming_agg_capacity": AGG_CAP,
+                           "streaming_join_capacity": JOIN_CAP,
+                           "streaming_join_match_factor": 2}}
+
+    async def deploy():
+        s = Session()
+        for stmt in q8.ddl(cfg, {"chunk_size": {"person": 64 * 8,
+                                                "auction": 3 * 64 * 8},
+                                 "chunks_per_interval": {"person": 1,
+                                                         "auction": 1}}, 7):
+            await s.execute(stmt)
+        return [ex for roots in s.catalog.mvs["q8"].deployment.roots.values()
+                for root in roots for ex in _iter_executor_chain(root)]
+
+    return asyncio.run(deploy())
+
+
+@pytest.mark.parametrize("program", ["person_apply", "person_rehash",
+                                     "person_watchdog_pack",
+                                     "person_evict_keys",
+                                     "person_persist_view",
+                                     "join_person_apply"])
+def test_q8_published_programs(q8_executors, one_chip, no_persistent_cache,
+                               program):
+    """`q8.sat` on one chip: the person aggregate's table is keyed by (id,
+    name, window_start, window_end) with the name an int32 dictionary id
+    beside three int64 — its apply, the same-capacity rehash that purges its
+    zombies (compiled at the first barrier, `_precompile_purge`), the
+    watchdog pack that now counts the live groups and those the cleaning
+    watermark is about to evict, the evict keys and the persist view — and
+    the join's apply of that side, whose rows carry the name too."""
+    from risingwave_tpu.stream.align import LEFT
+    from risingwave_tpu.stream.hash_agg import HashAggExecutor
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+    join, = [ex for ex in q8_executors if isinstance(ex, SortedJoinExecutor)]
+    person, = [ex for ex in q8_executors if isinstance(ex, HashAggExecutor)
+               and len(ex.group_key_indices) == 4]
+    assert [str(np.dtype(d)) for d in person._key_dtypes] == [
+        "int64", "int32", "int64", "int64"]
+    assert person.cleaning_watermark_key is not None
+    state = abstract(person.state, one_chip)
+    wm = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    ov = abstract(person._overflow_dev, one_chip)
+    compiled = {
+        "person_apply": lambda: person._apply._jitted.lower(
+            state, ov, abstract_chunk(person.input.schema, 64 * 8, one_chip)),
+        "person_rehash": lambda: person._rehash._jitted.lower(
+            state, person.capacity),
+        "person_watchdog_pack": lambda: person._watchdog_pack._jitted.lower(
+            state, ov, abstract(person._occ_dev, one_chip), wm),
+        "person_evict_keys": lambda: person._evict_keys._jitted.lower(
+            state, wm),
+        "person_persist_view": lambda: person._persist_view._jitted.lower(
+            state, n_slots=1024),
+        "join_person_apply": lambda: join._apply_counted._jitted.lower(
+            abstract(join.sides[LEFT], one_chip),
+            abstract(join.sides[1 - LEFT], one_chip),
+            abstract(join._errs_dev, one_chip),
+            abstract(join._match_dev, one_chip),
+            abstract_chunk(join.inputs[LEFT].schema, 2048, one_chip), wm,
+            side=LEFT, match_factor=join.match_factors[LEFT]),
+    }[program]().compile()
+    fits_one_chip(compiled)
 
 
 @pytest.fixture(scope="module")
