@@ -168,21 +168,50 @@ class StreamChunk:
         ops = np.asarray(self.ops)[vis]
         return cols, ops
 
+    def to_host(self) -> "HostChunk":
+        """Every lane of the chunk on the host, at full capacity. A pure
+        wait (`np.asarray` of concrete arrays; nothing is dispatched), so
+        it may run on a worker thread (`utils/d2h.py` `fetch_chunk`)."""
+        return HostChunk(
+            np.asarray(self.ops), np.asarray(self.vis),
+            [np.asarray(c.data) for c in self.columns],
+            [None if c.valid is None else np.asarray(c.valid)
+             for c in self.columns])
+
     def to_rows(self) -> list[tuple]:
         """Visible rows as python tuples (op, values...), NULL lanes as
         None. For materialize/sinks/tests — NULL-ness must survive the
         host boundary or outer-join padding rows materialize as zeros."""
-        vis = np.asarray(self.vis)
-        ops = np.asarray(self.ops)[vis]
-        cols = [np.asarray(c.data)[vis] for c in self.columns]
-        valids = [None if c.valid is None else np.asarray(c.valid)[vis]
-                  for c in self.columns]
-        out = []
-        for r in range(len(ops)):
-            out.append((int(ops[r]), tuple(
-                c[r].item() if v is None or v[r] else None
-                for c, v in zip(cols, valids))))
-        return out
+        return self.to_host().rows()
+
+
+@dataclass(frozen=True)
+class HostChunk:
+    """A chunk's lanes as numpy arrays (`StreamChunk.to_host`): `valids[j]`
+    is None where column j has no NULLs."""
+
+    ops: np.ndarray
+    vis: np.ndarray
+    cols: list
+    valids: list
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in (self.ops, self.vis, *self.cols,
+                                      *self.valids) if a is not None)
+
+    def rows(self) -> list[tuple]:
+        """The visible rows as `(op, values)`, NULL lanes as None."""
+        idx = np.flatnonzero(self.vis)
+        lanes = []
+        for c, v in zip(self.cols, self.valids):
+            lane = c[idx].tolist()
+            if v is not None:
+                lane = [x if ok else None
+                        for x, ok in zip(lane, v[idx].tolist())]
+            lanes.append(lane)
+        vals = zip(*lanes) if lanes else ((),) * len(idx)
+        return list(zip(self.ops[idx].tolist(), vals))
 
 
 def empty_chunk(schema: Schema, capacity: int = DEFAULT_CHUNK_CAPACITY) -> StreamChunk:

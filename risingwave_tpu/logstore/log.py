@@ -43,6 +43,7 @@ from __future__ import annotations
 import asyncio
 from typing import Iterator, Optional
 
+from ..serving.cache import pending_rows
 from ..state.serde import RowSerde
 from ..state.store import StateStore, WriteBatch
 from ..utils.metrics import (
@@ -338,18 +339,22 @@ class MvChangelogWriter:
         self.writer_idx = writer_idx
         self._pending: list = []
 
-    def on_rows(self, rows: list) -> None:
-        self._pending.extend(rows)
+    def on_rows(self, rows) -> None:
+        """As `MvChangelogHook.on_rows`: a row list or an `EffectiveChunk`,
+        turned into rows only by a barrier that stages them."""
+        if rows:
+            self._pending.append(rows)
 
     def on_barrier(self, sealed_epoch: int) -> None:
-        rows = self._pending
+        batches = self._pending
         self._pending = []
-        if not rows:
+        if not batches:
             return
         if not self.log.active:
             self.log.dropped_through = max(self.log.dropped_through,
                                            sealed_epoch)
             return
+        rows = pending_rows(batches)
         key = self.log.table_id.to_bytes(4, "big") + bytes([_ENTRIES]) \
             + sealed_epoch.to_bytes(8, "big") \
             + self.writer_idx.to_bytes(2, "big")

@@ -1,10 +1,9 @@
 """Native host runtime kernels (C++), compiled on first use.
 
 The reference's host runtime is native Rust end to end; here the pieces
-with real per-row Python overhead — batch key/value serde, vnode
-hashing and the SST record packer on the persistence path — are C++
-behind ctypes (which releases the GIL for the call). `_rowcodec.so`
-is built ONLY from the tracked `rowcodec.cc` next to this file (the
+with real per-row Python overhead — vnode hashing and the SST record
+packer on the persistence path — are C++ behind ctypes (which releases
+the GIL for the call). `_rowcodec.so` is built ONLY from the tracked `rowcodec.cc` next to this file (the
 artifact is git-ignored), with `g++` on first use. A machine without a
 toolchain keeps working on the pure-Python twins (`lib()` returns None
 and callers take them), but the choice is never silent: it is logged
@@ -51,11 +50,6 @@ def lib() -> Optional[ctypes.CDLL]:
             # stale or foreign-arch artifact: rebuild for THIS machine
             build()
             l = ctypes.CDLL(so)
-        l.mc_encode_i64.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
-        l.row_encode_i64.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_void_p]
         l.crc32_i64_cols.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
         l.sst_pack_fixed.argtypes = [
@@ -81,31 +75,6 @@ def lib() -> Optional[ctypes.CDLL]:
         _log.warning("native row codec unavailable (%r %s): the persist "
                      "path runs on the pure-Python row codec", e, detail)
         return None
-
-
-def mc_encode_i64_batch(vals: np.ndarray) -> Optional[np.ndarray]:
-    """vals [n, k] int64 -> [n, 9k] uint8 memcomparable keys (asc, no
-    nulls); None if the native lib is unavailable."""
-    l = lib()
-    if l is None:
-        return None
-    vals = np.ascontiguousarray(vals, dtype=np.int64)
-    n, k = vals.shape
-    out = np.empty((n, 9 * k), dtype=np.uint8)
-    l.mc_encode_i64(vals.ctypes.data, n, k, out.ctypes.data)
-    return out
-
-
-def row_encode_i64_batch(vals: np.ndarray, nb: int) -> Optional[np.ndarray]:
-    """vals [n, k] int64 -> [n, nb + 8k] uint8 value rows (no nulls)."""
-    l = lib()
-    if l is None:
-        return None
-    vals = np.ascontiguousarray(vals, dtype=np.int64)
-    n, k = vals.shape
-    out = np.empty((n, nb + 8 * k), dtype=np.uint8)
-    l.row_encode_i64(vals.ctypes.data, n, k, nb, out.ctypes.data)
-    return out
 
 
 def crc32_i64_batch(vals: np.ndarray) -> Optional[np.ndarray]:

@@ -3,42 +3,16 @@
 // The reference implements row serde / hashing in Rust (src/common/src/
 // row/, util/memcmp_encoding.rs, hash/); the TPU build keeps device
 // compute in XLA and gives the HOST runtime the same native treatment:
-// batch memcomparable key encoding, value-row encoding, the crc32 vnode
-// hash, and the SST record packer / unpacker for fixed-width sorted runs, each
-// vectorized over whole column batches instead of per-row Python. Byte
-// formats are bit-identical to state/serde.py, common/vnode.py and
-// state/sstable.py (golden-tested from tests/test_native.py).
+// the crc32 vnode hash, and the SST record packer / unpacker for fixed-width
+// sorted runs, each over whole batches instead of per-row Python. (Batch
+// key and value encoding is numpy's: state/serde.py BatchCodec.) Byte
+// formats are bit-identical to common/vnode.py and state/sstable.py
+// (golden-tested from tests/test_native.py).
 
 #include <cstdint>
 #include <cstring>
 
 extern "C" {
-
-// memcomparable for non-null ascending int64 fields:
-// field = 0x01 ++ bigendian(v XOR sign-flip). out stride = k * 9 bytes.
-void mc_encode_i64(const int64_t* vals, int64_t n, int64_t k,
-                   uint8_t* out) {
-    for (int64_t r = 0; r < n; ++r) {
-        uint8_t* p = out + r * k * 9;
-        for (int64_t c = 0; c < k; ++c) {
-            uint64_t u = (uint64_t)vals[r * k + c] ^ 0x8000000000000000ull;
-            *p++ = 0x01;
-            for (int b = 7; b >= 0; --b) *p++ = (uint8_t)(u >> (8 * b));
-        }
-    }
-}
-
-// value encoding for all-int64 rows with no nulls:
-// row = null bitmap (nb bytes, zero) ++ k * int64 little-endian
-void row_encode_i64(const int64_t* vals, int64_t n, int64_t k,
-                    int64_t nb, uint8_t* out) {
-    const int64_t stride = nb + 8 * k;
-    for (int64_t r = 0; r < n; ++r) {
-        uint8_t* p = out + r * stride;
-        std::memset(p, 0, (size_t)nb);
-        std::memcpy(p + nb, vals + r * k, (size_t)(8 * k));
-    }
-}
 
 // crc32 (poly 0xEDB88320) over the LE bytes of k int64 columns per row,
 // column-major in argument order — bit-identical to vnode.crc32_numpy

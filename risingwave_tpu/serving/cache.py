@@ -33,12 +33,43 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..common.chunk import OP_INSERT, OP_UPDATE_INSERT, HostChunk
 from ..common.types import Schema
 
 # effective changelog ops (post conflict-resolution): PUT upserts by pk
 # (matching the state table's last-write-wins mem-table), DEL removes
 OP_PUT = 1
 OP_DEL = -1
+
+
+class EffectiveChunk:
+    """The effective changelog of one chunk a NO_CHECK materialize wrote
+    in columns: its inserts land last-write-wins in the mem-table, upserts
+    at the storage level, so every visible row is a PUT or a DEL. It stays
+    the chunk's host lanes until a changelog tap KEEPS it (`rows`, once for
+    all taps): a tap nobody reads drops it at the barrier unread."""
+
+    __slots__ = ("host", "_rows")
+
+    def __init__(self, host: HostChunk):
+        self.host = host
+        self._rows = None
+
+    def rows(self) -> list:
+        if self._rows is None:
+            self._rows = [
+                (OP_PUT if op in (OP_INSERT, OP_UPDATE_INSERT) else OP_DEL,
+                 row) for op, row in self.host.rows()]
+        return self._rows
+
+
+def pending_rows(batches: list) -> list:
+    """A tap's pending interval as `[(OP_PUT | OP_DEL, row)]`: each batch
+    is such a list (`on_rows`) or an `EffectiveChunk`, in arrival order."""
+    out: list = []
+    for b in batches:
+        out.extend(b.rows() if isinstance(b, EffectiveChunk) else b)
+    return out
 
 _MIN_CAPACITY = 64
 
@@ -235,14 +266,17 @@ class MvChangelogHook:
         self._pending: list = []
         self._by_epoch: list = []   # [(sealed_epoch, rows)]
 
-    def on_rows(self, rows: list) -> None:
-        self._pending.extend(rows)
+    def on_rows(self, rows) -> None:
+        """`[(OP_PUT | OP_DEL, row)]` or an `EffectiveChunk` (never an
+        empty one): held as handed over, rows made only where kept."""
+        if rows:
+            self._pending.append(rows)
 
     def on_barrier(self, sealed_epoch: int) -> None:
-        rows = self._pending
+        batches = self._pending
         self._pending = []
-        if self.active and rows:
-            self._by_epoch.append((sealed_epoch, rows))
+        if self.active and batches:
+            self._by_epoch.append((sealed_epoch, pending_rows(batches)))
 
     def drain(self, upto_epoch: int) -> list:
         """Stamped batches with epoch <= upto_epoch, ascending."""

@@ -8,7 +8,7 @@ docs/checkpoint.md:38-44). The shape kept here:
   readable — mem-table read-through semantics match MemoryStateStore). A
   per-epoch buffer is a list of write SEGMENTS in staging order: a
   `ColumnarSegment` (state/store.py: the key matrix, value matrix and put
-  lane of one `StateTable.write_chunk_columns` call, as the native codec
+  lane of one `StateTable.write_chunk_columns` call, as the batch codec
   made them) or a dict (row-form writes, the log store, source offsets).
   Later segments overlay earlier ones; within a columnar segment the last
   row of a key counts.

@@ -153,3 +153,98 @@ class RowSerde:
         nulls = int.from_bytes(data[: self._nbytes_nulls], "little")
         vals = struct.unpack(self._fmt, data[self._nbytes_nulls:])
         return tuple(None if (nulls >> i) & 1 else v for i, v in enumerate(vals))
+
+
+# -------------------------------------------------------------- batch codec
+
+class BatchCodec:
+    """`encode_memcomparable` and `RowSerde.encode` over whole columns: the
+    same bytes, made by numpy for a batch at once. Every type of the engine
+    is fixed-width on the host (dictionary types are their int32 id), so a
+    table's keys are one `[n, key_width]` and its values one
+    `[n, value_width]` uint8 matrix whatever its schema, each laid out by a
+    packed structured dtype: a field store is one strided pass that swaps
+    the bytes where the layout asks. A NULL pk value is the one thing with
+    no fixed width (flag 0x00 and no body): such rows are the caller's to
+    write in row form. `key_prefix` bytes at the head of every key are left
+    to the caller (the table id and the vnode)."""
+
+    def __init__(self, schema: Schema, pk_indices: Sequence[int],
+                 descending: Optional[Sequence[bool]] = None,
+                 key_prefix: int = 0):
+        self._dtypes = [np.dtype(f.data_type.np_dtype) for f in schema]
+        self._nbytes_nulls = (len(schema) + 7) // 8
+        # value layout: null bitmap, then the `<`-packed fields
+        offsets, off = [], self._nbytes_nulls
+        for dt in self._dtypes:
+            offsets.append(off)
+            off += dt.itemsize
+        self._value_layout = np.dtype({
+            "names": [f"v{j}" for j in range(len(self._dtypes))],
+            "formats": [dt.newbyteorder("<") for dt in self._dtypes],
+            "offsets": offsets, "itemsize": off})
+        # key layout: per pk column the flag byte, then the big-endian body
+        self._pk = []              # (column, first byte, width, descending)
+        names, formats, offsets, off = [], [], [], key_prefix
+        for j, i in enumerate(pk_indices):
+            w = self._dtypes[i].itemsize
+            names += [f"flag{j}", f"body{j}"]
+            formats += ["u1", f">u{w}"]
+            offsets += [off, off + 1]
+            self._pk.append((i, off, w, bool(descending[j])
+                             if descending is not None else False))
+            off += 1 + w
+        self._key_layout = np.dtype({"names": names, "formats": formats,
+                                     "offsets": offsets, "itemsize": off})
+
+    @property
+    def key_width(self) -> int:
+        return self._key_layout.itemsize
+
+    def typed(self, cols: Sequence[np.ndarray],
+              valids: Optional[Sequence[Optional[np.ndarray]]] = None
+              ) -> list[np.ndarray]:
+        """The columns at the schema's dtypes, a NULL lane as the type's
+        zero (what `RowSerde.encode` writes and the vnode hash reads)."""
+        out = []
+        for j, (c, dt) in enumerate(zip(cols, self._dtypes)):
+            c = np.ascontiguousarray(c, dtype=dt)
+            v = None if valids is None else valids[j]
+            out.append(c if v is None else np.where(v, c, dt.type(0)))
+        return out
+
+    def encode_keys(self, cols: Sequence[np.ndarray]) -> np.ndarray:
+        """`[n, key_width]`: behind the prefix, the memcomparable pk of
+        each row of `cols` (from `typed`; no NULL among the pk lanes)."""
+        n = len(cols[0])
+        out = np.empty((n, self.key_width), dtype=np.uint8)
+        fields = out.view(self._key_layout).reshape(n)
+        for j, (i, off, w, desc) in enumerate(self._pk):
+            bits = cols[i].view(f"u{w}")
+            if cols[i].dtype.kind != "b":
+                top = bits.dtype.type(1 << (8 * w - 1))
+                # `_enc_float`: a negative flips every bit, else the sign
+                bits = (np.where(bits & top, ~bits, bits | top)
+                        if cols[i].dtype.kind == "f" else bits ^ top)
+            fields[f"flag{j}"] = 1
+            fields[f"body{j}"] = bits
+            if desc:
+                field = out[:, off:off + 1 + w]
+                np.bitwise_xor(field, 0xFF, out=field)    # 0xFF - byte
+        return out
+
+    def encode_values(self, cols: Sequence[np.ndarray],
+                      valids: Optional[Sequence[Optional[np.ndarray]]] = None
+                      ) -> np.ndarray:
+        """`[n, value_width]`: `RowSerde.encode` of each row of `cols`
+        (from `typed`, so a NULL lane already holds its zero)."""
+        n = len(cols[0])
+        out = np.empty((n, self._value_layout.itemsize), dtype=np.uint8)
+        out[:, :self._nbytes_nulls] = 0
+        fields = out.view(self._value_layout).reshape(n)
+        for j, c in enumerate(cols):
+            fields[f"v{j}"] = c
+            v = None if valids is None else valids[j]
+            if v is not None:
+                out[:, j // 8] |= (~v).astype(np.uint8) << np.uint8(j % 8)
+        return out

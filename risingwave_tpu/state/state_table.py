@@ -16,16 +16,21 @@ and recovery rebuilds HBM arrays by scanning this table. Consistency checks
 (mem_table.rs) and catch changelog bugs early.
 
 The mem-table is a list of write SEGMENTS in staging order. One
-`write_chunk_columns` call on an all-INT64, ascending-pk table is one
-`ColumnarSegment` (state/store.py): the `[n, K]` key matrix, `[n, V]` value
-matrix and put lane the native codec made, never taken apart into a `bytes`
-object per key — `commit` hands it to the store as it is, and it stays that
-way up to the L0 run (state/hummock.py, state/sstable.py). Every other
-write (insert / delete / update / write_chunk_rows, or a schema the batch
-codec cannot encode) goes to a dict segment, the row form. A later segment
-overlays an earlier one and within a columnar segment the last row of a key
-counts, so the last write wins exactly as one dict gave it. What reaches
-the object store (`RWS1`) is the same bytes either way.
+`write_chunk_columns` call is one `ColumnarSegment` (state/store.py),
+whatever the table's column types, NULLs in its value columns and a
+descending pk included: the `[n, K]` key matrix, `[n, V]` value matrix and
+put lane the batch codec made (state/serde.py `BatchCodec`: every type is
+fixed-width on the host), never taken apart into a `bytes` object per key —
+`commit` hands it to the store as it is, and it stays that way up to the L0
+run (state/hummock.py, state/sstable.py). What goes to a dict segment, the
+row form (`row_path_rows` counts it): a batch's rows with a NULL in a pk
+column, whose key is shorter than the others' (flag 0x00 and no body), and
+the writes that are no batch — insert / delete / update / write_chunk_rows
+(a source's offsets, dedup, a simple agg's one row, a table with a conflict
+check). A later segment overlays an earlier one and within a columnar
+segment the last row of a key counts, so the last write wins exactly as one
+dict gave it. What reaches the object store (`RWS1`) is the same bytes
+either way, and what `RowSerde.decode` / `decode_memcomparable` read.
 """
 
 from __future__ import annotations
@@ -36,7 +41,7 @@ import numpy as np
 
 from ..common.types import Schema
 from ..common.vnode import VNODE_COUNT, compute_vnodes_numpy
-from .serde import RowSerde, encode_memcomparable, decode_memcomparable
+from .serde import BatchCodec, RowSerde, encode_memcomparable
 from .store import (ColumnarSegment, StateStore, WriteBatch,
                     encode_table_key, segments_get, segments_range)
 
@@ -80,9 +85,8 @@ class StateTable:
         # rows `write_chunk_rows` has staged, ever: the row form's share of
         # this table's writes (a columnar segment counts none)
         self.row_path_rows = 0
-        self._all_i64 = all(
-            np.dtype(f.data_type.np_dtype).kind in "i" and
-            np.dtype(f.data_type.np_dtype).itemsize == 8 for f in schema)
+        self._codec = BatchCodec(schema, self.pk_indices, self.pk_descending,
+                                 key_prefix=5)
 
     # ------------------------------------------------------------- keys
     def _vnode_of(self, row: tuple) -> int:
@@ -195,59 +199,57 @@ class StateTable:
         return compute_vnodes_numpy(cols)
 
     def write_chunk_columns(self, ops: np.ndarray, cols: Sequence[np.ndarray],
-                            vis: np.ndarray) -> None:
+                            vis: np.ndarray,
+                            valids: Optional[Sequence[Optional[np.ndarray]]]
+                            = None) -> None:
         """Columnar batch write — the per-barrier persistence hot path.
 
-        For all-int64 schemas with ascending pk, key and value encoding run
-        in the native C++ codec (risingwave_tpu/native) over the whole
-        batch and the batch is staged as ONE columnar segment; otherwise
-        falls back to the per-row path. `ops` uses chunk Op encoding; rows
-        with vis False are skipped."""
-        from ..common.chunk import OP_INSERT, OP_UPDATE_INSERT
-        ops = np.asarray(ops)
-        vis = np.asarray(vis, dtype=bool)
+        Keys and values of the whole batch are encoded by the table's
+        `BatchCodec` (state/serde.py) and staged as ONE columnar segment,
+        whatever the schema. `ops` uses chunk Op encoding; rows with vis
+        False are skipped; `valids[j]` (None = no NULLs) marks column j's
+        NULL lanes. Only rows with a NULL in a pk column, whose key has no
+        fixed width, take the row form: no other key can equal theirs, so
+        their order against the segment does not matter."""
+        from ..common.chunk import OP_INSERT, OP_UPDATE_INSERT, HostChunk
+        if len(cols) != len(self.schema):
+            raise StateTableError(
+                f"table {self.table_id}: {len(cols)} columns written, "
+                f"{len(self.schema)} in its schema")
+        ops, vis = np.asarray(ops), np.asarray(vis, dtype=bool)
+        cols = [np.asarray(c) for c in cols]
+        if valids is not None:
+            valids = [None if v is None else np.asarray(v, dtype=bool)
+                      for v in valids]
+            pk_valids = [valids[i] for i in self.pk_indices
+                         if valids[i] is not None]
+            null_pk = vis & ~np.logical_and.reduce(pk_valids) \
+                if pk_valids else None
+            if null_pk is not None and null_pk.any():
+                self.write_chunk_rows(
+                    HostChunk(ops, null_pk, cols, valids).rows())
+                vis = vis & ~null_pk
         idx = np.flatnonzero(vis)
         if idx.size == 0:
             return
-        native_ok = (self._all_i64 and self.pk_descending is None)
-        enc_keys = enc_vals = None
-        if native_ok:
-            from ..native import mc_encode_i64_batch, row_encode_i64_batch
-            pk_mat = np.stack([np.asarray(cols[i], dtype=np.int64)[idx]
-                               for i in self.pk_indices], axis=1)
-            mc = mc_encode_i64_batch(pk_mat)
-            if mc is not None:
-                if self.dist_key_indices:
-                    # MUST match compute_vnodes_numpy / the device hash
-                    # (splitmix64) — the native crc32 batch is for the
-                    # serialization goldens only; using it here would
-                    # place batch-written rows under different keys than
-                    # per-row gets/deletes compute
-                    dist = [np.asarray(cols[i], dtype=np.int64)[idx]
-                            for i in self.dist_key_indices]
-                    vns = compute_vnodes_numpy(dist).astype(np.uint8)
-                else:
-                    vns = np.zeros(idx.size, dtype=np.uint8)
-                prefix = np.frombuffer(
-                    self.table_id.to_bytes(4, "big"), dtype=np.uint8)
-                enc_keys = np.concatenate([
-                    np.broadcast_to(prefix, (idx.size, 4)),
-                    vns[:, None], mc], axis=1)
-                all_mat = np.stack(
-                    [np.asarray(c, dtype=np.int64)[idx] for c in cols],
-                    axis=1)
-                enc_vals = row_encode_i64_batch(
-                    all_mat, self._serde._nbytes_nulls)
-        if enc_keys is not None:
-            ops_v = ops[idx]
-            self._mem.append(ColumnarSegment(
-                self.table_id, enc_keys, enc_vals,
-                (ops_v == OP_INSERT) | (ops_v == OP_UPDATE_INSERT)))
-            return
-        rows = [(int(ops[i]), tuple(
-            np.asarray(cols[j])[i].item() for j in range(len(cols))))
-            for i in idx]
-        self.write_chunk_rows(rows)
+        ops = ops[idx]
+        cols = [c[idx] for c in cols]
+        if valids is not None:
+            valids = [None if v is None else v[idx] for v in valids]
+        codec = self._codec
+        cols = codec.typed(cols, valids)
+        keys = codec.encode_keys(cols)
+        keys[:, :4] = np.frombuffer(self.table_id.to_bytes(4, "big"),
+                                    dtype=np.uint8)
+        # the vnode MUST be compute_vnodes_numpy's over the dist-key columns
+        # at their own dtypes (== the device hash), as `_vnode_of` has it:
+        # per-row gets and deletes compute their keys that way
+        keys[:, 4] = (compute_vnodes_numpy(
+            [cols[i] for i in self.dist_key_indices])
+            if self.dist_key_indices else 0)
+        self._mem.append(ColumnarSegment(
+            self.table_id, keys, codec.encode_values(cols, valids),
+            (ops == OP_INSERT) | (ops == OP_UPDATE_INSERT)))
 
     # ------------------------------------------------------------- reads
     def get_row(self, pk: tuple, dist_values: Optional[tuple] = None) -> Optional[tuple]:

@@ -52,7 +52,7 @@ def lazy_merge_ranges(streams):
 
 
 class ColumnarSegment:
-    """One batch of writes to one table as the native codec made it: a
+    """One batch of writes to one table as the batch codec made it: a
     `[n, K]` uint8 key matrix, a `[n, V]` uint8 value matrix and a put lane
     (False = tombstone; that row's value is ignored). Rows are in STAGING
     order and keys may repeat: the last row of a key is the write that

@@ -19,6 +19,12 @@ epoch at each barrier — the feed changelog subscriptions and serving
 replicas tail after the checkpoint commits. While no subscription has
 activated the log, the writer drops its buffer at each barrier, so
 unsubscribed MVs pay nothing durable.
+
+A NO_CHECK table (every MV over stream operators) takes each chunk as ONE
+columnar write from one fetch of the chunk's host lanes, and hands both
+taps those lanes (`serving/cache.py` `EffectiveChunk`): Python rows exist
+only where an active tap keeps them. OVERWRITE / IGNORE read, compare and
+write row by row.
 """
 
 from __future__ import annotations
@@ -27,10 +33,10 @@ import enum
 from typing import Optional
 
 from ..common.chunk import (
-    StreamChunk, OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT,
+    HostChunk, StreamChunk, OP_INSERT, OP_UPDATE_INSERT,
 )
 from ..state.state_table import StateTable
-from ..utils.d2h import fetch_small, off_loop
+from ..utils.d2h import fetch_chunk, off_loop
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
 
@@ -62,9 +68,9 @@ class MaterializeExecutor(Executor):
         async for msg in self.input.execute():
             if isinstance(msg, StreamChunk):
                 # the chunk is on the device until the program that makes
-                # it has run: that wait is taken off the loop
-                await off_loop(fetch_small, msg.vis)
-                self._apply(msg)
+                # it has run: that wait, and the copy of its lanes, are
+                # taken off the loop
+                self._apply(await off_loop(fetch_chunk, msg))
                 yield msg
             elif isinstance(msg, Barrier):
                 # a dataflow created mid-session initializes on its first
@@ -88,23 +94,22 @@ class MaterializeExecutor(Executor):
             else:
                 yield msg
 
-    def _apply(self, chunk: StreamChunk) -> None:
-        from ..serving.cache import OP_DEL, OP_PUT
-        rows = chunk.to_rows()
+    def _apply(self, host: HostChunk) -> None:
+        from ..serving.cache import OP_DEL, OP_PUT, EffectiveChunk
+        if not host.vis.any():
+            return
         hook = self.serving_hook
         clog = self.changelog_log
         if self.conflict is ConflictBehavior.NO_CHECK:
-            self.table.write_chunk_rows(rows)
-            if hook is not None or clog is not None:
-                # NO_CHECK inserts land last-write-wins in the mem-table,
-                # i.e. upserts at the storage level — mirror that exactly
-                eff = [(OP_PUT if op in (OP_INSERT, OP_UPDATE_INSERT)
-                        else OP_DEL, row) for op, row in rows]
-                if hook is not None:
-                    hook.on_rows(eff)
-                if clog is not None:
-                    clog.on_rows(eff)
+            self.table.write_chunk_columns(host.ops, host.cols, host.vis,
+                                           host.valids)
+            eff = EffectiveChunk(host)
+            if hook is not None:
+                hook.on_rows(eff)
+            if clog is not None:
+                clog.on_rows(eff)
             return
+        rows = host.rows()
         eff = []
         for op, row in rows:
             if op in (OP_INSERT, OP_UPDATE_INSERT):

@@ -397,14 +397,17 @@ class ActorObs:
                 phases[k] = phases.get(k, 0) + n
         # rows the chain's state tables took in row form since the last
         # barrier (state/state_table.py `row_path_rows`: a Python tuple and
-        # an encoded key a row). A deferred flush writes behind its
-        # barrier, so an interval reads what the store drained during it.
+        # an encoded key a row), 0 included: a batch of any schema is one
+        # columnar segment, so what is left is `write_chunk_rows`' own
+        # callers and a batch's NULL-pk rows. A deferred flush writes
+        # behind its barrier, so an interval reads what the store drained
+        # during it.
+        if self.tables:
+            phases["row_path_rows"] = 0
         for seen in self.tables:
             total = seen[0].row_path_rows
-            if total != seen[1]:
-                phases["row_path_rows"] = (phases.get("row_path_rows", 0)
-                                           + total - seen[1])
-                seen[1] = total
+            phases["row_path_rows"] += total - seen[1]
+            seen[1] = total
         if self.debug:
             if self._row_acc is not None:
                 self.row_count.inc(int(np.asarray(self._row_acc)))

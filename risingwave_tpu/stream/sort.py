@@ -138,8 +138,7 @@ class SortExecutor(StatefulUnaryExecutor):
         if getattr(self, "_dirty_persist", False) or flushed is not None:
             self._dirty_persist = False
             # snapshot the live buffer through the columnar batch path
-            # (native codec for all-int64 schemas — same hot path as
-            # hash_agg persistence)
+            # (the same hot path as hash_agg persistence)
             cols = [np.asarray(r) for r in self.rows]
             ops = np.zeros(self.capacity, dtype=np.int8)  # OP_INSERT
             self.state_table.write_chunk_columns(

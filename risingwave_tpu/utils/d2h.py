@@ -105,8 +105,16 @@ def fetch_small(dev) -> np.ndarray:
     return _in_wait_span(lambda: np.asarray(dev), lambda host: host.nbytes)
 
 
+def fetch_chunk(chunk):
+    """Blocking d2h of a whole chunk as it stands (`StreamChunk.to_host`:
+    every lane at full capacity, nothing packed, so nothing dispatched): a
+    pure wait like `fetch_small`, for the terminal executor that takes its
+    chunk to the host anyway. Not counted in d2h_bytes_total either."""
+    return _in_wait_span(chunk.to_host, lambda host: host.nbytes)
+
+
 async def off_loop(fetch, dev):
-    """`fetch(dev)` — `fetch_small` or `fetch_flat`, a pure wait — on a
+    """`fetch(dev)` — `fetch_small`, `fetch_flat` or `fetch_chunk`, a pure wait — on a
     worker thread, awaited: the caller's task is parked, the event loop
     runs the other actors and the uploader's continuations meanwhile.
     `dev` is dispatched by the caller, on the loop, before this is called.

@@ -494,10 +494,10 @@ COMPACTION_SECONDS = GLOBAL_METRICS.histogram("compaction_seconds")
 LSM_L0_RUNS = GLOBAL_METRICS.gauge("lsm_l0_runs")
 LSM_READ_AMP = GLOBAL_METRICS.gauge("lsm_read_amp")
 # Keys staged into a state store by `ingest_batch`, by the form they arrive
-# in: `columnar` = a ColumnarSegment (StateTable.write_chunk_columns on an
-# all-INT64, ascending-pk table: no Python object per key from there to the
-# L0 run), `row` = a dict (insert / delete / update / write_chunk_rows, the
-# log store, source offsets). columnar / (columnar + row) is the share of a
+# in: `columnar` = a ColumnarSegment (StateTable.write_chunk_columns, any
+# schema: no Python object per key from there to the L0 run), `row` = a
+# dict (insert / delete / update / write_chunk_rows, a batch's NULL-pk
+# rows, the log store, source offsets). columnar / (columnar + row) is the share of a
 # checkpoint's keys on the columnar path.
 STATE_WRITE_KEYS = {
     columnar: GLOBAL_METRICS.counter(

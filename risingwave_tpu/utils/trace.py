@@ -311,8 +311,9 @@ class EpochTrace:
     # "agg_evict_groups" (live groups its watermark cleaning zeroed),
     # "agg_purges" (same-capacity rebuilds that dropped the zombies; only
     # where one ran) and, with a retractable MIN/MAX,
-    # "agg_extrema_lossy_groups"; any actor whose state tables took rows in
-    # row form adds "row_path_rows" (stream/monitor.py); one that holds a
+    # "agg_extrema_lossy_groups"; any actor whose chain holds a state table
+    # adds "row_path_rows" (the rows they took in row form, 0 where every
+    # write was a columnar batch: stream/monitor.py); one that holds a
     # sorted join adds "join_persist_delete_rows" /
     # "join_persist_insert_rows" (rows its durable flush wrote), from its
     # watchdog fetch "join_live_rows" / "join_capacity" (the fuller pool)
@@ -430,7 +431,7 @@ class EpochTrace:
                 if "agg_purges" in ph:
                     line += f", {ph['agg_purges']} zombie purge(s)"
                 line += "]"
-            if "row_path_rows" in ph:
+            if ph.get("row_path_rows"):
                 line += f" [{ph['row_path_rows']} state rows in row form]"
             if "join_persist_delete_rows" in ph:
                 line += (f" [join persisted -"

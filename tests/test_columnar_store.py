@@ -42,9 +42,9 @@ def i64_schema(n):
 
 
 def make_tables(store):
-    """Three batch-encodable tables of pk width 1, 2, 3 (the last a
-    singleton: vnode 0), one whose FLOAT64 column keeps it on rows, and
-    one more batch-encodable table whose id raw writes use as well."""
+    """Three all-INT64 tables of pk width 1, 2, 3 (the last a singleton:
+    vnode 0), one of mixed widths and kinds with a VARCHAR's int32 id in
+    its pk, and one more INT64 table whose id raw writes use as well."""
     return [
         StateTable(store, 11, i64_schema(3), (0,), check_consistency=False),
         StateTable(store, 12, i64_schema(4), (0, 1),
@@ -52,8 +52,10 @@ def make_tables(store):
         StateTable(store, 13, i64_schema(7), (0, 1, 2),
                    dist_key_indices=(), check_consistency=False),
         StateTable(store, 14,
-                   schema(("k", DataType.INT64), ("f", DataType.FLOAT64)),
-                   (0,), check_consistency=False),
+                   schema(("k", DataType.INT64), ("f", DataType.FLOAT64),
+                          ("s", DataType.VARCHAR), ("b", DataType.BOOLEAN),
+                          ("h", DataType.INT16)),
+                   (0, 2), check_consistency=False),
         StateTable(store, CONTENDED, i64_schema(2), (0,),
                    check_consistency=False),
     ]
@@ -146,7 +148,9 @@ class Driver:
     def row(self, t: StateTable):
         r = self.rng
         if t.table_id == 14:
-            return (int(r.integers(DOMAIN)), float(r.integers(100)) / 4)
+            return (int(r.integers(DOMAIN)), float(r.integers(-100, 100)) / 4,
+                    int(r.integers(DOMAIN)), bool(r.integers(2)),
+                    int(r.integers(-(1 << 15), 1 << 15)))
         return tuple(int(r.integers(DOMAIN)) if i in t.pk_indices
                      else int(r.integers(-(1 << 40), 1 << 40))
                      for i in range(len(t.schema)))
@@ -345,19 +349,43 @@ def test_random_interleavings_upload_the_dict_builders_bytes(seed):
 
 
 @needs_native
-def test_all_i64_tables_reach_l0_as_arrays_and_the_rest_as_lists():
+@pytest.mark.parametrize("row_form", ["insert_only", "null_pk"])
+def test_batch_written_tables_reach_l0_as_arrays_and_row_form_ones_as_lists(
+        row_form):
+    """Whatever its column types, a table written in batches is a FixedPart
+    of the run. A ListPart is what row-form writes alone leave (a table
+    that only ever sees `insert`), or a key of another width among a
+    table's arrays: the row a batch's NULL pk lane put in row form."""
     d = Driver(3)
     for t in d.tables * 3:
         d.op_columns(t)
+    lists = StateTable(
+        d.store, 17, schema(("k", DataType.INT64), ("s", DataType.VARCHAR)),
+        (0, 1), check_consistency=False)
+    lists.init_epoch(d.epoch)
+    d.tables.append(lists)
+    if row_form == "insert_only":
+        lists.insert((1, 2))
+    else:
+        lists.write_chunk_columns(
+            np.zeros(3, dtype=np.int8),
+            [np.arange(3), np.arange(3, dtype=np.int32)], np.ones(3, bool),
+            valids=[None, np.asarray([True, False, True])])
+        assert lists.row_path_rows == 1 and len(lists._mem) == 2
     d.commit_tables()
-    d.checkpoint()
+    batch = d.store.seal(d.epoch - 1)
+    d.store.upload_sealed(batch)
+    d.store.commit_sealed(batch)
     run = d.store._l0[0]
     kinds = {int.from_bytes(p.min_key[:4], "big"): type(p)
              for p in run.parts}
     assert kinds == {11: FixedPart, 12: FixedPart, 13: FixedPart,
-                     14: ListPart, CONTENDED: FixedPart}
+                     14: FixedPart, CONTENDED: FixedPart, 17: ListPart}
     assert [p.min_key for p in run.parts] \
         == sorted(p.min_key for p in run.parts)
+    assert sorted(r for _k, r in lists.iter_all()) == (
+        [(1, 2)] if row_form == "insert_only"
+        else [(0, 0), (1, None), (2, 2)])
 
 
 def test_memory_store_takes_columnar_batches():

@@ -7,33 +7,9 @@ import pytest
 
 from risingwave_tpu.common import DataType, schema
 from risingwave_tpu.common.vnode import crc32_numpy
-from risingwave_tpu.native import (
-    crc32_i64_batch, lib, mc_encode_i64_batch, row_encode_i64_batch,
-)
-from risingwave_tpu.state.serde import RowSerde, encode_memcomparable
+from risingwave_tpu.native import crc32_i64_batch, lib
 
 pytestmark = pytest.mark.skipif(lib() is None, reason="no C++ toolchain")
-
-
-def test_mc_encode_matches_python():
-    rng = np.random.default_rng(1)
-    vals = rng.integers(-(1 << 62), 1 << 62, size=(64, 3))
-    out = mc_encode_i64_batch(vals)
-    types = [DataType.INT64] * 3
-    for r in range(64):
-        want = encode_memcomparable(tuple(int(v) for v in vals[r]), types)
-        assert out[r].tobytes() == want
-
-
-def test_row_encode_matches_python():
-    sch = schema(("a", DataType.INT64), ("b", DataType.INT64))
-    serde = RowSerde(sch)
-    rng = np.random.default_rng(2)
-    vals = rng.integers(-(1 << 62), 1 << 62, size=(32, 2))
-    out = row_encode_i64_batch(vals, nb=serde._nbytes_nulls)
-    for r in range(32):
-        want = serde.encode(tuple(int(v) for v in vals[r]))
-        assert out[r].tobytes() == want
 
 
 def test_crc32_matches_numpy_and_device_table():
@@ -45,7 +21,7 @@ def test_crc32_matches_numpy_and_device_table():
     np.testing.assert_array_equal(got, want)
 
 
-def test_write_chunk_columns_native_equals_rows():
+def test_write_chunk_columns_equals_rows():
     from risingwave_tpu.state import MemoryStateStore, StateTable
     sch = schema(("k", DataType.INT64), ("v", DataType.INT64),
                  ("w", DataType.INT64))
@@ -142,8 +118,8 @@ def test_stale_artifact_is_rebuilt(tmp_path, monkeypatch):
     plant(lambda tmp: tmp.write_bytes(b"not an ELF object"))
     assert load().sst_pack_fixed is not None           # unloadable: rebuilt
     old = tmp_path / "old.cc"                          # an older source's build
-    old.write_text('extern "C" { void mc_encode_i64() {} }\n')
+    old.write_text('extern "C" { void crc32_i64_cols() {} }\n')
     plant(lambda tmp: subprocess.run(
         ["g++", "-shared", "-fPIC", "-o", str(tmp), str(old)], check=True))
     lib_ = load()
-    assert lib_.sst_pack_fixed is not None and lib_.row_encode_i64 is not None
+    assert lib_.sst_pack_fixed is not None and lib_.crc32_i64_cols is not None

@@ -21,7 +21,7 @@ import pytest
 from benchmark.harness import check, drive
 from benchmark.queries import q8
 from benchmark.reference import nexmark_q8
-from risingwave_tpu.common.types import GLOBAL_DICT
+from risingwave_tpu.common.types import GLOBAL_DICT, DataType
 from risingwave_tpu.connectors import nexmark as nx
 from risingwave_tpu.connectors.nexmark import NexmarkConfig, NexmarkGenerator
 from risingwave_tpu.frontend import Session
@@ -30,7 +30,9 @@ from risingwave_tpu.state import HummockStateStore, LocalFsObjectStore
 from risingwave_tpu.stream.hash_agg import (
     ZOMBIE_PURGE_MARK, HashAggExecutor)
 from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
-from risingwave_tpu.utils.metrics import GLOBAL_METRICS, HASH_AGG_PURGES
+from risingwave_tpu.stream.monitor import _state_tables_of
+from risingwave_tpu.utils.metrics import (
+    GLOBAL_METRICS, HASH_AGG_PURGES, STATE_WRITE_KEYS)
 from risingwave_tpu.utils.trace import SPAN_LOG
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -191,10 +193,14 @@ async def test_the_mv_is_the_oracles_names_as_text(bucket):
     assert all(c is not None for c in joins[0].clean_specs), \
         "both sides of the join are cleaned by the window's watermark"
     aggs = _aggs(s)
-    assert aggs["person"].state_table._all_i64 is False
-    assert aggs["auction"].state_table._all_i64 is True
+    # the name travels as its int32 id: a width of its own in the person
+    # side's keys and values, which the batch codec lays out like any other
+    assert DataType.VARCHAR in aggs["person"].state_table.schema.data_types
+    assert DataType.VARCHAR not in \
+        aggs["auction"].state_table.schema.data_types
     await s.tick(5)
     _assert_is_the_oracles(_read(s), _offsets(5), cfg)
+    assert aggs["person"].state_table.row_path_rows == 0
     assert s.recoveries == 0
     await s.drop_all()
 
@@ -255,16 +261,37 @@ async def test_a_table_whose_groups_all_die_is_purged_and_compiles_nothing():
 
 async def test_the_row_form_writes_are_counted_per_actor():
     """The person aggregate's table, the join's left table and the MV hold
-    the name's int32 id: their writes take the per-row path, and the phase
-    dict of a checkpoint says how many rows that was."""
+    the name's int32 id, and their writes are columnar segments all the
+    same: the batch codec takes any fixed-width schema. Every actor whose
+    chain holds a state table says how many rows took the row form in a
+    checkpoint, 0 included; what is left is a source's offset row."""
     cfg = _config()
     s = Session(store=None)
     await _deploy(s, cfg)
+    await s.tick(1)
+    tables = [t for ex in drive.executors_of(s, q8.MV)
+              for t in _state_tables_of(ex)]
+    with_name = [t for t in tables
+                 if DataType.VARCHAR in t.schema.data_types]
+    assert len(with_name) == 3
+    before = {t.table_id: t.row_path_rows for t in tables}
+    columnar0, row0 = (STATE_WRITE_KEYS[c].value for c in (True, False))
     await s.tick(3)
-    rows = [ph["row_path_rows"] for ph in s.coord.tracer._ring[-1]
-            .phases.values() if "row_path_rows" in ph]
-    # at least the checkpoint's 512 person groups in, as many out
-    assert sum(rows) >= 2 * PERSONS
+    for t in with_name:
+        assert t.row_path_rows == 0
+    # at least the checkpoint's 512 person groups in, as many out, and the
+    # same through the join and the MV: none of them a key of the row form
+    assert STATE_WRITE_KEYS[True].value - columnar0 >= 3 * 3 * 2 * PERSONS
+    in_rows = sum(t.row_path_rows - before[t.table_id] for t in tables)
+    assert STATE_WRITE_KEYS[False].value - row0 == in_rows <= 3 * 2
+    phases = s.coord.tracer._ring[-1].phases
+    rows = [ph["row_path_rows"] for ph in phases.values()
+            if "row_path_rows" in ph]
+    assert len(rows) >= 5 and rows.count(0) >= 3
+    assert sum(rows) == in_rows // 3
+    # the rendered trace names the row form only where it took a row
+    assert s.coord.tracer._ring[-1].render().count(
+        "state rows in row form") == len(rows) - rows.count(0) == 2
     await s.drop_all()
 
 
@@ -342,7 +369,7 @@ _WRITER = """
 import asyncio, json, sys
 sys.path.insert(0, {root!r})
 import risingwave_tpu
-from risingwave_tpu.common.types import GLOBAL_DICT
+from risingwave_tpu.common.types import GLOBAL_DICT, DataType
 # a string of this process alone, minted before any vocabulary: every
 # name's id is one higher than a fresh process would give it
 GLOBAL_DICT.get_or_insert("minted-by-the-writer-alone")
@@ -364,7 +391,7 @@ _READER = """
 import asyncio, json, sys
 sys.path.insert(0, {root!r})
 import risingwave_tpu
-from risingwave_tpu.common.types import GLOBAL_DICT
+from risingwave_tpu.common.types import GLOBAL_DICT, DataType
 assert len(GLOBAL_DICT) == 0
 from benchmark.harness import drive
 from benchmark.queries import q8
