@@ -214,18 +214,6 @@ def extrema_lossy_groups(state: tuple, row_count) -> jnp.ndarray:
     return jnp.sum((state[2] & (row_count > 0)).astype(jnp.int32))
 
 
-def extrema_gather(state: tuple, sel, tgt, C_new: int, K: int, dtype):
-    """Rehash support: move group g's buffers via compaction select `sel`
-    and scatter to `tgt` (same contract as the scalar agg states)."""
-    vals, cnts, lossy = state
-    e_vals = jnp.zeros((C_new, K), dtype=dtype)
-    e_cnts = jnp.zeros((C_new, K), dtype=jnp.int32)
-    e_lossy = jnp.zeros(C_new, dtype=bool)
-    return (e_vals.at[tgt].set(vals[sel], mode="drop"),
-            e_cnts.at[tgt].set(cnts[sel], mode="drop"),
-            e_lossy.at[tgt].set(lossy[sel], mode="drop"))
-
-
 def extrema_mask_keep(state: tuple, keep) -> tuple:
     """Watermark eviction: zero the buffers of evicted groups."""
     vals, cnts, lossy = state

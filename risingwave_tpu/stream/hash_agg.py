@@ -40,15 +40,14 @@ from ..common.chunk import (
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall, AggKind
 from ..ops.extrema import (
-    extrema_emit, extrema_empty, extrema_gather, extrema_lossy_groups,
+    extrema_emit, extrema_empty, extrema_lossy_groups,
     extrema_mask_keep, extrema_underflow, extrema_update,
 )
 from ..memory.accounting import pytree_bytes
 from ..memory.spill import HostSpill
 from ..ops.hash_table import (
-    BUCKET_SLOTS, HashTable, compact_mask, lookup_or_insert,
-    lookup_or_insert_counted, lru_stamp,
-    needs_rebuild,
+    BUCKET_SLOTS, HashTable, compact_mask, lookup_or_insert_counted,
+    lru_stamp, needs_rebuild,
 )
 from ..ops.jit_state import jit_state
 from ..state.state_table import StateTable
@@ -57,7 +56,7 @@ from ..utils.trace import span
 from ..utils.metrics import (
     GLOBAL_METRICS, HASH_AGG_EMIT_ROWS, HASH_AGG_EVICT_GROUPS,
     HASH_AGG_EXTREMA_ERRORS, HASH_AGG_EXTREMA_LOSSY_GROUPS, HASH_AGG_PURGES,
-    HASH_PROBE_FALLBACK_ROWS,
+    HASH_AGG_REHASH_ROWS, HASH_PROBE_FALLBACK_ROWS,
 )
 from .executor import Executor
 from .message import Barrier, BarrierKind, Watermark
@@ -80,13 +79,21 @@ FLUSH_MIN_SLOTS = 128
 # bucket by the tail of its arrivals in one bucket, long before the mean
 # fill is near 16 (32,768 fresh keys into 2^19 slots: first unplaced rows
 # between 0.62 and 0.69 full, measured; memory_maintain's `_mem_cap_for`
-# keeps to the same 0.35 for the same reason). A purge costs the same
-# whenever it comes, an overflow costs the epoch.
+# keeps to the same 0.35 for the same reason). A purge costs what
+# survives it (`_rehash_keep`), an overflow costs the epoch.
 ZOMBIE_PURGE_MARK = 0.35
 
 # rows one call of the recovery's replay program takes (never more than
 # the table has slots)
 RECOVER_BATCH = 1 << 14
+
+# survivors one step of a rebuild's loop re-inserts (never more than the
+# old table has slots): a rebuild costs a step per block of survivors, and
+# no step is as wide as the table. On the chip a step of 2^12 takes 7.1 ms
+# and one of 2^14 18.5 ms (1,824 survivors of 2^19 slots: one step either
+# way), and 180,000 survivors of 2^20 slots take 0.207 s in 44 steps and
+# 0.221 s in 11 (PERF.md section 6, PR 43)
+REHASH_BLOCK = 1 << 12
 
 
 @jax.tree_util.register_pytree_node_class
@@ -323,8 +330,9 @@ class HashAggExecutor(Executor):
         """This barrier interval's `agg_emit_rows` and `agg_evict_groups`
         (and, with a retractable MIN/MAX, `agg_extrema_lossy_groups`) for
         the actor's phase dict: host numbers the watchdog fetch brought,
-        absent where it made none; `agg_purges` where the barrier purged
-        the table's zombies."""
+        absent where it made none; `agg_rehash_rows` (the groups it
+        re-inserted) where the barrier rebuilt the table, and `agg_purges`
+        where that dropped the zombies at the table's own capacity."""
         counts, self._phase_counts = self._phase_counts, {}
         return counts
 
@@ -533,46 +541,66 @@ class HashAggExecutor(Executor):
 
     def _rehash_impl(self, state: AggState, new_capacity: int) -> AggState:
         """Device-side rebuild: re-insert surviving groups into a fresh
-        table of `new_capacity` slots. Pure XLA — no host roundtrip; only a
-        capacity CHANGE triggers a recompile (distinct static shape)."""
+        table of `new_capacity` slots, a block at a time. Pure XLA — no
+        host roundtrip; only a capacity CHANGE triggers a recompile
+        (distinct static shape)."""
         keep = state.table.occupied & (
             (state.row_count > 0) | (state.dirty & state.prev_exists))
-        return self._rehash_keep(state, keep, new_capacity)
+        return self._rehash_keep(state, keep, new_capacity)[0]
 
     def _rehash_keep(self, state: AggState, keep: jnp.ndarray,
-                     new_capacity: int) -> AggState:
-        """Shared rebuild body: re-insert exactly the `keep` slots into a
-        fresh table (growth/purge keeps all survivors; memory eviction
-        additionally drops the cold groups)."""
-        fresh = HashTable.empty(new_capacity, self._key_dtypes)
-        # compact surviving entries to the front so insertion order is dense
-        C = state.table.capacity
-        sel, n_keep = compact_mask(keep)
-        active = jnp.arange(C) < n_keep
-        key_cols = [tk[sel] for tk in state.table.keys]
-        table, slots, n_un = lookup_or_insert(fresh, key_cols, active)
-        # n_un must be 0 by construction (new_capacity >= live set)
-        tgt = jnp.where(active, slots, new_capacity)
-        empty = self._empty_state(new_capacity)
-        def gather_call(j, os):
-            if self._retractable[j]:
-                return extrema_gather(os, sel, tgt, new_capacity,
-                                      self.minput_k,
-                                      self.specs[j].state_dtype)
-            return empty.agg_states[j].at[tgt].set(os[sel], mode="drop")
+                     new_capacity: int):
+        """Shared rebuild body: exactly `state`'s `keep` slots in a fresh
+        state of `new_capacity` (growth/purge keeps all survivors; memory
+        eviction additionally drops the cold groups), and the survivors no
+        slot was found for: none up to 0.69 of the new table in
+        tests/test_hash_agg_rehash.py, where ONE block leaves some at
+        half; a barrier's rebuild asks for half at most and fail-stops on
+        any.
 
-        return AggState(
-            table=table,
-            agg_states=tuple(
-                gather_call(j, os)
-                for j, os in enumerate(state.agg_states)),
-            row_count=empty.row_count.at[tgt].set(state.row_count[sel], mode="drop"),
-            dirty=empty.dirty.at[tgt].set(state.dirty[sel], mode="drop"),
-            prev_exists=empty.prev_exists.at[tgt].set(state.prev_exists[sel], mode="drop"),
-            prev_emit=tuple(
-                ep.at[tgt].set(op[sel], mode="drop")
-                for ep, op in zip(empty.prev_emit, state.prev_emit)),
-        )
+        The survivors are compacted to the front of ONE capacity-wide
+        index lane and re-inserted REHASH_BLOCK at a time by a loop whose
+        trip count the device takes from its own survivor count: a step
+        gathers a block's keys and lanes from the old state, inserts the
+        keys into the carried table and writes the lanes at their slots
+        (`_write_rows`, the replay's and the reload's step). What a
+        rebuild costs follows what survives it, whatever the capacities:
+        the compaction and the fresh state's fills are all that is as wide
+        as a table. Each block is placed by the bucket fills the blocks
+        before it left (`lookup_or_insert` reads them once a call), so the
+        survivors spread over their two buckets as a stream of chunks
+        would, not as one chunk of fresh keys (ZOMBIE_PURGE_MARK)."""
+        C = state.table.capacity
+        W = min(C, REHASH_BLOCK)
+        sel, n_keep = compact_mask(keep)
+        if C % W:
+            # a whole last block: a slice never starts where it would end
+            # past the lane
+            sel = jnp.pad(sel, (0, W - C % W))
+        lanes = (state.agg_states, state.row_count, state.dirty,
+                 state.prev_exists, state.prev_emit)
+
+        def block(carry):
+            i, fresh, n_un = carry
+            at = i * W
+            blk = jax.lax.dynamic_slice(sel, (at,), (W,))
+            active = at + jnp.arange(W, dtype=jnp.int32) < n_keep
+            fresh, un, _ = self._write_rows(
+                fresh, [tk[blk] for tk in state.table.keys], active,
+                *jax.tree_util.tree_map(lambda lane: lane[blk], lanes))
+            return i + 1, fresh, n_un + un.astype(jnp.int32)
+
+        # inside a shard_map a loop's carry keeps its type: what is the
+        # same on every shard (the empty state, the counters) enters the
+        # loop as per-shard as the survivors make it
+        per_shard = jax.typeof(n_keep).vma
+        init = jax.tree_util.tree_map(
+            lambda x: jax.lax.pcast(
+                x, tuple(per_shard - jax.typeof(x).vma), to="varying"),
+            (jnp.int32(0), self._empty_state(new_capacity), jnp.int32(0)))
+        _, fresh, n_un = jax.lax.while_loop(
+            lambda carry: carry[0] * W < n_keep, block, init)
+        return fresh, n_un
 
     # --------------------------------------------------------- rebuild
     def _rehash_to(self, new_capacity: int) -> None:
@@ -605,13 +633,13 @@ class HashAggExecutor(Executor):
         On a worker thread, started at the INITIAL barrier and awaited at
         the end of the first barrier after it (`execute`), not on the way:
         no purge is due before an interval has run, and a recovery does
-        not hold its replay for the two largest executables of the
-        operator, which it will not call for many checkpoints. Only this
-        pair: executables loaded side by side on the v5e's host each took
-        three to four times as long (PERF.md section 6, PR 40), so the
-        replay and the first apply stay where they are asked for, on the
-        loop. Returns the task, or None where there is nothing to
-        compile."""
+        not hold its replay for two executables it will not call for many
+        checkpoints (the rehash is an insert of REHASH_BLOCK rows in a
+        loop, as large as the replay's program). Only this pair:
+        executables loaded side by side on the v5e's host each took three
+        to four times as long (PERF.md section 6, PR 40), so the replay
+        and the first apply stay where they are asked for, on the loop.
+        Returns the task, or None where there is nothing to compile."""
         if self.cleaning_watermark_key is None or not self.watchdog_interval:
             return None
         state, capacity = self.state, self.capacity
@@ -711,8 +739,12 @@ class HashAggExecutor(Executor):
         come (a DISTINCT per window under a watermark) never reach 0.7, and
         no chunk of fresh keys meets a crowded table. The purge is the span
         `agg.purge`: the rehash's dispatch and the awaited readback of the
-        rebuilt occupancy (the wait is for the device to finish the
-        rehash; the loop is not held)."""
+        rebuilt occupancy (the wait is for the device to REACH the rehash
+        behind the barrier's other programs — the rehash itself costs a
+        block insert per REHASH_BLOCK survivors —; the loop is not held).
+        A rebuild that placed fewer groups than were alive fail-stops the
+        epoch, and one that ran says how many it re-inserted
+        (`agg_rehash_rows`, `hash_agg_rehash_rows_total`)."""
         occ, cap = self._occ_known, self.capacity
         grow_mark = occ > 0.7 * cap
         if not grow_mark and occ + self._claim_peak <= ZOMBIE_PURGE_MARK * cap:
@@ -740,12 +772,22 @@ class HashAggExecutor(Executor):
             self._rehash_to(new_cap)
             self._occ_known = int((await off_loop(
                 fetch_small, self._live_zombie(self.state)))[0])
+        if self._occ_known < live:
+            # after the barrier's flush and evict the survivors ARE the
+            # live groups: fewer slots occupied is a group the rebuild
+            # found no slot for, and its state is gone
+            raise RuntimeError(
+                f"hash-agg rebuild placed {self._occ_known} of {live} "
+                f"groups (capacity {new_cap}); recovery must replay the "
+                f"epoch with a larger table")
+        label = self.mem_name or self.identity
+        # the groups the rebuild re-inserted: what it cost
+        GLOBAL_METRICS.counter(HASH_AGG_REHASH_ROWS, executor=label).inc(live)
+        counts = self._phase_counts
+        counts["agg_rehash_rows"] = counts.get("agg_rehash_rows", 0) + live
         if new_cap == cap:
-            GLOBAL_METRICS.counter(
-                HASH_AGG_PURGES,
-                executor=self.mem_name or self.identity).inc()
-            self._phase_counts["agg_purges"] = (
-                self._phase_counts.get("agg_purges", 0) + 1)
+            GLOBAL_METRICS.counter(HASH_AGG_PURGES, executor=label).inc()
+            counts["agg_purges"] = counts.get("agg_purges", 0) + 1
 
     # ------------------------------------------------- HBM memory manager
     def state_bytes(self) -> int:
@@ -797,7 +839,7 @@ class HashAggExecutor(Executor):
         keep = (state.table.occupied
                 & ((state.row_count > 0) | (state.dirty & state.prev_exists))
                 & ~drop)
-        return self._rehash_keep(state, keep, new_capacity)
+        return self._rehash_keep(state, keep, new_capacity)[0]
 
     def _mem_fetch_stats(self, epoch: int):
         """(live mask, stamps, cold stamps asc, this-interval touch count)
@@ -1019,34 +1061,38 @@ class HashAggExecutor(Executor):
         as already emitted (`prev_exists`, `prev_emit` of its state), marked
         `dirty` or not: (state', rows no slot was found for, probe
         fallbacks)."""
+        ones = jnp.ones(active.shape[0], dtype=bool)
+        return self._write_rows(
+            state, key_cols, active,
+            tuple(cs if self._retractable[j]
+                  else cs.astype(state.agg_states[j].dtype)
+                  for j, cs in enumerate(call_cols)),
+            row_count, ones if dirty else None, ones,
+            tuple(self._call_emit(j, cs) for j, cs in enumerate(call_cols)))
+
+    def _write_rows(self, state: AggState, key_cols, active, agg_states,
+                    row_count, dirty, prev_exists, prev_emit):
+        """Find or claim a slot of `state`'s table for each active row's
+        key and write the row of every lane there (`agg_states` shaped as
+        the state's, a row a group; `dirty` None leaves that lane as it
+        is): (state', rows no slot was found for, probe fallbacks). Every
+        op is as wide as the rows, none as the table."""
         table, slots, n_un, n_fb = lookup_or_insert_counted(
             state.table, key_cols, active)
-        C = table.capacity
-        ok = active & (slots >= 0)
-        tgt = jnp.where(ok, slots, C)
-        agg_states, prev_emit = [], []
-        for j in range(len(self.specs)):
-            cs = call_cols[j]
-            if self._retractable[j]:
-                vals_b, cnts_b, lossy_b = cs
-                e_vals, e_cnts, e_lossy = state.agg_states[j]
-                agg_states.append((
-                    e_vals.at[tgt].set(vals_b, mode="drop"),
-                    e_cnts.at[tgt].set(cnts_b, mode="drop"),
-                    e_lossy.at[tgt].set(lossy_b, mode="drop")))
-            else:
-                agg_states.append(state.agg_states[j].at[tgt].set(
-                    cs.astype(state.agg_states[j].dtype), mode="drop"))
-            prev_emit.append(state.prev_emit[j].at[tgt].set(
-                self._call_emit(j, cs), mode="drop"))
+        tgt = jnp.where(active & (slots >= 0), slots, table.capacity)
+
+        def put(lane, rows):
+            return lane.at[tgt].set(rows, mode="drop")
+
         return AggState(
             table=table,
-            agg_states=tuple(agg_states),
-            row_count=state.row_count.at[tgt].set(row_count, mode="drop"),
-            dirty=(state.dirty.at[tgt].set(True, mode="drop") if dirty
-                   else state.dirty),
-            prev_exists=state.prev_exists.at[tgt].set(True, mode="drop"),
-            prev_emit=tuple(prev_emit),
+            agg_states=jax.tree_util.tree_map(put, state.agg_states,
+                                              tuple(agg_states)),
+            row_count=put(state.row_count, row_count),
+            dirty=state.dirty if dirty is None else put(state.dirty, dirty),
+            prev_exists=put(state.prev_exists, prev_exists),
+            prev_emit=jax.tree_util.tree_map(put, state.prev_emit,
+                                             tuple(prev_emit)),
         ), n_un, n_fb
 
     def _mem_reload_impl(self, state: AggState, overflow, key_cols,

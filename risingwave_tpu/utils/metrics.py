@@ -305,9 +305,13 @@ HASH_PROBE_FALLBACK_ROWS = GLOBAL_METRICS.counter(
 #   watermark cleaning zeroed (they stay as zombie slots until a purge).
 # - `hash_agg_purges_total{executor}`: same-capacity rebuilds that dropped
 #   the zombies (`_maybe_rebuild_at_barrier`; not from the fetch).
+# - `hash_agg_rehash_rows_total{executor}`: groups the barrier's rebuilds
+#   (purges and growths) re-inserted, which is what a rebuild costs; the
+#   live count of the fetch that decided the rebuild.
 HASH_AGG_EMIT_ROWS = "hash_agg_emit_rows_total"
 HASH_AGG_EVICT_GROUPS = "hash_agg_evict_groups_total"
 HASH_AGG_PURGES = "hash_agg_purges_total"
+HASH_AGG_REHASH_ROWS = "hash_agg_rehash_rows_total"
 HASH_AGG_EXTREMA_LOSSY_GROUPS = "hash_agg_extrema_lossy_groups"
 HASH_AGG_EXTREMA_ERRORS = "hash_agg_extrema_errors_total"
 

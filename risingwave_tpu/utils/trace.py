@@ -310,11 +310,12 @@ class EpochTrace:
     # "agg_emit_rows" (rows its barrier flush sent downstream),
     # "agg_evict_groups" (live groups its watermark cleaning zeroed),
     # "agg_purges" (same-capacity rebuilds that dropped the zombies; only
-    # where one ran) and, with a retractable MIN/MAX,
-    # "agg_extrema_lossy_groups"; any actor whose chain holds a state table
-    # adds "row_path_rows" (the rows they took in row form, 0 where every
-    # write was a columnar batch: stream/monitor.py); one that holds a
-    # sorted join adds "join_persist_delete_rows" /
+    # where one ran), "agg_rehash_rows" (the groups a rebuild, purge or
+    # growth, re-inserted; only where one ran) and, with a retractable
+    # MIN/MAX, "agg_extrema_lossy_groups"; any actor whose chain holds a
+    # state table adds "row_path_rows" (the rows they took in row form, 0
+    # where every write was a columnar batch: stream/monitor.py); one that
+    # holds a sorted join adds "join_persist_delete_rows" /
     # "join_persist_insert_rows" (rows its durable flush wrote), from its
     # watchdog fetch "join_live_rows" / "join_capacity" (the fuller pool)
     # and, on one chip, "join_match_rows" (rows its applies emitted) and
@@ -430,6 +431,8 @@ class EpochTrace:
                     line += f", evicted {ph['agg_evict_groups']} groups"
                 if "agg_purges" in ph:
                     line += f", {ph['agg_purges']} zombie purge(s)"
+                if "agg_rehash_rows" in ph:
+                    line += f", rehashed {ph['agg_rehash_rows']} groups"
                 line += "]"
             if ph.get("row_path_rows"):
                 line += f" [{ph['row_path_rows']} state rows in row form]"

@@ -578,7 +578,8 @@ async def test_one_device_q7_has_three_phase_keys_and_no_mesh_counts(
             # agg's and the join's actors the row counts of PRs 30, 34 and
             # 40 (utils/trace.py); nothing of the mesh
             assert set(p) - {"agg_emit_rows", "agg_evict_groups",
-                             "agg_purges", "row_path_rows",
+                             "agg_purges", "agg_rehash_rows",
+                             "row_path_rows",
                              "join_persist_delete_rows",
                              "join_persist_insert_rows", "join_live_rows",
                              "join_capacity", "join_match_rows",

@@ -32,7 +32,7 @@ from risingwave_tpu.stream.hash_agg import (
 from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
 from risingwave_tpu.stream.monitor import _state_tables_of
 from risingwave_tpu.utils.metrics import (
-    GLOBAL_METRICS, HASH_AGG_PURGES, STATE_WRITE_KEYS)
+    GLOBAL_METRICS, HASH_AGG_PURGES, HASH_AGG_REHASH_ROWS, STATE_WRITE_KEYS)
 from risingwave_tpu.utils.trace import SPAN_LOG
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -256,6 +256,50 @@ async def test_a_table_whose_groups_all_die_is_purged_and_compiles_nothing():
         if name == HASH_AGG_PURGES)
     assert label_purges >= purges
     _assert_is_the_oracles(_read(s), _offsets(13), cfg)
+    await s.drop_all()
+
+
+async def test_a_rebuild_says_how_many_groups_it_reinserted():
+    """What a rebuild costs is the groups that survive it. The actor's
+    phase dict carries `agg_rehash_rows` at a barrier that rebuilt a table
+    and at no other: the live groups of the fetch that decided the purge,
+    which is what the rebuilt table then holds; the rendered trace prints
+    it beside the purge, and `hash_agg_rehash_rows_total` moves by it."""
+    cfg = _config()
+    s = Session()
+    await _deploy(s, cfg)
+    await s.tick(1)
+    aggs = _aggs(s)
+
+    def series() -> float:
+        return sum(c.value for (name, _l), c in GLOBAL_METRICS.counters.items()
+                   if name == HASH_AGG_REHASH_ROWS)
+
+    start, rehashed, rebuilds = series(), 0, 0
+    for _ in range(12):
+        built = {name: agg.rebuilds for name, agg in aggs.items()}
+        await s.tick(1)
+        tr = s.coord.tracer._ring[-1]
+        rebuilt = [agg for name, agg in aggs.items()
+                   if agg.rebuilds > built[name]]
+        with_rows = [ph for ph in tr.phases.values()
+                     if "agg_rehash_rows" in ph]
+        assert len(with_rows) == len(rebuilt)
+        assert [ph for ph in tr.phases.values() if "agg_purges" in ph] \
+            == with_rows, "every rebuild here is a same-capacity purge"
+        # each aggregate is its actor's only one: the count is its table's
+        assert sorted(ph["agg_rehash_rows"] for ph in with_rows) \
+            == sorted(agg._occ_known for agg in rebuilt)
+        for ph in with_rows:
+            assert 0 < ph["agg_rehash_rows"] <= 2 * PERSONS
+            assert (f"zombie purge(s), rehashed {ph['agg_rehash_rows']} "
+                    f"groups]") in tr.render()
+        if not with_rows:
+            assert "rehashed" not in tr.render()
+        rehashed += sum(ph["agg_rehash_rows"] for ph in with_rows)
+        rebuilds += len(rebuilt)
+    assert rebuilds >= 2 and series() - start == rehashed
+    assert s.recoveries == 0
     await s.drop_all()
 
 

@@ -400,8 +400,8 @@ def test_q8_published_programs(q8_executors, one_chip, no_persistent_cache,
     """`q8.sat` on one chip: the person aggregate's table is keyed by (id,
     name, window_start, window_end) with the name an int32 dictionary id
     beside three int64 — its apply, the same-capacity rehash that purges its
-    zombies (compiled at the first barrier, `_precompile_purge`), the
-    watchdog pack that now counts the live groups and those the cleaning
+    zombies (a loop over blocks of survivors; compiled at the first
+    barrier, `_precompile_purge`), the watchdog pack that now counts the live groups and those the cleaning
     watermark is about to evict, the evict keys and the persist view — and
     the join's apply of that side, whose rows carry the name too."""
     from risingwave_tpu.stream.align import LEFT
@@ -436,6 +436,31 @@ def test_q8_published_programs(q8_executors, one_chip, no_persistent_cache,
             side=LEFT, match_factor=join.match_factors[LEFT]),
     }[program]().compile()
     fits_one_chip(compiled)
+
+
+def test_the_rehash_sorts_blocks_of_survivors_not_the_table(
+        q8_executors, one_chip):
+    """The person aggregate's purge at the cell's width, 2^19 slots,
+    lowered for the chip (nothing is compiled): every sort of
+    `hash_agg_rehash` is inside the loop over blocks of survivors and
+    REHASH_BLOCK rows wide. Before PR 43 the survivors were ONE chunk as
+    wide as the table: eight 2^19-row sorts for ~2,000 survivors, 0.6 s a
+    purge on the chip."""
+    import re
+
+    from risingwave_tpu.stream.hash_agg import REHASH_BLOCK, HashAggExecutor
+    person, = [ex for ex in q8_executors if isinstance(ex, HashAggExecutor)
+               and len(ex.group_key_indices) == 4]
+    C = 1 << 19
+    state = abstract(jax.eval_shape(lambda: person._empty_state(C)),
+                     one_chip)
+    hlo = person._rehash._jitted.lower(state, C).compiler_ir(
+        dialect="hlo").as_hlo_text()
+    sorts = [line for line in hlo.splitlines() if " sort(" in line]
+    rows = {int(n) for line in sorts
+            for n in re.findall(r"\[(\d+)\]", line.split(" sort(")[0])}
+    assert sorts and rows == {REHASH_BLOCK} and REHASH_BLOCK < C
+    assert " while(" in hlo
 
 
 @pytest.fixture(scope="module")
@@ -635,6 +660,22 @@ def test_fused_sharded_agg_on_the_4_device_mesh(q7_mesh_executors, mesh4,
     ov = jax.ShapeDtypeStruct((4, 2), jnp.int32, sharding=sharded)
     obs = jax.ShapeDtypeStruct((4, 2), jnp.int32, sharding=sharded)
     ex._watchdog_pack._jitted.lower(ov, i64, i32, obs).compile()
+
+
+def test_sharded_agg_purge_on_the_4_device_mesh(q7_mesh_executors, mesh4,
+                                                no_persistent_cache):
+    """The mesh agg's zombie purge on the four described chips: the
+    rehash's loop over blocks of survivors inside `shard_map`, a trip
+    count per shard and no collective in it."""
+    from risingwave_tpu.parallel.mesh import VNODE_AXIS
+    ex = q7_mesh_executors["ShardedHashAggExecutor"]
+    sharded = NamedSharding(mesh4, P(VNODE_AXIS))
+    compiled = ex._purge._jitted.lower(abstract(ex.state, sharded)).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    assert not any(op in text for op in ("all-reduce", "all-to-all",
+                                         "all-gather", "collective-permute"))
+    fits_one_chip(compiled)
 
 
 def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
