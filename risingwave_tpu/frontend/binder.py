@@ -396,12 +396,6 @@ class StreamPlanner:
                 clean_specs=(clean_l, clean_r),
                 mesh_devices=self.cfg(
                     "streaming_parallelism_devices", 1),
-                mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
-                mesh_shuffle_slack=self.cfg(
-                    "streaming_mesh_shuffle_slack", 0),
-                mesh_shuffle_adaptive=self.cfg(
-                    "streaming_mesh_shuffle_adaptive", 1),
-                mesh_chain=self.cfg("streaming_mesh_chain", 1),
                 watchdog_interval=wd,
                 durable=self.durable()),
                 inputs=(Exchange(lf), Exchange(rf)))
@@ -1351,12 +1345,6 @@ class StreamPlanner:
             # host-ordered)
             ow_args.update(
                 mesh_devices=self.cfg("streaming_parallelism_devices", 1),
-                mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
-                mesh_shuffle_slack=self.cfg(
-                    "streaming_mesh_shuffle_slack", 0),
-                mesh_shuffle_adaptive=self.cfg(
-                    "streaming_mesh_shuffle_adaptive", 1),
-                mesh_chain=self.cfg("streaming_mesh_chain", 1),
                 watchdog_interval=(
                     1 if self.cfg("streaming_watchdog", 1) else None))
         frag.root = Node(
@@ -1456,12 +1444,6 @@ class StreamPlanner:
                 pk_indices=list(pk_hint),
                 capacity=self.cfg("streaming_top_n_capacity", 1 << 14),
                 mesh_devices=md,
-                mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
-                mesh_shuffle_slack=self.cfg(
-                    "streaming_mesh_shuffle_slack", 0),
-                mesh_shuffle_adaptive=self.cfg(
-                    "streaming_mesh_shuffle_adaptive", 1),
-                mesh_chain=self.cfg("streaming_mesh_chain", 1),
                 watchdog_interval=wd),
             inputs=(Exchange(fid),)), dispatch="simple"))
         # ranks can change retroactively: no watermark survives a TopN
@@ -1636,12 +1618,6 @@ class StreamPlanner:
                     cleaning_watermark_col=(wm_keys[0] if wm_keys
                                             else None),
                     mesh_devices=md,
-                    mesh_shuffle=self.cfg("streaming_mesh_shuffle", 1),
-                    mesh_shuffle_slack=self.cfg(
-                        "streaming_mesh_shuffle_slack", 0),
-                    mesh_shuffle_adaptive=self.cfg(
-                        "streaming_mesh_shuffle_adaptive", 1),
-                    mesh_chain=self.cfg("streaming_mesh_chain", 1),
                     watchdog_interval=wd),
                 inputs=(Exchange(fid),)),
                 dispatch="hash",

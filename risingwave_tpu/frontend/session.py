@@ -122,34 +122,6 @@ class Session:
         # analogue of the reference's parallel-unit placement
         # (meta/src/stream/stream_graph/schedule.rs)
         "streaming_parallelism_devices": (1, int),
-        # 1 (default): mesh fragments run the FUSED data plane — the
-        # exchange into a sharded agg/join is an in-program
-        # lax.all_to_all over ICI (parallel/exchange.mesh_ingest_chunk),
-        # one shard_map program per barrier interval. 0 restores the
-        # replicated-chunk + per-shard-mask plane.
-        "streaming_mesh_shuffle": (1, int),
-        # per-(src,dst) send-bucket sizing for the fused shuffle: 0 =
-        # zero-drop (bucket = the full per-shard slice, overflow
-        # impossible under any key skew); k > 0 = k * ceil(slice/shards)
-        # — near-linear per-shard compute for balanced keys, with
-        # on-device overflow counting that FAIL-STOPS the epoch
-        # (mesh_shuffle_dropped_rows_total) if the skew beats the slack
-        "streaming_mesh_shuffle_slack": (0, int),
-        # 1 (default): when the manual slack is 0, send-bucket sizing
-        # ADAPTS to the observed per-shard receive demand (EWMA + peak,
-        # refreshed at each barrier watchdog fetch, 2x pow2 headroom) —
-        # zero-drop sizing until enough intervals are observed, fail-stop
-        # overflow semantics unchanged. 0 pins zero-drop sizing.
-        "streaming_mesh_shuffle_adaptive": (1, int),
-        # 1 (default): fuse eligible producer->shuffle->consumer CHAINS
-        # onto the mesh (plan/build._fuse_mesh_chains): stateless
-        # producer stages (project / hop_window over a source) hollow out
-        # and run INSIDE the downstream sharded executor's fused program
-        # — zero host hops per steady barrier interval
-        # (mesh_host_round_trips_total{chain} == 0). 0 keeps eligible
-        # chains on the per-chunk host plane (counter still runs — the
-        # unfused baseline scripts/mesh_profile.py compares against).
-        "streaming_mesh_chain": (1, int),
         "streaming_over_window_capacity": (1 << 14, int),
         "streaming_top_n_capacity": (1 << 14, int),
         "streaming_dynamic_filter_capacity": (1 << 14, int),
@@ -219,23 +191,6 @@ class Session:
         # than this logs format_stuck_barrier_report once and bumps
         # barrier_stalls_total; 0 disables the watchdog
         "barrier_stall_threshold_ms": (60000, int),
-        # ---- metrics history (utils/metrics_history.py) ----
-        # sample the allowlisted series every N collected barriers into
-        # bounded per-series rings (the rw_metrics system table + the
-        # autoscaler's time-series substrate). 0 disables sampling.
-        "metrics_history_interval": (1, int),
-        # newest samples kept per series at full resolution; the same
-        # count again survives downsampled (every k-th evicted sample)
-        "metrics_history_retention": (512, int),
-        # coarse-tier keep ratio: 1 of every k evicted samples survives
-        "metrics_history_downsample": (8, int),
-        # comma-separated series allowlist; '' = the built-in default
-        # (barrier latency, exchange pressure, source lag, HBM, ...)
-        "metrics_history_series": ("", str),
-        # 1 = also append each pulse to a crc-framed log next to the
-        # event log (subdir "metrics", torn-tail framing) so rw_metrics
-        # history survives a restart; 0 (default) = ring only
-        "metrics_history_durable": (0, int),
         # 1 (default): exchange channels buffer the uncommitted message
         # suffix (trimmed at every checkpoint commit) and an actor
         # failure whose blast radius is contained to ONE terminal
@@ -452,15 +407,6 @@ class Session:
         self.coord.scrubber.event_log = self.event_log
         self.coord.logstore.event_log = self.event_log
         # metrics history: session-owned store, coordinator-paced pulse
-        objects = getattr(self.store, "objects", None)
-        durable = bool(self.config.get("metrics_history_durable", 0))
-        root = getattr(objects, "root", None) if durable else None
-        self.metrics_history.configure(
-            interval=self.config.get("metrics_history_interval", 1),
-            retention=self.config.get("metrics_history_retention", 512),
-            downsample=self.config.get("metrics_history_downsample", 8),
-            series=self.config.get("metrics_history_series", ""),
-            root=root)
         self.coord.metrics_history = self.metrics_history
 
     def _apply_logstore_config(self) -> None:
@@ -784,12 +730,7 @@ class Session:
                 # runtime-mutable on the live ServingManager/pool
                 self._apply_serving_config()
             elif stmt.name in ("metric_level",
-                               "barrier_stall_threshold_ms",
-                               "metrics_history_interval",
-                               "metrics_history_retention",
-                               "metrics_history_downsample",
-                               "metrics_history_series",
-                               "metrics_history_durable"):
+                               "barrier_stall_threshold_ms"):
                 # runtime-mutable: re-instruments live actors / adjusts
                 # the stuck-barrier watchdog (cluster-wide when attached)
                 self._apply_obs_config()
@@ -2029,7 +1970,8 @@ class Session:
                 for root in dep.roots.get(fid, []):
                     node = root
                     while node is not None:
-                        if hasattr(node, "preload_replay"):
+                        if callable(getattr(node, "preload_replay",
+                                            None)):
                             return node
                         node = getattr(node, "input", None)
                 return None

@@ -212,18 +212,15 @@ class BarrierCoordinator:
         # collection (one entry in EpochState.remaining, one fence on the
         # sharded state — a collective boundary), where the host-exchange
         # alternative is S actors = S collections + S per-device fences
-        # per epoch. The registry makes that legible to /healthz, tests
-        # and the mesh_profile gate.
+        # per epoch. The registry makes that legible to /healthz and tests.
         self.mesh_fragments: dict[int, tuple[int, str]] = {}
         self._mesh_shuffle_labels: dict[int, tuple] = {}
         # ---- fused mesh CHAINS (plan/build.py _fuse_mesh_chains) ----
-        # chain label -> {"fids": (producer..., consumer), "hollow": bool,
+        # chain label -> {"fids": (producer..., consumer),
         # "consumer_actor": id}. A chain spans MULTIPLE fragments whose
         # producer stages were hollowed into the consumer's fused program:
         # one epoch fence covers the whole chain (hollow producers are
-        # fence-exempt — they dispatch no device programs), and the
-        # mesh_host_round_trips_total{chain} counter asserts the
-        # zero-host-hop claim per interval.
+        # fence-exempt — they dispatch no device programs).
         self.mesh_chains: dict[str, dict] = {}
         # ---- cluster mode (cluster/meta_service.py) ----
         # worker_id -> WorkerHandle: barriers are ALSO injected over RPC
@@ -294,17 +291,14 @@ class BarrierCoordinator:
         GLOBAL_METRICS.gauge("mesh_fragment_shards",
                              actor=str(actor_id)).set(float(n_shards))
 
-    def register_mesh_chain(self, chain: str, fids, hollow: bool,
+    def register_mesh_chain(self, chain: str, fids,
                             consumer_actor: int) -> None:
         """A fused mesh chain announces itself: producer fragments
         `fids[:-1]` run hollow (their stages execute inside the consumer
         fragment's fused program), `fids[-1]` is the consumer whose fence
-        covers the chain. hollow=False records an ELIGIBLE chain left on
-        the per-chunk host plane (streaming_mesh_chain=0) — the host-hop
-        counter still runs, giving the unfused comparison baseline."""
+        covers the chain."""
         from ..utils.metrics import GLOBAL_METRICS
         self.mesh_chains[chain] = {"fids": tuple(fids),
-                                   "hollow": bool(hollow),
                                    "consumer_actor": int(consumer_actor)}
         GLOBAL_METRICS.gauge("mesh_chain_fragments", chain=chain).set(
             float(len(fids)))
@@ -313,8 +307,6 @@ class BarrierCoordinator:
         from ..utils.metrics import GLOBAL_METRICS
         if self.mesh_chains.pop(chain, None) is not None:
             GLOBAL_METRICS.remove("mesh_chain_fragments", chain=chain)
-            GLOBAL_METRICS.remove("mesh_host_round_trips_total",
-                                  chain=chain)
 
     def unregister_mesh_fragment(self, actor_id: int) -> None:
         from ..utils.metrics import GLOBAL_METRICS
@@ -620,9 +612,9 @@ class BarrierCoordinator:
                                 except Exception as e:  # noqa: BLE001
                                     worker_reports[wid] = \
                                         f"(unreachable: {e!r})"
-                        # stderr, NOT stdout: bench.py and the profile
-                        # gates parse this process's stdout for JSON
-                        # result lines — a multi-line diagnosis landing
+                        # stderr, NOT stdout: benchmark/run.py and the
+                        # profile scripts print JSON result lines on this
+                        # process's stdout — a multi-line diagnosis landing
                         # there mid-measurement would corrupt the parse
                         # (the watchdog is a diagnostic channel, and
                         # diagnostics belong on stderr)

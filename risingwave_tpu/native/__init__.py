@@ -7,7 +7,7 @@ the GIL for the call). `_rowcodec.so` is built ONLY from the tracked `rowcodec.c
 artifact is git-ignored), with `g++` on first use. A machine without a
 toolchain keeps working on the pure-Python twins (`lib()` returns None
 and callers take them), but the choice is never silent: it is logged
-once, at WARNING with the reason, and `chip_smoke.py` reports it.
+once, at WARNING with the reason.
 """
 
 from __future__ import annotations

@@ -33,9 +33,8 @@ def shuffle_cap_out(local_rows: int, n_shards: int, slack: int = 0) -> int:
     regardless of key skew (a chunk whose rows all share one hot vnode —
     e.g. a tumble-window group key inside one barrier interval — routes
     everything to a single shard). The receive buffer is then
-    n_shards * local_rows = the global chunk capacity, i.e. the fused
-    path costs no more compute than the replicated-and-masked path while
-    still moving the data over ICI instead of the host.
+    n_shards * local_rows = the global chunk capacity: each shard's apply
+    is as wide as the whole chunk, the data moves over ICI, not the host.
 
     slack = k > 0 sizes for BALANCED routing with k× headroom:
     cap_out = k * ceil(local_rows / n_shards), so each shard's receive
@@ -132,9 +131,9 @@ def mesh_ingest_chunk(chunk: StreamChunk, key_indices, vnode_to_shard_table,
     `local_chunk` has capacity n_shards * cap_out and holds exactly the
     rows this shard owns, in source-shard-major order. Because the host
     chunk is sliced CONTIGUOUSLY over the mesh axis, source-shard-major
-    order IS the original chunk order restricted to the owned rows — the
-    same relative order the replicated-and-masked path sees, so per-shard
-    executor semantics (pk-run netting, extrema updates) are unchanged.
+    order IS the original chunk order restricted to the owned rows, so
+    per-shard executor semantics (pk-run netting, extrema updates) are the
+    unsharded executor's.
 
     key_indices=None is the mesh-to-mesh NoShuffle leg: the upstream
     shards already own their rows under the downstream distribution, so

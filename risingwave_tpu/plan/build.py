@@ -246,8 +246,8 @@ def _register_mesh(dep: Deployment, env: BuildEnv, root,
     """The fused mesh plane: an exchange -> sharded-executor chain that
     the builders lowered onto the device mesh announces itself to the
     barrier coordinator — the fragment's S shards collect every epoch as
-    ONE actor (a single collective boundary), and /healthz + the
-    mesh_profile gate can see the mesh topology. The executor's
+    ONE actor (a single collective boundary), and /healthz can see the
+    mesh topology. The executor's
     MeshIngestLog (the mesh-plane replay point) registers next to the
     exchange replay buffers so the commit pulse trims it to the
     uncommitted ingest suffix."""
@@ -282,6 +282,26 @@ def _register_mesh(dep: Deployment, env: BuildEnv, root,
             return                  # one registration per actor
 
 
+def _hollow_producer(dep: Deployment, env, stages, u_fid, c_fid, chain,
+                     actors_by_id) -> None:
+    """The producer half of a fused chain, once its stages are installed
+    as the consumer's preludes: the stages pass chunks through, their
+    actors dispatch nothing and leave the epoch fence to the consumer,
+    and the chain registers with the coordinator."""
+    for s in stages:
+        s.mesh_hollow = True
+    for aid in dep.frag_actor_ids.get(u_fid, []):
+        a = actors_by_id.get(aid)
+        if a is not None:
+            a.fence_exempt = True
+    reg = getattr(env.coord, "register_mesh_chain", None)
+    if reg is not None:
+        c_aids = dep.frag_actor_ids.get(c_fid, [])
+        reg(chain, (u_fid, c_fid), c_aids[0] if c_aids else -1)
+        if chain not in dep.mesh_chains:
+            dep.mesh_chains.append(chain)
+
+
 def _fuse_join_sides(dep: Deployment, graph, env, consumers, c_fid, frag,
                      join, actors_by_id) -> None:
     """Two-input chain fusion for the sharded join: hollow eligible
@@ -289,12 +309,10 @@ def _fuse_join_sides(dep: Deployment, graph, env, consumers, c_fid, frag,
     per-side chain (`f<u>-f<c>s<side>`): the sides' producers differ, so
     one side may hollow while the other keeps its host stages — the
     fused program runs whichever preludes installed for the side it is
-    tracing. Side order comes from the plan tree: the sorted_join node's
+    tracing; a chain is registered when, and only when, it is hollowed. Side order comes from the plan tree: the sorted_join node's
     input legs, each a direct Exchange leaf (an in-fragment subtree
     between exchange and join disqualifies that side — the built input
     is then not a ChannelInput)."""
-    if not getattr(join, "mesh_shuffle", False):
-        return
     legs = getattr(join, "inputs", ())
     if len(legs) != 2 or any(type(i).__name__ != "ChannelInput"
                              for i in legs):
@@ -315,7 +333,6 @@ def _fuse_join_sides(dep: Deployment, graph, env, consumers, c_fid, frag,
     if jnode is None or len(jnode.inputs) != 2 \
             or not all(isinstance(i, Exchange) for i in jnode.inputs):
         return
-    hollow = bool(getattr(join, "mesh_chain_fuse", True))
     for side, leg in enumerate(jnode.inputs):
         u_fid = leg.upstream
         uf = graph.fragments.get(u_fid)
@@ -332,31 +349,12 @@ def _fuse_join_sides(dep: Deployment, graph, env, consumers, c_fid, frag,
                               or type(p_node).__name__ == "ChannelInput"):
             continue
         chain = f"f{u_fid}-f{c_fid}s{side}"
-        for s in stages:
-            s.mesh_chain_hop = chain
-            if hollow:
-                s.mesh_hollow = True
-        if hollow:
-            if not join._mesh_preludes.get(side):
-                join.set_mesh_preludes(
-                    side, [s.mesh_prelude_fn() for s in reversed(stages)],
-                    chain=chain)
-            for aid in dep.frag_actor_ids.get(u_fid, []):
-                a = actors_by_id.get(aid)
-                if a is not None:
-                    a.fence_exempt = True
-        else:
-            # host-plane fallback hops count against ONE chain name per
-            # executor (last side registered); both chains still appear
-            # in the coordinator's registry for the topology view
-            join.mesh_chain = chain
-        reg = getattr(env.coord, "register_mesh_chain", None)
-        if reg is not None:
-            c_aids = dep.frag_actor_ids.get(c_fid, [])
-            reg(chain, (u_fid, c_fid), hollow,
-                c_aids[0] if c_aids else -1)
-            if chain not in dep.mesh_chains:
-                dep.mesh_chains.append(chain)
+        if not join._mesh_preludes.get(side):
+            join.set_mesh_preludes(
+                side, [s.mesh_prelude_fn() for s in reversed(stages)],
+                chain=chain)
+        _hollow_producer(dep, env, stages, u_fid, c_fid, chain,
+                         actors_by_id)
 
 
 def _fuse_mesh_chains(dep: Deployment, graph, env, consumers) -> None:
@@ -378,10 +376,8 @@ def _fuse_mesh_chains(dep: Deployment, graph, env, consumers) -> None:
     Eligibility is conservative — any miss leaves the PR 8 per-fragment
     plane untouched: producer must be singleton, local, single-consumer
     (source-sharing fragments keep their host stages); Filter never
-    qualifies (its UD/UI pair fixup reads across rows). With
-    streaming_mesh_chain=0 the chain still REGISTERS and the host-hop
-    counter still runs un-hollowed — that is the unfused comparison
-    baseline scripts/mesh_profile.py measures against.
+    qualifies (its UD/UI pair fixup reads across rows). A chain is
+    registered when, and only when, it is hollowed.
 
     Runs after build_graph and again after rebuild_fragment (idempotent:
     surviving hollow producers re-hollow, a surviving consumer keeps its
@@ -409,7 +405,7 @@ def _fuse_mesh_chains(dep: Deployment, graph, env, consumers) -> None:
                                  node, actors_by_id)
                 break
             node = getattr(node, "input", None)
-        if sharded is None or not getattr(sharded, "mesh_shuffle", False):
+        if sharded is None:
             continue
         if type(getattr(sharded, "input", None)).__name__ \
                 != "ChannelInput":
@@ -439,30 +435,13 @@ def _fuse_mesh_chains(dep: Deployment, graph, env, consumers) -> None:
                               or type(p_node).__name__ == "ChannelInput"):
             continue
         chain = f"f{u_fid}-f{c_fid}"
-        hollow = bool(getattr(sharded, "mesh_chain_fuse", True))
-        for s in stages:
-            s.mesh_chain_hop = chain
-            if hollow:
-                s.mesh_hollow = True
-        if hollow:
-            if not sharded._mesh_preludes:
-                # source-most stage runs first inside the fused program
-                sharded.set_mesh_preludes(
-                    [s.mesh_prelude_fn() for s in reversed(stages)],
-                    chain=chain)
-            for aid in dep.frag_actor_ids.get(u_fid, []):
-                a = actors_by_id.get(aid)
-                if a is not None:
-                    a.fence_exempt = True
-        else:
-            sharded.mesh_chain = chain
-        reg = getattr(env.coord, "register_mesh_chain", None)
-        if reg is not None:
-            c_aids = dep.frag_actor_ids.get(c_fid, [])
-            reg(chain, (u_fid, c_fid), hollow,
-                c_aids[0] if c_aids else -1)
-            if chain not in dep.mesh_chains:
-                dep.mesh_chains.append(chain)
+        if not sharded._mesh_preludes:
+            # source-most stage runs first inside the fused program
+            sharded.set_mesh_preludes(
+                [s.mesh_prelude_fn() for s in reversed(stages)],
+                chain=chain)
+        _hollow_producer(dep, env, stages, u_fid, c_fid, chain,
+                         actors_by_id)
 
 
 def _build_fragment_actor(graph, env, dep, channels, built_schema,
@@ -951,22 +930,14 @@ def _build_hash_agg(args, inputs, ctx: ActorCtx, key):
     if md > 1:
         from ..parallel.mesh import make_mesh
         from ..stream.sharded_agg import ShardedHashAggExecutor
-        ex = ShardedHashAggExecutor(
+        return ShardedHashAggExecutor(
             inputs[0], args["group_key_indices"], args["agg_calls"],
             mesh=make_mesh(md),
             capacity=args.get("capacity", 1 << 16) // md,
             state_table=st,
             group_key_names=args.get("group_key_names"),
             cleaning_watermark_col=args.get("cleaning_watermark_col"),
-            watchdog_interval=args.get("watchdog_interval", 1),
-            mesh_shuffle=bool(args.get("mesh_shuffle", True)),
-            mesh_shuffle_slack=args.get("mesh_shuffle_slack", 0),
-            mesh_shuffle_adaptive=bool(
-                args.get("mesh_shuffle_adaptive", True)))
-        # per-statement chain-fusion opt-out (streaming_mesh_chain=0):
-        # the post-build fusion pass reads this off the executor
-        ex.mesh_chain_fuse = bool(args.get("mesh_chain", True))
-        return ex
+            watchdog_interval=args.get("watchdog_interval", 1))
     return HashAggExecutor(
         inputs[0], args["group_key_indices"], args["agg_calls"],
         capacity=args.get("capacity", 1 << 16),
@@ -995,12 +966,8 @@ def _build_sorted_join(args, inputs, ctx: ActorCtx, key):
         from ..parallel.mesh import make_mesh
         from ..stream.sharded_join import ShardedSortedJoinExecutor
         cls = ShardedSortedJoinExecutor
-        extra = dict(mesh=make_mesh(md),
-                     mesh_shuffle=bool(args.get("mesh_shuffle", True)),
-                     mesh_shuffle_slack=args.get("mesh_shuffle_slack", 0),
-                     mesh_shuffle_adaptive=bool(
-                         args.get("mesh_shuffle_adaptive", True)))
-    ex = cls(
+        extra = dict(mesh=make_mesh(md))
+    return cls(
         inputs[0], inputs[1], **extra,
         left_key_indices=args["left_key_indices"],
         right_key_indices=args["right_key_indices"],
@@ -1020,11 +987,6 @@ def _build_sorted_join(args, inputs, ctx: ActorCtx, key):
         state_tables=state_tables,
         temporal=args.get("temporal", False),
         watchdog_interval=args.get("watchdog_interval", 1))
-    if md > 1:
-        # per-statement chain-fusion opt-out, read by _fuse_mesh_chains'
-        # two-input walk (join-side producer hollowing)
-        ex.mesh_chain_fuse = bool(args.get("mesh_chain", True))
-    return ex
 
 
 @register_builder("general_over_window")
@@ -1040,19 +1002,13 @@ def _build_general_over_window(args, inputs, ctx: ActorCtx, key):
     if md > 1 and args["partition_by"]:
         from ..parallel.mesh import make_mesh
         from ..stream.sharded_over_window import ShardedOverWindowExecutor
-        ex = ShardedOverWindowExecutor(
+        return ShardedOverWindowExecutor(
             inputs[0], args["partition_by"], args["order_specs"],
             args["windows"],
             capacity=args.get("capacity", 1 << 14) // md,
             state_table=st, pk_indices=pk,
             watchdog_interval=args.get("watchdog_interval", 1),
-            mesh=make_mesh(md),
-            mesh_shuffle=bool(args.get("mesh_shuffle", True)),
-            mesh_shuffle_slack=args.get("mesh_shuffle_slack", 0),
-            mesh_shuffle_adaptive=bool(
-                args.get("mesh_shuffle_adaptive", True)))
-        ex.mesh_chain_fuse = bool(args.get("mesh_chain", True))
-        return ex
+            mesh=make_mesh(md))
     return GeneralOverWindowExecutor(
         inputs[0], args["partition_by"], args["order_specs"],
         args["windows"], capacity=args.get("capacity", 1 << 14),
@@ -1219,7 +1175,7 @@ def _build_retract_top_n(args, inputs, ctx: ActorCtx, key):
     if md > 1:
         from ..parallel.mesh import make_mesh
         from ..stream.sharded_top_n import ShardedTopNExecutor
-        ex = ShardedTopNExecutor(
+        return ShardedTopNExecutor(
             inputs[0], args.get("group_key_indices", ()),
             order_col=args.get("order_col"),
             order_specs=args.get("order_specs"),
@@ -1228,13 +1184,7 @@ def _build_retract_top_n(args, inputs, ctx: ActorCtx, key):
             capacity=args.get("capacity", 1 << 14) // md,
             state_table=st, pk_indices=pk,
             watchdog_interval=args.get("watchdog_interval", 1),
-            mesh=make_mesh(md),
-            mesh_shuffle=bool(args.get("mesh_shuffle", True)),
-            mesh_shuffle_slack=args.get("mesh_shuffle_slack", 0),
-            mesh_shuffle_adaptive=bool(
-                args.get("mesh_shuffle_adaptive", True)))
-        ex.mesh_chain_fuse = bool(args.get("mesh_chain", True))
-        return ex
+            mesh=make_mesh(md))
     return RetractableTopNExecutor(
         inputs[0], args.get("group_key_indices", ()),
         order_col=args.get("order_col"),

@@ -33,7 +33,6 @@ class HopWindowExecutor(StatelessUnaryExecutor):
     # ProjectExecutor — same contract; hop is row-wise per input row, the
     # K copies of a row stay on the producing shard until the shuffle).
     mesh_hollow = False
-    mesh_chain_hop = None
 
     def mesh_prelude_fn(self):
         return self._step_impl
@@ -90,9 +89,6 @@ class HopWindowExecutor(StatelessUnaryExecutor):
                 if self.mesh_hollow:
                     yield msg       # expansion runs fused downstream
                     continue
-                if self.mesh_chain_hop is not None:
-                    from .monitor import mesh_host_round_trip
-                    mesh_host_round_trip(self.mesh_chain_hop)
                 yield self._step(msg)
             elif isinstance(msg, Watermark):
                 wm = self.map_watermark(msg)

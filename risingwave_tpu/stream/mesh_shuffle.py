@@ -1,10 +1,14 @@
 """Host half of a fused mesh fragment's in-mesh shuffle.
 
 The sharded executors (`sharded_agg`, `sharded_join`, `sharded_store`)
-route their rows inside one shard_map program
-(`parallel/exchange.mesh_ingest_chunk`). What the host does around that
-program is the same for all of them and lives here:
+take a chunk row-sliced over the mesh axis and route its rows inside one
+shard_map program (`parallel/exchange.mesh_ingest_chunk`). What the host
+does around that program is the same for all of them and lives here:
 
+* ENTRY — `_mesh_chunk`: a chunk whose capacity the shard count does not
+  divide grows to the next multiple with invisible tail rows; the chain
+  preludes (`set_mesh_preludes`) and the replay point (`MeshIngestLog`,
+  `preload_replay`).
 * SIZING — the per-(src, dst) send capacity a program is traced with:
   the manual `mesh_shuffle_slack`, else the adaptive hint derived from
   the send demand the barrier watchdog observed, else zero-drop sizing.
@@ -25,14 +29,16 @@ program is the same for all of them and lives here:
 
 from __future__ import annotations
 
+from collections import deque
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..common.chunk import StreamChunk, _pack_programs
 from ..parallel.exchange import shuffle_cap_out
-from ..parallel.mesh import VNODE_AXIS
+from ..parallel.mesh import VNODE_AXIS, vnode_to_shard
 from ..utils.metrics import (GLOBAL_METRICS, MESH_SHUFFLE_COUNTERS,
                              MESH_SHUFFLE_DROPPED, MESH_SHUFFLE_MAX_FILL)
 
@@ -51,6 +57,53 @@ def fold_shuffle_obs(obs, fill, local_vis):
                       obs[OBS_ROWS] + rows])
 
 
+class MeshIngestLog:
+    """Host-side per-interval ingest snapshot of a fused mesh fragment —
+    the mesh-plane REPLAY POINT. Every chunk entering the fused
+    shard_map program is also retained here BY REFERENCE (device arrays
+    are immutable and the ingest path never donates them, so holding
+    them moves no data), stamped with the epoch its barrier seals, and
+    dropped when that epoch COMMITS — the coordinator trims this log
+    through the same pulse that trims the exchange replay buffers
+    (plan/build.py registers it next to the fragment's channels). The
+    log therefore always holds exactly the uncommitted ingest suffix,
+    bounded by `checkpoint_max_inflight`; a mesh fragment failure
+    re-runs the fused program from the committed epoch over this
+    suffix (delivered back through the armed frontier channels) instead
+    of tearing down the deployment. A hard cap backstops executors
+    driven without a coordinator (engine-level tests)."""
+
+    HARD_CAP = 8
+    replay_enabled = True
+
+    def __init__(self):
+        self._pending: list = []
+        self._log = deque()
+
+    def note(self, item) -> None:
+        self._pending.append(item)
+
+    def seal(self, epoch: int) -> None:
+        """Stamp the open interval's ingests with the epoch its barrier
+        seals (called from the executor's barrier-time persist)."""
+        if self._pending:
+            self._log.append((epoch, self._pending))
+            self._pending = []
+            while len(self._log) > self.HARD_CAP:
+                self._log.popleft()
+
+    def trim_replay(self, committed_epoch: int) -> None:
+        while self._log and self._log[0][0] <= committed_epoch:
+            self._log.popleft()
+
+    def entries(self) -> list:
+        return list(self._log)
+
+    def chunk_count(self) -> int:
+        return sum(len(chunks) for _, chunks in self._log) \
+            + len(self._pending)
+
+
 class MeshShuffleHost:
     """Mixin of the sharded executors; needs `self.mesh`, `self.n_shards`
     and `self.identity`."""
@@ -59,8 +112,11 @@ class MeshShuffleHost:
     # does ("<flow>/<identity>@a<actor>"); bare executors use their identity
     mesh_label: Optional[str] = None
 
-    def _init_mesh_shuffle(self, slack: int, adaptive: bool,
+    def _init_mesh_shuffle(self, mesh, slack: int,
                            watchdog_on: bool) -> None:
+        self.mesh = mesh
+        self.n_shards = mesh.shape[VNODE_AXIS]
+        self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
         self.mesh_shuffle_slack = int(slack)
         if self.mesh_shuffle_slack and not watchdog_on:
             raise ValueError(
@@ -69,16 +125,24 @@ class MeshShuffleHost:
                 "unchecked and a checkpoint could commit with rows "
                 "missing; transfer-free pipelines must use slack 0 "
                 "(zero-drop sizing)")
-        # adaptive shuffle slack (ROADMAP 3c): send-bucket capacity derived
-        # from OBSERVED per-destination demand (watchdog-fetched max fill,
-        # asymmetric EWMA + peak floor), instead of the manual slack var.
-        # Engages only under zero-drop default sizing (manual slack stays
-        # an override) and only with the watchdog fetch active — overflow
+        # adaptive shuffle slack: send-bucket capacity derived from
+        # OBSERVED per-destination demand (watchdog-fetched max fill,
+        # asymmetric EWMA + peak floor). Engages only under zero-drop
+        # sizing and only with the watchdog fetch active — overflow
         # under an adapted cap still fail-stops, recovery replays, and the
         # fresh executor restarts at zero-drop sizing.
-        self.mesh_shuffle_adaptive = (bool(adaptive)
-                                      and self.mesh_shuffle_slack == 0
+        self.mesh_shuffle_adaptive = (self.mesh_shuffle_slack == 0
                                       and watchdog_on)
+        # mesh-chain fusion (plan/build._fuse_mesh_chains): hollow producer
+        # stage impls run INSIDE the fused program, before the shuffle
+        self._mesh_preludes = ()
+        self.mesh_chain: Optional[str] = None
+        self._replay_preload: list = []
+        # fused dispatches (one per interval batch in steady state)
+        self.mesh_shuffle_applies = 0
+        # mesh-plane replay point: the uncommitted ingest suffix, held
+        # host-side by reference
+        self.ingest_log = MeshIngestLog()
         self._cap_hint: Optional[int] = None
         self._fill_ewma = 0.0
         self._fill_peak = 0
@@ -89,10 +153,48 @@ class MeshShuffleHost:
         self._interval_bytes = 0
         self._interval = dict(_NO_INTERVAL)
 
+    # ------------------------------------------------------------- entry
+    def _mesh_chunk(self, chunk: StreamChunk) -> StreamChunk:
+        """What the fused program is handed for `chunk`: shard_map slices
+        the rows contiguously over the mesh axis, so a capacity the shard
+        count does not divide grows to the next multiple with invisible
+        tail rows (row order and update-pair adjacency kept). Any other
+        chunk comes back as the SAME object, without a dispatch."""
+        short = -chunk.capacity % self.n_shards
+        if not short:
+            return chunk
+        return _pack_programs()["pad"](chunk, chunk.capacity + short)
+
+    def set_mesh_preludes(self, fns, chain: Optional[str] = None) -> None:
+        """Install hollow producer-stage impls (project / hop_window
+        `_step_impl`s, root-to-source order reversed so the source-most
+        runs first) to execute INSIDE the fused program, upstream of the
+        shuffle. Must install before the first fused trace — the compiled
+        programs close over the prelude list."""
+        assert self.mesh_shuffle_applies == 0, \
+            "mesh preludes must install before the first fused dispatch"
+        self._mesh_preludes = tuple(fns)
+        self.mesh_chain = chain
+
+    def preload_replay(self, chunks) -> None:
+        """Channel-free mesh replay: the uncommitted ingest suffix
+        captured from the crashed executor's MeshIngestLog (plus its
+        undrained pending chunks) is fed straight into the fused
+        program — staged here, installed into the pending queue by the
+        executor's recovery at the INITIAL barrier (AFTER the durable
+        state rebuild; the INITIAL's own drain runs before it, so
+        prepending now would apply the suffix to pre-recovery state),
+        then re-run as one fused scan at the next barrier and re-noted
+        into the fresh log by that drain. The frontier channels skip
+        these chunks by identity (Channel.begin_replay skip_refs);
+        barriers and watermarks still replay through them for epoch
+        alignment."""
+        self._replay_preload = list(chunks)
+
     # ------------------------------------------------------------ sizing
     def _trace_cap(self, local_rows: int) -> int:
-        """Per-(src,dst) send capacity at TRACE time: the manual slack
-        override wins; otherwise the adaptive hint (2x pow2-quantized
+        """Per-(src,dst) send capacity at TRACE time: the constructor's
+        slack wins; otherwise the adaptive hint (2x pow2-quantized
         observed peak demand) once enough barriers have been observed;
         zero-drop sizing until then."""
         if not self.mesh_shuffle_adaptive or self._cap_hint is None:

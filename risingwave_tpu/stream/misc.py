@@ -65,10 +65,8 @@ class NoOpExecutor(StatelessUnaryExecutor):
     identity = "NoOp"
     # Mesh-chain fusion: identity is trivially safe per-shard, so NoOp
     # plan padding must not break the prelude-capable producer walk
-    # (q5's source -> project -> NoOp leg). It does no device work, so
-    # un-hollowed NoOps never count a host round trip either.
+    # (q5's source -> project -> NoOp leg).
     mesh_hollow = False
-    mesh_chain_hop = None
 
     def mesh_prelude_fn(self):
         return lambda chunk: chunk

@@ -92,29 +92,6 @@ def dispatcher_channels(d) -> list:
     return [c for c in out if isinstance(c, Channel)]
 
 
-def mesh_host_round_trip(chain: str, n: int = 1) -> None:
-    """Count one host-plane crossing inside a registered mesh chain.
-
-    A "round trip" is any per-chunk work a fused chain had to do on the
-    host between its source and its sharded consumer: a producer stage
-    running un-hollowed, or the sharded executor falling back to the
-    per-chunk host-ingest plane.  Steady-state fused intervals must keep
-    this at zero — barrier-time control, persist d2h, and the ingest-log
-    replay point are sanctioned and never counted here."""
-    GLOBAL_METRICS.counter(
-        "mesh_host_round_trips_total", chain=str(chain)).inc(n)
-
-
-def mesh_host_round_trips(chain: Optional[str] = None) -> int:
-    """Current total of host-plane crossings, optionally for one chain."""
-    snap = GLOBAL_METRICS.snapshot()
-    total = 0
-    for e in snap.get("mesh_host_round_trips_total", []):
-        if chain is None or e["labels"].get("chain") == str(chain):
-            total += int(e["value"])
-    return total
-
-
 def dispatcher_fanout(d) -> int:
     """Number of output channels a dispatcher feeds right now (Tap
     fanout is runtime-extendable, so this re-reads on every call)."""

@@ -31,7 +31,6 @@ class ProjectExecutor(StatelessUnaryExecutor):
     # program (zero host hops). Watermark mapping stays host-side active:
     # watermarks are control metadata in output coordinates either way.
     mesh_hollow = False
-    mesh_chain_hop: Optional[str] = None  # chain label when registered un-hollowed
 
     def mesh_prelude_fn(self):
         """Pure chunk->chunk map safe to run per-SHARD inside shard_map.
@@ -70,9 +69,6 @@ class ProjectExecutor(StatelessUnaryExecutor):
     def map_chunk(self, chunk):
         if self.mesh_hollow:
             return chunk            # prelude runs fused downstream
-        if self.mesh_chain_hop is not None:
-            from .monitor import mesh_host_round_trip
-            mesh_host_round_trip(self.mesh_chain_hop)
         return self._step(chunk)
 
     def map_watermark(self, wm: Watermark):

@@ -7,26 +7,19 @@ dispatcher+merge pair collapses INTO the jitted step: state lives sharded
 along the `vnode` mesh axis (global arrays [S*C], each shard seeing a
 local [C] table).
 
-Two input planes:
-
-* FUSED MESH SHUFFLE (default, `mesh_shuffle=True`): the whole fragment —
-  source-side dispatch, hash exchange, stateful apply — is ONE
-  shard_map-ed program per barrier interval. The host chunk is sliced
-  CONTIGUOUSLY over the mesh axis (shard s holds rows [s*L, (s+1)*L)),
-  each shard vnode-routes its slice to the owner shards with
-  `parallel/exchange.mesh_ingest_chunk` (`lax.all_to_all` over ICI — no
-  host Channel hop, no replication), and applies its local hash table to
-  exactly the rows it owns. Chunks buffered within an interval batch into
-  one `lax.scan` inside the same shard_map program, so device dispatches
-  per interval scale with neither chunk count nor shard count. Shuffle
-  overflow (per-pair capacity from `mesh_shuffle_slack`; 0 = zero-drop
-  sizing) accumulates on device and FAIL-STOPS the epoch at the barrier
-  watchdog fetch.
-
-* REPLICATED MASK (fallback: `mesh_shuffle=False`, or a chunk whose
-  capacity does not divide by the shard count): the input chunk is
-  replicated and each shard masks it down to its own vnodes — the
-  "exchange" is a visibility mask on ICI-resident data.
+The input plane is the FUSED MESH SHUFFLE: the whole fragment —
+source-side dispatch, hash exchange, stateful apply — is ONE shard_map-ed
+program per barrier interval. The host chunk is sliced CONTIGUOUSLY over
+the mesh axis (shard s holds rows [s*L, (s+1)*L); a capacity the shard
+count does not divide is padded first, `MeshShuffleHost._mesh_chunk`),
+each shard vnode-routes its slice to the owner shards with
+`parallel/exchange.mesh_ingest_chunk` (`lax.all_to_all` over ICI — no
+host Channel hop, no replication), and applies its local hash table to
+exactly the rows it owns. Chunks buffered within an interval batch into
+one `lax.scan` inside the same shard_map program, so device dispatches
+per interval scale with neither chunk count nor shard count. Shuffle
+overflow accumulates on device and FAIL-STOPS the epoch at the barrier
+watchdog fetch.
 
 The barrier flush runs per shard and concatenates
 along the shard axis into one global changelog chunk.
@@ -39,7 +32,7 @@ Durability: fully supported — `_persist` runs a per-shard persist view
 (each shard's dirty rows compact to its local prefix) and ships all
 shards' prefixes in two packed d2h calls into the state table, and
 `recover` rebuilds the sharded device state by routing durable rows
-through the same vnode->shard map the apply path masks by. Per-shard
+through the same vnode->shard map the apply path routes by. Per-shard
 capacity stays static at runtime (growth would need a global re-layout;
 recovery may re-size from the worst shard's row count), and the
 transfer-free purge path works per shard.
@@ -47,7 +40,6 @@ transfer-free purge path works per shard.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -56,69 +48,20 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.chunk import StreamChunk
-from ..common.vnode import compute_vnodes
 from ..expr.agg import AggCall
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
-from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
+from ..parallel.mesh import VNODE_AXIS, shard_map
 from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from .executor import Executor
 from .hash_agg import AggState, HashAggExecutor
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 
 
-class MeshIngestLog:
-    """Host-side per-interval ingest snapshot of a fused mesh fragment —
-    the mesh-plane REPLAY POINT. Every chunk entering the fused
-    shard_map program is also retained here BY REFERENCE (device arrays
-    are immutable and the ingest path never donates them, so holding
-    them moves no data), stamped with the epoch its barrier seals, and
-    dropped when that epoch COMMITS — the coordinator trims this log
-    through the same pulse that trims the exchange replay buffers
-    (plan/build.py registers it next to the fragment's channels). The
-    log therefore always holds exactly the uncommitted ingest suffix,
-    bounded by `checkpoint_max_inflight`; a mesh fragment failure
-    re-runs the fused program from the committed epoch over this
-    suffix (delivered back through the armed frontier channels) instead
-    of tearing down the deployment. A hard cap backstops executors
-    driven without a coordinator (engine-level tests)."""
-
-    HARD_CAP = 8
-    replay_enabled = True
-
-    def __init__(self):
-        from collections import deque
-        self._pending: list = []
-        self._log = deque()
-
-    def note(self, item) -> None:
-        self._pending.append(item)
-
-    def seal(self, epoch: int) -> None:
-        """Stamp the open interval's ingests with the epoch its barrier
-        seals (called from the executor's barrier-time persist)."""
-        if self._pending:
-            self._log.append((epoch, self._pending))
-            self._pending = []
-            while len(self._log) > self.HARD_CAP:
-                self._log.popleft()
-
-    def trim_replay(self, committed_epoch: int) -> None:
-        while self._log and self._log[0][0] <= committed_epoch:
-            self._log.popleft()
-
-    def entries(self) -> list:
-        return list(self._log)
-
-    def chunk_count(self) -> int:
-        return sum(len(chunks) for _, chunks in self._log) \
-            + len(self._pending)
-
-
 class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
     """HashAgg over `mesh`: state sharded on the vnode axis, input routed
-    to its owner shard by the fused in-mesh shuffle (or replicated and
-    masked as the fallback). `capacity` is PER SHARD."""
+    to its owner shard by the fused in-mesh shuffle. `capacity` is PER
+    SHARD."""
 
     def __init__(self, input: Executor, group_key_indices: Sequence[int],
                  agg_calls: Sequence[AggCall], mesh: Mesh,
@@ -127,24 +70,12 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
                  group_key_names: Optional[Sequence[str]] = None,
                  cleaning_watermark_col: Optional[int] = None,
                  watchdog_interval: Optional[int] = 1,
-                 mesh_shuffle: bool = True,
-                 mesh_shuffle_slack: int = 0,
-                 mesh_shuffle_adaptive: bool = True):
-        self.mesh = mesh
-        self.n_shards = mesh.shape[VNODE_AXIS]
-        self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
-        self.mesh_shuffle = bool(mesh_shuffle)
-        self._init_mesh_shuffle(mesh_shuffle_slack, mesh_shuffle_adaptive,
+                 mesh_shuffle_slack: int = 0):
+        # `mesh_shuffle_slack` undersizes the send buckets: no SQL reaches
+        # it, it is the seam through which tests drive the shuffle's
+        # overflow FAIL-STOP; 0 is zero-drop sizing, then adaptive
+        self._init_mesh_shuffle(mesh, mesh_shuffle_slack,
                                 watchdog_interval is not None)
-        # mesh-chain fusion (plan/build._fuse_mesh_chains): hollow producer
-        # stage impls run INSIDE the fused program, before the shuffle
-        self._mesh_preludes: tuple = ()
-        self.mesh_chain: Optional[str] = None
-        self._replay_preload: list = []
-        # fused-plane dispatch count (one per interval batch in steady
-        # state): tests and scripts/mesh_profile.py assert the fused
-        # exchange actually engaged
-        self.mesh_shuffle_applies = 0
         super().__init__(input, group_key_indices, agg_calls,
                          capacity=capacity, state_table=state_table,
                          group_key_names=group_key_names,
@@ -155,27 +86,10 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         # match the parent's — the sharded AggState and the per-shard
         # accumulators are threaded, never aliased. Chunk batching runs
         # through the FUSED shard_map scan (_drain_pending below); the
-        # parent's unsharded scan programs are never built here.
-        self._use_chunk_batching = self.mesh_shuffle
+        # parent's unsharded apply and scan programs are never built here.
         mesh_kw = dict(mesh=mesh)
         shard = P(VNODE_AXIS)
         repl = P()
-
-        def apply_sharded(state, overflow, chunk):
-            my = jax.lax.axis_index(VNODE_AXIS)
-            key_cols = [chunk.columns[i].data
-                        for i in self.group_key_indices]
-            vn = compute_vnodes(key_cols)
-            mine = chunk.vis & (self._routing[vn] == my)
-            local = StreamChunk(chunk.columns, chunk.ops, mine,
-                                chunk.schema)
-            st, ov, occ = self._apply_impl(state, overflow[0], local)
-            return st, ov[None], occ[None]
-
-        self._apply = jit_state(shard_map(
-            apply_sharded, in_specs=(shard, shard, repl),
-            out_specs=(shard, shard, shard), **mesh_kw),
-            donate_argnums=(0, 1), name="sharded_agg_apply")
 
         # ---- fused mesh shuffle: exchange + apply in ONE program ----
         # the chunk enters SHARDED over the row axis (in_spec P(vnode):
@@ -254,9 +168,6 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
             out_specs=(shard, shard, shard, shard), **mesh_kw),
             name="sharded_agg_persist_view")
 
-        # mesh-plane replay point: the uncommitted ingest suffix, held
-        # host-side by reference (see MeshIngestLog)
-        self.ingest_log = MeshIngestLog()
         # per-shard watchdog accumulators replace the parent's scalars
         sharding = NamedSharding(mesh, P(VNODE_AXIS))
         self._overflow_dev = jax.device_put(
@@ -272,30 +183,6 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         self._shuffle_obs_dev = self._fresh_shuffle_obs()
 
     # ------------------------------------------------ fused mesh shuffle
-    def set_mesh_preludes(self, fns, chain: Optional[str] = None) -> None:
-        """Install hollow producer-stage impls (project / hop_window
-        `_step_impl`s, root-to-source order reversed so the source-most
-        runs first) to execute INSIDE the fused program, upstream of the
-        shuffle. Must install before the first fused trace — the compiled
-        programs close over the prelude list."""
-        assert self.mesh_shuffle_applies == 0, \
-            "mesh preludes must install before the first fused dispatch"
-        self._mesh_preludes = tuple(fns)
-        self.mesh_chain = chain
-
-    def _prelude_host(self, chunk: StreamChunk) -> StreamChunk:
-        """Per-chunk host fallback: run the hollowed producer stages
-        eagerly so the replicated-mask path sees the transformed schema
-        it expects. Counted as host round trips by the caller."""
-        for fn in self._mesh_preludes:
-            chunk = fn(chunk)
-        return chunk
-
-    def _count_host_hop(self, n: int = 1) -> None:
-        if self.mesh_chain is not None:
-            from .monitor import mesh_host_round_trip
-            mesh_host_round_trip(self.mesh_chain, n)
-
     def _fused_step(self, state, overflow, dropped, obs, chunk):
         """One chunk's preludes + shuffle + apply, INSIDE shard_map
         (per-shard views; `chunk` fields are this shard's local [L] row
@@ -364,35 +251,19 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
             donate_argnums=(0, 1, 2, 3),
             name=f"sharded_agg_apply_fused_scan{k}")
 
-    def _fused_eligible(self, chunk: StreamChunk) -> bool:
-        # shard_map row-slices the chunk contiguously over the mesh axis,
-        # which needs the capacity to divide evenly; everything else
-        # (including every power-of-two capacity >= n_shards) is eligible
-        return self.mesh_shuffle and chunk.capacity % self.n_shards == 0
-
     def _apply_chunk_raw(self, chunk: StreamChunk) -> None:
-        if self._fused_eligible(chunk):
-            (self.state, self._overflow_dev, self._dropped_dev,
-             self._occ_dev, self._shuffle_obs_dev) = self._get_fused_apply()(
-                self.state, self._overflow_dev, self._dropped_dev,
-                self._shuffle_obs_dev, chunk)
-            self._count_shuffle_dispatch(chunk)
-            self.mesh_shuffle_applies += 1
-        else:
-            # per-chunk host-plane fallback: a chain member couldn't stay
-            # fused, so the hollowed producer stages (if any) run here on
-            # the host and the crossing is counted against the chain
-            if self._mesh_preludes:
-                chunk = self._prelude_host(chunk)
-            self._count_host_hop()
-            self.state, self._overflow_dev, self._occ_dev = self._apply(
-                self.state, self._overflow_dev, chunk)
+        (self.state, self._overflow_dev, self._dropped_dev,
+         self._occ_dev, self._shuffle_obs_dev) = self._get_fused_apply()(
+            self.state, self._overflow_dev, self._dropped_dev,
+            self._shuffle_obs_dev, chunk)
+        self._count_shuffle_dispatch(chunk)
+        self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
 
     def _drain_pending(self) -> None:
         """Interval drain: a multi-chunk run goes through the fused
-        shard_map scan (one dispatch); single chunks and ineligible
-        capacities fall back to the per-chunk programs. The parent's
+        shard_map scan (one dispatch), a single chunk or a mixed run
+        through the per-chunk fused program. The parent's
         unsharded scan machinery is bypassed entirely — its programs
         would mis-handle the sharded global state."""
         p = self._pending_chunks
@@ -403,15 +274,18 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         # program consumes it (references only — chunks are never
         # donated on the ingest path). With preludes installed, the RAW
         # source chunk is the replay point — re-running the fused program
-        # re-runs the hollowed producer stages too.
+        # re-runs the hollowed producer stages too. The log holds the
+        # chunk AS IT CAME, the object the frontier channels skip by
+        # identity after a preload; the drain pads it, a replay's too.
         for ch in p:
             self.ingest_log.note(ch)
+        p = [self._mesh_chunk(ch) for ch in p]
         # replay preloads bypass _enqueue_chunk's shape splitting, so the
         # scan's jnp.stack needs an explicit uniformity check here
         uniform = len({(c.capacity, len(c.columns),
                         tuple(col.valid is not None for col in c.columns))
                        for c in p}) == 1
-        if len(p) == 1 or not self._fused_eligible(p[0]) or not uniform:
+        if len(p) == 1 or not uniform:
             if not self._mesh_preludes:
                 # raw-schema chunks under preludes would confuse the
                 # spill reload walk; the sharded agg never spills anyway
@@ -441,21 +315,6 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         self._count_shuffle_dispatch(p[0], chunks=k)
         self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
-
-    def preload_replay(self, chunks) -> None:
-        """Channel-free mesh replay (ROADMAP 3d): the uncommitted ingest
-        suffix captured from the crashed executor's MeshIngestLog (plus
-        its undrained pending chunks) is fed straight into the fused
-        program — staged here, installed into the pending queue by
-        `recover()` at the INITIAL barrier (AFTER the durable state
-        rebuild; the INITIAL's own drain runs before recover, so
-        prepending now would apply the suffix to pre-recovery state),
-        then re-run as one fused scan at the next barrier and re-noted
-        into the fresh log by that drain. The frontier channels skip
-        these chunks by identity (Channel.begin_replay skip_refs);
-        barriers and watermarks still replay through them for epoch
-        alignment."""
-        self._replay_preload = list(chunks)
 
     # ------------------------------------------------------------ state
     def _initial_state(self, capacity: int) -> AggState:
@@ -568,7 +427,7 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
 
     def recover(self, barrier_epoch: int) -> None:
         """Rebuild SHARDED device state: rows partition by
-        vnode-of-group-key (the same routing the apply path masks by),
+        vnode-of-group-key (the same routing the apply path shuffles by),
         each shard's slice is built locally with the parent's machinery,
         and the slices concatenate along the mesh axis. The durable
         persist path is the parent's unchanged — its snapshot-diff view
@@ -577,9 +436,9 @@ class ShardedHashAggExecutor(MeshShuffleHost, HashAggExecutor):
         # now that the durable state rebuild is about to run on pre-crash
         # committed state (the INITIAL barrier's drain already ran, so
         # these apply in one fused scan at the NEXT barrier).
-        preload = getattr(self, "_replay_preload", None)
-        if preload:
-            self._pending_chunks = list(preload) + self._pending_chunks
+        if self._replay_preload:
+            self._pending_chunks = self._replay_preload \
+                + self._pending_chunks
             self._replay_preload = []
         if self.state_table is None:
             return

@@ -14,14 +14,13 @@ hashing on both sides => co-partitioned probes are shard-local. The
 per-shard output chunks concatenate along the shard axis into one global
 changelog chunk. `capacity` is PER SHARD.
 
-Like the sharded agg, the default input plane is the FUSED MESH SHUFFLE
-(`mesh_shuffle=True`): the chunk enters row-sliced over the mesh axis and
+Like the sharded agg's, the input plane is the FUSED MESH SHUFFLE: the
+chunk enters row-sliced over the mesh axis (a capacity the shard count
+does not divide is padded first, `MeshShuffleHost._mesh_chunk`) and
 `parallel/exchange.mesh_ingest_chunk` routes rows to their owner shard
 with one in-program `lax.all_to_all` — exchange + probe + state update is
 ONE device program per chunk, with shuffle overflow accumulated on device
-and fail-stopped at the barrier watchdog. Chunks whose capacity does not
-divide by the shard count (and `mesh_shuffle=False`) fall back to the
-replicated-and-masked plane.
+and fail-stopped at the barrier watchdog.
 
 Inherits ALL semantics (inner/outer, degrees, per-chunk eviction,
 netting) from SortedJoinExecutor — `_apply_impl` / `_evict_impl` run
@@ -37,11 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common.chunk import StreamChunk
-from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
-from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
+from ..parallel.mesh import VNODE_AXIS, shard_map
 from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from .align import LEFT, RIGHT
 from .executor import Executor
@@ -59,52 +56,13 @@ def _vec_n(state: SortedSideState) -> SortedSideState:
 
 class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
     def __init__(self, left: Executor, right: Executor, mesh: Mesh,
-                 mesh_shuffle: bool = True, mesh_shuffle_slack: int = 0,
-                 mesh_shuffle_adaptive: bool = True, **kwargs):
-        self.mesh = mesh
-        self.n_shards = mesh.shape[VNODE_AXIS]
-        self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
-        self.mesh_shuffle = bool(mesh_shuffle)
+                 **kwargs):
         self._init_mesh_shuffle(
-            mesh_shuffle_slack, mesh_shuffle_adaptive,
-            kwargs.get("watchdog_interval", 1) is not None)
-        self.mesh_shuffle_applies = 0
-        # mesh-chain preludes: same contract as ShardedHashAggExecutor
-        self._mesh_preludes: dict = {}   # side -> tuple of prelude fns
-        self.mesh_chain = None
-        # mesh-plane replay point (sharded_agg.py MeshIngestLog): the
-        # uncommitted (side, chunk) ingest suffix, held by reference
-        from .sharded_agg import MeshIngestLog
-        self.ingest_log = MeshIngestLog()
+            mesh, 0, kwargs.get("watchdog_interval", 1) is not None)
+        # per side: the two legs' producers differ (`set_mesh_preludes`)
+        self._mesh_preludes: dict = {}
         super().__init__(left, right, **kwargs)
         shard, repl = P(VNODE_AXIS), P()
-
-        def make_apply(side, mf):
-            def apply_sharded(own, other, errs, chunk, wm):
-                my = jax.lax.axis_index(VNODE_AXIS)
-                key_cols = [chunk.columns[i].data
-                            for i in self.key_indices[side]]
-                vn = compute_vnodes(key_cols)
-                mine = chunk.vis & (self._routing[vn] == my)
-                local = StreamChunk(chunk.columns, chunk.ops, mine,
-                                    chunk.schema)
-                out = self._apply_impl(_scalar_n(own), _scalar_n(other),
-                                       errs[0], local, wm, side,
-                                       match_factor=mf)
-                own2, odeg, cols, ops, vis, errs2, _ = out
-                return (_vec_n(own2), odeg, cols, ops, vis, errs2[None],
-                        own2.n.reshape((1,)))
-            # donation mirrors the parent's: ONLY the sharded error
-            # accumulator (arg 2) — the side states stay aliased by the
-            # diff base (_snap: the state as of the last flush, which the
-            # persist reads the deleted rows from, per shard, at the
-            # positions no live row's `src` lane carries any more)
-            return jit_state(shard_map(
-                apply_sharded, mesh=mesh,
-                in_specs=(shard, shard, shard, repl, repl),
-                out_specs=(shard, shard, shard, shard, shard, shard,
-                           shard)), donate_argnums=(2,),
-                name=f"sharded_join_apply_s{side}")
 
         # ---- fused mesh shuffle: exchange + probe in ONE program ----
         # the chunk enters SHARDED over the row axis; the in-mesh
@@ -138,9 +96,11 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                         (dropped[0] + n_drop)[None],
                         fold_shuffle_obs(obs[0], fill, local.vis)[None],
                         own2.n.reshape((1,)))
-            # donation: the error + shuffle-drop + shuffle-observation
-            # accumulators (threaded); side states stay aliased by the
-            # diff base (_snap, as above)
+            # donation: ONLY the error + shuffle-drop + shuffle-observation
+            # accumulators (threaded) — the side states stay aliased by the
+            # diff base (_snap: the state as of the last flush, which the
+            # persist reads the deleted rows from, per shard, at the
+            # positions no live row's `src` lane carries any more)
             return jit_state(shard_map(
                 apply_fused, mesh=mesh,
                 in_specs=(shard, shard, shard, shard, shard, shard,
@@ -148,70 +108,45 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                 out_specs=(shard,) * 9), donate_argnums=(2, 3, 4),
                 name=f"sharded_join_apply_fused_s{side}")
 
-        # sharded programs trace per (side, match_factor, fused): the
-        # steady state uses the per-side factors, recovery's generous
-        # replay buffer gets its own trace instead of being refused
+        # sharded programs trace per (side, match_factor): the steady
+        # state uses the per-side factors, recovery's generous replay
+        # buffer gets its own trace instead of being refused
         applies: dict = {}
 
-        def apply_program(side, mf, fused, use_pre):
+        def apply_program(side, mf, use_pre):
             # programs also key by the adaptive cap hint active at trace
             # time (None = zero-drop sizing)
-            key = (side, mf, fused, self._cap_hint if fused else None,
-                   use_pre)
+            key = (side, mf, self._cap_hint, use_pre)
             if key not in applies:
-                applies[key] = (make_apply_fused(side, mf, use_pre)
-                                if fused else make_apply(side, mf))
+                applies[key] = make_apply_fused(side, mf, use_pre)
             return applies[key]
         self._apply_program = apply_program
 
         def apply_dispatch(own, other, errs, chunk, wm, side,
                            match_factor=None):
+            chunk = self._mesh_chunk(chunk)
             mf = match_factor or self.match_factors[side]
-            fused = (self.mesh_shuffle
-                     and chunk.capacity % self.n_shards == 0)
             # state replay (recover) feeds join-schema rows, not raw
             # source chunks: skip chain preludes AND the ingest log
             use_pre = not getattr(self, "_state_replay", False)
-            prog = apply_program(side, mf, fused, use_pre)
-            if fused:
-                # replay point: retain the ingest by reference before
-                # the fused program consumes it (sharded_agg.py
-                # MeshIngestLog — the mesh-plane uncommitted suffix).
-                # State-replay chunks are NOT raw ingest and must not
-                # be re-notable.
-                if use_pre:
-                    self.ingest_log.note((side, chunk))
-                (own2, odeg, cols, ops, vis, errs2, self._dropped_dev,
-                 self._shuffle_obs_dev, n) = prog(
-                    own, other, errs, self._dropped_dev,
-                    self._shuffle_obs_dev, chunk, wm)
-                self._count_shuffle_dispatch(chunk, side, mf, use_pre)
-                self.mesh_shuffle_applies += 1
-                return own2, odeg, cols, ops, vis, errs2, n
-            # per-chunk host-plane fallback: hollowed producer stages (if
-            # any) run here eagerly; the crossing counts against the chain
-            if use_pre and self._mesh_preludes.get(side):
-                for fn in self._mesh_preludes[side]:
-                    chunk = fn(chunk)
-            if use_pre and self.mesh_chain is not None:
-                from .monitor import mesh_host_round_trip
-                mesh_host_round_trip(self.mesh_chain)
-            return prog(own, other, errs, chunk, wm)
+            # replay point: retain the ingest by reference before the
+            # fused program consumes it (MeshIngestLog — the mesh-plane
+            # uncommitted suffix)
+            if use_pre:
+                self.ingest_log.note((side, chunk))
+            (own2, odeg, cols, ops, vis, errs2, self._dropped_dev,
+             self._shuffle_obs_dev, n) = apply_program(side, mf, use_pre)(
+                own, other, errs, self._dropped_dev,
+                self._shuffle_obs_dev, chunk, wm)
+            self._count_shuffle_dispatch(chunk, side, mf, use_pre)
+            self.mesh_shuffle_applies += 1
+            return own2, odeg, cols, ops, vis, errs2, n
         self._apply = apply_dispatch
 
         def replay_dispatch(*args, **kwargs):
             own2, odeg, _, _, _, errs2, n = apply_dispatch(*args, **kwargs)
             return own2, odeg, errs2, n
         self._replay = replay_dispatch
-
-        def set_mesh_preludes(side, fns, chain=None):
-            assert self.mesh_shuffle_applies == 0, \
-                "mesh preludes must install before the first fused " \
-                "dispatch"
-            self._mesh_preludes[side] = tuple(fns)
-            if chain is not None:
-                self.mesh_chain = chain
-        self.set_mesh_preludes = set_mesh_preludes
 
         def make_evict(side):
             def evict_sharded(own, wm, kh):
@@ -254,6 +189,17 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
                  jnp.max(so[:, OBS_ROWS])[None],
                  jnp.sum(nl)[None], jnp.sum(nr)[None]]),
             name="sharded_join_watchdog_pack")
+
+    # the join applies chunk by chunk and emits as it goes: its replay is
+    # the frontier channels', never a preloaded interval
+    preload_replay = None
+
+    def set_mesh_preludes(self, side, fns, chain=None) -> None:
+        assert self.mesh_shuffle_applies == 0, \
+            "mesh preludes must install before the first fused dispatch"
+        self._mesh_preludes[side] = tuple(fns)
+        if chain is not None:
+            self.mesh_chain = chain
 
     def _sharded_empty(self, side: int) -> SortedSideState:
         S = self.n_shards
@@ -367,7 +313,7 @@ class ShardedSortedJoinExecutor(MeshShuffleHost, SortedJoinExecutor):
 
     def _recover_reset(self, s: int, rows: list) -> None:
         """Per-shard capacity is sized by the WORST shard's row count
-        (rows route by vnode-of-key, same as the apply-path masking)."""
+        (rows route by vnode-of-key, as the apply path's shuffle does)."""
         if rows:
             keys = [np.asarray([r[k] for r in rows], dtype=np.int64)
                     for k in self.key_indices[s]]

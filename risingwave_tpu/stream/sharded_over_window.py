@@ -41,9 +41,7 @@ class ShardedOverWindowExecutor(ShardedSortedStoreMixin,
                  state_table=None,
                  pk_indices: Optional[Sequence[int]] = None,
                  watchdog_interval: Optional[int] = 1,
-                 *, mesh, mesh_shuffle: bool = True,
-                 mesh_shuffle_slack: int = 0,
-                 mesh_shuffle_adaptive: bool = True):
+                 *, mesh):
         if not partition_by:
             raise ValueError(
                 "ShardedOverWindowExecutor shards along the partition "
@@ -53,8 +51,7 @@ class ShardedOverWindowExecutor(ShardedSortedStoreMixin,
                          capacity, state_table, pk_indices,
                          watchdog_interval)
         self.route_key_indices = self.partition_by
-        self._init_sharded(mesh, mesh_shuffle, mesh_shuffle_slack,
-                           mesh_shuffle_adaptive, watchdog_interval)
+        self._init_sharded(mesh, watchdog_interval)
         self.identity = (f"ShardedOverWindow[S={self.n_shards}]"
                          f"(p={self.partition_by}, o={self.order_specs}, "
                          f"f={[w.kind for w in self.windows]})")

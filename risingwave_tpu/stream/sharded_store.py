@@ -9,8 +9,10 @@ shape to sharded_agg.py, the pattern this module mirrors:
 * state arrays go global [S*C] with per-shard [C] views under shard_map
   (`capacity` becomes PER SHARD); the live count and error counters go
   per-shard ([S] / [S*2] int32, mesh-sharded);
-* the FUSED plane routes each chunk's rows to their owner shard with
-  `mesh_ingest_chunk` (one all_to_all over ICI — no host hop) keyed on
+* each chunk enters row-sliced over the mesh axis (a capacity the shard
+  count does not divide is padded, `MeshShuffleHost._mesh_chunk`) and its
+  rows route to their owner shard with `mesh_ingest_chunk` (one
+  all_to_all over ICI — no host hop) keyed on
   the executor's ROUTING KEY (group/partition axis; the stream key for
   a global top-N), then applies `sorted_store_apply` per shard; chunks
   buffered within a barrier interval batch into one `lax.scan` inside
@@ -44,12 +46,10 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common.chunk import StreamChunk
-from ..common.vnode import compute_vnodes
 from ..ops.jit_state import jit_state
 from ..parallel.exchange import mesh_ingest_chunk, shuffle_bytes
-from ..parallel.mesh import VNODE_AXIS, shard_map, vnode_to_shard
+from ..parallel.mesh import VNODE_AXIS, shard_map
 from ..utils.d2h import fetch_small, off_loop
-from .sharded_agg import MeshIngestLog
 from .mesh_shuffle import OBS_FILL, OBS_ROWS, MeshShuffleHost, fold_shuffle_obs
 from .sorted_join import _HSENTINEL
 from .sorted_store import sorted_store_apply
@@ -73,23 +73,12 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
     _overflow_what = "sharded sorted store"
 
     # --------------------------------------------------------------- init
-    def _init_sharded(self, mesh, mesh_shuffle: bool,
-                      mesh_shuffle_slack: int, mesh_shuffle_adaptive: bool,
+    def _init_sharded(self, mesh,
                       watchdog_interval: Optional[int]) -> None:
-        self.mesh = mesh
-        self.n_shards = mesh.shape[VNODE_AXIS]
-        self._routing = jnp.asarray(vnode_to_shard(self.n_shards))
-        self.mesh_shuffle = bool(mesh_shuffle)
-        self._init_mesh_shuffle(mesh_shuffle_slack, mesh_shuffle_adaptive,
-                                watchdog_interval is not None)
-        self._mesh_preludes: tuple = ()
-        self.mesh_chain: Optional[str] = None
-        self._replay_preload: list = []
-        self.mesh_shuffle_applies = 0
+        self._init_mesh_shuffle(mesh, 0, watchdog_interval is not None)
         self._pending_chunks: list = []
         self._batch_max = 8
         self._occ_known = 0
-        self.ingest_log = MeshIngestLog()
         self._alloc_sharded_store()
         self._build_sharded_programs()
 
@@ -141,29 +130,9 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
     def _build_sharded_programs(self) -> None:
         """(Re)wrap the step impls in shard_map — called at init and
         after a recovery re-size (the programs close over capacity)."""
-        shard, repl = P(VNODE_AXIS), P()
+        shard = P(VNODE_AXIS)
         mesh_kw = dict(mesh=self.mesh)
         name = type(self).__name__
-
-        def apply_sharded(khash, cols, valids, n, errs, chunk):
-            # replicated-mask fallback: every shard sees the whole chunk
-            # and masks it down to the rows it owns
-            my = jax.lax.axis_index(VNODE_AXIS)
-            key_cols = [chunk.columns[i].data
-                        for i in self.route_key_indices]
-            vn = compute_vnodes(key_cols)
-            mine = chunk.vis & (self._routing[vn] == my)
-            local = StreamChunk(chunk.columns, chunk.ops, mine,
-                                chunk.schema)
-            kh, c, v, n2, e2 = sorted_store_apply(
-                khash, cols, valids, n[0], errs, local,
-                pk_idx=self.pk_indices, capacity=self.capacity)
-            return kh, c, v, n2[None], e2
-
-        self._apply = jit_state(shard_map(
-            apply_sharded, in_specs=(shard,) * 5 + (repl,),
-            out_specs=(shard,) * 5, **mesh_kw),
-            donate_argnums=(0, 1, 2, 3, 4), name=f"{name}_apply")
 
         def flush_sharded(khash, cols, valids, n, sh, sc, sv, sn):
             nh, nc, nv, n2, oc, ops, vis = self._flush_local(
@@ -198,24 +167,6 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
         self._fused_scans: dict = {}
 
     # ------------------------------------------------ fused mesh shuffle
-    def set_mesh_preludes(self, fns, chain: Optional[str] = None) -> None:
-        """Install hollow producer-stage impls (root-to-source reversed)
-        to run INSIDE the fused program, upstream of the shuffle."""
-        assert self.mesh_shuffle_applies == 0, \
-            "mesh preludes must install before the first fused dispatch"
-        self._mesh_preludes = tuple(fns)
-        self.mesh_chain = chain
-
-    def _prelude_host(self, chunk: StreamChunk) -> StreamChunk:
-        for fn in self._mesh_preludes:
-            chunk = fn(chunk)
-        return chunk
-
-    def _count_host_hop(self, n: int = 1) -> None:
-        if self.mesh_chain is not None:
-            from .monitor import mesh_host_round_trip
-            mesh_host_round_trip(self.mesh_chain, n)
-
     def _fused_step(self, khash, cols, valids, n, errs, dropped, obs,
                     chunk):
         """Preludes + in-mesh shuffle + sorted-store apply for ONE chunk,
@@ -282,29 +233,15 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
             donate_argnums=(0, 1, 2, 3, 4, 5, 6),
             name=f"{type(self).__name__}_apply_fused_scan{k}")
 
-    def _fused_eligible(self, chunk: StreamChunk) -> bool:
-        return self.mesh_shuffle and chunk.capacity % self.n_shards == 0
-
     def _apply_chunk_raw(self, chunk: StreamChunk) -> None:
-        if self._fused_eligible(chunk):
-            (self.khash, self.cols, self.valids, self.n, self._errs_dev,
-             self._dropped_dev, self._shuffle_obs_dev) = \
-                self._get_fused_apply()(
-                    self.khash, self.cols, self.valids, self.n,
-                    self._errs_dev, self._dropped_dev,
-                    self._shuffle_obs_dev, chunk)
-            self._count_shuffle_dispatch(chunk)
-            self.mesh_shuffle_applies += 1
-        else:
-            # per-chunk host-plane fallback: hollowed producer stages run
-            # eagerly and the crossing counts against the chain
-            if self._mesh_preludes:
-                chunk = self._prelude_host(chunk)
-            self._count_host_hop()
-            (self.khash, self.cols, self.valids, self.n,
-             self._errs_dev) = self._apply(
+        (self.khash, self.cols, self.valids, self.n, self._errs_dev,
+         self._dropped_dev, self._shuffle_obs_dev) = \
+            self._get_fused_apply()(
                 self.khash, self.cols, self.valids, self.n,
-                self._errs_dev, chunk)
+                self._errs_dev, self._dropped_dev,
+                self._shuffle_obs_dev, chunk)
+        self._count_shuffle_dispatch(chunk)
+        self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
 
     def _drain_pending(self) -> None:
@@ -315,13 +252,16 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
         # replay point: retain the interval's ingest BEFORE the fused
         # program consumes it (references only). With preludes installed
         # the RAW source chunk is the replay point — re-running the fused
-        # program re-runs the hollowed producer stages too.
+        # program re-runs the hollowed producer stages too. The log holds
+        # the chunk AS IT CAME (the frontier channels skip a preloaded
+        # chunk by identity); the drain pads it, a replay's too.
         for ch in p:
             self.ingest_log.note(ch)
+        p = [self._mesh_chunk(ch) for ch in p]
         uniform = len({(c.capacity, len(c.columns),
                         tuple(col.valid is not None for col in c.columns))
                        for c in p}) == 1
-        if len(p) == 1 or not self._fused_eligible(p[0]) or not uniform:
+        if len(p) == 1 or not uniform:
             for ch in p:
                 self._apply_chunk_raw(ch)
             return
@@ -343,12 +283,6 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
         self._count_shuffle_dispatch(p[0], chunks=k)
         self.mesh_shuffle_applies += 1
         self._applied_since_flush = True
-
-    def preload_replay(self, chunks) -> None:
-        """Channel-free mesh replay: the crashed executor's uncommitted
-        ingest suffix, staged here and installed into the pending queue
-        by recover_state at the INITIAL barrier."""
-        self._replay_preload = list(chunks)
 
     # -------------------------------------------------------------- hooks
     def on_chunk(self, chunk: StreamChunk) -> None:
@@ -402,8 +336,8 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
             # raw (pre-prelude) chunks are the replay point, but the
             # state table persists EXECUTOR-SCHEMA rows: run the hollow
             # producer stages host-side before writing through
-            if self._mesh_preludes:
-                c = self._prelude_host(c)
+            for fn in self._mesh_preludes:
+                c = fn(c)
             vis = np.asarray(c.vis)
             if vis.any():
                 self.state_table.write_chunk_columns(
@@ -417,9 +351,9 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
         the vnode routing, rebuild each shard's local store, concatenate
         along the mesh axis, then seed the diff baseline with one
         discarded sharded flush (same rationale as the parents')."""
-        preload = getattr(self, "_replay_preload", None)
-        if preload:
-            self._pending_chunks = list(preload) + self._pending_chunks
+        if self._replay_preload:
+            self._pending_chunks = self._replay_preload \
+                + self._pending_chunks
             self._replay_preload = []
             # the template only flushes epochs that saw input: mark the
             # preloaded suffix as pending work so the NEXT barrier drains
@@ -456,7 +390,7 @@ class ShardedSortedStoreMixin(MeshShuffleHost):
             partial(sorted_store_apply, pk_idx=self.pk_indices,
                     capacity=C),
             donate_argnums=(0, 1, 2, 3, 4),
-            name=f"{type(self).__name__}_recover_apply")
+            name=f"{type(self).__name__}_recover_rows")
         locals_ = []
         for part_rows in by_shard:
             kh = jnp.full(C, _HSENTINEL, dtype=jnp.int64)

@@ -60,9 +60,7 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
                  state_table=None,
                  pk_indices: Optional[Sequence[int]] = None,
                  watchdog_interval: Optional[int] = 1,
-                 *, mesh, mesh_shuffle: bool = True,
-                 mesh_shuffle_slack: int = 0,
-                 mesh_shuffle_adaptive: bool = True):
+                 *, mesh):
         # parent ctor builds the single-device [C] store + programs;
         # _init_sharded replaces them with the [S*C] mesh-sharded layout
         # (capacity is PER SHARD from here on)
@@ -78,8 +76,7 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
             assert self.offset + self.limit <= capacity, \
                 "global top-N needs offset+limit <= per-shard capacity " \
                 "(each shard contributes that many candidates)"
-        self._init_sharded(mesh, mesh_shuffle, mesh_shuffle_slack,
-                           mesh_shuffle_adaptive, watchdog_interval)
+        self._init_sharded(mesh, watchdog_interval)
         self.identity = (f"ShardedTopN[S={self.n_shards}]"
                          f"(g={self.group_key_indices}, "
                          f"by={self.order_specs}, k={limit})")

@@ -13,8 +13,8 @@ cache key, so a path that moves never hits). If it is not set, the
 cache is `<checkout>/.jax_cache` (git-ignored), a fixed path.
 `enable_persistent_cache()` is the only code in the repo that touches
 the cache setting; importing `risingwave_tpu` alone sets none. Every
-entry point that re-runs canned shapes calls it: chip_smoke.py,
-bench.py, tests/conftest.py, the scripts/*_profile.py CI gates, and the
+entry point that re-runs canned shapes calls it: benchmark/run.py,
+tests/conftest.py, the scripts/*_profile.py CI gates, and the
 cluster worker (a compute node restarted by recovery recompiles nothing
 it compiled in a previous life).
 """

@@ -388,8 +388,7 @@ TRACE_SPANS_DROPPED = GLOBAL_METRICS.counter("trace_spans_dropped_total")
 # stream/sharded_*.py, host half in stream/mesh_shuffle.py). The series:
 # - `mesh_shuffle_dropped_rows_total`: rows the in-mesh all_to_all
 #   shuffle dropped because a (src, dst) send bucket overflowed its
-#   per-pair capacity (streaming_mesh_shuffle_slack sized it too tight
-#   for the key skew). Nonzero is a FAIL-STOP: the owning executor
+#   per-pair capacity (sized too tight for the key skew). Nonzero is a FAIL-STOP: the owning executor
 #   raises at the barrier watchdog fetch before the epoch's checkpoint
 #   commits, so a dropped row is never silently absent from durable
 #   state.
@@ -405,10 +404,9 @@ TRACE_SPANS_DROPPED = GLOBAL_METRICS.counter("trace_spans_dropped_total")
 #   demand of the last interval: the adaptive slack's signal). All of
 #   them ride the watchdog fetch the barrier makes anyway; the labelled
 #   ones go when their fragment does.
-# - `mesh_fragment_shards{actor=...}`, `mesh_chain_fragments{chain=...}`,
-#   `mesh_host_round_trips_total{chain=...}`: set when a fused mesh
-#   fragment / chain registers with the barrier coordinator
-#   (meta/barrier_manager.py), removed when it is dropped.
+# - `mesh_fragment_shards{actor=...}`, `mesh_chain_fragments{chain=...}`:
+#   set when a fused mesh fragment / chain registers with the barrier
+#   coordinator (meta/barrier_manager.py), removed when it is dropped.
 MESH_SHUFFLE_DROPPED = GLOBAL_METRICS.counter(
     "mesh_shuffle_dropped_rows_total")
 MESH_SHUFFLE_ROWS = GLOBAL_METRICS.counter("mesh_shuffle_rows_total")

@@ -30,8 +30,8 @@ from typing import Optional
 
 from .metrics import GLOBAL_METRICS
 
-# series the autoscaler / stall autopsies care about out of the box;
-# `metrics_history_series` (frontend/session.py) overrides the list.
+# series the stall autopsies care about out of the box (the session runs
+# the constructor's defaults; `configure(series=...)` overrides the list)
 DEFAULT_SERIES = (
     "meta_barrier_latency_seconds",
     "checkpoint_inflight_epochs",
@@ -48,7 +48,7 @@ DEFAULT_SERIES = (
     "barrier_stalls_total",
 )
 
-# stall-relevant subset dumped by bench.py deadline-abort autopsies
+# stall-relevant subset of `dump_tail`
 STALL_SERIES = (
     "meta_barrier_latency_seconds",
     "checkpoint_inflight_epochs",
@@ -227,7 +227,7 @@ class MetricsHistory:
 
     def dump_tail(self, names=STALL_SERIES, k: int = 8) -> str:
         """Human-readable last-K-samples digest of the stall-relevant
-        series — bench.py deadline-abort autopsies print this."""
+        series, for a stall autopsy."""
         lines = []
         with self._lock:
             items = sorted(self._series.items())

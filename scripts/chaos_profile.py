@@ -633,8 +633,7 @@ async def _run_mesh_topn_crash(tmp: str) -> dict:
         "recoveries": s.recoveries,
         "last_recovery": s.last_recovery,
         "total_actors": total_actors,
-        "replanned_sharded": bool(replanned)
-        and all(t.mesh_shuffle for t in replanned),
+        "replanned_sharded": bool(replanned),
     }
     await s.drop_all()
     return out

@@ -1,6 +1,6 @@
 """Test harness: the suite runs on the CPU, on eight virtual devices.
 
-Tests never take a chip (the chip path is proven by `python chip_smoke.py`
+Tests never take a chip (the chip path is proven by `benchmark/run.py`
 on the machine that has one): they must be deterministic and multi-device
 wherever they run, so this file forces the CPU backend with
 `--xla_force_host_platform_device_count=8` before jax initializes — by

@@ -2,8 +2,7 @@
 (ISSUE 8 / ROADMAP item 2): the exchange -> sharded-executor chain runs
 as ONE shard_map program per barrier interval — rows vnode-route to
 their owner shard via an in-program lax.all_to_all
-(parallel/exchange.mesh_ingest_chunk) instead of replicate-and-mask or
-host channel hops.
+(parallel/exchange.mesh_ingest_chunk), no host channel hop.
 
 Covered here:
   * bit-identical results vs the single-device executor for a q7-shaped
@@ -16,7 +15,7 @@ Covered here:
   * mesh fragments register with the barrier coordinator as ONE actor
     covering all shards
   * persistent-compile-cache namespacing by backend + machine
-    fingerprint (the MULTICHIP_r05 cpu_aot_loader hazard)
+    fingerprint (the cpu_aot_loader hazard)
 """
 
 import asyncio
@@ -58,9 +57,10 @@ def barrier(curr, prev, kind=BarrierKind.CHECKPOINT):
     return Barrier(EpochPair(curr, prev), kind)
 
 
-def bid_chunk(rng, n=64, cap=64, epoch=0):
+def bid_chunk(rng, n=64, cap=64, epoch=0, price=None):
     auction = rng.integers(0, 40, n).astype(np.int64)
-    price = rng.integers(1, 10_000, n).astype(np.int64)
+    if price is None:
+        price = rng.integers(1, 10_000, n).astype(np.int64)
     ts = (epoch * W // 2 + rng.integers(0, W, n)).astype(np.int64)
     wend = ts - ts % W + W
     return StreamChunk.from_numpy(BID, [auction, price, wend],
@@ -121,7 +121,6 @@ async def test_fused_agg_bit_identical_and_one_dispatch_per_interval():
         [AggCall(AggKind.MAX, 1, BID[1].data_type, append_only=True),
          count_star()],
         mesh=mesh, capacity=64)
-    assert sh.mesh_shuffle, "fused plane must be the default"
     d0 = _fused_dispatches()
     got = changelog(await drive(sh))
     d1 = _fused_dispatches()
@@ -193,23 +192,62 @@ async def test_fused_agg_crash_recover_bit_identical():
     assert got == want and len(got) > 0
 
 
-async def test_fused_agg_non_divisible_capacity_falls_back():
-    """A chunk whose capacity does not divide by the shard count cannot
-    row-slice over the mesh — it must take the replicated-mask path and
-    still produce identical results."""
+def _padded_case(what, msgs, mesh):
+    """(sharded executor, its unsharded twin) over the same messages."""
+    from risingwave_tpu.stream.retract_top_n import RetractableTopNExecutor
+    from risingwave_tpu.stream.sharded_top_n import ShardedTopNExecutor
+    from risingwave_tpu.stream.sorted_join import SortedJoinExecutor
+
+    def src():
+        return ScriptSource(BID, list(msgs))
+    if what == "agg":
+        calls = [count_star(), agg_sum(1)]
+        return (ShardedHashAggExecutor(src(), [0], calls, mesh=mesh,
+                                       capacity=32),
+                HashAggExecutor(src(), [0], calls, capacity=256))
+    if what == "join":
+        # both sides take the same chunks: auction = auction, pk = price
+        kw = dict(left_key_indices=[0], right_key_indices=[0],
+                  left_pk_indices=[1], right_pk_indices=[1],
+                  match_factor=16)
+        return (ShardedSortedJoinExecutor(src(), src(), mesh,
+                                          capacity=64, **kw),
+                SortedJoinExecutor(src(), src(), capacity=512, **kw))
+    kw = dict(group_key_indices=(0,), order_col=1, limit=3,
+              pk_indices=(1,))
+    return (ShardedTopNExecutor(src(), mesh=mesh, capacity=64, **kw),
+            RetractableTopNExecutor(src(), capacity=512, **kw))
+
+
+@pytest.mark.parametrize("what,cap", [("agg", 44), ("agg", 4),
+                                      ("join", 44), ("top_n", 44)])
+async def test_non_divisible_capacity_is_padded_and_stays_fused(what, cap):
+    """A capacity the shard count does not divide (44 % 8, and 4 rows
+    under the 8 shards) is padded with invisible tail rows where the
+    chunk enters the mesh executor and runs the FUSED program like any
+    other: same results as the unsharded executor, no shuffle drop."""
     rng = np.random.default_rng(7)
-    msgs = [barrier(1, 0, BarrierKind.INITIAL),
-            bid_chunk(rng, n=44, cap=44),        # 44 % 8 != 0
-            barrier(2, 1)]
-    mesh = make_mesh(8)
-    sh = ShardedHashAggExecutor(
-        ScriptSource(BID, msgs), [0], [count_star(), agg_sum(1)],
-        mesh=mesh, capacity=32)
+    # prices unique: the join's and the top-N's pk
+    chunks = [bid_chunk(rng, n=cap, cap=cap,
+                        price=np.arange(cap, dtype=np.int64) + 1000 * i)
+              for i in range(2)]
+    msgs = [barrier(1, 0, BarrierKind.INITIAL), *chunks, barrier(2, 1)]
+    sh, plain = _padded_case(what, msgs, make_mesh(8))
+    padded = sh._mesh_chunk(chunks[0])
+    assert padded.capacity == (48 if cap == 44 else 8)
+    assert int(np.asarray(padded.vis).sum()) == cap
+    whole = bid_chunk(rng)                       # 64 rows: 8 a shard
+    assert sh._mesh_chunk(whole) is whole, \
+        "a capacity the shard count divides must come back untouched"
+    before = MESH_SHUFFLE_DROPPED.value
     got = changelog(await drive(sh))
-    assert sh.mesh_shuffle_applies == 0, "44-cap chunk must not fuse"
-    plain = HashAggExecutor(
-        ScriptSource(BID, msgs), [0], [count_star(), agg_sum(1)],
-        capacity=256)
+    assert sh.mesh_shuffle_applies > 0, "padded chunk must run fused"
+    assert MESH_SHUFFLE_DROPPED.value == before
+    if what != "join":
+        # the replay point holds the chunks as they came: the frontier
+        # channels skip a preloaded chunk by identity
+        logged = [c for _, cs in sh.ingest_log.entries() for c in cs]
+        assert len(logged) == 2 and logged[0] is chunks[0]
     want = changelog(await drive(plain))
     assert got == want and len(got) > 0
 
@@ -331,7 +369,7 @@ async def test_fused_join_planned_bit_identical_and_recovers(tmp_path):
                 if isinstance(node, ShardedSortedJoinExecutor):
                     joins.append(node)
                 node = getattr(node, "input", None)
-    assert len(joins) == 1 and joins[0].mesh_shuffle
+    assert len(joins) == 1
     # the fused chain registered as ONE actor covering 8 shards
     assert any(n == 8 for n, _ in s.coord.mesh_fragments.values())
     await s.tick(2)
@@ -354,8 +392,7 @@ async def test_fused_join_planned_bit_identical_and_recovers(tmp_path):
                 if isinstance(node, ShardedSortedJoinExecutor):
                     joins2.append(node)
                 node = getattr(node, "input", None)
-    assert joins2 and joins2[0].mesh_shuffle, \
-        "recovery replanned without the fused mesh"
+    assert joins2, "recovery replanned without the fused mesh"
     got = Counter(s.query("SELECT id, window_start FROM mj"))
     assert got == _join_oracle(s, "mj")
     assert sum(got.values()) > 0
@@ -451,28 +488,22 @@ def _chain_rows(s):
     return sorted(s.query("SELECT auction, window_end, maxprice, n FROM m"))
 
 
-async def test_mesh_chain_fused_zero_host_hops_one_dispatch():
-    """Tentpole contract: the q7-shaped source -> project -> sharded-agg
-    chain fuses — producer stages hollow into preludes of the consumer's
-    shard_map program, ZERO per-chunk host hops per steady interval,
-    exactly one fused dispatch per interval, and the materialized rows
+async def test_fused_mesh_chain_one_dispatch_per_interval():
+    """The q7-shaped source -> project -> sharded-agg chain fuses —
+    producer stages hollow into preludes of the consumer's shard_map
+    program, exactly one fused dispatch per interval, and the materialized rows
     are bit-identical to the single-device recount at the quiesced
     offset."""
-    from risingwave_tpu.stream.monitor import mesh_host_round_trips
     from risingwave_tpu.stream.source import SourceExecutor
     s = await _chain_session()
     chains = dict(s.coord.mesh_chains)
     assert len(chains) == 1
     (chain, info), = chains.items()
-    assert info["hollow"], "chain must hollow by default"
     agg = _chain_agg(s)
     assert agg.mesh_chain == chain and len(agg._mesh_preludes) == 2, \
         "both producer project stages must install as preludes"
-    h0 = mesh_host_round_trips()
     a0 = agg.mesh_shuffle_applies
     await s.tick(4)
-    assert mesh_host_round_trips() - h0 == 0, \
-        "fused steady interval must not touch the host per chunk"
     assert agg.mesh_shuffle_applies - a0 == 4, \
         "one fused dispatch per barrier interval"
     await _quiesce(s)
@@ -493,47 +524,17 @@ def _iter_chain(root):
         node = getattr(node, "input", None)
 
 
-async def test_mesh_chain_unfused_fallback_identical():
-    """SET streaming_mesh_chain = 0: the chain still registers (the
-    host-hop counter runs — that is the PR 8 comparison plane) but the
-    producer stages stay host-side, pay counted per-chunk hops, and the
-    results stay bit-identical."""
-    from risingwave_tpu.stream.monitor import mesh_host_round_trips
-    from risingwave_tpu.stream.source import SourceExecutor
-    s = await _chain_session(pre=("SET streaming_mesh_chain = 0",))
-    (chain, info), = dict(s.coord.mesh_chains).items()
-    assert not info["hollow"]
-    agg = _chain_agg(s)
-    assert agg.mesh_chain == chain and not agg._mesh_preludes
-    h0 = mesh_host_round_trips(chain)
-    await s.tick(3)
-    assert mesh_host_round_trips(chain) - h0 > 0, \
-        "un-hollowed producer stages must count host hops"
-    await _quiesce(s)
-    srcs = [node for roots in s.catalog.mvs["m"].deployment.roots.values()
-            for root in roots
-            for node in _iter_chain(root)
-            if isinstance(node, SourceExecutor)]
-    offset = max(g.connector.offset for g in srcs)
-    assert _chain_rows(s) == _chain_oracle(offset) and offset > 0
-    await s.drop_all()
-
-
 async def test_mesh_chain_crash_recovers_fused_with_preload(tmp_path):
     """Crash the fused consumer actor mid-stream: mesh-scope recovery
     rebuilds it, the chain re-fuses (preludes reinstalled, hollow
     producers intact), the captured MeshIngestLog suffix preloads into
-    the rebuilt fused program (channel-free replay — zero host hops
-    through recovery), and the MV converges bit-identical to the host
+    the rebuilt fused program (channel-free replay), and the MV converges bit-identical to the host
     recount at the committed offset."""
     from oracle import committed_offsets
     from risingwave_tpu.state import HummockStateStore, LocalFsObjectStore
-    from risingwave_tpu.stream.monitor import mesh_host_round_trips
     store = HummockStateStore(LocalFsObjectStore(str(tmp_path / "d")))
     s = await _chain_session(store=store)
     (chain, info), = dict(s.coord.mesh_chains).items()
-    assert info["hollow"]
-    h0 = mesh_host_round_trips(chain)
     await s.tick(3)
     dep = s.catalog.mvs["m"].deployment
     by_id = {a.actor_id: i for i, a in enumerate(dep.actors)}
@@ -546,13 +547,10 @@ async def test_mesh_chain_crash_recovers_fused_with_preload(tmp_path):
     await s.tick(3, max_recoveries=8)
     assert s.recoveries >= 1
     assert s.last_recovery["scope"] == "mesh"
-    (chain2, info2), = dict(s.coord.mesh_chains).items()
-    assert chain2 == chain and info2["hollow"], \
+    assert list(s.coord.mesh_chains) == [chain], \
         "recovery must re-fuse the chain"
     agg = _chain_agg(s)
     assert len(agg._mesh_preludes) == 2
-    assert mesh_host_round_trips(chain) - h0 == 0, \
-        "channel-free replay must not reintroduce per-chunk host hops"
     await _quiesce(s)
     offset = committed_offsets(s, "m")["bid"]
     assert _chain_rows(s) == _chain_oracle(offset) and offset > 0
@@ -560,7 +558,7 @@ async def test_mesh_chain_crash_recovers_fused_with_preload(tmp_path):
 
 
 async def test_adaptive_shuffle_slack_sizes_from_observed_occupancy():
-    """Adaptive slack (no manual streaming_mesh_shuffle_slack): after a
+    """Adaptive slack (no `mesh_shuffle_slack` given): after a
     few watchdog observations the executor derives a power-of-two cap
     hint >= 2x the worst observed per-(src,dst) send-bucket demand, keeps
     zero-drop semantics, and stays bit-identical to the single-device
@@ -591,8 +589,8 @@ async def test_adaptive_shuffle_slack_sizes_from_observed_occupancy():
 
 
 async def test_manual_slack_overrides_adaptive():
-    """An explicit streaming_mesh_shuffle_slack keeps the PR 8 manual
-    sizing — adaptive derivation stays off."""
+    """An explicit `mesh_shuffle_slack` keeps the manual sizing —
+    adaptive derivation stays off."""
     mesh = make_mesh(8)
     sh = ShardedHashAggExecutor(
         ScriptSource(BID, []), [0], [count_star()], mesh=mesh,
@@ -603,15 +601,13 @@ async def test_manual_slack_overrides_adaptive():
 
 # ------------------------------------------- two-input fused join chains
 
-async def test_fused_join_chain_hollows_both_sides_zero_host_hops():
+async def test_fused_join_chain_hollows_both_sides():
     """Two-input auto-fusion: the q8-shaped join's per-side producer
     fragments (TUMBLE projects over each source leg) hollow into
     per-side preludes of the join's fused shard_map programs — one
-    registered chain per side — and a steady fused interval pays ZERO
-    per-chunk host hops while staying bit-identical to the host recount
-    at the quiesced committed offsets."""
+    registered chain per side — bit-identical to the host recount at the
+    quiesced committed offsets."""
     from risingwave_tpu.frontend import Session
-    from risingwave_tpu.stream.monitor import mesh_host_round_trips
     s = Session()
     await s.execute("SET streaming_durability = 0")
     await s.execute("SET streaming_parallelism_devices = 8")
@@ -621,8 +617,6 @@ async def test_fused_join_chain_hollows_both_sides_zero_host_hops():
     chains = dict(s.coord.mesh_chains)
     sides = sorted(c for c in chains if c[-2:] in ("s0", "s1"))
     assert len(sides) == 2, f"expected one chain per join side: {chains}"
-    assert all(chains[c]["hollow"] for c in sides), \
-        "both join sides must hollow by default"
     joins = [node for roots in
              s.catalog.mvs["mj"].deployment.roots.values()
              for root in roots for node in _iter_chain(root)
@@ -632,12 +626,9 @@ async def test_fused_join_chain_hollows_both_sides_zero_host_hops():
     assert set(join._mesh_preludes) == {0, 1} \
         and all(join._mesh_preludes.values()), \
         "both sides must install prelude stacks"
-    h0 = mesh_host_round_trips()
     a0 = join.mesh_shuffle_applies
     await s.tick(3)
     assert join.mesh_shuffle_applies > a0, "fused join never engaged"
-    assert mesh_host_round_trips() - h0 == 0, \
-        "fused two-input chain must not touch the host per chunk"
     await _quiesce(s)
     got = Counter(s.query("SELECT id, window_start FROM mj"))
     assert got == _join_oracle(s, "mj") and sum(got.values()) > 0

@@ -70,7 +70,7 @@ def no_persistent_cache():
 
 @pytest.fixture(scope="module")
 def q7_executors():
-    """The q7 plan of bench.py / chip_smoke.py, deployed (no data is run)."""
+    """The q7 plan of the benchmark's `q7.sat`, deployed (no data is run)."""
     from risingwave_tpu.frontend import Session
     from risingwave_tpu.plan.build import _iter_executor_chain
 
@@ -699,7 +699,7 @@ def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
         own, other = (abstract(join.sides[s], sharded)
                       for s in (side, 1 - side))
         compiled = join._apply_program(
-            side, join.match_factors[side], True, True)._jitted.lower(
+            side, join.match_factors[side], True)._jitted.lower(
             own, other, *acc,
             abstract_chunk(join.inputs[side].schema, cap, sharded),
             wm).compile()
