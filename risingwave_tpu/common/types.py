@@ -176,6 +176,10 @@ class StringDictionary:
                     self._ids[s] = i
         return i
 
+    def lookup(self, s: str) -> "int | None":
+        """The id of `s`, or None: a read that never mints one."""
+        return self._ids.get(s)
+
     def encode_many(self, strings) -> np.ndarray:
         return np.asarray([self.get_or_insert(s) for s in strings], dtype=np.int32)
 

@@ -200,8 +200,9 @@ class StreamPlanner:
         return self._source_frags[name]
 
     # ----------------------------------------------------------- relations
-    def plan_rel(self, rel) -> tuple[int, Scope, RelInfo]:
-        """Returns (fragment id, scope over its output, stream info)."""
+    def plan_rel(self, rel, rank_filter=None) -> tuple[int, Scope, RelInfo]:
+        """Returns (fragment id, scope over its output, stream info).
+        `rank_filter`: what `_match_rank_filter` found over a subquery."""
         if isinstance(rel, ast.TableRel):
             # an MV name resolves to a backfilled stream scan over it
             # (MV-on-MV, reference StreamScan/Chain); sources otherwise
@@ -454,7 +455,7 @@ class StreamPlanner:
             # no intermediate MV)
             from ..common.types import Field
             sub_fid, names, types, pk_hint, ao, wm = self._plan_query(
-                rel.select)
+                rel.select, rank_filter=rank_filter)
             schema = Schema(tuple(Field(n, t)
                                   for n, t in zip(names, types)))
             return (sub_fid, Scope.of(schema, rel.alias),
@@ -496,11 +497,13 @@ class StreamPlanner:
         return BoundPlan(self.graph, mv.fid, out, tuple(pk_hint),
                          append_only)
 
-    def _plan_query(self, sel: ast.Select):
+    def _plan_query(self, sel: ast.Select, rank_filter=None):
         """Plan one SELECT (no materialization). Returns (fragment id,
         out names, out DataTypes, pk_hint, append_only) — pk_hint is the
         output positions forming the stream key, or None when the stream
-        is keyless append-only (caller adds a row_id)."""
+        is keyless append-only (caller adds a row_id). `rank_filter`
+        (limit, emit_rank): the SELECT is the subquery of a rank filter,
+        its ROW_NUMBER() window plans a group top-N."""
         top_spec = (list(sel.order_by), sel.limit, sel.offset)
         want_top_n = sel.limit is not None
         if (sel.order_by or sel.offset) and not want_top_n:
@@ -525,7 +528,13 @@ class StreamPlanner:
         if fused is not None:
             return fused
 
-        fid, scope, info = self.plan_rel(rel)
+        # `rank <= N` directly over ROW_NUMBER() OVER (PARTITION BY ..): the
+        # subquery plans a group top-N and the conjunct goes
+        matched = _match_rank_filter(rel, where, sel)
+        if matched is not None:
+            where = matched[1]
+        fid, scope, info = self.plan_rel(
+            rel, rank_filter=None if matched is None else matched[0])
         frag = self.graph.fragments[fid]
         sel = ast.Select(expand_star(sel.items, scope.schema), rel,
                          where, sel.group_by, list(sel.order_by),
@@ -593,7 +602,7 @@ class StreamPlanner:
                              inputs=(frag.root,))
 
         if any(isinstance(it.expr, ast.WindowFunc) for it in sel.items):
-            out = self._plan_over_window(sel, fid, scope, info)
+            out = self._plan_over_window(sel, fid, scope, info, rank_filter)
             if want_top_n:
                 out = self._plan_top_n(top_spec, out)
             return out
@@ -1223,11 +1232,22 @@ class StreamPlanner:
         return True
 
     def _plan_over_window(self, sel: ast.Select, fid: int, scope: Scope,
-                          info: RelInfo):
+                          info: RelInfo, rank_filter=None):
         """SELECT items with OVER clauses -> a general_over_window node
         computing every window function in one pass (reference:
         StreamOverWindow from LogicalOverWindow; all calls must share one
-        window definition, like the reference's OverWindow grouping)."""
+        window definition, like the reference's OverWindow grouping).
+
+        With `rank_filter` = (limit, emit_rank) — the SELECT is the
+        subquery of `WHERE rank <= limit` and its one window function is
+        ROW_NUMBER() with a PARTITION BY (`_match_rank_filter`) — the node
+        is a `retract_top_n` WITH group keys instead (reference:
+        over_window_to_topn_rule -> StreamGroupTopN, append-only when its
+        input is): order = the window's ORDER BY, then the stream key
+        ascending; the rank is an output column only where the outer query
+        reads it. It stays in the fragment of its input; where the session
+        is parallel that fragment hash-dispatches on the partition columns
+        into a fragment of the top-N's own."""
         from ..common.types import Field
         from ..stream.general_over_window import WindowSpec
         frag = self.graph.fragments[fid]
@@ -1276,6 +1296,11 @@ class StreamPlanner:
                           + (Field("_row_id", DataType.SERIAL),))
             scope = Scope(sch2, dict(scope.names))
             sk = (len(sch2) - 1,)
+
+        if rank_filter is not None:
+            return self._plan_group_top_n(
+                sel, fid, scope, info, rank_filter, partition_by,
+                order_specs, sk)
 
         windows = []
         for j, w in enumerate(wfs):
@@ -1371,18 +1396,7 @@ class StreamPlanner:
                 exprs.append(bind_scalar(it.expr, ext_scope))
                 names.append(it.alias or auto_name(it.expr, j))
         from ..expr.ir import InputRef
-        key_pos = []
-        for ki in sk:
-            found = None
-            for j2, e2 in enumerate(exprs):
-                if isinstance(e2, InputRef) and e2.index == ki:
-                    found = j2
-                    break
-            if found is None:
-                exprs.append(col(ki, ext_scope.schema[ki].data_type))
-                names.append(f"_sk{ki}")
-                found = len(exprs) - 1
-            key_pos.append(found)
+        key_pos = _keep_stream_key(exprs, names, sk, ext_scope.schema)
         frag.root = Node("project", dict(exprs=exprs, names=names),
                          inputs=(frag.root,))
         # EOWC output is append-only (final rows, exactly once) and
@@ -1395,6 +1409,58 @@ class StreamPlanner:
                 if isinstance(e2, InputRef) and e2.index == oc)
         return (fid, names, [e.ret_type for e in exprs], tuple(key_pos),
                 eowc, wm_out)
+
+    def _plan_group_top_n(self, sel, fid: int, scope: Scope, info: RelInfo,
+                          rank_filter, partition_by, order_specs, sk):
+        """The rank-filter half of `_plan_over_window`: the node, then the
+        SELECT's projection with the hidden stream key."""
+        limit, emit_rank = rank_filter
+        frag = self.graph.fragments[fid]
+        ordered = {c for c, _ in order_specs}
+        md = self.cfg("streaming_parallelism_devices", 1)
+        args = dict(
+            group_key_indices=list(partition_by),
+            order_specs=list(order_specs) + [(k, False) for k in sk
+                                             if k not in ordered],
+            limit=limit, offset=0, durable=self.durable(),
+            pk_indices=list(sk),
+            capacity=self.cfg("streaming_top_n_capacity", 1 << 14),
+            mesh_devices=md,
+            watchdog_interval=(
+                1 if self.cfg("streaming_watchdog", 1) else None),
+            append_only=bool(info.append_only), emit_rank=emit_rank)
+        if self.parallelism > 1 and md == 1:
+            # every group whole on one actor
+            frag.dispatch = "hash"
+            frag.dist_key_indices = tuple(partition_by)
+            # (its own dispatch: by the stream key, re-pointed below at
+            # where the projection leaves it)
+            frag = self.graph.add(Fragment(self.fid(), Node(
+                "retract_top_n", args, inputs=(Exchange(fid),)),
+                dispatch="hash", dist_key_indices=tuple(sk),
+                parallelism=self.parallelism))
+            fid = frag.fid
+        else:
+            frag.root = Node("retract_top_n", args, inputs=(frag.root,))
+        in_width = len(scope.schema)
+        exprs, names = [], []
+        for j, it in enumerate(sel.items):
+            if isinstance(it.expr, ast.WindowFunc):
+                if emit_rank:
+                    exprs.append(col(in_width, DataType.INT64))
+                    names.append(it.alias)
+            else:
+                exprs.append(bind_scalar(it.expr, scope))
+                names.append(it.alias or auto_name(it.expr, j))
+        key_pos = _keep_stream_key(exprs, names, sk, scope.schema)
+        frag.root = Node("project", dict(exprs=exprs, names=names),
+                         inputs=(frag.root,))
+        if frag.dispatch == "hash":
+            frag.dist_key_indices = tuple(key_pos)
+        # ranks can change retroactively: no watermark survives, and the
+        # output retracts whatever the input did
+        return (fid, names, [e.ret_type for e in exprs], tuple(key_pos),
+                False, frozenset())
 
     def _plan_top_n(self, top_spec, planned):
         """Streaming ORDER BY + LIMIT -> RetractableTopN over the query's
@@ -1655,6 +1721,98 @@ class StreamPlanner:
         wm_out = frozenset(key_out[kj] for kj in wm_keys)
         return (agg.fid, names, [e.ret_type for e in post], tuple(pk),
                 wm_out)
+
+
+def _keep_stream_key(exprs: list, names: list, sk, schema) -> list:
+    """Positions of the stream-key columns `sk` in the projection `exprs` /
+    `names`, each appended as a hidden `_sk<i>` column where the SELECT
+    does not carry it (the reference appends hidden stream-key columns the
+    same way)."""
+    from ..expr.ir import InputRef
+    key_pos = []
+    for ki in sk:
+        found = next((j for j, e in enumerate(exprs)
+                      if isinstance(e, InputRef) and e.index == ki), None)
+        if found is None:
+            exprs.append(col(ki, schema[ki].data_type))
+            names.append(f"_sk{ki}")
+            found = len(exprs) - 1
+        key_pos.append(found)
+    return key_pos
+
+
+def _names_column(e, name: str) -> bool:
+    """Whether the AST `e` reads a column called `name` (or `*`)."""
+    if isinstance(e, ast.ColRef):
+        return e.name in (name, "*")
+    if isinstance(e, (list, tuple)):
+        return any(_names_column(x, name) for x in e)
+    if hasattr(e, "__dataclass_fields__") and not isinstance(
+            e, (ast.Select, ast.SubqueryRel, ast.TableRel, ast.WindowRel,
+                ast.JoinRel)):
+        return any(_names_column(getattr(e, f), name)
+                   for f in e.__dataclass_fields__)
+    return False
+
+
+def _rank_bound(conj, name: str, qualifier) -> Optional[int]:
+    """N where `conj` is `name <= N`, `name < N + 1`, `name = 1` (or the
+    mirrored comparison) over a plain reference to the rank column."""
+    if not isinstance(conj, ast.BinOp):
+        return None
+    flip = {"less_than": "greater_than", "greater_than": "less_than",
+            "less_than_or_equal": "greater_than_or_equal",
+            "greater_than_or_equal": "less_than_or_equal",
+            "equal": "equal"}
+    op, ref, lit_ = conj.op, conj.left, conj.right
+    if isinstance(ref, ast.Lit) and op in flip:
+        op, ref, lit_ = flip[op], lit_, ref
+    if not (isinstance(ref, ast.ColRef) and ref.name == name
+            and ref.qualifier in (None, qualifier)
+            and isinstance(lit_, ast.Lit) and isinstance(lit_.value, int)
+            and not isinstance(lit_.value, bool)):
+        return None
+    n = {"less_than_or_equal": lit_.value, "less_than": lit_.value - 1,
+         "equal": 1 if lit_.value == 1 else None}.get(op)
+    return n if n is not None and n >= 1 else None
+
+
+def _match_rank_filter(rel, where, sel: ast.Select):
+    """((limit, emit_rank), the rest of `where`) where `rel` is a subquery
+    whose ONE window function is an aliased ROW_NUMBER() OVER (PARTITION BY
+    .. ORDER BY ..) and a conjunct of `where` bounds that column from
+    above; None otherwise (RANK() with ties, several window functions, no
+    partition: the general over-window plan). `emit_rank`: whether
+    anything else of the outer SELECT reads the column."""
+    if not isinstance(rel, ast.SubqueryRel) or where is None:
+        return None
+    inner = rel.select
+    if (inner.group_by or inner.order_by or inner.limit is not None
+            or inner.offset or getattr(inner, "emit_on_close", False)):
+        return None
+    wfs = [it for it in inner.items if isinstance(it.expr, ast.WindowFunc)]
+    if len(wfs) != 1:
+        return None
+    w, name = wfs[0].expr, wfs[0].alias
+    if (name is None or w.func.name != "row_number" or not w.partition_by
+            or not w.order_by):
+        return None
+    limit, rest = None, []
+    for conj in split_conjuncts(where):
+        n = _rank_bound(conj, name, rel.alias)
+        if n is None:
+            rest.append(conj)
+        else:
+            limit = n if limit is None else min(limit, n)
+    if limit is None:
+        return None
+    emit_rank = _names_column(
+        [[it.expr for it in sel.items], rest, sel.group_by,
+         [e for e, _ in sel.order_by]], name)
+    remaining = None
+    for c in rest:
+        remaining = c if remaining is None else ast.BinOp("and", remaining, c)
+    return (limit, emit_rank), remaining
 
 
 def split_conjuncts(e) -> list:

@@ -218,7 +218,7 @@ class Show:
 @dataclass
 class SubqueryRel:
     select: object              # Select
-    alias: str
+    alias: Optional[str] = None
 
 
 @dataclass
@@ -591,8 +591,8 @@ class Parser:
                 elif self.peek().kind == "ident" \
                         and self.peek().val not in KEYWORDS:
                     alias = self.next().val
-                if alias is None:
-                    raise SqlError("FROM subquery needs an alias")
+                # an alias is optional (upstream's nexmark q18 / q19 write
+                # none): its columns then resolve unqualified only
                 return SubqueryRel(sub, alias)
             rel = self._relation()
             self.expect("op", ")")

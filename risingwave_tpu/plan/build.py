@@ -1171,29 +1171,23 @@ def _build_retract_top_n(args, inputs, ctx: ActorCtx, key):
     if args.get("durable"):
         st = ctx.env.state_table(ctx.table_id(key), inputs[0].schema, pk,
                                  vnode_bitmap=ctx.vnode_bitmap)
+    kw = dict(order_col=args.get("order_col"),
+              order_specs=args.get("order_specs"),
+              limit=args["limit"], offset=args.get("offset", 0),
+              descending=args.get("descending", False),
+              state_table=st, pk_indices=pk,
+              watchdog_interval=args.get("watchdog_interval", 1),
+              append_only=args.get("append_only", False),
+              emit_rank=args.get("emit_rank", False))
+    groups = args.get("group_key_indices", ())
+    capacity = args.get("capacity", 1 << 14)
     md = args.get("mesh_devices", 1)
     if md > 1:
         from ..parallel.mesh import make_mesh
         from ..stream.sharded_top_n import ShardedTopNExecutor
-        return ShardedTopNExecutor(
-            inputs[0], args.get("group_key_indices", ()),
-            order_col=args.get("order_col"),
-            order_specs=args.get("order_specs"),
-            limit=args["limit"], offset=args.get("offset", 0),
-            descending=args.get("descending", False),
-            capacity=args.get("capacity", 1 << 14) // md,
-            state_table=st, pk_indices=pk,
-            watchdog_interval=args.get("watchdog_interval", 1),
-            mesh=make_mesh(md))
-    return RetractableTopNExecutor(
-        inputs[0], args.get("group_key_indices", ()),
-        order_col=args.get("order_col"),
-        order_specs=args.get("order_specs"),
-        limit=args["limit"], offset=args.get("offset", 0),
-        descending=args.get("descending", False),
-        capacity=args.get("capacity", 1 << 14),
-        state_table=st, pk_indices=pk,
-        watchdog_interval=args.get("watchdog_interval", 1))
+        return ShardedTopNExecutor(inputs[0], groups, capacity=capacity // md,
+                                   mesh=make_mesh(md), **kw)
+    return RetractableTopNExecutor(inputs[0], groups, capacity=capacity, **kw)
 
 
 @register_builder("sink")
@@ -1385,6 +1379,10 @@ def infer_fragment_schemas(graph: StreamGraph,
                                 for nm, e in zip(names, a["exprs"])))
         if k in ("filter", "no_op", "dedup", "retract_top_n",
                  "materialize", "sink", "dynamic_filter"):
+            if k == "retract_top_n" and a.get("emit_rank"):
+                from ..stream.retract_top_n import RANK_COLUMN
+                return Schema(tuple(ins[0])
+                              + (SchemaField(RANK_COLUMN, DataType.INT64),))
             return ins[0]
         if k == "row_id_gen":
             return Schema(tuple(ins[0])

@@ -144,6 +144,13 @@ def render_node(node, depth: int = 0) -> list:
                  f" rkeys={node.args['right_key_indices']}")
     if node.kind == "project":
         extra = f" names={node.args.get('names')}"
+    if node.kind == "retract_top_n" and node.args.get("group_key_indices"):
+        # the rank-filter plan (a group top-N); ORDER BY .. LIMIT has none
+        extra = (f" group={list(node.args['group_key_indices'])}"
+                 f" order={[tuple(o) for o in node.args['order_specs']]}"
+                 f" limit={node.args['limit']}"
+                 + (" append_only" if node.args.get("append_only") else "")
+                 + (" emit_rank" if node.args.get("emit_rank") else ""))
     out = [f"{'  ' * depth}{node.kind}{extra}"]
     for i in node.inputs:
         out.extend(render_node(i, depth + 1))

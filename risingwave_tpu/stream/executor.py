@@ -105,8 +105,9 @@ class StatefulUnaryExecutor(Executor):
         """Barrier-time changelog emission (None = nothing to emit)."""
         return None
 
-    def persist(self, barrier: Barrier, flushed: Optional[StreamChunk]) -> None:
-        """Write state rows + commit the state table at this barrier."""
+    def persist(self, barrier: Barrier, flushed: Optional[StreamChunk]):
+        """Write state rows + commit the state table at this barrier; an
+        `async def` override is awaited."""
         if self.state_table is not None:
             self.state_table.commit(barrier.epoch.curr)
 
@@ -144,7 +145,11 @@ class StatefulUnaryExecutor(Executor):
                 if self._applied_since_flush:
                     self._applied_since_flush = False
                     flushed = self.flush()
-                self.persist(msg, flushed)
+                pending = self.persist(msg, flushed)
+                if pending is not None:
+                    # a persist that hands its writes to the checkpoint's
+                    # uploader (utils/d2h.py defer_prefix_flush)
+                    await pending
                 self.on_clean_barrier(msg)
                 if flushed is not None:
                     yield flushed

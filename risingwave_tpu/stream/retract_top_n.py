@@ -1,58 +1,128 @@
-"""Retractable (Group)TopN — full-input sorted state, per-barrier diff.
+"""(Group)TopN — a dense sorted store ranked at every barrier.
 
-Reference: src/stream/src/executor/top_n/ (top_n_cache.rs): the
-retractable path persists ALL input rows so a deleted top row can be
-refilled from below; the cache keeps the top-K hot. The append-only
-variant lives in top_n.py; THIS executor handles retracting inputs
-(e.g. TopN over an aggregation's changelog).
+Reference: src/stream/src/executor/top_n/ (top_n_cache.rs, group_top_n.rs,
+group_top_n_appendonly.rs). Two things plan this executor (frontend/
+binder.py): `ORDER BY ... LIMIT` over a query's changelog (`_plan_top_n`: no
+group key, a singleton fragment) and a RANK FILTER — `rank <= N` / `< N` /
+`= 1` directly over `ROW_NUMBER() OVER (PARTITION BY g ORDER BY o)` — which
+becomes a group top-N in the fragment of its input (`_plan_over_window`:
+upstream's `over_window_to_topn_rule`). Any other use of a window function
+stays a `general_over_window`.
 
-TPU re-design: the whole live input lives in a dense array store sorted
-by a 63-bit hash of the ROW KEY (the stream key — retractions address
-rows by it), maintained with the same searchsorted/merge machinery as
-sorted_join.py's own-side update. Nothing data-dependent per chunk.
-At each barrier the flush program:
+The input lives in a dense array store sorted by a 63-bit hash of the ROW
+KEY (the stream key — retractions address rows by it), maintained with the
+same searchsorted/merge machinery as sorted_join.py's own-side update
+(sorted_store.py). At each barrier the live rows are lexsorted by (group
+hash, order key, stream key) — iterated stable argsorts — and ranked within
+their group runs. TIES on the order key are broken by the stream key
+ascending (for a generated `_row_id`: arrival order, the earlier row
+first), as upstream orders its cache by (order key, stream key); never by
+the key's hash.
 
-  1. lexsorts live rows by (group hash, order key, row key) — iterated
-     stable argsorts, compile-friendly;
-  2. ranks rows within their group runs (cummax over run starts);
-  3. selects ranks in [offset, offset+limit) as the NEW top set;
-  4. diffs it against the LAST EMITTED top set by full-row hash
-     membership (two searchsorteds) and emits Deletes for dropped rows
-     and Inserts for new ones — refill-from-below falls out naturally:
-     when a top row is retracted, rank promotion pulls the next row in
-     and the diff emits it.
+What the store keeps depends on the input:
 
-The one top-N executor: every ORDER BY ... LIMIT and rank filter plans
-it, append-only inputs included. Given a state table it persists each
-interval's input chunks by stream key and replays the stored rows on
-recovery.
+* RETRACTING input (a top-N over an aggregate's or a join's changelog): ALL
+  input rows, so a deleted top row is refilled from below — rank promotion
+  pulls the next row in. The last emitted top set is a second store sorted
+  by a full-row hash; the new set is diffed against it by membership (two
+  searchsorteds): Deletes for rows that left, Inserts for rows that came. A
+  rank that moved is a Delete + Insert of the row under its old and new
+  rank. The state table holds every input row.
+
+* APPEND-ONLY input (the binder knows: `info.append_only`): only the rows
+  that can still rank — at most `offset + limit` a group after each barrier
+  — because nothing can promote a dropped row. The store carries one hidden
+  int32 lane per row, the rank it was last emitted under (none yet: fresh
+  since the last barrier, or kept below `offset`), so the store IS the
+  emitted baseline: no second copy. The barrier runs two programs around
+  ONE small fetch: `retract_top_n_rank` sorts and ranks, and counts what
+  changed; `retract_top_n_emit` gathers the changed rows — an Insert, a
+  Delete (pushed past rank N: dropped from the store for good), or, where
+  the rank is an output column, an adjacent UpdateDelete / UpdateInsert
+  pair under the old and the new rank — and compacts the store to the kept
+  rows. The state table holds exactly the kept rows.
+
+Either way the emitted chunk is as wide as a power of two over twice the
+rows that changed (the count rides the barrier's one fetch; the width only
+grows, as a hash agg's flush), never `2 x capacity`; without a watchdog
+fetch (`watchdog_interval=None`) nothing tells the host the count and the
+chunk is capacity-wide. The durable write is columnar and off the event
+loop: the barrier dispatches the packs, the checkpoint uploader waits for
+them and writes (`utils/d2h.py` `defer_prefix_flush`).
+
+`emit_rank` adds the 1-based rank within the group as a last INT64 output
+column (`ROW_NUMBER()` selected above the rank filter).
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from functools import partial
-
-from ..common.chunk import Column, StreamChunk, OP_DELETE, OP_INSERT
+from ..common.chunk import (
+    Column, StreamChunk, OP_DELETE, OP_INSERT, OP_UPDATE_DELETE,
+    OP_UPDATE_INSERT, op_sign,
+)
+from ..common.types import DataType, Field, Schema
 from ..ops.hash_table import stable_lexsort
+from ..ops.jit_state import jit_state
+from ..ops.monotone_move import compact
+from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
+from ..utils.metrics import (
+    GLOBAL_METRICS, TOP_N_EMIT_ROWS, TOP_N_LIVE_ROWS, TOP_N_PRUNED_ROWS,
+)
+from ..utils.trace import span
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier, Watermark
-from ..ops.jit_state import jit_state
-from ..utils.d2h import fetch_small, off_loop
-from .sorted_join import _HSENTINEL, key_hash
+from .sorted_join import _HSENTINEL, grow_sorted_arrays, key_hash
 from .sorted_store import GrowableSortedStore, sorted_store_apply
+
+# the hidden lane of an append-only store: the 0-based rank a row was last
+# emitted under, or one of these
+_FRESH = -2          # arrived since the last barrier
+_UNEMITTED = -1      # kept at a barrier, below `offset`: never sent
+
+# the narrowest chunk a flush lays out
+FLUSH_MIN_ROWS = 256
+
+RANK_COLUMN = "_rank"
+
+
+def _valid_bits(valids) -> jnp.ndarray:
+    """Validity lanes (at most 63) as ONE int64 lane, bit j = column j
+    valid: what a persist view ships instead of a bool lane a column."""
+    bits = jnp.zeros(valids[0].shape[0], dtype=jnp.int64)
+    for j, v in enumerate(valids):
+        bits = bits | (v.astype(jnp.int64) << j)
+    return bits
+
+
+def _valids_of_bits(bits: np.ndarray, n_cols: int) -> Optional[list]:
+    """Host inverse of `_valid_bits`; None where no cell is NULL."""
+    if bool(np.all(bits == (1 << n_cols) - 1)):   # an empty lane too
+        return None
+    return [((bits >> j) & 1).astype(bool) for j in range(n_cols)]
+
+
+def _nth_set(mask: jnp.ndarray, width: int):
+    """(src, n): for slot s of `width`, the position of the s-th set bit of
+    `mask` (clipped where s >= n), and the number of set bits. A search of
+    `width` slots through the prefix sum: no capacity-wide scatter."""
+    cum = jnp.cumsum(mask.astype(jnp.int32))
+    src = jnp.searchsorted(cum, jnp.arange(width, dtype=jnp.int32),
+                           side="right")
+    return jnp.clip(src, 0, mask.shape[0] - 1).astype(jnp.int32), cum[-1]
 
 
 class RetractableTopNExecutor(GrowableSortedStore,
                               StatefulUnaryExecutor):
-    """Output: the rows whose rank within their group (by order_col,
-    direction) falls in [offset, offset+limit), maintained incrementally
-    under inserts AND retractions."""
+    """Output: the rows whose rank within their group (by `order_specs`,
+    ties by the stream key) falls in [offset, offset+limit), maintained
+    incrementally under inserts AND, unless `append_only`, retractions."""
 
     def __init__(self, input: Executor,
                  group_key_indices: Sequence[int],
@@ -62,9 +132,14 @@ class RetractableTopNExecutor(GrowableSortedStore,
                  capacity: int = 1 << 14,
                  state_table=None,
                  pk_indices: Optional[Sequence[int]] = None,
-                 watchdog_interval: Optional[int] = 1):
+                 watchdog_interval: Optional[int] = 1,
+                 append_only: bool = False,
+                 emit_rank: bool = False):
         self.input = input
-        self.schema = input.schema
+        self.store_schema = input.schema
+        self.emit_rank = bool(emit_rank)
+        self.schema = input.schema if not emit_rank else Schema(
+            tuple(input.schema) + (Field(RANK_COLUMN, DataType.INT64),))
         self.pk_indices = tuple(
             pk_indices if pk_indices is not None
             else (input.pk_indices or range(len(input.schema))))
@@ -76,88 +151,145 @@ class RetractableTopNExecutor(GrowableSortedStore,
             assert order_col is not None
             order_specs = [(order_col, descending)]
         self.order_specs = tuple((int(c), bool(d)) for c, d in order_specs)
+        # ties: the stream-key columns the order key does not name already
+        ordered = {c for c, _ in self.order_specs}
+        self._tie_cols = tuple(p for p in self.pk_indices
+                               if p not in ordered)
         self.limit = limit
         self.offset = offset
         self.capacity = capacity
+        self.append_only = bool(append_only)
         self.identity = (f"RetractTopN(g={self.group_key_indices}, "
-                         f"by={self.order_specs}, k={limit})")
+                         f"by={self.order_specs}, k={limit}"
+                         f"{', append-only' if append_only else ''})")
         C = capacity
         dts = tuple(f.data_type.jnp_dtype for f in input.schema)
         self._col_dtypes = dts
-        # dense store sorted by row-key hash
+        self._n_in = len(dts)
+        # dense store sorted by row-key hash; an append-only store's last
+        # lane is the emitted rank (see the module docstring)
+        lanes = dts + ((jnp.int32,) if self.append_only else ())
         self.khash = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
-        self.cols = tuple(jnp.zeros(C, dtype=dt) for dt in dts)
-        self.valids = tuple(jnp.zeros(C, dtype=bool) for _ in dts)
+        self.cols = tuple(jnp.zeros(C, dtype=dt) for dt in lanes)
+        self.valids = tuple(jnp.zeros(C, dtype=bool) for _ in lanes)
         self.n = jnp.int32(0)
-        # last emitted top set, as a sorted array of full-row hashes plus
-        # the row payloads (for emitting deletes)
-        self.top_hash = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
-        self.top_cols = tuple(jnp.zeros(C, dtype=dt) for dt in dts)
-        self.top_valids = tuple(jnp.zeros(C, dtype=bool) for _ in dts)
-        self.top_n = jnp.int32(0)
         self._errs_dev = jnp.zeros(2, dtype=jnp.int32)  # [row_ovf, del_miss]
-        # the dense store pytree (khash, cols, valids, n) + errs is
-        # threaded and aliased nowhere (the emitted top set is a fresh
-        # gather): donate. _flush consumes/replaces the top_* triplet.
-        self._apply = jit_state(
-            partial(sorted_store_apply, pk_idx=self.pk_indices,
-                    capacity=self.capacity),
-            donate_argnums=(0, 1, 2, 3, 4), name="retract_top_n_apply")
-        # ONE d2h fetch per barrier: errs and the live count ride together
-        self._wd_pack = jit_state(
-            lambda e, n: jnp.concatenate([e, n[None].astype(jnp.int32)]),
-            name="retract_top_n_wd_pack")
-        self._flush = jit_state(self._flush_impl,
-                                donate_argnums=(4, 5, 6, 7),
-                                name="retract_top_n_flush")
-        # durability: the state table materializes the FULL input row set
-        # keyed by the stream key (the reference's TopN state table holds
-        # all input rows too, top_n_state.rs); each epoch's buffered
-        # chunks apply to it at the barrier, recovery re-inserts them
+        if not self.append_only:
+            # last emitted top set, as a sorted array of full-row hashes
+            # plus the row payloads (for emitting deletes), rank included
+            # where it is an output column
+            top_dts = dts + ((jnp.int64,) if self.emit_rank else ())
+            self.top_hash = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
+            self.top_cols = tuple(jnp.zeros(C, dtype=dt) for dt in top_dts)
+            self.top_valids = tuple(jnp.zeros(C, dtype=bool)
+                                    for _ in top_dts)
+            self.top_n = jnp.int32(0)
+        # the width of the flush's chunk (and of the persist view): a power
+        # of two over twice the count the barrier's fetch brought, only
+        # ever wider (each new one is a compile here and downstream)
+        self._emit_width = FLUSH_MIN_ROWS
+        self._persist_width = FLUSH_MIN_ROWS
+        # what a barrier's programs left for flush() / persist()
+        self._ranked = self._flushed = None
+        self._ready: Optional[StreamChunk] = None
+        self._persist_view = None
+        self._persist_counts: Optional[tuple] = None
+        self._pack_dev = None
         self._epoch_chunks: list[StreamChunk] = []
+        self._phase_counts: dict = {}
+        self._build_programs()
         self._init_stateful(state_table, watchdog_interval)
 
-    # ------------------------------------------------------------- flush
+    def _build_programs(self) -> None:
+        """The programs close over the capacity: built at construction and
+        again after a growth."""
+        C = self.capacity
+        if self.append_only:
+            self._apply = jit_state(self._apply_fresh_impl,
+                                    donate_argnums=(0, 1, 2, 3, 4),
+                                    name="retract_top_n_apply")
+            self._rank = jit_state(self._rank_impl,
+                                   name="retract_top_n_rank")
+            self._emit = jit_state(
+                self._emit_impl, donate_argnums=(0, 1, 2),
+                static_argnames=("width", "persist_width", "durable"),
+                name="retract_top_n_emit")
+        else:
+            # the dense store pytree (khash, cols, valids, n) + errs is
+            # threaded and aliased nowhere (the emitted top set is a fresh
+            # gather): donate. _flush consumes/replaces the top_* triplet.
+            self._apply = jit_state(
+                partial(sorted_store_apply, pk_idx=self.pk_indices,
+                        capacity=C),
+                donate_argnums=(0, 1, 2, 3, 4), name="retract_top_n_apply")
+            self._flush = jit_state(self._flush_impl,
+                                    donate_argnums=(4, 5, 6, 7),
+                                    name="retract_top_n_flush")
+            self._narrow = jit_state(self._narrow_impl,
+                                     static_argnames=("width",),
+                                     name="retract_top_n_narrow")
+        # ONE d2h fetch per barrier: errs and the live count ride together
+        self._wd_pack = jit_state(
+            lambda e, n, m: jnp.concatenate(
+                [e, jnp.stack([n, m]).astype(jnp.int32)]),
+            name="retract_top_n_wd_pack")
+
+    # ------------------------------------------------------------ ranking
+    def _sort_keys(self, cols) -> list:
+        """The lexsort's keys within a group, least significant first: the
+        stream key (ascending), then the order keys; DESC via bitwise
+        complement (overflow-free order reversal on ints), negation on
+        floats."""
+        keys = [cols[p] for p in reversed(self._tie_cols)]
+        for c, desc in reversed(self.order_specs):
+            oval = cols[c]
+            if jnp.issubdtype(oval.dtype, jnp.floating):
+                keys.append(-oval if desc else oval)
+            else:
+                keys.append(~oval if desc else oval)
+        return keys
+
+    def _rank_rows(self, cols, live):
+        """(order, rank, s_live): the permutation that sorts the live rows
+        by (group, order key, stream key) with the dead ones behind, each
+        sorted position's 0-based rank within its group run, and which
+        sorted positions are live."""
+        C = live.shape[0]
+        imax = jnp.iinfo(jnp.int64).max
+        ghash = (key_hash([cols[i] for i in self.group_key_indices])
+                 if self.group_key_indices
+                 else jnp.zeros(C, dtype=jnp.int64))
+        g = jnp.where(live, ghash, imax)
+        order = stable_lexsort(tuple(self._sort_keys(cols) + [g]))
+        s_g = g[order]
+        new_run = jnp.concatenate([jnp.array([True]),
+                                   s_g[1:] != s_g[:-1]])
+        pos = jnp.arange(C, dtype=jnp.int32)
+        run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
+        return order, pos - run_start, live[order]
+
+    # ------------------------------------------- retracting input: flush
     def _flush_impl(self, khash, cols, valids, n, top_hash, top_cols,
                     top_valids, top_n):
         """Compute the new top set, diff vs the last emitted one."""
         C = self.capacity
         live = jnp.arange(C, dtype=jnp.int32) < n
-        ghash = (key_hash([cols[i] for i in self.group_key_indices])
-                 if self.group_key_indices
-                 else jnp.zeros(C, dtype=jnp.int64))
-        # order keys least-significant first for the lexsort; DESC via
-        # bitwise complement (overflow-free order reversal on ints)
-        okeys = []
-        for c, desc in reversed(self.order_specs):
-            oval = cols[c]
-            if jnp.issubdtype(oval.dtype, jnp.floating):
-                okeys.append(-oval if desc else oval)
-            else:
-                # bitwise complement reverses int order overflow-free
-                okeys.append(~oval if desc else oval)
-        # sort live rows by (group, order..., row hash); dead rows last
-        order = stable_lexsort(tuple(
-            [khash] + okeys
-            + [jnp.where(live, ghash, jnp.iinfo(jnp.int64).max)]))
-        s_g = jnp.where(live, ghash, jnp.iinfo(jnp.int64).max)[order]
-        new_run = jnp.concatenate([jnp.array([True]),
-                                   s_g[1:] != s_g[:-1]])
-        pos = jnp.arange(C, dtype=jnp.int32)
-        run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
-        rank = pos - run_start
-        s_live = live[order]
+        order, rank, s_live = self._rank_rows(cols, live)
         in_top = s_live & (rank >= self.offset) & (
             rank < self.offset + self.limit)
-        # full-row hash identifies a row across top sets
         s_cols = [c[order] for c in cols]
+        s_valids = [v[order] for v in valids]
+        if self.emit_rank:
+            s_cols.append(rank.astype(jnp.int64) + 1)
+            s_valids.append(in_top)
+        # full-row hash identifies a row (under its rank) across top sets
         rhash = key_hash(s_cols)
         topk = jnp.where(in_top, rhash, _HSENTINEL)
         torder = jnp.argsort(topk, stable=True)
         new_hash = topk[torder]
         n_top = jnp.sum(in_top.astype(jnp.int32))
         new_cols = tuple(c[torder] for c in s_cols)
-        new_valids = tuple(v[order][torder] for v in valids)
+        new_valids = tuple(v[torder] for v in s_valids)
 
         # membership diffs via searchsorted (hashes are sorted arrays)
         def member(a_hash, a_n, b_hash):
@@ -182,26 +314,343 @@ class RetractableTopNExecutor(GrowableSortedStore,
         return (new_hash, new_cols, new_valids, n_top.astype(jnp.int32),
                 out_cols, ops, vis)
 
+    @staticmethod
+    def _narrow_impl(out_cols, ops, vis, width: int):
+        """The visible rows of the flush's `2 x capacity` layout, in their
+        order (every Delete before every Insert), as a chunk `width`
+        wide."""
+        src, n = _nth_set(vis, width)
+        return (tuple(Column(c.data[src], c.valid[src]) for c in out_cols),
+                ops[src], jnp.arange(width, dtype=jnp.int32) < n)
+
+    # ----------------------------------------- append-only input: programs
+    def _apply_fresh_impl(self, khash, cols, valids, n, errs,
+                          chunk: StreamChunk):
+        """`sorted_store_apply` of the chunk with the hidden lane set to
+        `_FRESH`. A retraction has no business here: it counts as a delete
+        that matched nothing and fail-stops the barrier."""
+        N = chunk.capacity
+        fresh = Column(jnp.full(N, _FRESH, dtype=jnp.int32))
+        n_retract = jnp.sum((chunk.vis & (op_sign(chunk.ops) < 0))
+                            .astype(jnp.int32))
+        kh, c, v, n2, e2 = sorted_store_apply(
+            khash, cols, valids, n, errs,
+            StreamChunk(chunk.columns[:self._n_in] + (fresh,), chunk.ops,
+                        chunk.vis, chunk.schema),
+            pk_idx=self.pk_indices, capacity=self.capacity)
+        return kh, c, v, n2, e2.at[1].add(n_retract.astype(e2.dtype))
+
+    def _changes(self, order, rank, s_live, erank):
+        """Per SORTED position, what the barrier does with the row there:
+        (ins, dele, upd) for the changelog, (p_ins, p_del) for the state
+        table, `kept` for the store, and the rank it was emitted under."""
+        s_er = erank[order]
+        K = self.offset + self.limit
+        kept = s_live & (rank < K)
+        in_win = kept & (rank >= self.offset)
+        was = s_live & (s_er >= 0)
+        fresh = s_live & (s_er == _FRESH)
+        ins = in_win & ~was
+        dele = was & ~in_win
+        upd = (in_win & was & (s_er != rank) if self.emit_rank
+               else jnp.zeros_like(ins))
+        return ins, dele, upd, fresh & kept, s_live & ~fresh & ~kept, \
+            kept, s_er
+
+    def _rank_impl(self, cols, n, errs):
+        """The capacity-wide half of the barrier: sort and rank, and count.
+        Returns (order, rank, pack); pack = [row overflow, delete misses,
+        live rows, rows the changelog takes, state-table inserts, deletes,
+        rows kept, rows pruned]."""
+        C = self.capacity
+        live = jnp.arange(C, dtype=jnp.int32) < n
+        order, rank, s_live = self._rank_rows(cols[:self._n_in], live)
+        ins, dele, upd, p_ins, p_del, kept, _ = self._changes(
+            order, rank, s_live, cols[-1])
+
+        def count(m):
+            return jnp.sum(m.astype(jnp.int32))
+
+        pack = jnp.concatenate([errs, jnp.stack([
+            n.astype(jnp.int32),
+            count(ins) + count(dele) + 2 * count(upd),
+            count(p_ins), count(p_del), count(kept),
+            count(s_live & ~kept)])])
+        return order.astype(jnp.int32), rank.astype(jnp.int32), pack
+
+    def _emit_impl(self, khash, cols, valids, n, order, rank, *,
+                   width: int, persist_width: int, durable: bool):
+        """The barrier's other half, at the widths the counts allow: the
+        changelog chunk (`width` rows), the state table's inserts and
+        deletes (`persist_width` rows each; None where not `durable`), and
+        the store compacted to the kept rows with their ranks as the new
+        baseline."""
+        C = self.capacity
+        live = jnp.arange(C, dtype=jnp.int32) < n
+        data, dvalid = cols[:self._n_in], valids[:self._n_in]
+        ins, dele, upd, p_ins, p_del, kept, s_er = self._changes(
+            order, rank, live[order], cols[-1])
+
+        # changelog: a changed row takes one slot, a rank shift two
+        # adjacent ones (UpdateDelete under the old rank, UpdateInsert
+        # under the new)
+        slots = (ins.astype(jnp.int32) + dele.astype(jnp.int32)
+                 + 2 * upd.astype(jnp.int32))
+        cum = jnp.cumsum(slots)
+        s = jnp.arange(width, dtype=jnp.int32)
+        src = jnp.clip(jnp.searchsorted(cum, s, side="right"), 0,
+                       C - 1).astype(jnp.int32)
+        first = s == cum[src] - slots[src]
+        row = order[src]
+        is_upd, is_ins = upd[src], ins[src]
+        ops = jnp.where(
+            is_upd, jnp.where(first, OP_UPDATE_DELETE, OP_UPDATE_INSERT),
+            jnp.where(is_ins, OP_INSERT, OP_DELETE)).astype(jnp.int8)
+        vis = s < cum[-1]
+        out_cols = [Column(c[row], v[row]) for c, v in zip(data, dvalid)]
+        if self.emit_rank:
+            old = (is_upd & first) | (~is_upd & ~is_ins)
+            out_cols.append(Column(
+                jnp.where(old, s_er[src], rank[src]).astype(jnp.int64) + 1,
+                vis))
+
+        view = None
+        if durable:
+            i_src, _ = _nth_set(p_ins, persist_width)
+            i_row = order[i_src]
+            d_src, _ = _nth_set(p_del, persist_width)
+            d_row = order[d_src]
+            view = ([c[i_row] for c in data]
+                    + [_valid_bits([v[i_row] for v in dvalid])],
+                    [data[p][d_row] for p in self.pk_indices])
+
+        # the store: rows past rank `offset + limit` go (nothing can
+        # promote them), the rest carry their rank as the next baseline
+        inv = jnp.argsort(order)
+        rank_at = rank[inv]
+        keep = live & (rank_at < self.offset + self.limit)
+        erank = jnp.where(keep & (rank_at >= self.offset), rank_at,
+                          _UNEMITTED).astype(jnp.int32)
+        lanes = compact(
+            keep, [khash, *data, erank, *valids],
+            [_HSENTINEL] + [0] * (self._n_in + 1)
+            + [False] * (self._n_in + 1))
+        nl = self._n_in + 1
+        return (lanes[0], tuple(lanes[1:1 + nl]), tuple(lanes[1 + nl:]),
+                jnp.sum(keep.astype(jnp.int32)), tuple(out_cols), ops, vis,
+                view)
+
     # -------------------------------------------------------------- hooks
-    def on_chunk(self, chunk: StreamChunk) -> None:
+    def _apply_chunk(self, chunk: StreamChunk) -> None:
         (self.khash, self.cols, self.valids, self.n,
          self._errs_dev) = self._apply(self.khash, self.cols, self.valids,
                                        self.n, self._errs_dev, chunk)
-        if self.state_table is not None:
+
+    def on_chunk(self, chunk: StreamChunk) -> None:
+        self._apply_chunk(chunk)
+        if self.state_table is not None and not self.append_only:
             self._epoch_chunks.append(chunk)
         return None
 
-    def persist(self, barrier: Barrier, flushed) -> None:
-        if self.state_table is None:
+    def _width_for(self, count: int, width: int) -> int:
+        """`width`, or the power of two over twice `count` where `count`
+        no longer fits it; never past what a flush can emit at all."""
+        if count > width:
+            while width < 2 * count:
+                width *= 2
+        return min(width, 2 * self.capacity)
+
+    def _run_flush(self, n_emit: Optional[int], n_persist: int = 0,
+                   discard: bool = False) -> None:
+        """Dispatch the barrier's emitting program(s) at the widths the
+        counts ask for (`n_emit` None: no fetch brought them, the chunk is
+        capacity-wide; `discard`: a recovery's baseline pass, whose output
+        nobody reads, at the widths there are) and leave the chunk for
+        `flush()`, the state table's rows for `persist()`."""
+        durable = self.state_table is not None and not discard
+        if self.append_only:
+            C = self.capacity
+            if discard:
+                width, pwidth = self._emit_width, self._persist_width
+            elif n_emit is None:
+                width, pwidth = 2 * C, C
+            else:
+                width = self._emit_width = self._width_for(
+                    n_emit, self._emit_width)
+                pwidth = self._persist_width = min(C, self._width_for(
+                    n_persist, self._persist_width))
+            order, rank = self._ranked
+            self._ranked = None
+            (self.khash, self.cols, self.valids, self.n, out_cols, ops,
+             vis, view) = self._emit(
+                self.khash, self.cols, self.valids, self.n, order, rank,
+                width=width, persist_width=pwidth, durable=durable)
+            self._persist_view = view
+        else:
+            out_cols, ops, vis = self._flushed
+            self._flushed = None
+            if n_emit is not None:
+                self._emit_width = self._width_for(n_emit,
+                                                   self._emit_width)
+                out_cols, ops, vis = self._narrow(
+                    out_cols, ops, vis, width=self._emit_width)
+        self._ready = StreamChunk(tuple(out_cols), ops, vis, self.schema)
+
+    def _run_rank(self):
+        """Dispatch the capacity-wide program of the barrier; returns the
+        device scalar(s) the watchdog's pack takes along."""
+        if self.append_only:
+            order, rank, pack = self._rank(self.cols, self.n,
+                                           self._errs_dev)
+            self._ranked = (order, rank)
+            self._pack_dev = pack
+            return pack
+        (self.top_hash, self.top_cols, self.top_valids, self.top_n,
+         out_cols, ops, vis) = self._flush(
+            self.khash, self.cols, self.valids, self.n,
+            self.top_hash, self.top_cols, self.top_valids, self.top_n)
+        self._flushed = (out_cols, ops, vis)
+        return self._wd_pack(self._errs_dev, self.n,
+                             jnp.sum(vis.astype(jnp.int32)))
+
+    def flush(self) -> Optional[StreamChunk]:
+        if self._ready is None:
+            # no watchdog fetch at this barrier: rank and emit back to
+            # back, at the full width
+            self._run_rank()
+            self._run_flush(None)
+        out, self._ready = self._ready, None
+        return out
+
+    async def check_watchdog(self) -> None:
+        """The barrier's ONE fetch: the error counters, the live count and,
+        where the interval applied anything, what the flush is about to
+        emit — so `topn.flush` spans the ranking program's dispatch, the
+        awaited fetch and the emitting program's dispatch."""
+        if not self._applied_since_flush:
+            vals = await off_loop(fetch_small, self._wd_pack(
+                self._errs_dev, self.n, jnp.int32(0)))
+            self._fail_on(int(vals[0]), int(vals[1]))
             return
-        for c in self._epoch_chunks:
-            vis = np.asarray(c.vis)
-            if vis.any():
-                self.state_table.write_chunk_columns(
-                    np.asarray(c.ops), [np.asarray(col.data)
-                                        for col in c.columns], vis)
-        self._epoch_chunks = []
-        self.state_table.commit(barrier.epoch.curr)
+        with span("topn.flush"):
+            vals = [int(v) for v in await off_loop(fetch_small,
+                                                   self._run_rank())]
+            self._fail_on(vals[0], vals[1])
+            if self.append_only:
+                n_live, n_emit, p_ins, p_del, n_kept, n_pruned = vals[2:]
+                self._run_flush(n_emit, max(p_ins, p_del))
+                self._persist_counts = (p_ins, p_del)
+            else:
+                n_live, n_emit = vals[2:]
+                n_kept, n_pruned = n_live, 0
+                self._run_flush(n_emit)
+        self._note_counts(n_kept, n_emit, n_pruned)
+        # the fullest the store was: what the next interval may ask for
+        self._maybe_grow(n_live)
+
+    def _fail_on(self, n_overflow: int, n_miss: int) -> None:
+        if n_overflow:
+            raise RuntimeError(
+                f"retractable TopN overflow ({n_overflow} rows dropped; "
+                f"capacity {self.capacity})")
+        if n_miss:
+            raise RuntimeError(
+                f"retractable TopN: {n_miss} deletes matched no row"
+                + (" (a retraction reached an append-only top-N)"
+                   if self.append_only else ""))
+
+    def _note_counts(self, n_live: int, n_emit: int, n_pruned: int) -> None:
+        """What the barrier's fetch brought, into the registry and kept
+        for the epoch trace: the rows the store holds after the barrier
+        over its capacity, the rows the changelog took (inserts, deletes
+        and both halves of update pairs), the rows dropped as beyond rank
+        N."""
+        label = self.identity
+        GLOBAL_METRICS.gauge(TOP_N_LIVE_ROWS, executor=label).set(
+            float(n_live))
+        GLOBAL_METRICS.counter(TOP_N_EMIT_ROWS, executor=label).inc(n_emit)
+        GLOBAL_METRICS.counter(TOP_N_PRUNED_ROWS, executor=label).inc(
+            n_pruned)
+        self._phase_counts = dict(
+            topn_live_rows=n_live, topn_capacity=self.capacity,
+            topn_emit_rows=n_emit, topn_pruned_rows=n_pruned)
+
+    def take_phase_counts(self) -> dict:
+        """This barrier's `topn_live_rows` / `topn_capacity` /
+        `topn_emit_rows` / `topn_pruned_rows` for the actor's phase dict:
+        host numbers the watchdog fetch brought, absent where it made
+        none."""
+        counts, self._phase_counts = self._phase_counts, {}
+        return counts
+
+    # -------------------------------------------------------- durability
+    async def persist(self, barrier: Barrier, flushed) -> None:
+        """The state table's share of the barrier, columnar and off the
+        loop: the packs are dispatched here, the checkpoint's uploader
+        waits for them and writes. An append-only store writes the rows it
+        kept of the interval's arrivals and deletes the ones it pruned; a
+        full store writes the interval's input chunks as they came."""
+        st = self.state_table
+        if st is None:
+            return
+        n_cols = self._n_in
+        counts_dev = None
+        if self.append_only:
+            view, self._persist_view = self._persist_view, None
+            known, self._persist_counts = self._persist_counts, None
+            if view is not None and known is None:
+                # no watchdog fetch brought the counts: the rank program's
+                # pack is awaited with the flush instead
+                counts_dev = self._pack_dev
+            self._pack_dev = None
+
+            def plan(counts):
+                if view is None:
+                    groups = []
+                else:
+                    p_ins, p_del = known if counts is None else (
+                        int(counts[4]), int(counts[5]))
+                    groups = [(view[0], p_ins), (view[1], p_del)]
+                return groups, write
+
+            def write(outs):
+                if outs:
+                    ins, dels = outs
+                    self._write_rows(
+                        OP_INSERT, ins[:n_cols],
+                        _valids_of_bits(ins[n_cols], n_cols))
+                    cols = [np.zeros(len(dels[0]), dtype=np.int64)] * n_cols
+                    for p, lane in zip(self.pk_indices, dels):
+                        cols[p] = lane
+                    self._write_rows(OP_DELETE, cols, None)
+                st.commit(barrier.epoch.curr)
+        else:
+            chunks, self._epoch_chunks = self._epoch_chunks, []
+            groups = [
+                ([c.ops, c.vis] + [col.data for col in c.columns[:n_cols]]
+                 + [_valid_bits([col.valid_mask()
+                                 for col in c.columns[:n_cols]])],
+                 c.capacity) for c in chunks]
+
+            def write(outs):
+                for host in outs:
+                    st.write_chunk_columns(
+                        host[0], host[2:2 + n_cols], host[1].astype(bool),
+                        _valids_of_bits(host[2 + n_cols], n_cols))
+                st.commit(barrier.epoch.curr)
+
+            def plan(_counts):
+                return groups, write
+
+        await defer_prefix_flush(st.store, barrier.epoch.prev, st.table_id,
+                                 counts_dev, plan)
+
+    def _write_rows(self, op: int, cols, valids) -> None:
+        n = len(cols[0])
+        if n:
+            self.state_table.write_chunk_columns(
+                np.full(n, op, dtype=np.int8), cols, np.ones(n, dtype=bool),
+                valids)
 
     def recover_state(self, epoch: int) -> None:
         rows = [r for _, r in self.state_table.iter_all()]
@@ -209,51 +658,49 @@ class RetractableTopNExecutor(GrowableSortedStore,
             return
         self._presize_for(len(rows))
         from ..state.storage_table import rows_to_columns
-        cap = 1 << max(6, (len(rows) - 1).bit_length())
+        # every batch at ONE capacity, the last short one too: one apply
+        # program whatever the row count
+        cap = min(1 << 14, self.capacity)
         for ofs in range(0, len(rows), cap):
-            part = rows[ofs:ofs + cap]
-            arrays, valids = rows_to_columns(self.schema, part)
-            c = StreamChunk.from_numpy(
-                self.schema, arrays, capacity=cap,
-                valids=[None if v.all() else v for v in valids])
-            (self.khash, self.cols, self.valids, self.n,
-             self._errs_dev) = self._apply(self.khash, self.cols,
-                                           self.valids, self.n,
-                                           self._errs_dev, c)
+            arrays, valids = rows_to_columns(self.store_schema,
+                                             rows[ofs:ofs + cap])
+            self._apply_chunk(StreamChunk.from_numpy(
+                self.store_schema, arrays, capacity=cap,
+                valids=[None if v.all() else v for v in valids]))
         # Seed the diff BASELINE: the downstream MV materialized exactly
         # the top set of this recovered (checkpoint-consistent) store, so
         # compute it once and DISCARD the output — the next real flush
         # then emits only genuine changes. Without this, rows that left
         # the top set across the rebuild would never receive a Delete
         # (re-emitting inserts is idempotent; omitted deletes are not).
-        (self.top_hash, self.top_cols, self.top_valids, self.top_n,
-         _c, _o, _v) = self._flush(
-            self.khash, self.cols, self.valids, self.n,
-            self.top_hash, self.top_cols, self.top_valids, self.top_n)
+        # An append-only store held only kept rows: the pass prunes
+        # nothing and stamps every row with the rank the MV has it under.
+        self._run_rank()
+        self._run_flush(None, discard=True)
+        self._ready = self._persist_view = self._pack_dev = None
 
-    def flush(self) -> Optional[StreamChunk]:
-        (self.top_hash, self.top_cols, self.top_valids, self.top_n,
-         out_cols, ops, vis) = self._flush(
-            self.khash, self.cols, self.valids, self.n,
-            self.top_hash, self.top_cols, self.top_valids, self.top_n)
-        return StreamChunk(out_cols, ops, vis, self.schema)
-
+    # ------------------------------------------------------------- growth
     _SECONDARY = ("top_hash", "top_cols", "top_valids")
 
-    async def check_watchdog(self) -> None:
-        vals = await off_loop(fetch_small,
-                              self._wd_pack(self._errs_dev, self.n))
-        if int(vals[0]):
-            raise RuntimeError(
-                f"retractable TopN overflow ({int(vals[0])} rows dropped; "
-                f"capacity {self.capacity})")
-        if int(vals[1]):
-            raise RuntimeError(
-                f"retractable TopN: {int(vals[1])} deletes matched no row")
-        self._maybe_grow(int(vals[2]))
+    def state_bytes(self) -> int:
+        if not self.append_only:
+            return super().state_bytes()
+        from ..memory.accounting import pytree_bytes
+        return pytree_bytes((self.khash, self.cols, self.valids))
+
+    def _grow_to(self, new_c: int) -> None:
+        self.khash, self.cols, self.valids = grow_sorted_arrays(
+            self.khash, self.cols, self.valids, new_c)
+        if not self.append_only:
+            self.top_hash, self.top_cols, self.top_valids = \
+                grow_sorted_arrays(self.top_hash, self.top_cols,
+                                   self.top_valids, new_c)
+        self.capacity = new_c
+        self._build_programs()
 
     def fence_tokens(self) -> list:
-        return [self.n, self.top_n] + super().fence_tokens()
+        own = [self.n] if self.append_only else [self.n, self.top_n]
+        return own + super().fence_tokens()
 
     def map_watermark(self, wm: Watermark) -> Optional[Watermark]:
         return None          # ranks can change; no watermark survives

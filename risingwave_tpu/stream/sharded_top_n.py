@@ -25,9 +25,12 @@ Two ranking modes, picked by the plan shape:
   over ICI per barrier, not O(n): the store itself never leaves the
   shards.
 
-Both modes rank by the parent's exact (order keys, row-key hash) total
+Both modes rank by the parent's exact (order keys, stream key) total
 order, so the selected set — and therefore the emitted diff — is
-bit-identical to the single-device executor's.
+identical to the single-device executor's over a retracting input; the
+chunk stays `2 x capacity` wide per shard and an append-only input is kept
+in full (the single-device executor narrows the one and prunes the
+other).
 """
 
 from __future__ import annotations
@@ -60,14 +63,21 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
                  state_table=None,
                  pk_indices: Optional[Sequence[int]] = None,
                  watchdog_interval: Optional[int] = 1,
+                 append_only: bool = False,
+                 emit_rank: bool = False,
                  *, mesh):
         # parent ctor builds the single-device [C] store + programs;
         # _init_sharded replaces them with the [S*C] mesh-sharded layout
         # (capacity is PER SHARD from here on)
+        # (an append-only input is kept in full here too: the mesh store
+        # prunes nothing, which is only more than it needs)
         super().__init__(input, group_key_indices, order_col, limit,
                          offset, descending, order_specs, capacity,
-                         state_table, pk_indices, watchdog_interval)
+                         state_table, pk_indices, watchdog_interval,
+                         append_only=False, emit_rank=emit_rank)
         self.global_mode = not self.group_key_indices
+        assert not (self.global_mode and emit_rank), \
+            "a rank column needs a group key (the rank-filter plan)"
         # global mode routes on the stream key: a retraction carries the
         # same pk as its insert, so netting stays shard-local
         self.route_key_indices = (self.group_key_indices
@@ -81,6 +91,9 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
                          f"(g={self.group_key_indices}, "
                          f"by={self.order_specs}, k={limit})")
 
+    def _store_schema(self):
+        return self.store_schema     # the input's: no rank column
+
     # ------------------------------------------------------------- flush
     def _flush_local(self, khash, cols, valids, n, top_hash, top_cols,
                      top_valids, top_n):
@@ -91,18 +104,6 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
                                     top_cols, top_valids, top_n)
         return self._flush_impl_global(khash, cols, valids, n, top_hash,
                                        top_cols, top_valids, top_n)
-
-    def _okeys_of(self, cols):
-        # the parent's descending encodings: order comparisons must be
-        # IDENTICAL local vs global or candidate pruning would be unsound
-        okeys = []
-        for c, desc in reversed(self.order_specs):
-            oval = cols[c]
-            if jnp.issubdtype(oval.dtype, jnp.floating):
-                okeys.append(-oval if desc else oval)
-            else:
-                okeys.append(~oval if desc else oval)
-        return okeys
 
     def _flush_impl_global(self, khash, cols, valids, n, top_hash,
                            top_cols, top_valids, top_n):
@@ -115,9 +116,10 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
 
         # stage 1 — local rank: each shard's best K rows are the only
         # possible global top members (same total order ⇒ local rank is
-        # a lower bound on global rank)
+        # a lower bound on global rank; the parent's `_sort_keys` both
+        # times, or candidate pruning would be unsound)
         order = stable_lexsort(tuple(
-            [khash] + self._okeys_of(cols)
+            self._sort_keys(cols)
             + [jnp.where(live, jnp.zeros(C, dtype=jnp.int64), imax)]))
         cand = order[:K]
 
@@ -125,14 +127,13 @@ class ShardedTopNExecutor(ShardedSortedStoreMixin, RetractableTopNExecutor):
             return jax.lax.all_gather(x, VNODE_AXIS, tiled=True)
 
         g_live = g(live[cand])
-        g_khash = g(khash[cand])
         g_cols = [g(c[cand]) for c in cols]
         g_valids = [g(v[cand]) for v in valids]
 
         # stage 2 — global re-rank of the S*K replicated candidates;
         # dead padding sorts last, rank == position (single group)
         gorder = stable_lexsort(tuple(
-            [g_khash] + self._okeys_of(g_cols)
+            self._sort_keys(g_cols)
             + [jnp.where(g_live, jnp.zeros(G, dtype=jnp.int64), imax)]))
         s_live = g_live[gorder]
         pos = jnp.arange(G, dtype=jnp.int32)

@@ -1,6 +1,9 @@
-"""Dense sorted row store — the shared state layout for retraction-capable
-executors that must hold their FULL input (retractable TopN, general
-OverWindow).
+"""Dense sorted row store — the shared state layout for the executors that
+rank or frame rows they hold: general OverWindow and a top-N over a
+RETRACTING input hold their FULL input here; a top-N over an append-only
+input (retract_top_n.py) holds only the rows that can still rank, pruned at
+every barrier, with one hidden lane for the rank each was last emitted
+under.
 
 Rows live in a dense prefix [0, n) of fixed-capacity arrays sorted by a
 63-bit hash of the STREAM KEY (retractions address rows by it), maintained
@@ -112,7 +115,8 @@ class GrowableSortedStore:
     same-capacity secondary (the last-emitted set): doubles both at 0.7
     occupancy instead of fail-stopping, and pre-sizes before a recovery
     replay so state that grew past the constructor capacity recovers.
-    Subclasses set _SECONDARY to the (hash, cols, valids) attr names."""
+    Subclasses set _SECONDARY to the (hash, cols, valids) attr names (the
+    top-N overrides `_grow_to`: its append-only form has no secondary)."""
 
     _SECONDARY: tuple = ()
 

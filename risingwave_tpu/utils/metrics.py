@@ -339,6 +339,21 @@ JOIN_LIVE_ROWS = "join_live_rows"
 JOIN_MATCH_ROWS = "join_match_rows_total"
 JOIN_MATCH_BUFFER_PEAK = "join_match_buffer_peak"
 
+# Top-N (stream/retract_top_n.py), labelled only, `executor` = the
+# executor's `identity`; all from the barrier watchdog's ONE fetch, so a
+# transfer-free top-N (`streaming_watchdog = 0`) publishes none:
+# - `top_n_live_rows{executor}`: rows the device store holds once the
+#   barrier has pruned it (an append-only input: at most offset + limit a
+#   group, which is also what the state table holds; a retracting input:
+#   every input row). Over the store's capacity it is the fill.
+# - `top_n_emit_rows_total{executor}`: rows the barrier flushes sent
+#   downstream: inserts, deletes and both halves of update pairs.
+# - `top_n_pruned_rows_total{executor}`: rows an append-only store dropped
+#   as beyond rank offset + limit (nothing can promote them again).
+TOP_N_LIVE_ROWS = "top_n_live_rows"
+TOP_N_EMIT_ROWS = "top_n_emit_rows_total"
+TOP_N_PRUNED_ROWS = "top_n_pruned_rows_total"
+
 # HBM memory manager (memory/manager.py): exact accounted device-state
 # bytes vs. the configured budget, plus eviction/reload activity. The
 # global series always render; per-executor `hbm_state_bytes{executor=..}`

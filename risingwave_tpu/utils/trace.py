@@ -56,6 +56,14 @@ cover.
                                                                  the awaited readback of the rebuilt
                                                                  occupancy, its `dispatch:hash_agg_*`
                                                                  and `d2h_wait` children
+  topn.flush (the barrier poll)       stream/retract_top_n.py    a top-N's barrier: the dispatch of
+                                                                 its capacity-wide ranking program,
+                                                                 the awaited readback of its ONE
+                                                                 watchdog pack (errors, live rows,
+                                                                 what changed) and the dispatch of
+                                                                 the emitting program at the width
+                                                                 the counts allow; children
+                                                                 `dispatch:retract_top_n_*`, `d2h_wait`
   d2h_wait (the poll or flush.stage)  utils/d2h.py               a worker thread blocked until the
                                                                  device reaches and ships a buffer,
                                                                  the actor's task (or the uploader's)
@@ -105,7 +113,8 @@ collect (only the keys that end in `_ns` are times):
   persist_wait_ns   the `d2h_wait` spans inside the barrier poll: the
                     actor parked on an awaited fetch (the loop is not
                     held), part of persist_ns
-  mesh_* / agg_* / join_*   row and byte counts (see `EpochTrace.phases`)
+  mesh_* / agg_* / join_* / topn_*   row and byte counts (see
+                    `EpochTrace.phases`)
 """
 
 from __future__ import annotations
@@ -323,7 +332,12 @@ class EpochTrace:
     # one chunk found, of the side nearest its match buffer's width); one
     # that holds a snapshot join-agg adds "snapshot_rows" /
     # "snapshot_capacity" (its fact store) and "snapshot_dim_rows", from
-    # the counts fetch its barrier makes.
+    # the counts fetch its barrier makes; one that holds a top-N adds, from
+    # its watchdog fetch, "topn_live_rows" / "topn_capacity" (rows its store
+    # holds once the barrier has pruned it), "topn_emit_rows" (rows its
+    # flush sent downstream: inserts, deletes, both halves of update pairs)
+    # and "topn_pruned_rows" (rows an append-only store dropped as beyond
+    # rank N).
     # Counts, not nanoseconds: only the keys that end in "_ns" are times
     # (the module docstring lists them: apply / persist / align and their
     # parts input_wait / fence / dispatch / apply_wait / persist_wait).
@@ -448,6 +462,11 @@ class EpochTrace:
                              f"peak {ph['join_match_peak']} of "
                              f"{ph['join_match_width']} candidates")
                 line += "]"
+            if "topn_live_rows" in ph:
+                line += (f" [top-N holds {ph['topn_live_rows']} of "
+                         f"{ph['topn_capacity']} rows, emitted "
+                         f"{ph['topn_emit_rows']}, pruned "
+                         f"{ph['topn_pruned_rows']}]")
             if "snapshot_rows" in ph:
                 line += (f" [snapshot holds {ph['snapshot_rows']} of "
                          f"{ph['snapshot_capacity']} rows, "

@@ -97,13 +97,18 @@ def _script(seed, n_rounds=4, n_groups=12, per_round=48, delete_frac=0.25):
     return msgs
 
 
-@pytest.mark.parametrize("group_keys,desc", [((0,), False), ((0,), True),
-                                             ((), False), ((), True)])
-async def test_sharded_topn_matches_single_device(group_keys, desc):
+@pytest.mark.parametrize("group_keys,desc,rank", [
+    ((0,), False, False), ((0,), True, False), ((), False, False),
+    ((), True, False),
+    # the rank-filter plan's arguments: the rank as an output column (both
+    # executors emit a moved rank as a Delete + Insert over a retracting
+    # input, so the accumulated MVs agree rank for rank)
+    ((0,), True, True)])
+async def test_sharded_topn_matches_single_device(group_keys, desc, rank):
     msgs = _script(seed=5 + len(group_keys) + desc)
     mesh = make_mesh(8)
     kw = dict(group_key_indices=group_keys, order_col=1, limit=3,
-              descending=desc, pk_indices=(2,))
+              descending=desc, pk_indices=(2,), emit_rank=rank)
     sharded = ShardedTopNExecutor(ScriptSource(msgs), mesh=mesh,
                                   capacity=64, **kw)
     got = mv_apply(await drive(sharded))
@@ -113,6 +118,7 @@ async def test_sharded_topn_matches_single_device(group_keys, desc):
     plain = RetractableTopNExecutor(ScriptSource(msgs), capacity=512, **kw)
     want = mv_apply(await drive(plain))
     assert got == want and len(got) > 0
+    assert all(len(row) == 3 + rank for row in got)
 
 
 async def test_sharded_global_topn_offset_refill_across_shards():

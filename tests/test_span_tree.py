@@ -488,9 +488,12 @@ def test_the_benchmark_lists_the_new_readers():
         assert by_name[name]["source"] == "device_trace"
     assert "q5.sat" not in by_name["join_dev_s_per_ckpt"]["workloads"]
     # every cell runs a hash agg but q17.sat, whose one stateful executor is
-    # the snapshot join-agg (its own reader: snapshot_dev_s_per_ckpt)
+    # the snapshot join-agg (its own reader: snapshot_dev_s_per_ckpt), and
+    # q19.sat, whose one is the group top-N (topn_dev_s_per_ckpt)
     assert set(by_name["agg_dev_s_per_ckpt"]["workloads"]) == {
-        w["name"] for w in bm["workloads"]} - {"q17.sat"}
+        w["name"] for w in bm["workloads"]} - {"q17.sat", "q19.sat"}
     assert by_name["snapshot_dev_s_per_ckpt"]["workloads"] == ["q17.sat"]
+    assert by_name["topn_dev_s_per_ckpt"]["workloads"] == ["q19.sat"]
+    assert by_name["topn_dev_s_per_ckpt"]["source"] == "device_trace"
     assert GLOBAL_METRICS.counter("trace_spans_dropped_total") \
         is TRACE_SPANS_DROPPED

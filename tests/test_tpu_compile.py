@@ -707,3 +707,38 @@ def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
         fits_one_chip(compiled)
     n = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharded)
     join._watchdog_pack_sh._jitted.lower(*acc, n, n).compile()
+
+
+def _top_n_programs(capacity: int, chunk_rows: int, one_chip):
+    """The three programs of an append-only group top-N over bid rows (the
+    benchmark's `q19.sat`: nine lanes a row with the row id, three of them
+    int32 dictionary ids), lowered for the described chip: (name, lowered)."""
+    from risingwave_tpu.connectors.nexmark import BID_SCHEMA
+    from risingwave_tpu.stream.executor import Executor
+    from risingwave_tpu.stream.retract_top_n import RetractableTopNExecutor
+    from risingwave_tpu.stream.row_id import RowIdGenExecutor
+
+    class Bids(Executor):
+        schema = BID_SCHEMA
+
+    rows = RowIdGenExecutor(Bids())
+    top = RetractableTopNExecutor(
+        rows, (0,), order_specs=[(2, True), (7, False)], limit=10,
+        capacity=capacity, pk_indices=(7,), append_only=True,
+        emit_rank=True)
+    store = abstract((top.khash, top.cols, top.valids, top.n), one_chip)
+    errs = abstract(top._errs_dev, one_chip)
+    ranked = jax.ShapeDtypeStruct((capacity,), jnp.int32, sharding=one_chip)
+    yield "apply", top._apply._jitted.lower(
+        *store, errs, abstract_chunk(rows.schema, chunk_rows, one_chip))
+    yield "rank", top._rank._jitted.lower(store[1], store[3], errs)
+    yield "emit", top._emit._jitted.lower(
+        *store, ranked, ranked, width=chunk_rows,
+        persist_width=chunk_rows, durable=True)
+
+
+def test_append_only_group_top_n(one_chip, no_persistent_cache):
+    """The merge into the sorted store, the capacity-wide sort and rank, the
+    gather of what changed with the store's compaction."""
+    for _name, lowered in _top_n_programs(1 << 14, CHUNK, one_chip):
+        fits_one_chip(lowered.compile())
