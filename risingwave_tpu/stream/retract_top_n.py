@@ -9,38 +9,48 @@ becomes a group top-N in the fragment of its input (`_plan_over_window`:
 upstream's `over_window_to_topn_rule`). Any other use of a window function
 stays a `general_over_window`.
 
-The input lives in a dense array store sorted by a 63-bit hash of the ROW
-KEY (the stream key — retractions address rows by it), maintained with the
-same searchsorted/merge machinery as sorted_join.py's own-side update
-(sorted_store.py). At each barrier the live rows are lexsorted by (group
-hash, order key, stream key) — iterated stable argsorts — and ranked within
-their group runs. TIES on the order key are broken by the stream key
-ascending (for a generated `_row_id`: arrival order, the earlier row
-first), as upstream orders its cache by (order key, stream key); never by
-the key's hash.
-
-What the store keeps depends on the input:
+The rows live in a dense array store (sorted_store.py): a prefix [0, n) of
+fixed-capacity lanes, merged into by log-step moves. What it holds, and in
+WHICH ORDER, depends on the input. TIES on the order key are broken by the
+stream key ascending either way (for a generated `_row_id`: arrival order,
+the earlier row first), as upstream orders its `TopNCache` by (order key,
+stream key); never by the key's hash.
 
 * RETRACTING input (a top-N over an aggregate's or a join's changelog): ALL
   input rows, so a deleted top row is refilled from below — rank promotion
-  pulls the next row in. The last emitted top set is a second store sorted
-  by a full-row hash; the new set is diffed against it by membership (two
-  searchsorteds): Deletes for rows that left, Inserts for rows that came. A
-  rank that moved is a Delete + Insert of the row under its old and new
-  rank. The state table holds every input row.
+  pulls the next row in — sorted by a 63-bit hash of the ROW KEY (the
+  stream key: retractions address rows by it; `sorted_store_apply`). At
+  each barrier the live rows are lexsorted by (group hash, order key,
+  stream key) — iterated stable argsorts over the capacity — and ranked
+  within their group runs. The last emitted top set is a second store
+  sorted by a full-row hash; the new set is diffed against it by
+  membership (two searchsorteds): Deletes for rows that left, Inserts for
+  rows that came. A rank that moved is a Delete + Insert of the row under
+  its old and new rank. The state table holds every input row.
 
 * APPEND-ONLY input (the binder knows: `info.append_only`): only the rows
   that can still rank — at most `offset + limit` a group after each barrier
-  — because nothing can promote a dropped row. The store carries one hidden
-  int32 lane per row, the rank it was last emitted under (none yet: fresh
-  since the last barrier, or kept below `offset`), so the store IS the
-  emitted baseline: no second copy. The barrier runs two programs around
-  ONE small fetch: `retract_top_n_rank` sorts and ranks, and counts what
-  changed; `retract_top_n_emit` gathers the changed rows — an Insert, a
-  Delete (pushed past rank N: dropped from the store for good), or, where
-  the rank is an output column, an adjacent UpdateDelete / UpdateInsert
-  pair under the old and the new rank — and compacts the store to the kept
-  rows. The state table holds exactly the kept rows.
+  — because nothing can promote a dropped row, and nothing ever has to
+  find a row by its key. So the store is kept in RANK ORDER: ascending by
+  (group hash, order keys as `_sort_keys` states them, stream-key tie
+  columns), the group hash its first lane. A chunk is sorted by that key
+  (N rows, not the capacity), each row's place found by ONE lexicographic
+  search through the store's key lanes, and the lanes moved by the joins'
+  merge given the two ranks (`sorted_join._merge_sorted`). The store
+  carries one hidden int32 lane per row, the rank it was last emitted
+  under (none yet: fresh since the last barrier, or kept below `offset`),
+  so the store IS the emitted baseline: no second copy. The barrier runs
+  two programs around ONE small fetch: `retract_top_n_rank` reads a row's
+  rank off the run boundaries of the group-hash lane (position less run
+  start: no sort, no gather) and counts what changed; `retract_top_n_emit`
+  gathers the changed rows — an Insert, a Delete (pushed past rank N:
+  dropped from the store for good), or, where the rank is an output
+  column, an adjacent UpdateDelete / UpdateInsert pair under the old and
+  the new rank — and compacts the store to the kept rows, which leaves
+  them in rank order. Two groups whose hashes collide rank as one run (the
+  retracting form draws its runs on the same hash). The state table holds
+  exactly the kept rows; a recovery merges them back in whatever order
+  they come.
 
 Either way the emitted chunk is as wide as a power of two over twice the
 rows that changed (the count rides the barrier's one fetch; the width only
@@ -74,12 +84,18 @@ from ..ops.monotone_move import compact
 from ..utils.d2h import defer_prefix_flush, fetch_small, off_loop
 from ..utils.metrics import (
     GLOBAL_METRICS, TOP_N_EMIT_ROWS, TOP_N_LIVE_ROWS, TOP_N_PRUNED_ROWS,
+    TOP_N_SORTED_ROWS,
 )
 from ..utils.trace import span
 from .executor import Executor, StatefulUnaryExecutor
 from .message import Barrier, Watermark
-from .sorted_join import _HSENTINEL, grow_sorted_arrays, key_hash
-from .sorted_store import GrowableSortedStore, sorted_store_apply
+from .sorted_join import (
+    _HSENTINEL, _merge_sorted, _rank_of_ascending, grow_sorted_arrays,
+    key_hash,
+)
+from .sorted_store import (
+    GrowableSortedStore, segment_starts, sorted_store_apply,
+)
 
 # the hidden lane of an append-only store: the 0-based rank a row was last
 # emitted under, or one of these
@@ -116,6 +132,21 @@ def _nth_set(mask: jnp.ndarray, width: int):
     src = jnp.searchsorted(cum, jnp.arange(width, dtype=jnp.int32),
                            side="right")
     return jnp.clip(src, 0, mask.shape[0] - 1).astype(jnp.int32), cum[-1]
+
+
+def _lex_lt(a: Sequence, b: Sequence) -> jnp.ndarray:
+    """a < b, lane-wise lexicographic over the key lanes, the most
+    significant first, in the order a sort puts them: every NaN alike and
+    behind every number, -0.0 and 0.0 alike."""
+    lt = jnp.zeros(a[0].shape, dtype=bool)
+    eq = jnp.ones(a[0].shape, dtype=bool)
+    for x, y in zip(a, b):
+        x_lt, x_eq = x < y, x == y
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            x_nan, y_nan = jnp.isnan(x), jnp.isnan(y)
+            x_lt, x_eq = x_lt | (y_nan & ~x_nan), x_eq | (x_nan & y_nan)
+        lt, eq = lt | (eq & x_lt), eq & x_eq
+    return lt
 
 
 class RetractableTopNExecutor(GrowableSortedStore,
@@ -166,8 +197,10 @@ class RetractableTopNExecutor(GrowableSortedStore,
         dts = tuple(f.data_type.jnp_dtype for f in input.schema)
         self._col_dtypes = dts
         self._n_in = len(dts)
-        # dense store sorted by row-key hash; an append-only store's last
-        # lane is the emitted rank (see the module docstring)
+        # the dense store: `khash` is the row-key hash it is sorted by, or,
+        # for an append-only input, the GROUP hash that leads its rank
+        # order; an append-only store's last lane is the emitted rank (see
+        # the module docstring)
         lanes = dts + ((jnp.int32,) if self.append_only else ())
         self.khash = jnp.full(C, _HSENTINEL, dtype=jnp.int64)
         self.cols = tuple(jnp.zeros(C, dtype=dt) for dt in lanes)
@@ -190,13 +223,16 @@ class RetractableTopNExecutor(GrowableSortedStore,
         self._emit_width = FLUSH_MIN_ROWS
         self._persist_width = FLUSH_MIN_ROWS
         # what a barrier's programs left for flush() / persist()
-        self._ranked = self._flushed = None
+        self._flushed = None
         self._ready: Optional[StreamChunk] = None
         self._persist_view = None
         self._persist_counts: Optional[tuple] = None
         self._pack_dev = None
         self._epoch_chunks: list[StreamChunk] = []
         self._phase_counts: dict = {}
+        # rows the interval's chunks brought to an append-only store's
+        # N-wide sorts (their capacities: what a sort costs by)
+        self._chunk_rows = 0
         self._build_programs()
         self._init_stateful(state_table, watchdog_interval)
 
@@ -249,23 +285,23 @@ class RetractableTopNExecutor(GrowableSortedStore,
                 keys.append(~oval if desc else oval)
         return keys
 
+    def _group_hash(self, cols) -> jnp.ndarray:
+        """A hash of the group columns (0 where there is no group key):
+        what a rank's runs are drawn on, and the first lane of an
+        append-only store's order."""
+        if not self.group_key_indices:
+            return jnp.zeros(cols[0].shape[0], dtype=jnp.int64)
+        return key_hash([cols[i] for i in self.group_key_indices])
+
     def _rank_rows(self, cols, live):
         """(order, rank, s_live): the permutation that sorts the live rows
         by (group, order key, stream key) with the dead ones behind, each
         sorted position's 0-based rank within its group run, and which
         sorted positions are live."""
-        C = live.shape[0]
-        imax = jnp.iinfo(jnp.int64).max
-        ghash = (key_hash([cols[i] for i in self.group_key_indices])
-                 if self.group_key_indices
-                 else jnp.zeros(C, dtype=jnp.int64))
-        g = jnp.where(live, ghash, imax)
+        g = jnp.where(live, self._group_hash(cols), _HSENTINEL)
         order = stable_lexsort(tuple(self._sort_keys(cols) + [g]))
-        s_g = g[order]
-        new_run = jnp.concatenate([jnp.array([True]),
-                                   s_g[1:] != s_g[:-1]])
-        pos = jnp.arange(C, dtype=jnp.int32)
-        run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
+        _, run_start = segment_starts(g[order])
+        pos = jnp.arange(live.shape[0], dtype=jnp.int32)
         return order, pos - run_start, live[order]
 
     # ------------------------------------------- retracting input: flush
@@ -324,72 +360,126 @@ class RetractableTopNExecutor(GrowableSortedStore,
                 ops[src], jnp.arange(width, dtype=jnp.int32) < n)
 
     # ----------------------------------------- append-only input: programs
+    def _key_lanes(self, ghash, cols) -> list:
+        """The composite key an append-only store is ordered by, the most
+        significant lane first: group hash, order keys, stream-key ties."""
+        return [ghash] + self._sort_keys(cols)[::-1]
+
+    def _place(self, khash, cols, n, q_keys) -> jnp.ndarray:
+        """For every query key (N lanes-wise keys, as `_key_lanes`), the
+        number of stored rows whose composite key is below it: ONE
+        lexicographic binary search through the live prefix, the key
+        lanes gathered at N positions a step."""
+        C, N = self.capacity, q_keys[0].shape[0]
+        key_cols = sorted({c for c, _ in self.order_specs}
+                          | set(self._tie_cols))
+
+        def step(_, bounds):
+            lo, hi = bounds
+            mid = (lo + hi) >> 1
+            at = jnp.minimum(mid, C - 1)
+            below = _lex_lt(
+                self._key_lanes(khash[at], {c: cols[c][at]
+                                            for c in key_cols}), q_keys)
+            open_ = lo < hi
+            return (jnp.where(open_ & below, mid + 1, lo),
+                    jnp.where(open_ & ~below, mid, hi))
+
+        lo, _ = jax.lax.fori_loop(
+            0, C.bit_length(), step,
+            (jnp.zeros(N, dtype=jnp.int32),
+             jnp.full(N, n, dtype=jnp.int32)))
+        return lo
+
     def _apply_fresh_impl(self, khash, cols, valids, n, errs,
                           chunk: StreamChunk):
-        """`sorted_store_apply` of the chunk with the hidden lane set to
-        `_FRESH`. A retraction has no business here: it counts as a delete
-        that matched nothing and fail-stops the barrier."""
-        N = chunk.capacity
-        fresh = Column(jnp.full(N, _FRESH, dtype=jnp.int32))
-        n_retract = jnp.sum((chunk.vis & (op_sign(chunk.ops) < 0))
-                            .astype(jnp.int32))
-        kh, c, v, n2, e2 = sorted_store_apply(
-            khash, cols, valids, n, errs,
-            StreamChunk(chunk.columns[:self._n_in] + (fresh,), chunk.ops,
-                        chunk.vis, chunk.schema),
-            pk_idx=self.pk_indices, capacity=self.capacity)
-        return kh, c, v, n2, e2.at[1].add(n_retract.astype(e2.dtype))
+        """Merge the chunk's rows into the store IN RANK ORDER: sort the
+        chunk by the composite key (N wide), find each row's place by one
+        search, move the lanes by the joins' merge given the two ranks.
+        The hidden lane of a new row is `_FRESH`. No row of the chunk
+        equals a stored one (the stream key is in the key). A retraction
+        has no business here: it is counted as a delete that matched
+        nothing and fail-stops the barrier."""
+        N, C, nk = chunk.capacity, self.capacity, self._n_in + 1
+        signs = op_sign(chunk.ops)
+        is_ins = chunk.vis & (signs > 0)
+        n_new = jnp.sum(is_ins.astype(jnp.int32))
+        n_retract = jnp.sum((chunk.vis & (signs < 0)).astype(jnp.int32))
+        data = [c.data.astype(dt) for c, dt in zip(chunk.columns,
+                                                   self._col_dtypes)]
+        ghash = jnp.where(is_ins, self._group_hash(data), _HSENTINEL)
+        order = stable_lexsort(tuple(self._sort_keys(data) + [ghash]))
+        s_ghash = ghash[order]
+        s_data = [d[order] for d in data]
+        idx = self._place(khash, cols, n, self._key_lanes(s_ghash, s_data))
+        # stored rows are all kept (a dense prefix: nothing to compact) and
+        # none equals a new one: the ranks `sorted_join._merge_ranks` takes
+        # from its one search
+        new_ok = jnp.arange(N, dtype=jnp.int32) < n_new
+        new_lt = _rank_of_ascending(idx, new_ok.astype(jnp.int32), C)
+        moved, n_after, n_row_overflow = _merge_sorted(
+            jnp.arange(C, dtype=jnp.int32) < n, False, n_new,
+            [khash, *cols, *valids], [_HSENTINEL] + [0] * nk + [False] * nk,
+            [s_ghash, *s_data, jnp.full(N, _FRESH, dtype=jnp.int32)]
+            + [c.valid_mask()[order] for c in chunk.columns[:self._n_in]]
+            + [jnp.ones(N, dtype=bool)], ranks=(new_lt, idx))
+        errs = errs + jnp.stack([n_row_overflow, n_retract]).astype(
+            errs.dtype)
+        return (moved[0], tuple(moved[1:1 + nk]), tuple(moved[1 + nk:]),
+                n_after, errs)
 
-    def _changes(self, order, rank, s_live, erank):
-        """Per SORTED position, what the barrier does with the row there:
-        (ins, dele, upd) for the changelog, (p_ins, p_del) for the state
-        table, `kept` for the store, and the rank it was emitted under."""
-        s_er = erank[order]
+    def _store_ranks(self, khash, n):
+        """(rank, live): the store is in rank order, so a row's 0-based
+        rank within its group is its distance from the start of its run
+        of the group-hash lane."""
+        pos = jnp.arange(self.capacity, dtype=jnp.int32)
+        _, run_start = segment_starts(khash)
+        return pos - run_start, pos < n
+
+    def _changes(self, rank, live, erank):
+        """Per stored row, what the barrier does with it: (ins, dele, upd)
+        for the changelog, (p_ins, p_del) for the state table, `kept` for
+        the store."""
         K = self.offset + self.limit
-        kept = s_live & (rank < K)
+        kept = live & (rank < K)
         in_win = kept & (rank >= self.offset)
-        was = s_live & (s_er >= 0)
-        fresh = s_live & (s_er == _FRESH)
+        was = live & (erank >= 0)
+        fresh = live & (erank == _FRESH)
         ins = in_win & ~was
         dele = was & ~in_win
-        upd = (in_win & was & (s_er != rank) if self.emit_rank
+        upd = (in_win & was & (erank != rank) if self.emit_rank
                else jnp.zeros_like(ins))
-        return ins, dele, upd, fresh & kept, s_live & ~fresh & ~kept, \
-            kept, s_er
+        return ins, dele, upd, fresh & kept, live & ~fresh & ~kept, kept
 
-    def _rank_impl(self, cols, n, errs):
-        """The capacity-wide half of the barrier: sort and rank, and count.
-        Returns (order, rank, pack); pack = [row overflow, delete misses,
-        live rows, rows the changelog takes, state-table inserts, deletes,
-        rows kept, rows pruned]."""
-        C = self.capacity
-        live = jnp.arange(C, dtype=jnp.int32) < n
-        order, rank, s_live = self._rank_rows(cols[:self._n_in], live)
-        ins, dele, upd, p_ins, p_del, kept, _ = self._changes(
-            order, rank, s_live, cols[-1])
+    def _rank_impl(self, khash, erank, n, errs):
+        """The barrier's counts, from the store as it stands: no sort, no
+        gather, nothing handed to the emitting program. Returns pack =
+        [row overflow, delete misses, live rows, rows the changelog takes,
+        state-table inserts, deletes, rows kept, rows pruned]."""
+        rank, live = self._store_ranks(khash, n)
+        ins, dele, upd, p_ins, p_del, kept = self._changes(rank, live, erank)
 
         def count(m):
             return jnp.sum(m.astype(jnp.int32))
 
-        pack = jnp.concatenate([errs, jnp.stack([
+        return jnp.concatenate([errs, jnp.stack([
             n.astype(jnp.int32),
             count(ins) + count(dele) + 2 * count(upd),
             count(p_ins), count(p_del), count(kept),
-            count(s_live & ~kept)])])
-        return order.astype(jnp.int32), rank.astype(jnp.int32), pack
+            count(live & ~kept)])])
 
-    def _emit_impl(self, khash, cols, valids, n, order, rank, *,
+    def _emit_impl(self, khash, cols, valids, n, *,
                    width: int, persist_width: int, durable: bool):
         """The barrier's other half, at the widths the counts allow: the
-        changelog chunk (`width` rows), the state table's inserts and
-        deletes (`persist_width` rows each; None where not `durable`), and
-        the store compacted to the kept rows with their ranks as the new
-        baseline."""
+        changelog chunk (`width` rows, in rank order), the state table's
+        inserts and deletes (`persist_width` rows each; None where not
+        `durable`), and the store compacted to the kept rows — still in
+        rank order — with their ranks as the new baseline."""
         C = self.capacity
-        live = jnp.arange(C, dtype=jnp.int32) < n
         data, dvalid = cols[:self._n_in], valids[:self._n_in]
-        ins, dele, upd, p_ins, p_del, kept, s_er = self._changes(
-            order, rank, live[order], cols[-1])
+        erank = cols[-1]
+        rank, live = self._store_ranks(khash, n)
+        ins, dele, upd, p_ins, p_del, kept = self._changes(rank, live, erank)
 
         # changelog: a changed row takes one slot, a rank shift two
         # adjacent ones (UpdateDelete under the old rank, UpdateInsert
@@ -398,11 +488,10 @@ class RetractableTopNExecutor(GrowableSortedStore,
                  + 2 * upd.astype(jnp.int32))
         cum = jnp.cumsum(slots)
         s = jnp.arange(width, dtype=jnp.int32)
-        src = jnp.clip(jnp.searchsorted(cum, s, side="right"), 0,
+        row = jnp.clip(jnp.searchsorted(cum, s, side="right"), 0,
                        C - 1).astype(jnp.int32)
-        first = s == cum[src] - slots[src]
-        row = order[src]
-        is_upd, is_ins = upd[src], ins[src]
+        first = s == cum[row] - slots[row]
+        is_upd, is_ins = upd[row], ins[row]
         ops = jnp.where(
             is_upd, jnp.where(first, OP_UPDATE_DELETE, OP_UPDATE_INSERT),
             jnp.where(is_ins, OP_INSERT, OP_DELETE)).astype(jnp.int8)
@@ -411,33 +500,28 @@ class RetractableTopNExecutor(GrowableSortedStore,
         if self.emit_rank:
             old = (is_upd & first) | (~is_upd & ~is_ins)
             out_cols.append(Column(
-                jnp.where(old, s_er[src], rank[src]).astype(jnp.int64) + 1,
+                jnp.where(old, erank[row], rank[row]).astype(jnp.int64) + 1,
                 vis))
 
         view = None
         if durable:
-            i_src, _ = _nth_set(p_ins, persist_width)
-            i_row = order[i_src]
-            d_src, _ = _nth_set(p_del, persist_width)
-            d_row = order[d_src]
+            i_row, _ = _nth_set(p_ins, persist_width)
+            d_row, _ = _nth_set(p_del, persist_width)
             view = ([c[i_row] for c in data]
                     + [_valid_bits([v[i_row] for v in dvalid])],
                     [data[p][d_row] for p in self.pk_indices])
 
         # the store: rows past rank `offset + limit` go (nothing can
         # promote them), the rest carry their rank as the next baseline
-        inv = jnp.argsort(order)
-        rank_at = rank[inv]
-        keep = live & (rank_at < self.offset + self.limit)
-        erank = jnp.where(keep & (rank_at >= self.offset), rank_at,
-                          _UNEMITTED).astype(jnp.int32)
+        stamped = jnp.where(kept & (rank >= self.offset), rank,
+                            _UNEMITTED).astype(jnp.int32)
         lanes = compact(
-            keep, [khash, *data, erank, *valids],
+            kept, [khash, *data, stamped, *valids],
             [_HSENTINEL] + [0] * (self._n_in + 1)
             + [False] * (self._n_in + 1))
         nl = self._n_in + 1
         return (lanes[0], tuple(lanes[1:1 + nl]), tuple(lanes[1 + nl:]),
-                jnp.sum(keep.astype(jnp.int32)), tuple(out_cols), ops, vis,
+                jnp.sum(kept.astype(jnp.int32)), tuple(out_cols), ops, vis,
                 view)
 
     # -------------------------------------------------------------- hooks
@@ -448,7 +532,9 @@ class RetractableTopNExecutor(GrowableSortedStore,
 
     def on_chunk(self, chunk: StreamChunk) -> None:
         self._apply_chunk(chunk)
-        if self.state_table is not None and not self.append_only:
+        if self.append_only:
+            self._chunk_rows += chunk.capacity
+        elif self.state_table is not None:
             self._epoch_chunks.append(chunk)
         return None
 
@@ -479,11 +565,9 @@ class RetractableTopNExecutor(GrowableSortedStore,
                     n_emit, self._emit_width)
                 pwidth = self._persist_width = min(C, self._width_for(
                     n_persist, self._persist_width))
-            order, rank = self._ranked
-            self._ranked = None
             (self.khash, self.cols, self.valids, self.n, out_cols, ops,
              vis, view) = self._emit(
-                self.khash, self.cols, self.valids, self.n, order, rank,
+                self.khash, self.cols, self.valids, self.n,
                 width=width, persist_width=pwidth, durable=durable)
             self._persist_view = view
         else:
@@ -497,14 +581,14 @@ class RetractableTopNExecutor(GrowableSortedStore,
         self._ready = StreamChunk(tuple(out_cols), ops, vis, self.schema)
 
     def _run_rank(self):
-        """Dispatch the capacity-wide program of the barrier; returns the
-        device scalar(s) the watchdog's pack takes along."""
+        """Dispatch the barrier's ranking program (an append-only store:
+        ranks off the run boundaries, and the counts; else the capacity-wide
+        sort, rank and diff); returns the device scalar(s) the watchdog's
+        pack takes along."""
         if self.append_only:
-            order, rank, pack = self._rank(self.cols, self.n,
-                                           self._errs_dev)
-            self._ranked = (order, rank)
-            self._pack_dev = pack
-            return pack
+            self._pack_dev = self._rank(self.khash, self.cols[-1], self.n,
+                                        self._errs_dev)
+            return self._pack_dev
         (self.top_hash, self.top_cols, self.top_valids, self.top_n,
          out_cols, ops, vis) = self._flush(
             self.khash, self.cols, self.valids, self.n,
@@ -564,8 +648,13 @@ class RetractableTopNExecutor(GrowableSortedStore,
         for the epoch trace: the rows the store holds after the barrier
         over its capacity, the rows the changelog took (inserts, deletes
         and both halves of update pairs), the rows dropped as beyond rank
-        N."""
+        N, and the rows the interval sorted to keep the store ranked (an
+        append-only store: its chunks'; else the capacity, at the flush)."""
         label = self.identity
+        n_sorted = self._chunk_rows if self.append_only else self.capacity
+        self._chunk_rows = 0
+        GLOBAL_METRICS.counter(TOP_N_SORTED_ROWS, executor=label).inc(
+            n_sorted)
         GLOBAL_METRICS.gauge(TOP_N_LIVE_ROWS, executor=label).set(
             float(n_live))
         GLOBAL_METRICS.counter(TOP_N_EMIT_ROWS, executor=label).inc(n_emit)
@@ -573,11 +662,13 @@ class RetractableTopNExecutor(GrowableSortedStore,
             n_pruned)
         self._phase_counts = dict(
             topn_live_rows=n_live, topn_capacity=self.capacity,
-            topn_emit_rows=n_emit, topn_pruned_rows=n_pruned)
+            topn_emit_rows=n_emit, topn_pruned_rows=n_pruned,
+            topn_sorted_rows=n_sorted)
 
     def take_phase_counts(self) -> dict:
         """This barrier's `topn_live_rows` / `topn_capacity` /
-        `topn_emit_rows` / `topn_pruned_rows` for the actor's phase dict:
+        `topn_emit_rows` / `topn_pruned_rows` / `topn_sorted_rows` for the
+        actor's phase dict:
         host numbers the watchdog fetch brought, absent where it made
         none."""
         counts, self._phase_counts = self._phase_counts, {}

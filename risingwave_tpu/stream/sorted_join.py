@@ -296,7 +296,7 @@ def _merge_ranks(khash: jnp.ndarray, dead_cum: jnp.ndarray,
 
 def _merge_sorted(keep: jnp.ndarray, drops: bool, n_new,
                   pool: Sequence[jnp.ndarray], fills: Sequence,
-                  new: Sequence):
+                  new: Sequence, ranks: Optional[tuple] = None):
     """The stable merge of the chunk's new rows into the pool rows with
     `keep`: the state the merge leaves, every lane dense and in hash
     order, padding included. `pool` are the pool's lanes [C], the order
@@ -305,7 +305,9 @@ def _merge_sorted(keep: jnp.ndarray, drops: bool, n_new,
     hashes behind (None: a new row gets that lane's fill). `drops` says
     statically whether `keep` can leave a gap in the live prefix (a side
     that neither cleans nor retracts only appends: its kept rows need no
-    compaction).
+    compaction). `ranks` is (new_lt, kept_le) as `_merge_ranks` states
+    them, from a caller whose order is more than its first lane (a top-N
+    ranked by several key lanes): the merge then searches nothing.
 
     No row overtakes another on its way. A kept pool row slides left
     over the dropped rows before it (`compact`), then right past the new
@@ -317,7 +319,8 @@ def _merge_sorted(keep: jnp.ndarray, drops: bool, n_new,
     dead_cum = jnp.cumsum((~keep).astype(jnp.int32))
     n_kept = C - dead_cum[C - 1]
     new_ok = jnp.arange(N, dtype=jnp.int32) < n_new
-    new_lt, kept_le = _merge_ranks(pool[0], dead_cum, new[0], new_ok)
+    new_lt, kept_le = ranks if ranks is not None else _merge_ranks(
+        pool[0], dead_cum, new[0], new_ok)
     pool, fills = list(pool), list(fills)
     occupied = keep
     if drops:

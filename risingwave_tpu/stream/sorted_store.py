@@ -5,14 +5,19 @@ input (retract_top_n.py) holds only the rows that can still rank, pruned at
 every barrier, with one hidden lane for the rank each was last emitted
 under.
 
-Rows live in a dense prefix [0, n) of fixed-capacity arrays sorted by a
-63-bit hash of the STREAM KEY (retractions address rows by it), maintained
-with the same searchsorted/merge machinery as sorted_join.py's own-side
-update: per chunk, one jitted program nets within-chunk pk runs, finds
-delete victims by (hash, pk) match, and merge-inserts the survivors
-through the join's own `_merge_sorted` (kept rows and new rows move by
-log-step shifts, `ops/monotone_move.py`: no index per stored row) —
-static shapes, no data-dependent control flow.
+Rows live in a dense prefix [0, n) of fixed-capacity arrays. Where rows
+can be retracted (`sorted_store_apply`: OverWindow, a retracting top-N,
+the mesh top-N) the prefix is sorted by a 63-bit hash of the STREAM KEY
+(retractions address rows by it), maintained with the same
+searchsorted/merge machinery as sorted_join.py's own-side update: per
+chunk, one jitted program nets within-chunk pk runs, finds delete victims
+by (hash, pk) match, and merge-inserts the survivors through the join's
+own `_merge_sorted` (kept rows and new rows move by log-step shifts,
+`ops/monotone_move.py`: no index per stored row) — static shapes, no
+data-dependent control flow. An append-only top-N never looks a row up:
+it keeps the same lanes in RANK order instead (group hash first; its own
+apply in retract_top_n.py, the same merge given its ranks) and reads
+ranks off `segment_starts`.
 
 Reference analogue: the row-holding state tables behind
 top_n_state.rs / over_window's partition cache — re-designed dense for

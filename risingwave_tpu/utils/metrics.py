@@ -350,9 +350,14 @@ JOIN_MATCH_BUFFER_PEAK = "join_match_buffer_peak"
 #   downstream: inserts, deletes and both halves of update pairs.
 # - `top_n_pruned_rows_total{executor}`: rows an append-only store dropped
 #   as beyond rank offset + limit (nothing can promote them again).
+# - `top_n_sorted_rows_total{executor}`: rows the barrier intervals SORTED
+#   to keep the store ranked: an append-only store (kept in rank order)
+#   sorts the chunks it merges, a retracting one its whole capacity at
+#   every flush. Over the intervals' rows it says which form ran.
 TOP_N_LIVE_ROWS = "top_n_live_rows"
 TOP_N_EMIT_ROWS = "top_n_emit_rows_total"
 TOP_N_PRUNED_ROWS = "top_n_pruned_rows_total"
+TOP_N_SORTED_ROWS = "top_n_sorted_rows_total"
 
 # HBM memory manager (memory/manager.py): exact accounted device-state
 # bytes vs. the configured budget, plus eviction/reload activity. The

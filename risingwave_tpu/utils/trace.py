@@ -57,7 +57,7 @@ cover.
                                                                  occupancy, its `dispatch:hash_agg_*`
                                                                  and `d2h_wait` children
   topn.flush (the barrier poll)       stream/retract_top_n.py    a top-N's barrier: the dispatch of
-                                                                 its capacity-wide ranking program,
+                                                                 its ranking (and counting) program,
                                                                  the awaited readback of its ONE
                                                                  watchdog pack (errors, live rows,
                                                                  what changed) and the dispatch of
@@ -336,8 +336,9 @@ class EpochTrace:
     # its watchdog fetch, "topn_live_rows" / "topn_capacity" (rows its store
     # holds once the barrier has pruned it), "topn_emit_rows" (rows its
     # flush sent downstream: inserts, deletes, both halves of update pairs)
-    # and "topn_pruned_rows" (rows an append-only store dropped as beyond
-    # rank N).
+    # "topn_pruned_rows" (rows an append-only store dropped as beyond
+    # rank N) and "topn_sorted_rows" (rows the interval sorted to keep the
+    # store ranked: the chunks' for an append-only store, else the capacity).
     # Counts, not nanoseconds: only the keys that end in "_ns" are times
     # (the module docstring lists them: apply / persist / align and their
     # parts input_wait / fence / dispatch / apply_wait / persist_wait).
@@ -466,7 +467,8 @@ class EpochTrace:
                 line += (f" [top-N holds {ph['topn_live_rows']} of "
                          f"{ph['topn_capacity']} rows, emitted "
                          f"{ph['topn_emit_rows']}, pruned "
-                         f"{ph['topn_pruned_rows']}]")
+                         f"{ph['topn_pruned_rows']}, sorted "
+                         f"{ph['topn_sorted_rows']}]")
             if "snapshot_rows" in ph:
                 line += (f" [snapshot holds {ph['snapshot_rows']} of "
                          f"{ph['snapshot_capacity']} rows, "

@@ -26,7 +26,8 @@ from risingwave_tpu.state.storage_table import StorageTable
 from risingwave_tpu.stream.general_over_window import (
     GeneralOverWindowExecutor)
 from risingwave_tpu.stream.retract_top_n import RetractableTopNExecutor
-from risingwave_tpu.utils.metrics import GLOBAL_METRICS, TOP_N_EMIT_ROWS
+from risingwave_tpu.utils.metrics import (
+    GLOBAL_METRICS, TOP_N_EMIT_ROWS, TOP_N_SORTED_ROWS)
 from risingwave_tpu.utils.trace import SPAN_LOG
 
 # at this seed the parent's hash tie-break put 21 other bids into the MV
@@ -129,6 +130,7 @@ def test_the_references_bids_are_the_connectors_strings_included():
 
 
 def test_a_dictionary_lookup_never_inserts():
+    GLOBAL_DICT.get_or_insert("apple")   # whatever ran before in this process
     before = len(GLOBAL_DICT)
     ids = q19.dictionary_ids(np.asarray(["no such string, ever", "apple"]))
     assert ids[0] == -1 and len(GLOBAL_DICT) == before
@@ -257,6 +259,15 @@ async def test_the_flush_is_a_span_and_nothing_compiles_after_two_barriers():
     kids = [sp.name for sp in spans if sp.parent == found[0].sid]
     assert sorted(kids) == ["d2h_wait", "dispatch:retract_top_n_emit",
                             "dispatch:retract_top_n_rank"]
+    # the store is kept in rank order: an interval sorts the rows its
+    # chunks brought, never the capacity (the counter says which form ran)
+    ph = [p for p in tr.phases.values() if "topn_sorted_rows" in p]
+    assert len(ph) == 1
+    assert ph[0]["topn_sorted_rows"] == BIDS < ph[0]["topn_capacity"]
+    assert "sorted 4096]" in tr.render()
+    top = _top(s)
+    assert GLOBAL_METRICS.counter(
+        TOP_N_SORTED_ROWS, executor=top.identity).value >= 6 * BIDS
     _assert_is_the_oracles(s, 6 * BIDS, seed=7)
     await s.drop_all()
 

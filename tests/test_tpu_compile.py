@@ -709,10 +709,13 @@ def test_fused_sharded_join_on_the_4_device_mesh(q7_mesh_executors, mesh4,
     join._watchdog_pack_sh._jitted.lower(*acc, n, n).compile()
 
 
-def _top_n_programs(capacity: int, chunk_rows: int, one_chip):
-    """The three programs of an append-only group top-N over bid rows (the
-    benchmark's `q19.sat`: nine lanes a row with the row id, three of them
-    int32 dictionary ids), lowered for the described chip: (name, lowered)."""
+def _top_n_programs(capacity: int, chunk_rows: int, one_chip,
+                    append_only: bool = True):
+    """The programs of a group top-N over bid rows (the benchmark's
+    `q19.sat`: nine lanes a row with the row id, three of them int32
+    dictionary ids), lowered for the described chip: (name, lowered). An
+    append-only input: the merge, the rank and the emit; a retracting one:
+    the merge and the flush."""
     from risingwave_tpu.connectors.nexmark import BID_SCHEMA
     from risingwave_tpu.stream.executor import Executor
     from risingwave_tpu.stream.retract_top_n import RetractableTopNExecutor
@@ -724,21 +727,53 @@ def _top_n_programs(capacity: int, chunk_rows: int, one_chip):
     rows = RowIdGenExecutor(Bids())
     top = RetractableTopNExecutor(
         rows, (0,), order_specs=[(2, True), (7, False)], limit=10,
-        capacity=capacity, pk_indices=(7,), append_only=True,
+        capacity=capacity, pk_indices=(7,), append_only=append_only,
         emit_rank=True)
     store = abstract((top.khash, top.cols, top.valids, top.n), one_chip)
     errs = abstract(top._errs_dev, one_chip)
-    ranked = jax.ShapeDtypeStruct((capacity,), jnp.int32, sharding=one_chip)
     yield "apply", top._apply._jitted.lower(
         *store, errs, abstract_chunk(rows.schema, chunk_rows, one_chip))
-    yield "rank", top._rank._jitted.lower(store[1], store[3], errs)
+    if not append_only:
+        yield "flush", top._flush._jitted.lower(*store, *abstract(
+            (top.top_hash, top.top_cols, top.top_valids, top.top_n),
+            one_chip))
+        return
+    yield "rank", top._rank._jitted.lower(store[0], store[1][-1], store[3],
+                                          errs)
     yield "emit", top._emit._jitted.lower(
-        *store, ranked, ranked, width=chunk_rows,
-        persist_width=chunk_rows, durable=True)
+        *store, width=chunk_rows, persist_width=chunk_rows, durable=True)
+
+
+def _wide_ops(compiled, op: str, width: int) -> list:
+    """The instructions `op` of the compiled program that read or write an
+    array `width` elements long."""
+    return [ln.strip() for ln in compiled.as_text().splitlines()
+            if f" {op}(" in ln and f"[{width}]" in ln]
 
 
 def test_append_only_group_top_n(one_chip, no_persistent_cache):
-    """The merge into the sorted store, the capacity-wide sort and rank, the
-    gather of what changed with the store's compaction."""
-    for _name, lowered in _top_n_programs(1 << 14, CHUNK, one_chip):
-        fits_one_chip(lowered.compile())
+    """The merge into the store kept in RANK order (the chunk's rows are
+    sorted and placed by one search), the rank by run boundaries and the
+    gather of what changed with the store's compaction: no program sorts
+    the capacity, and the rank program gathers nothing at all. A
+    retracting top-N keeps its capacity-wide sorts: the two forms are
+    separate programs."""
+    cap = 1 << 14
+    assert cap != CHUNK
+    compiled = {name: low.compile()
+                for name, low in _top_n_programs(cap, CHUNK, one_chip)}
+    assert list(compiled) == ["apply", "rank", "emit"]
+    for exe in compiled.values():
+        fits_one_chip(exe)
+        assert not _wide_ops(exe, "sort", cap)
+    # the chunk's lexsort is N wide; the rank program holds no sort and no
+    # gather of any width, the emit no sort
+    assert len(_wide_ops(compiled["apply"], "sort", CHUNK)) >= 3
+    rank = compiled["rank"].as_text()
+    assert " sort(" not in rank and " gather(" not in rank
+    assert " sort(" not in compiled["emit"].as_text()
+    flush = dict(_top_n_programs(cap, CHUNK, one_chip,
+                                 append_only=False))["flush"].compile()
+    fits_one_chip(flush)
+    assert len(_wide_ops(flush, "sort", cap)) >= 4
+    assert _wide_ops(flush, "gather", cap)
