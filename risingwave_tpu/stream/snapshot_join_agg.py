@@ -19,8 +19,10 @@ TPU re-design: don't propagate the storm — re-evaluate. All inputs of
 the sub-plan are APPEND-ONLY, so the whole sub-plan is a pure function
 of the accumulated input prefixes. The executor accumulates inputs in
 dense device stores and, at each barrier, ONE jitted program sorts the
-fact rows by key and recomputes per-group aggregates (segment reductions
-over the sorted runs), the threshold predicate, dim-key membership (the
+fact rows by key (the rows ride the sort as its payload) and recomputes
+per-group aggregates (segment reductions over the sorted runs), the
+threshold predicate (a group's values reach its rows along the run: no
+capacity-wide lane is moved by an index vector), dim-key membership (the
 few dim keys searched IN the sorted fact keys, their runs marked in a
 difference array and spread by a prefix sum), and the final global
 aggregates (plain reductions) — then emits the one-row changelog diff
@@ -51,6 +53,7 @@ from ..common.chunk import (
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall, AggKind
 from ..ops.jit_state import jit_state
+from ..ops.monotone_move import _shifted, compact, expand
 from ..utils.d2h import (
     _bucket, fetch_flat, fetch_small, off_loop, pack_for_fetch,
     unpack_fetched)
@@ -82,6 +85,46 @@ def _reduce_all(spec, vals, signs) -> jnp.ndarray:
     else:
         raise NotImplementedError(k)
     return st[None]
+
+
+@jax.jit
+def _fill_forward(have: jnp.ndarray, lanes: list) -> list:
+    """Every position takes each lane's value at the nearest position at or
+    before it that `have`s one (position 0 does), by log-step doubling: a
+    stage reads 2^k behind and selects. A position still without a value
+    after the stages below 2^k has no holder within 2^k - 1 behind it, so
+    the holder that feeds the position 2^k behind is its nearest too."""
+    C = have.shape[0]
+    steps = tuple(1 << b for b in range((C - 1).bit_length()))
+    ks = jnp.asarray(steps, jnp.int32)
+
+    def stage(i, carry):
+        have, lanes = carry
+        behind = lambda x: _shifted(x, ks[i], +1, jnp.zeros((), x.dtype))
+        take = ~have & behind(have)
+        return have | take, [jnp.where(take, behind(x), x) for x in lanes]
+
+    if steps:
+        _, lanes = jax.lax.fori_loop(0, len(steps), stage, (have, lanes))
+    return lanes
+
+
+def _spread_over_runs(newrun: jnp.ndarray, lanes: list) -> list:
+    """Lanes in GROUP space (slot g = the g-th run of the sorted rows) ->
+    row space (every row of run g reads slot g): `lane[cumsum(newrun) - 1]`
+    with no index vector. The rows are sorted, so a group is a run: one
+    monotone move puts slot g on its run's first row (run g starts at or
+    after g, and no nearer to it than run g - 1 to g - 1), a forward fill
+    copies it along the run."""
+    C = newrun.shape[0]
+    if not lanes:
+        return []
+    pos = jnp.arange(C, dtype=jnp.int32)
+    start, = compact(newrun, [pos], [0])
+    n_groups = jnp.sum(newrun, dtype=jnp.int32)
+    heads, _ = expand(pos < n_groups, start - pos, C - 1, lanes,
+                      [0] * len(lanes))
+    return _fill_forward(newrun, heads)
 
 
 class SnapshotJoinAggExecutor(Executor):
@@ -257,11 +300,31 @@ class SnapshotJoinAggExecutor(Executor):
         # (SQL equi semantics): push those rows into the sentinel region
         # with the dead lanes so they join nothing and pollute no group
         skey = jnp.where(live & fvalids[self.fact_key], fk, _I64_MAX)
-        order = jnp.argsort(skey)
-        live_s = live[order]
-        sfk = skey[order]
-        cols_s = tuple(c[order] for c in fcols)
-        valids_s = tuple(v[order] for v in fvalids)
+        # the rows ride the sort: ONE stable sort on the key whose payload is
+        # the data lanes the program reads below and one word a row holding
+        # `live` (bit 0) and every column's validity (bit k + 1). An index
+        # gather of a capacity-wide lane costs a v5e 9-20 ns an element
+        # whatever the indices are; a sort moves its payload at memory speed
+        # (PERF.md section 6, PR 47). Stable, so the permutation (and with it the
+        # order a FLOAT64 sum adds a run's rows in) is `argsort(skey)`'s.
+        word = jnp.uint32 if len(fvalids) < 32 else jnp.uint64
+        bits = live.astype(word)
+        for k, v in enumerate(fvalids):
+            bits |= v.astype(word) << (k + 1)
+        # every column rides (the planner hands this executor only the
+        # columns its plan reads) but an integer key: `sfk` is that column
+        # wherever its row is live and its validity bit is set
+        rides = [k for k, c in enumerate(fcols) if k != self.fact_key
+                 or not jnp.issubdtype(c.dtype, jnp.integer)]
+        sfk, bits_s, *rode = jax.lax.sort(
+            (skey, bits, *(fcols[k] for k in rides)), num_keys=1,
+            is_stable=True)
+        live_s = (bits_s & 1) != 0
+        valids_s = tuple((bits_s >> (k + 1)) & 1 != 0
+                         for k in range(len(fvalids)))
+        cols_s = [sfk.astype(fcols[self.fact_key].dtype)] * len(fcols)
+        for k, lane in zip(rides, rode):
+            cols_s[k] = lane
         newrun = jnp.concatenate(
             [jnp.ones(1, dtype=bool), sfk[1:] != sfk[:-1]])
         gid = (jnp.cumsum(newrun) - 1).astype(jnp.int32)
@@ -286,15 +349,21 @@ class SnapshotJoinAggExecutor(Executor):
             out_valid = (cnt > 0) if call.kind is not AggKind.COUNT \
                 else jnp.ones(C, dtype=bool)
             sub_outs.append(Column(spec.emit(st), out_valid))
-        # per-group item exprs, gathered back to the row level by gid
+        # per-group item exprs, spread over their runs to the row level
         # (each row's lookup key IS the group key — the planner enforces
         # that the A-side equi column equals the GROUP BY column)
-        row_sub = []
-        for e in self.sub_items:
-            c = e.eval(sub_outs)
-            row_sub.append(Column(
-                c.data[gid],
-                None if c.valid is None else c.valid[gid]))
+        gexists = None
+        if self.sub_filter is not None:
+            # a group whose rows ALL fail the subquery WHERE produces no
+            # A row, so the inner join drops its fact rows (residue
+            # validity covers sum/min/max/avg outputs, but count() is 0
+            # and valid — existence must be checked explicitly)
+            gexists = jax.ops.segment_sum(
+                (sub_sign != 0).astype(jnp.int32), gid, C) > 0
+        per_group, tree = jax.tree_util.tree_flatten(
+            ([e.eval(sub_outs) for e in self.sub_items], gexists))
+        row_sub, gexists = tree.unflatten(
+            _spread_over_runs(newrun, per_group))
 
         if self.residue is not None:
             pred = self.residue.eval(env_fact + row_sub)
@@ -317,14 +386,8 @@ class SnapshotJoinAggExecutor(Executor):
             jnp.concatenate([lo, hi])].add(
                 jnp.concatenate([w, -w]), mode="drop")
         member = (jnp.cumsum(marks) > 0) & (sfk != _I64_MAX)
-        if self.sub_filter is not None:
-            # a group whose rows ALL fail the subquery WHERE produces no
-            # A row, so the inner join drops its fact rows (residue
-            # validity covers sum/min/max/avg outputs, but count() is 0
-            # and valid — existence must be checked explicitly)
-            gexists = jax.ops.segment_sum(
-                (sub_sign != 0).astype(jnp.int32), gid, C) > 0
-            member &= gexists[gid]
+        if gexists is not None:
+            member &= gexists
 
         msign = (live_s & keep & member).astype(jnp.int32)
         fin_outs = []

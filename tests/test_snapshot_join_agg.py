@@ -247,19 +247,22 @@ class _Input:
 
 
 def _flush_executor(agg=AggKind.SUM, x_type=DataType.INT64, capacity=64,
-                    dim_capacity=8):
+                    dim_capacity=8, square=False):
     """SELECT <agg>(x) FROM L JOIN P ON P.pk = L.k
        JOIN (SELECT k, sum(q) AS s FROM L GROUP BY k) A
-         ON A.k = L.k AND L.q < A.s"""
+         ON A.k = L.k AND L.q < A.s
+    (`square`: the subquery selects sum(q) * sum(q) AS s, a threshold a
+    one-row group can pass)"""
     final = AggCall(agg, 2, DataType.INT64 if agg is AggKind.COUNT
                     else x_type, True)
+    s = col(0, DataType.INT64)
     return SnapshotJoinAggExecutor(
         _Input(schema(("k", DataType.INT64), ("q", DataType.INT64),
                       ("x", x_type))),
         _Input(schema(("pk", DataType.INT64))),
         fact_key=0, dim_key=0,
         sub_agg_calls=[AggCall(AggKind.SUM, 1, DataType.INT64, True)],
-        sub_items=[col(0, DataType.INT64)],
+        sub_items=[call("multiply", s, s) if square else s],
         residue=call("less_than", col(1, DataType.INT64),
                      col(3, DataType.INT64)),
         final_agg_calls=[final], final_items=[col(0, final.ret_type)],
@@ -267,27 +270,60 @@ def _flush_executor(agg=AggKind.SUM, x_type=DataType.INT64, capacity=64,
         capacity=capacity, dim_capacity=dim_capacity)
 
 
-def _numpy_statement(facts, parts, agg):
-    """The query over every row so far: facts as (k | None, q, x) triples,
-    parts as keys. None where no row is aggregated (SQL NULL; 0 for a
-    count)."""
-    keyed = [r for r in facts if r[0] is not None]
-    k = np.asarray([r[0] for r in keyed], dtype=np.int64)
-    q = np.asarray([r[1] for r in keyed], dtype=np.int64)
-    x = np.asarray([r[2] for r in keyed])
-    s = np.asarray([q[k == key].sum() for key in k], dtype=np.int64)
-    sel = x[np.isin(k, np.asarray(parts, dtype=np.int64)) & (q < s)]
+def _aggregated(facts, parts, square=False):
+    """Per fact, in arrival order: does the query aggregate it. Facts are
+    (k, q, x) triples, any of the three None for SQL NULL; parts are keys.
+    A NULL key joins nothing, a NULL quantity is in no sum and under no
+    threshold, a group whose quantities are all NULL has no threshold."""
+    sums = {}
+    for k, q, _ in facts:
+        if k is not None and q is not None:
+            sums[k] = sums.get(k, 0) + q
+    thr = {k: s * s if square else s for k, s in sums.items()}
+    return np.asarray([k in thr and k in parts and q is not None
+                       and q < thr[k] for k, q, _ in facts], dtype=bool)
+
+
+def _numpy_statement(facts, parts, agg, square=False):
+    """The query over every row so far. None where no row is aggregated
+    (SQL NULL; 0 for a count); a NULL x is aggregated into nothing."""
+    sel = [x for (_, _, x), s in zip(facts, _aggregated(facts, parts, square))
+           if s and x is not None]
     if agg is AggKind.COUNT:
         return len(sel)
-    if not len(sel):
+    if not sel:
         return None
     return {AggKind.SUM: np.sum, AggKind.MIN: np.min,
-            AggKind.MAX: np.max}[agg](sel).item()
+            AggKind.MAX: np.max}[agg](np.asarray(sel)).item()
+
+
+def _sum_in_key_arrival_order(facts, parts, capacity, within_key=1):
+    """A FLOAT64 SUM as the barrier program takes it: one reduction over the
+    `capacity` lanes laid out by (key, arrival) -- NULL keys and dead lanes
+    behind every key, in their own arrival order -- holding x where the row
+    is aggregated and 0 elsewhere. `within_key=-1` lays a key's rows out
+    last arrival first: what an unstable sort might do."""
+    import jax.numpy as jnp
+    last = np.iinfo(np.int64).max
+    key = np.full(capacity, last, dtype=np.int64)
+    key[:len(facts)] = [last if k is None else k for k, _, _ in facts]
+    lane = np.zeros(capacity, dtype=np.float64)
+    lane[:len(facts)] = [x if s and x is not None else 0.0 for (_, _, x), s
+                         in zip(facts, _aggregated(facts, parts))]
+    order = np.lexsort((within_key * np.arange(capacity), key))
+    return float(jnp.sum(jnp.asarray(lane[order])))
 
 
 # part 5: the quantities sum to 4, so its second line does not pass `<`
 _LINES = [(5, 0, 10), (5, 4, 20), (7, 2, 40), (7, 3, 80), (7, 9, 160),
           (8, 1, 320), (8, 1, 640)]
+# run g (key 100 + g) holds g + 1 rows, arriving round robin: 91 rows; a
+# row's quantity is its run's number, so a run's threshold is its own
+_STAIRS = [(100 + g, g, 1 << (g + r)) for r in range(13)
+           for g in range(r, 13)]
+# sums whose low bits depend on the order of the addends
+_ABSORBING = [0.1, 1e15 + 0.3, 7.7e-3, 2e15, 1e-9, 2.5, 1e15, 0.7, 3e15,
+              3e-7, 1.1, 9e14, 5e14, 0.3]
 # case -> (executor options, [(lineitems, part keys) of each barrier])
 FLUSH_CASES = {
     # a part no lineitem names, a lineitem no part names
@@ -315,6 +351,55 @@ FLUSH_CASES = {
     "float64_sum": (dict(x_type=DataType.FLOAT64),
                     [([(k, q, x * 0.1 + 1e-9) for k, q, x in _LINES],
                       [5, 7]), ([], [8])]),
+    # the validity bits ride the sort in one word. A NULL quantity inside a
+    # run (its data lane reads 1, which would pass) is in no sum and passes
+    # nothing: part 7's threshold is 5 without it, 6 with it
+    "null_quantity_in_a_run": (
+        {}, [(_LINES[:3] + [(7, None, 1280)] + _LINES[3:]
+              + [(7, None, 2560), (7, 5, 5120)], [5, 7, 8])]),
+    # a NULL x passes but adds nothing, and count(x) does not count it
+    "null_x_in_a_run": (
+        {}, [(_LINES[:3] + [(7, 1, None)] + _LINES[3:] + [(8, 0, None)],
+              [7, 8])]),
+    "null_x_not_counted": (
+        dict(agg=AggKind.COUNT),
+        [(_LINES[:3] + [(7, 1, None)] + _LINES[3:] + [(8, 0, None)],
+          [7, 8])]),
+    # every quantity of part 6 is NULL: its sum is NULL, it has no
+    # threshold, its rows join nothing -- until one quantity is there
+    "group_of_null_quantities": (
+        {}, [(_LINES + [(6, None, 1280), (6, None, 2560)], [6, 7]),
+             ([(6, 3, 5120), (6, 2, 10240)], [])]),
+    # ONE run as long as the store (48 rows: the fill crosses 2^k edges
+    # that are no run's edge), the store exactly full
+    "one_run_spans_the_store": (
+        dict(capacity=48),
+        [([(7, q % 5, 1 << (q % 40)) for q in range(30)], [7]),
+         ([(7, 100 + q, 1 << (q % 40)) for q in range(30, 47)]
+          + [(7, -5000, 1)], [])]),
+    # every run one row long, keys arriving out of order, the store exactly
+    # full: group g's threshold q * q reaches row g and no other
+    "all_keys_distinct": (
+        dict(capacity=48, square=True),
+        [([((k * 29) % 48, k % 4 - 1, 1 << (k % 40)) for k in range(48)],
+          [1, 5, 6, 7, 40, 47])]),
+    # distinct keys in front of a sentinel run of NULL keys and dead lanes
+    "distinct_keys_then_dead_lanes": (
+        dict(capacity=80, square=True),
+        [([((k * 7) % 50, k % 5 - 2, 1 << (k % 40)) for k in range(50)]
+          + [(None, 3, 1 << 41), (None, -2, 1 << 42)], [0, 3, 4, 11, 49]),
+         ([], [7, 8])]),
+    # runs of 1, 2, .. 13 rows, each with its own threshold
+    "runs_of_every_length": (
+        dict(capacity=96),
+        [(_STAIRS[:40], [100, 101, 105, 108, 112]),
+         (_STAIRS[40:], [103, 111])]),
+    # one key's rows arrive between other keys' over two barriers: the sum
+    # adds them in arrival order
+    "float64_sum_order": (
+        dict(x_type=DataType.FLOAT64),
+        [([(k, 1, x) for x in _ABSORBING[:7] for k in (9, 7, 5)], [7]),
+         ([(k, 1, x) for x in _ABSORBING[7:] for k in (7, 9)], [9])]),
 }
 
 
@@ -323,17 +408,21 @@ def test_flush_against_a_numpy_statement(case):
     opts, barriers = FLUSH_CASES[case]
     ex = _flush_executor(**opts)
     agg = ex.final_agg_calls[0].kind
+    square = opts.get("square", False)
     fsch, dsch = (i.schema for i in ex.inputs)
     facts, parts, shown = [], [], "nothing yet"
     for new_facts, new_parts in barriers:
         if new_facts:
-            key_there = np.asarray([r[0] is not None for r in new_facts])
-            cols = [[5 if r[0] is None else r[0] for r in new_facts],
-                    [r[1] for r in new_facts], [r[2] for r in new_facts]]
+            # a NULL cell's data lane reads what would join and pass
+            there = [np.asarray([r[c] is not None for r in new_facts])
+                     for c in range(3)]
+            cols = [[blank if r[c] is None else r[c] for r in new_facts]
+                    for c, blank in enumerate((5, 1, 999))]
             (ex._fcols, ex._fvalids, ex._fn, ex._errs) = ex._append_fact(
                 ex._fcols, ex._fvalids, ex._fn, ex._errs,
-                StreamChunk.from_numpy(fsch, cols, capacity=16,
-                                       valids=[key_there, None, None]))
+                StreamChunk.from_numpy(
+                    fsch, cols, capacity=max(16, len(new_facts)),
+                    valids=there))
         if new_parts:
             ex._dkeys, ex._dn, ex._errs = ex._append_dim(
                 ex._dkeys, ex._dn, ex._errs,
@@ -345,7 +434,7 @@ def test_flush_against_a_numpy_statement(case):
             ex._prev, ex._prev_valid, ex._emitted)
         assert not np.asarray(ex._errs).any()
         assert (int(ex._fn), int(ex._dn)) == (len(facts), len(parts))
-        want = _numpy_statement(facts, parts, agg)
+        want = _numpy_statement(facts, parts, agg, square)
         if shown == "nothing yet":
             expect = [(OP_INSERT, want)]
         elif shown == want:
@@ -357,59 +446,117 @@ def test_flush_against_a_numpy_statement(case):
             assert [op for op, _ in got] == [op for op, _ in expect]
             np.testing.assert_allclose([v for _, v in got],
                                        [v for _, v in expect], rtol=1e-12)
+            # to the bit: the rows of a run are added in arrival order
+            exact = _sum_in_key_arrival_order(facts, parts, ex.capacity)
+            assert got[-1][1].hex() == exact.hex()
         else:
             assert got == expect, (len(facts), len(parts))
         shown = want
+    if case == "float64_sum_order":
+        # the case can tell: another order within a key, another sum
+        assert exact != _sum_in_key_arrival_order(
+            facts, parts, ex.capacity, within_key=-1)
     if case == "part_store_full":
         assert len(parts) == ex.dim_capacity
-    if case == "fact_store_full":
+    if case in ("fact_store_full", "one_run_spans_the_store",
+                "all_keys_distinct"):
         assert len(facts) == ex.capacity
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, inside=()):
     """Every equation of a jaxpr, those of its sub-jaxprs (loop bodies,
-    pjit, cond branches) included."""
+    jits, cond branches) included, each with the names of the jits that
+    enclose it, outermost first."""
     for e in jaxpr.eqns:
-        yield e
+        yield e, inside
+        within = inside + ((e.params["name"],) if e.primitive.name in (
+            "jit", "pjit") else ())
         for p in e.params.values():
             for sub in (p if isinstance(p, (list, tuple)) else (p,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _eqns(sub, within)
+
+
+def _flush_jaxpr(C, Cd):
+    import jax
+    ex = _flush_executor(capacity=C, dim_capacity=Cd)
+    return jax.make_jaxpr(ex._flush_impl)(
+        ex._fcols, ex._fvalids, ex._fn, ex._dkeys, ex._dn, ex._prev,
+        ex._prev_valid, ex._emitted).jaxpr
+
+
+def _carried(e):
+    """What a loop equation carries from step to step (nothing for any
+    other equation)."""
+    if e.primitive.name == "scan":
+        n = e.params["num_consts"]
+        return e.invars[n:n + e.params["num_carry"]]
+    if e.primitive.name == "while":
+        return e.invars[e.params["cond_nconsts"]
+                        + e.params["body_nconsts"]:]
+    return ()
+
+
+_rows = lambda v: v.aval.shape[:1]  # noqa: E731
+# the loops that CARRY capacity-wide lanes by design: a stage shifts every
+# lane by a power of two and selects (no index per row)
+STAGE_LOOPS = {"_move", "_fill_forward"}
 
 
 def test_no_capacity_wide_pass_asks_a_tiny_target():
     """Three ops the barrier program must not grow back (2.63 + 0.86 s of a
     5.49 s checkpoint at 2^23, PERF.md PR 39): a search loop that carries
     one query per fact row, a gather of as many indices out of the dim
-    store, a scatter-add of every row onto one address."""
-    import jax
+    store, a scatter-add of every row onto one address. A loop that carries
+    `C` rows is a stage loop of a log-step move or fill, told apart by the
+    jit it sits in; the searches carry `Cd` queries."""
     C, Cd = 4096, 16
-    ex = _flush_executor(capacity=C, dim_capacity=Cd)
-    jaxpr = jax.make_jaxpr(ex._flush_impl)(
-        ex._fcols, ex._fvalids, ex._fn, ex._dkeys, ex._dn, ex._prev,
-        ex._prev_valid, ex._emitted).jaxpr
-    rows = lambda v: v.aval.shape[:1]  # noqa: E731
-    loops = 0
-    for e in _eqns(jaxpr):
+    searches = 0
+    for e, inside in _eqns(_flush_jaxpr(C, Cd)):
         name = e.primitive.name
-        if name == "scan":
-            n = e.params["num_consts"]
-            carried = e.invars[n:n + e.params["num_carry"]]
-        elif name == "while":
-            carried = e.invars[e.params["cond_nconsts"]
-                               + e.params["body_nconsts"]:]
-        else:
-            carried = ()
-        loops += bool(carried)
-        assert all(rows(v) != (C,) for v in carried), \
-            f"a loop carries {C} queries: {e}"
+        carried = _carried(e)
+        if any(_rows(v) == (C,) for v in carried):
+            assert inside and inside[-1] in STAGE_LOOPS, \
+                f"a loop carries {C} queries: {inside} {e}"
+        elif carried:
+            assert inside[-1] == "searchsorted" and all(
+                _rows(v) in ((), (Cd,)) for v in carried), (inside, e)
+            searches += 1
         if name == "gather":
             operand, indices = e.invars[:2]
-            assert not (rows(operand) == (Cd,) and rows(indices) == (C,)), \
+            assert not (_rows(operand) == (Cd,) and _rows(indices) == (C,)), \
                 f"{C} indices gathered from the {Cd}-key dim store: {e}"
         if name.startswith("scatter"):
             assert e.invars[0].aval.size > 1, \
                 f"a scatter onto one address: {e}"
     # the two searches of the dim keys in the sorted fact keys are there
-    assert loops == 2
+    assert searches == 2
+
+
+def test_no_capacity_wide_lane_moves_by_an_index_vector():
+    """The barrier program gathers no `C`-row lane by `C` indices (thirteen
+    such passes, `[order]` of the columns and `[gid]` of a group's values,
+    were 1.15 s of a 3.0 s checkpoint at 2^23: PERF.md PR 47): the rows ride
+    ONE sort as payload, a group's values reach its rows by a move and a
+    fill whose stages read at an offset. Inside a stage loop there is no
+    gather of more than the one step it reads from its table."""
+    C, Cd = 4096, 16
+    sorts, stage_loops = [], 0
+    for e, inside in _eqns(_flush_jaxpr(C, Cd)):
+        name = e.primitive.name
+        if name == "gather":
+            operand, indices = e.invars[:2]
+            assert not (_rows(operand) == (C,) and _rows(indices) == (C,)), \
+                f"{C} rows gathered by {C} indices: {inside} {e}"
+            assert not set(inside) & STAGE_LOOPS or indices.aval.size == 1, \
+                f"a stage gathers by index: {inside} {e}"
+        if name == "sort" and _rows(e.invars[0]) == (C,):
+            sorts.append(e)
+        stage_loops += any(_rows(v) == (C,) for v in _carried(e))
+    # one stable sort, one key, the word and the two other columns behind it
+    (srt,) = sorts
+    assert srt.params["num_keys"] == 1 and srt.params["is_stable"]
+    assert len(srt.invars) == 4
+    # run starts to group space, group values to the run starts, the fill
+    assert stage_loops == 3

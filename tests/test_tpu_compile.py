@@ -502,7 +502,13 @@ def test_q17_snapshot_join_agg_programs(q17_snapshot, one_chip,
     division of int64 on the chip), the persist pack's dynamic-offset window,
     and the spec-following generator with its seed a dynamic argument. At the
     cell's 2^23 rows the flush compiled in 158 s by hand (the parent's beside
-    it in 146 s; PERF.md, PR 39)."""
+    it in 146 s; PERF.md, PR 39). Since PR 47 the rows ride the flush's sort
+    as payload (eight 32-bit operands where the argsort had three) and a sort
+    compiles by its operands: on the chip's host the 2^23 program compiled
+    in ~126 s (a cold warm-up of 130.9 s against 4.8 s warm; the parent's
+    ~99 s in the same call: 106.0 against 6.8), for a described v5e on this
+    sandbox's CPU in 258 s (the parent's beside it in 137 s; PERF.md,
+    PR 47)."""
     from risingwave_tpu.connectors import tpch
     snap = q17_snapshot
     assert snap.capacity == JOIN_CAP and len(snap._fcols) == 3
